@@ -7,8 +7,7 @@ the ring IN-PROGRAM (dynamic-update-slice at a traced offset), every
 complete 16000-sample window slides out as a device array straight into
 the speech filter, and the 75%-overlap advance is a static roll in the
 same program.  Zero host round-trips between windows — the host path pays
-a full D2H + concatenate + H2D per window, which is most of why the
-BENCH_ALL_r5 speech_commands row idled at 0.0026 MFU.
+a full D2H + concatenate + H2D per window.
 
 Exactly 3 programs compile for the aggregator's lifetime (ring init,
 append, window+advance; the continuous-serving 3-program discipline), and

@@ -2,9 +2,8 @@
 
 The overlay decode pins full output geometry (RGBA media), so the
 HBM-residency planner cannot select deeplab's native-stride reduced output
-— every frame ships its full-resolution class map over the D2H link
-(BENCH_ALL_r5 measured this exact shape at 458.9 fps vs 15710 for the
-native-stride classmap row: 34x from fetching less).  ``nns-lint --deep``
+— every frame ships its full-resolution class map over the D2H link,
+256x the bytes of the native-stride classmap.  ``nns-lint --deep``
 flags it statically when a calibrated link is configured::
 
     NNS_TPU_LINK_D2H_MBPS=38.2 NNS_TPU_LINK_RTT_MS=88 \

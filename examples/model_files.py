@@ -18,13 +18,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# Script entry point: re-assert JAX_PLATFORMS through the live config in
-# case a site hook pre-imported jax (which makes the env var arrive too
-# late) — same pattern as bench.py / tools/smoke_tpu.py.
-from nnstreamer_tpu.core.platform import honor_jax_platforms  # noqa: E402
-
-honor_jax_platforms()
-
 import nnstreamer_tpu as nt  # noqa: E402
 from nnstreamer_tpu.models import gguf, llama, tflite_build  # noqa: E402
 

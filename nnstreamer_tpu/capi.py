@@ -25,17 +25,6 @@ _next_id = [1]
 _lock = threading.Lock()
 
 
-def _on_fresh_embed() -> None:
-    """Called by the C library ONLY when it created the interpreter: the
-    process env is the sole configuration channel there, so JAX_PLATFORMS
-    is honored.  When loaded into an existing Python process this never
-    runs — a host app's programmatic jax.config pin wins (the library
-    invariant from core/platform.py)."""
-    from .core.platform import honor_jax_platforms
-
-    honor_jax_platforms()
-
-
 def _spec_str(spec: TensorsSpec) -> str:
     """``dims,dtype`` per tensor, ';'-joined: "3:8:8:1,float32;..." """
     if spec is None:

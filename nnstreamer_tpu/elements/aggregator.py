@@ -20,8 +20,7 @@ TPU-first extension — **device mode** (``device=true``, docs/ARCHITECTURE.md
 between dispatches instead of a host ``np.concatenate``.  The host path
 fetches every incoming buffer to host, concatenates, slices, and re-uploads
 downstream — for a windowed audio pipeline that is one full D2H+H2D round
-trip per window, and BENCH_ALL_r5's speech_commands row idles at 0.0026 MFU
-largely on it.  In device mode the ring update runs IN-PROGRAM:
+trip per window, during which the device idles.  In device mode the ring update runs IN-PROGRAM:
 
 * the carry is a fixed-shape jax Array of ``need + step`` samples along the
   frames axis (``need`` = window, ``step`` = samples per incoming buffer);

@@ -62,6 +62,11 @@ class _InflightCredit:
 class AppSrc(SourceElement):
     """Application-driven source: ``pipeline.push(name, array)`` feeds it.
 
+    A pushed array is taken by reference and belongs to the pipeline from
+    then on: a stage thread reads it later, and the device transfer that
+    reads it is asynchronous (the CPU client aliases aligned numpy memory
+    outright).  An app that reuses its frame buffer pushes a copy.
+
     Props: ``caps`` (caps string describing what the app will push),
     ``max-buffers`` (feed queue bound), ``block`` (push blocks when full),
     ``max-inflight`` (END-TO-END admission bound: at most N pushed buffers

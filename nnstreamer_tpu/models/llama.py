@@ -624,8 +624,7 @@ def quantize_int8(params: Dict) -> Dict:
     mats + lm_head as int8 halves bytes/token vs bf16.  Consumption is
     scale-AFTER-dot (see :func:`_mm`): the int8->bf16 convert fuses into
     the dot's operand read so dequant costs no extra HBM traffic, which
-    premultiplying the scale would break (measured 4x/mat on chip —
-    PROFILE_LLM_r5.json).  Norms and the embedding table (gather — tiny
+    premultiplying the scale would break (tools/probe_int8_dot.py).  Norms and the embedding table (gather — tiny
     per-token traffic) stay full precision.
 
     Quantization runs ON DEVICE via jit: 7B params are materialized in
@@ -1204,7 +1203,6 @@ def forward_seq_parallel(mesh, params, tokens, cfg: LlamaConfig,
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import shard_map
     from ..parallel.ring import ring_attention_local
 
     n_seq = int(mesh.shape.get("seq", 1))
@@ -1230,7 +1228,7 @@ def forward_seq_parallel(mesh, params, tokens, cfg: LlamaConfig,
         x = _rmsnorm(x, params["ln_out"], cfg.norm_eps)
         return _lm_head(params, x, dt)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fwd, mesh=mesh,
         in_specs=(P(), P(None, "seq")),
         out_specs=P(None, "seq", None),

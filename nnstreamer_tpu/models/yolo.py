@@ -122,8 +122,7 @@ def _poly_coeffs(g: int, n_out: int, n_anchor: int, box_a):
     head, out = A*sigmoid(raw)^2 + B*sigmoid(raw) + C over the flattened
     [N_s, n_out] scale block — the whole box decode as ONE lane-friendly
     pass (the textbook slice/meshgrid/stack form builds minor-dim-3/4
-    tensors that TPU pads to 128 lanes; measured 16 of 26 ms of the v5s
-    step, PROFILE_YOLO_r5.json).  ``box_a``: [n_anchor, 2] quadratic
+    tensors that TPU pads to 128 lanes; ROADMAP S5).  ``box_a``: [n_anchor, 2] quadratic
     coefficients for the w/h channels (4*anchor, already in the head's
     output units).  Channels: 0/1 affine cell-centers, 2/3 quadratic
     w/h, the rest identity (scores)."""
@@ -416,8 +415,8 @@ def apply_v5s(params, x, *, classes: int, size: int,
 
     B = x.shape[0]
     # Detect head as the fused polynomial decode (see _poly_coeffs —
-    # the textbook slice/meshgrid/stack form measured 16 of the 26 ms
-    # batch-32 step, PROFILE_YOLO_r5.json).  Anchors are pixels of the
+    # the textbook slice/meshgrid/stack form pads minor-dim-3/4 tensors
+    # to 128 lanes).  Anchors are pixels of the
     # NETWORK INPUT (ultralytics convention): normalize by the actual
     # input size.
     raws, abc = [], []

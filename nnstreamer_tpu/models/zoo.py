@@ -81,7 +81,6 @@ def _ensure_builtin():
     global _builtin_loaded
     if _builtin_loaded:
         return
-    _builtin_loaded = True
     for mod in (
         "nnstreamer_tpu.models.testmodels",
         "nnstreamer_tpu.models.mobilenet",
@@ -92,10 +91,8 @@ def _ensure_builtin():
         "nnstreamer_tpu.models.audio",
         "nnstreamer_tpu.models.llama",
     ):
-        try:
-            importlib.import_module(mod)
-        except ImportError:
-            pass
+        importlib.import_module(mod)
+    _builtin_loaded = True
 
 
 def build(name: str, opts: Optional[Dict[str, str]] = None) -> ModelBundle:

@@ -136,14 +136,7 @@ extern "C" int nnstpu_init(void) {
         PyObject *mod = PyImport_ImportModule("nnstreamer_tpu.capi");
         if (mod) {
             /* Fresh embed: the process env (JAX_PLATFORMS etc.) is the
-             * only configuration channel, so honor it now.  When loaded
-             * into an existing interpreter (branch below) this is NOT
-             * done — a host app's programmatic jax.config pin wins. */
-            PyObject *r = PyObject_CallMethod(mod, "_on_fresh_embed", NULL);
-            if (!r) {
-                PyErr_Clear();
-            }
-            Py_XDECREF(r);
+             * only configuration channel; the fresh jax import reads it. */
             g_mod.store(mod, std::memory_order_release);
             g_inited.store(1, std::memory_order_release);
         } else {

@@ -35,13 +35,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:  # pragma: no cover - environment probe
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 # pallas_call has no GSPMD partitioning rule, so a paged-attention
@@ -245,8 +240,7 @@ def flash_attention(
             # the compiled XLA path.
             return attention_reference(q, k, v, causal=causal, scale=scale_v)
     if (
-        not _HAVE_PALLAS
-        or sq % block_q
+        sq % block_q
         or skv % block_k
         or k.shape != v.shape
         or h % hkv
@@ -361,7 +355,17 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
     nb = (L + bs - 1) // bs  # live blocks only — the traffic contract
 
     q = q_ref[:].astype(jnp.float32) * scale  # [H, D]
-    qg = q.reshape(1, hkv, G, D)
+
+    def heads(blk):
+        # [bs, hkv, D] -> [bs, H, D]: query head h reads kv head h // G.
+        # Flat heads keep D in the lane dim and H in the sublane dim for
+        # every value below — the grouped [bs, hkv, G] statistics put G in
+        # the lane position, which Mosaic cannot reduce over axis 0.
+        blk = blk.astype(jnp.float32)
+        if G == 1:
+            return blk
+        return jnp.broadcast_to(
+            blk[:, :, None, :], (bs, hkv, G, D)).reshape(bs, H, D)
 
     def scoped(kbuf, vbuf, ksem, vsem):
         def kdma(slot, i):
@@ -389,33 +393,32 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
 
             kdma(slot, i).wait()
             vdma(slot, i).wait()
-            kblk = kbuf[slot].astype(jnp.float32)  # [bs, hkv, D]
-            vblk = vbuf[slot].astype(jnp.float32)
+            kblk = heads(kbuf[slot])  # [bs, H, D]
+            vblk = heads(vbuf[slot])
             # decode GEMV: VPU mul-reduce (no transposes — Mosaic keeps
-            # the 128-lane minor dim intact); scores [bs, hkv, G]
-            s = jnp.sum(qg * kblk[:, :, None, :], axis=-1)
+            # the 128-lane minor dim intact); scores [bs, H]
+            s = jnp.sum(q[None] * kblk, axis=-1)
             # the final block is partially valid: the single query sits
             # at position L-1 and attends positions < L
-            pos = i * bs + jax.lax.broadcasted_iota(
-                jnp.int32, (bs, hkv, G), 0)
+            pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, H), 0)
             s = jnp.where(pos < L, s, -jnp.inf)
-            m_new = jnp.maximum(m, jnp.max(s, axis=0))
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
             shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            p = jnp.exp(s - shift[None])
+            p = jnp.exp(s - shift)
             alpha = jnp.exp(jnp.where(jnp.isfinite(m), m, shift) - shift)
-            l_new = l * alpha + jnp.sum(p, axis=0)
-            acc_new = acc * alpha[:, :, None] + jnp.sum(
-                p[:, :, :, None] * vblk[:, :, None, :], axis=0)
+            l_new = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+            acc_new = acc * alpha.reshape(H, 1) + jnp.sum(
+                p[:, :, None] * vblk, axis=0)
             return m_new, l_new, acc_new
 
-        m0 = jnp.full((hkv, G), -jnp.inf, jnp.float32)
-        l0 = jnp.zeros((hkv, G), jnp.float32)
-        acc0 = jnp.zeros((hkv, G, D), jnp.float32)
+        m0 = jnp.full((1, H), -jnp.inf, jnp.float32)
+        l0 = jnp.zeros((1, H), jnp.float32)
+        acc0 = jnp.zeros((H, D), jnp.float32)
         m, l, acc = jax.lax.fori_loop(0, nb, body, (m0, l0, acc0))
         # L == 0 (idle slot): l stays 0 and the row emits zeros — finite
         # garbage the serve loop never reads
-        o_ref[:] = (acc / jnp.maximum(l[:, :, None], 1e-30)).reshape(
-            H, D).astype(o_ref.dtype)
+        o_ref[:] = (acc / jnp.maximum(l.reshape(H, 1), 1e-30)).astype(
+            o_ref.dtype)
 
     pl.run_scoped(
         scoped,
@@ -448,8 +451,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
             return paged_attention_reference(
                 q, k_pool, v_pool, block_tables, context_lens, scale=scale_v)
     if (
-        not _HAVE_PALLAS
-        or not paged_kernel_enabled()  # TP traces need the shardable path
+        not paged_kernel_enabled()  # TP traces need the shardable path
         or T != 1
         or H % hkv
         or k_pool.shape != v_pool.shape
@@ -458,8 +460,6 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     ):
         return paged_attention_reference(
             q, k_pool, v_pool, block_tables, context_lens, scale=scale_v)
-
-    import functools as _ft
 
     # sentinel entries must not index past the pool when a DMA is (never)
     # issued for them; clip on host side of the call
@@ -475,7 +475,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
         out_specs=pl.BlockSpec((None, H, D), lambda b, *_: (b, 0, 0)),
     )
     out = pl.pallas_call(
-        _ft.partial(_paged_kernel, scale=scale_v),
+        functools.partial(_paged_kernel, scale=scale_v),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,

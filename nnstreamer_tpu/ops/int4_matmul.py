@@ -1,7 +1,7 @@
 """Weight-only int4 (w4a16) matmul as a Pallas TPU kernel.
 
-Why a kernel: the 7B decode step is HBM-bound at the chip's measured
-~490 GB/s (PROFILE_LLM_r5.json), so bytes/token is the only lever left.
+Why a kernel: the 7B decode step is bound by streaming the weights from
+HBM (ROADMAP S3), so bytes/token is the lever.
 Nibble-packing weights halves bytes, but XLA cannot consume a packed
 buffer in one pass — the natural two-dot formulation fuses each nibble's
 unpack into its own dot and reads every packed byte TWICE (measured
@@ -44,13 +44,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:  # pragma: no cover - environment probe
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: Kernel applies only to decode-shaped activations: at large B*T the
 #: f32 accumulator [B, F] would blow VMEM, and prefill amortizes weight
@@ -212,8 +207,7 @@ def matmul_int4(h, packed, scale, *, block_d2: Optional[int] = None,
         if jax.default_backend() != "tpu":
             return matmul_int4_reference(h, packed, scale, out_dtype=odt)
     bd, fb = _pick_blocks(d2, F, B, block_d2)  # (0, 0) -> fall back
-    if (not _HAVE_PALLAS or not kernel_enabled() or not fb
-            or B > _MAX_KERNEL_ROWS):
+    if not kernel_enabled() or not fb or B > _MAX_KERNEL_ROWS:
         return matmul_int4_reference(h, packed, scale, out_dtype=odt)
 
     hlo, hhi = h[:, :d2], h[:, d2:]

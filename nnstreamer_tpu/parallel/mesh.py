@@ -136,26 +136,3 @@ def local_batch(mesh, global_batch: int) -> int:
     if global_batch % d:
         raise ValueError(f"global batch {global_batch} not divisible by data={d}")
     return global_batch // d
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    ``jax.shard_map`` (with its ``check_vma`` kwarg) only exists in newer
-    releases; older ones ship ``jax.experimental.shard_map.shard_map`` whose
-    equivalent kwarg is ``check_rep``.  Every shard_map call site in the
-    framework goes through here so version skew stays one function wide.
-    """
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )

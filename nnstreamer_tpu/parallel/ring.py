@@ -117,10 +117,8 @@ def ring_attention(mesh, q, k, v, *, causal: bool = True,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from .mesh import shard_map
-
     spec = P(None, "seq", None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention_local, causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),

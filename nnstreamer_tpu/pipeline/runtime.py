@@ -1796,9 +1796,8 @@ class _CapsFilter(Element):
     this, the idiomatic quantized-boundary pin
     (``transform ! other/tensors,types=uint8 ! tensor_filter``) left the
     transform (and any decoder tail behind a post-filter pin) OUTSIDE
-    the fused filter dispatch: three stages, two queue hops, and the
-    quant row ran 0.2217 MFU against 0.247 for the identical fused graph
-    (BENCH_ALL_r5).  The fused identity costs nothing — XLA folds it
+    the fused filter dispatch: three stages, two queue hops.  The fused
+    identity costs nothing — XLA folds it
     away — and bit-identity with the split path is pinned by tests.
     """
 

@@ -77,22 +77,27 @@ HBM_CATEGORIES: Tuple[str, ...] = ("params", "kv_pool", "agg_rings",
 #: byte-level noise on tiny stages is not an estimate failure
 HBM_WARN_FLOOR = 1 << 20
 
-#: peak dense-matmul TFLOPs per chip by device_kind substring (bf16
-#: where the MXU has one).  ``Config.peak_tflops`` overrides; the CPU
-#: fallback makes MFU numbers on the host proxy *indicative only* (the
-#: gauge still proves the attribution plumbing end to end).
-_PEAK_TFLOPS_BY_KIND: Tuple[Tuple[str, float], ...] = (
-    ("v5p", 459.0), ("v5e", 197.0), ("v5", 459.0),
-    ("v4", 275.0), ("v3", 123.0), ("v2", 45.0),
-    ("cpu", 0.1),
-)
+#: peak dense bf16 matmul TFLOP/s of one chip, keyed by the lowercased
+#: ``device_kind`` jax reports (Google Cloud TPU documentation, per
+#: generation; a v5e chip reports "TPU v5 lite", a v5p chip "TPU v5").
+#: ``Config.peak_tflops`` overrides.  The CPU row makes MFU numbers on the
+#: host *indicative only* (the gauge still proves the attribution
+#: plumbing end to end); an accelerator missing from the table is an
+#: error, not a default.
+_PEAK_TFLOPS_BY_KIND: Dict[str, float] = {
+    "tpu v2": 45.0, "tpu v3": 123.0, "tpu v4": 275.0,
+    "tpu v5 lite": 197.0, "tpu v5e": 197.0,
+    "tpu v5": 459.0, "tpu v5p": 459.0,
+    "cpu": 0.1,
+}
 
 _peak_cache: Dict[str, float] = {}
 
 
 def peak_flops() -> float:
     """Peak FLOP/s of one local device — ``Config.peak_tflops`` when set
-    (``NNS_TPU_PEAK_TFLOPS``), else the device_kind table above."""
+    (``NNS_TPU_PEAK_TFLOPS``), else the device_kind table above.  Raises
+    ``KeyError`` for a non-CPU device the table does not list."""
     from ..core.config import get_config
 
     cfg = get_config()
@@ -101,19 +106,16 @@ def peak_flops() -> float:
     got = _peak_cache.get("flops")
     if got is not None:
         return got
-    kind = "cpu"
-    try:
-        import jax
+    import jax
 
-        kind = str(jax.devices()[0].device_kind).lower()
-    except Exception:  # noqa: BLE001 - attribution must not crash
-        pass
-    val = 0.1e12
-    for sub, tf in _PEAK_TFLOPS_BY_KIND:
-        if sub in kind:
-            val = tf * 1e12
-            break
-    _peak_cache["flops"] = val
+    dev = jax.devices()[0]
+    kind = "cpu" if dev.platform == "cpu" else str(dev.device_kind).lower()
+    if kind not in _PEAK_TFLOPS_BY_KIND:
+        raise KeyError(
+            f"no peak FLOP/s for device_kind {dev.device_kind!r}: add it to "
+            "utils/xray._PEAK_TFLOPS_BY_KIND with its source, or set "
+            "NNS_TPU_PEAK_TFLOPS")
+    val = _peak_cache["flops"] = _PEAK_TFLOPS_BY_KIND[kind] * 1e12
     return val
 
 

@@ -1,0 +1,57 @@
+"""chip_smoke.py's dry run: the same leg functions the chip runs, at toy
+size on the CPU (kernels interpreted), so a chip call is never spent on a
+fault the sandbox could have shown.  ``python chip_smoke.py`` itself always
+demands the chip — pinned by the exit-code test."""
+
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+
+
+def test_stream_leg_toy():
+    facts = chip_smoke.stream_leg(size=16, batch=4, batches=8)
+    assert facts["device_batches"] == 8 and facts["host_batches"] == 4
+    # donation is compiled out on CPU; the leg checks it is ON elsewhere
+    assert facts["donation_active"] is False
+
+
+def test_sharded_stream_leg_toy():
+    facts = chip_smoke.sharded_stream_leg(size=16, batch=4, n_push=16,
+                                          replicas=4)
+    assert facts["mesh_shape"] == [4, 1]
+    assert len(facts["shard_counters"]) == 4
+
+
+def test_serving_leg_toy():
+    facts = chip_smoke.serving_leg(model="llama_tiny", max_new=6,
+                                   prompt_lens=(5, 40, 3), slots=3,
+                                   first_token_timeout=300.0)
+    assert facts["programs"] == 3 and facts["replay_identical"]
+    assert facts["full_depth"] and facts["tokens"] == 4 * 6
+
+
+def test_serving_leg_cuts_only_depth():
+    facts = chip_smoke.serving_leg(model="llama_tiny", n_layers=1,
+                                   max_new=4, prompt_lens=(5, 9, 3),
+                                   first_token_timeout=300.0)
+    assert facts["n_layers"] == 1 and not facts["full_depth"]
+    assert facts["dim"] == 128
+
+
+def test_kernel_leg_toy_interpreted():
+    facts = chip_smoke.kernel_leg(n_heads=4, n_kv_heads=(4, 2), head_dim=64,
+                                  dim=256, ffn=512, slots=3, block_size=8,
+                                  seq=128, context=30, interpret=True)
+    names = " ".join(facts["rel_err"])
+    for kernel in ("flash_attention", "paged_attention", "matmul_int4"):
+        assert kernel in names
+
+
+def test_main_refuses_cpu(capsys):
+    assert chip_smoke.main() != 0
+    out = capsys.readouterr()
+    assert '"ok"' not in out.out  # no result line without the chip
+    assert "cpu" in out.err

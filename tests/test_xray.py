@@ -106,6 +106,38 @@ def test_signature_drift_diff_names_the_field():
     assert "arity" in explain_signature_drift(base, base + base)
 
 
+# -- peak table --------------------------------------------------------------
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.fixture
+def fresh_peak(monkeypatch):
+    monkeypatch.setattr(xray, "_peak_cache", {})
+
+    def use(platform, kind):
+        import jax
+
+        monkeypatch.setattr(jax, "devices",
+                            lambda: [_FakeDevice(platform, kind)])
+    return use
+
+
+def test_peak_flops_keyed_by_device_kind(fresh_peak):
+    # a v5e chip reports "TPU v5 lite": it matched neither "v5e" nor "v5p"
+    # by substring and got the v5p peak
+    fresh_peak("tpu", "TPU v5 lite")
+    assert xray.peak_flops() == 197e12
+
+
+def test_peak_flops_unknown_accelerator_is_an_error(fresh_peak):
+    fresh_peak("tpu", "TPU v9 imaginary")
+    with pytest.raises(KeyError, match="TPU v9 imaginary"):
+        xray.peak_flops()
+
+
 # -- tracked programs / registry -------------------------------------------
 
 def test_tracked_program_registers_compiles_and_costs():
@@ -157,7 +189,7 @@ def test_budget_overflow_fires_census_drift_with_diff(caplog):
     # at debug only (one ring dump per key — the watchdog discipline)
     with caplog.at_level(logging.WARNING):
         caplog.clear()
-        fn(tok, np.int64(1), np.float32(2.0))  # a 3rd signature
+        fn(tok, np.int64(1), np.int16(2))  # a 3rd signature
     assert reg.drift_count() == 2
     assert metrics.snapshot().get("xray.census_drifts") == 2
     assert not any(r.levelno >= logging.WARNING for r in caplog.records)

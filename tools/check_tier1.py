@@ -208,9 +208,9 @@ PROTO_BASELINE = os.path.join(REPO, "tools", "proto_baseline.txt")
 #: Config.hbm_budget_bytes, not just the report text.
 ASR_GATE_BUDGET = str(1 << 16)
 
-#: calibrated link the fetch gate pins for the deliberately fetch-bound
-#: example (the BENCH_ALL_r5 ``link_calibration`` row: 38.2 MB/s d2h,
-#: 88 ms small-fetch RTT) — the ``fetch-bound`` diagnostic must fire and
+#: a SLOW link the fetch gate pins for the deliberately fetch-bound
+#: example (38.2 MB/s d2h, 88 ms small-fetch RTT — fixed gate inputs, not
+#: a measurement of any current machine) — the ``fetch-bound`` diagnostic must fire and
 #: be baseline-accepted, proving planned fetch bytes are actually priced
 #: against Config.link_d2h_mbps, not just rendered.
 FETCH_GATE_D2H_MBPS = "38.2"
