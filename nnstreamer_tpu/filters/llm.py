@@ -51,11 +51,11 @@ from ..core.registry import register_filter
 from ..core.types import TensorFormat, TensorsSpec
 from ..models import llama
 from ..models.zoo import build as build_model
-from ..utils import elastic
+from ..utils import elastic, tracing
 from ..core.meta_keys import (META_ABORT_REASON, META_QUERY_CONN,
                               META_ENQUEUE_NS, META_STREAM_ABORTED,
                               META_STREAM_ID, META_STREAM_INDEX,
-                              META_STREAM_LAST)
+                              META_STREAM_LAST, META_TRACE_ID)
 from ..core.meta_keys import META_TENANT as _META_TENANT
 from .base import (Framework, FrameworkError, parse_custom_options,
                    place_swapped_params)
@@ -68,6 +68,13 @@ from .base import (Framework, FrameworkError, parse_custom_options,
 #: door re-associates delivery; callers may re-stamp snapshot["meta"]
 #: before adopt_stream).
 _SNAPSHOT_META_DROP = (META_ENQUEUE_NS, META_QUERY_CONN)
+#: the flight-recorder track (and profiler-annotation prefix) of the
+#: continuous loop's spans — docs/OBSERVABILITY.md
+_SERVE_STAGE = "llm.serve"
+#: (trace id, admission ns, first prefilled position) of a request admitted
+#: while tracing was off: its later spans carry no id, and it gets no
+#: serve.prefill span
+_NO_TRACE = (None, None, 0)
 
 log = logger(__name__)
 
@@ -1050,9 +1057,11 @@ class _ContinuousLoop:
                 logits, pool = llama.forward_paged(
                     params, tok[:, None], pool, tables, p, cfg,
                     compute_dtype=fw.dtype)
-                kstep = slot_keys(keys, p + 1, TAG_SAMPLE)
-                nxt = llama.sample_token_per_slot(
-                    logits[:, -1], kstep, temperature, fw.top_k, fw.top_p)
+                with jax.named_scope("sampler"):
+                    kstep = slot_keys(keys, p + 1, TAG_SAMPLE)
+                    nxt = llama.sample_token_per_slot(
+                        logits[:, -1], kstep, temperature, fw.top_k,
+                        fw.top_p)
                 return (nxt, pool, p + 1), nxt
 
             (tok, pool, _), toks = lax.scan(
@@ -1477,11 +1486,6 @@ class _ContinuousLoop:
                     cmd["ev"].set()
                 self._idle.set()
 
-    def _span(self, rec, kind: str, t0_ns: int, **args) -> None:
-        if rec is not None and rec.active:
-            now = time.monotonic_ns()
-            rec.record(kind, "llm.serve", None, t0_ns, now - t0_ns, **args)
-
     def _run_inner(self) -> None:
         import dataclasses as _dc
         import functools as _ft
@@ -1649,16 +1653,24 @@ class _ContinuousLoop:
         self._slot_time: list = [None] * B
         eos = getattr(fw.tokenizer, "eos", -1) if fw.stop_eos else -1
 
-        import os as _os
-        trace = _os.environ.get("NNSTPU_SERVE_TRACE") == "1"
+        def begin(kind: str, tid=None, /, **args):
+            """Open one span of this loop on the ring and on the
+            profiler's clock (``tracing.span``).  Callers test ``rec is
+            not None`` first: with ``trace_mode=off`` nothing is built."""
+            return tracing.span(rec, kind, _SERVE_STAGE, tid,
+                                **args).begin()
 
-        def _tr(tag):
-            if trace:
-                # stderr: stdout carries bench.py's line-delimited JSON
-                import sys as _sys
-
-                print(f"[serve {time.monotonic():.3f}] {tag}",
-                      file=_sys.stderr, flush=True)
+        def decode_closed(kind: str, sp_wait, **args):
+            """The chunk (or speculative round) materialized: its ring
+            span opened at the dispatch in step 3, so it is recorded from
+            stamps; the blocking wait alone was its profiler annotation
+            (held: never a ring span of its own).  Delivery starts."""
+            sp_wait.end(hold=True)
+            rec.record(kind, _SERVE_STAGE, None, t_dec,
+                       sp_wait.ts + sp_wait.dur - t_dec, iter=it,
+                       occupancy=int(live.sum()), wait_ns=sp_wait.dur,
+                       **args)
+            return begin("serve.emit", iter=it)
 
         def take_blocks(need: int) -> list:
             """Allocate ``need`` private blocks (refcount 1) off the
@@ -1736,7 +1748,7 @@ class _ContinuousLoop:
             fork would avoid the spike but mint a program the closed
             census (serving_plan/tracecheck/xray) would have to price;
             revisit if silicon pools sized to the HBM edge OOM here."""
-            t0 = time.monotonic_ns()
+            sp = begin("serve.cow_fork") if rec is not None else None
             new = take_blocks(1)[0]
             src_i = np.asarray([src], np.int32)
             new_i = np.asarray([new], np.int32)
@@ -1748,8 +1760,8 @@ class _ContinuousLoop:
                 draft_pool["v"] = draft_pool["v"].at[:, new_i].set(
                     draft_pool["v"][:, src_i])
             metrics.count("llm.serve.cow_forks")
-            self._span(rec, "serve.cow_fork", t0, src=int(src),
-                       dst=int(new))
+            if sp is not None:
+                sp.end(src=int(src), dst=int(new))
             return new
 
         def chain_hashes(row: np.ndarray, full: int) -> list:
@@ -1776,8 +1788,19 @@ class _ContinuousLoop:
         #: set each admission phase, so no path can leak entries.
         chain_cache: Dict[int, list] = {}
 
+        #: while tracing is on retire() banks the retiring stream's count
+        #: of delivered tokens, so serve.emit reads `tokens` and `retired`
+        #: as deltas around the delivery loop: nothing is counted per token
+        n_retired = n_banked = 0
+
+        def delivered() -> int:
+            return n_banked + int(sidx.sum())
+
         def retire(s: int) -> None:
-            nonlocal pos_dev
+            nonlocal pos_dev, n_retired, n_banked
+            if rec is not None:
+                n_retired += 1
+                n_banked += int(sidx[s])
             release(slot_blocks[s])
             slot_blocks[s] = []
             tables[s, :] = self.sentinel
@@ -1907,11 +1930,26 @@ class _ContinuousLoop:
             np.asarray(toks_w)
         release(warm_blocks)
         tables[0, :] = self.sentinel
-        _tr("warmup done")
 
+        n_iter = 0  # iterations that progressed: the spans' `iter`
         while not self._stop.is_set():
             progressed = False
+            # Phase spans (docs/OBSERVABILITY.md): serve.iter is the
+            # parent; intake, admit_pass, prefill_chunk, first_token and
+            # emit tile it.  With trace_mode=off `rec` is None and every
+            # site below is one pointer test.  The three spans open
+            # before the iteration knows whether it will do anything are
+            # HELD and reach the ring only if it progressed — an idle
+            # loop spinning at 50 Hz must not evict the flight recorder.
             rec = getattr(fw, "_trace_rec", None)
+            if rec is not None:
+                if rec.active:
+                    it = n_iter + 1
+                    sp_iter = begin("serve.iter", iter=it)
+                    sp_phase = begin("serve.intake", iter=it)
+                    n_taken = -len(self._waiting)
+                else:
+                    rec = None
             # 0. drain the thread-handoff queue into the admission-order
             # list (FIFO preserved when the head defers on capacity)
             while True:
@@ -1919,6 +1957,8 @@ class _ContinuousLoop:
                     self._waiting.append(self._pending.get_nowait())
                 except _q.Empty:
                     break
+            if rec is not None:
+                n_taken += len(self._waiting)
 
             # 0b. control commands (Pipeline.drain_stream/adopt_stream):
             # executed HERE, at a chunk boundary, where every slot's
@@ -1944,7 +1984,8 @@ class _ContinuousLoop:
                          if ent[1].get(elastic.META_STREAM_ID) == sid),
                         None)
                     if s is not None:
-                        t0 = time.monotonic_ns()
+                        sp = (begin("elastic.drain")
+                              if rec is not None else None)
                         n_used = math.ceil(int(pos[s]) / bs)
                         ids = np.asarray(slot_blocks[s][:n_used],
                                          np.int32)
@@ -1988,14 +2029,13 @@ class _ContinuousLoop:
                         }
                         nb = len(slot_blocks[s])
                         retire(s)
-                        self._span(rec, "elastic.drain", t0,
-                                   stream_id=sid, state="live",
-                                   blocks=nb)
-                        _tr(f"drained slot {s} (stream {sid})")
+                        if sp is not None:
+                            sp.end(stream_id=sid, state="live", blocks=nb)
                         progressed = True
                         cmd["ev"].set()
                     elif wi is not None:
-                        t0 = time.monotonic_ns()
+                        sp = (begin("elastic.drain")
+                              if rec is not None else None)
                         ent = self._waiting.pop(wi)
                         cmd["result"] = {
                             "version": 2, "kind": "queued",
@@ -2010,8 +2050,8 @@ class _ContinuousLoop:
                         elastic.unregister_stream(sid)
                         self._owned_sids.discard(sid)
                         self._cancelled.pop(sid, None)
-                        self._span(rec, "elastic.drain", t0,
-                                   stream_id=sid, state="queued",
+                        if sp is not None:
+                            sp.end(stream_id=sid, state="queued",
                                    blocks=0)
                         progressed = True
                         cmd["ev"].set()
@@ -2026,7 +2066,6 @@ class _ContinuousLoop:
                         cmd["ev"].set()
                 elif cmd["kind"] == "adopt":
                     snap = cmd["snapshot"]
-                    t0 = time.monotonic_ns()
                     sid = int(snap.get(META_STREAM_ID, 0))
                     if sid <= 0 or sid in elastic.live_stream_ids():
                         # cross-process snapshots may collide with a
@@ -2036,14 +2075,16 @@ class _ContinuousLoop:
                     meta = dict(snap.get("meta") or {})
                     meta[elastic.META_STREAM_ID] = sid
                     if snap.get("kind") == "queued":
+                        sp = (begin("elastic.adopt")
+                              if rec is not None else None)
                         self._owned_sids.add(sid)
                         elastic.register_stream(
                             sid, _ft.partial(self._mark_cancel, sid))
                         self._waiting.append(
                             (np.asarray(snap["prompt"], np.int32), meta,
                              cmd["emit"], time.monotonic()))
-                        self._span(rec, "elastic.adopt", t0,
-                                   stream_id=sid, state="queued",
+                        if sp is not None:
+                            sp.end(stream_id=sid, state="queued",
                                    blocks=0)
                         cmd["result"] = sid
                         progressed = True
@@ -2069,6 +2110,8 @@ class _ContinuousLoop:
                             f"({len(free)} free, "
                             f"{math.ceil(need_tok / bs)} needed)")
                     else:
+                        sp = (begin("elastic.adopt")
+                              if rec is not None else None)
                         s = freeslots[0]
                         blocks = alloc(need_tok)
                         slot_blocks[s] = blocks
@@ -2129,10 +2172,9 @@ class _ContinuousLoop:
                         elastic.register_stream(
                             sid, _ft.partial(self._mark_cancel, sid))
                         metrics.gauge(f"llm.serve.slot{s}.occupied", 1.0)
-                        self._span(rec, "elastic.adopt", t0,
-                                   stream_id=sid, state="live", slot=s,
+                        if sp is not None:
+                            sp.end(stream_id=sid, state="live", slot=s,
                                    blocks=len(blocks))
-                        _tr(f"adopted stream {sid} into slot {s}")
                         cmd["result"] = sid
                         progressed = True
                     cmd["ev"].set()
@@ -2145,20 +2187,21 @@ class _ContinuousLoop:
                     # Placement copies onto the live leaves' shardings
                     # (TP pspecs carry over) with FRESH buffers, so a
                     # trainer donating its own tree can't invalidate us.
-                    t0 = time.monotonic_ns()
+                    sp = begin("learn.swap") if rec is not None else None
                     try:
                         params = place_swapped_params(params, cmd["tree"])
                     except Exception as e:  # noqa: BLE001 - caller's error
                         cmd["error"] = str(e)
+                        if sp is not None:
+                            sp.end(hold=True)
                     else:
                         fw.bundle.params = params
                         self.param_version += 1
                         metrics.count("llm.serve.param_swaps")
                         metrics.gauge("llm.serve.param_version",
                                       float(self.param_version))
-                        self._span(rec, "learn.swap", t0,
-                                   version=self.param_version)
-                        _tr(f"params swapped (v{self.param_version})")
+                        if sp is not None:
+                            sp.end(version=self.param_version)
                         cmd["result"] = self.param_version
                         progressed = True
                     cmd["ev"].set()
@@ -2193,29 +2236,35 @@ class _ContinuousLoop:
                         self._admitting.remove(st)
                         s = st["slot"]
                     if s is not None:
-                        t0 = time.monotonic_ns()
+                        sp = (begin("serve.reap", slot=s, stream_id=sid,
+                                    reason=reason)
+                              if rec is not None else None)
                         nb = len(slot_blocks[s])
                         live_slot = slots[s] is not None
                         meta, emit_cb = (slots[s] if live_slot
                                          else (st["meta"], st["emit"]))
                         metrics.count("llm.serve.reaped")
                         metrics.count("llm.serve.reaped_blocks", nb)
-                        self._span(rec, "serve.reap", t0, slot=s,
-                                   stream_id=sid, blocks=nb,
-                                   reason=reason)
-                        _tr(f"reaped slot {s} (stream {sid}: {reason})")
                         # mid-prefill streams emitted nothing: their
                         # terminator is index 0, not the slot's stale
                         # previous-occupant counter
                         reject(meta, emit_cb, reason,
                                idx=int(sidx[s]) if live_slot else 0)
                         retire(s)
+                        if sp is not None:
+                            sp.end(blocks=nb)
                         progressed = True
                     elif not any(
                             ent[1].get(elastic.META_STREAM_ID) == sid
                             for ent in self._waiting):
                         # already finished/unknown: clear the mark
                         self._cancelled.pop(sid, None)
+
+            if rec is not None:
+                sp_intake = sp_phase.end(hold=True, n=n_taken)
+                sp_phase = begin("serve.admit_pass", iter=it)
+                n_looked = 0
+                n_admitted = -len(self._admitting)
 
             # 1. admission: move waiting prompts into free slots while a
             # slot AND the stream's full block reservation are available.
@@ -2235,6 +2284,8 @@ class _ContinuousLoop:
             wi = 0
             while wi < len(self._waiting):
                 prompt, meta, emit, t_enq = self._waiting[wi]
+                if rec is not None:
+                    n_looked += 1
                 sid = meta.get(elastic.META_STREAM_ID)
                 mark = self._cancelled.get(sid)
                 if mark is not None and time.monotonic() >= mark[1]:
@@ -2337,7 +2388,14 @@ class _ContinuousLoop:
                         progressed = True
                         continue
                     break  # pool full: defer admission, never overflow
-                t_admit = time.monotonic_ns()
+                if rec is not None:
+                    # request-bound spans carry the request's trace id
+                    # (stamped at ingress while tracing is on)
+                    tid = meta.get(META_TRACE_ID)
+                    sp = begin("serve.admit", tid, iter=it)
+                    t_admit = sp.ts
+                else:
+                    t_admit = time.monotonic_ns()
                 self._waiting.pop(wi)
                 s = freeslots[0]
                 blocks = list(matched_ids[:shared])
@@ -2356,8 +2414,10 @@ class _ContinuousLoop:
                 if shared:
                     metrics.count("llm.serve.prefix_hits")
                     metrics.count("llm.serve.prefix_hit_blocks", shared)
-                    self._span(rec, "serve.prefix_hit", t_admit, slot=s,
-                               blocks=shared, tokens=p0)
+                    if rec is not None:
+                        rec.record("serve.prefix_hit", _SERVE_STAGE, None,
+                                   t_admit, time.monotonic_ns() - t_admit,
+                                   slot=s, blocks=shared, tokens=p0)
                 # chunk-multiple padding (replaces the old power-of-two
                 # prompt bucketing on this path: waste < one chunk);
                 # only the suffix [p0, P) is prefilled
@@ -2371,11 +2431,21 @@ class _ContinuousLoop:
                     "P": P, "p": p0, "n": n, "meta": meta, "emit": emit,
                     "first": None, "hashes": hashes,
                     "last_tok": int(prompt[0, T - 1])})
-                self._span(rec, "serve.admit", t_admit, slot=s, tokens=T,
-                           blocks=phys, shared=shared)
-                _tr(f"admitted slot {s} ({T} tokens, {len(blocks)} "
-                    f"blocks, {shared} shared)")
+                if rec is not None:
+                    # what this request's later spans need of its admission
+                    self._admitting[-1]["trace"] = (tid, t_admit, p0)
+                    sp.end(slot=s, tokens=T, blocks=phys, shared=shared)
+                    # the request's wait, from the stamp submit() took:
+                    # ends where its serve.prefill will start
+                    enq_ns = int(t_enq * 1e9)
+                    rec.record("serve.queue", _SERVE_STAGE, tid, enq_ns,
+                               t_admit - enq_ns, tid=tid, slot=s,
+                               tokens=T, blocks=phys, shared=shared)
                 progressed = True
+            if rec is not None:
+                sp_admit = sp_phase.end(
+                    hold=True, looked=n_looked,
+                    admitted=n_admitted + len(self._admitting))
 
             # 2. chunked prefill: dispatch up to prefill_budget tokens of
             # [1, C] prefill chunks straight into the admitting streams'
@@ -2386,9 +2456,12 @@ class _ContinuousLoop:
             newly_live = []  # (slot, state) — first token syncs in step 4
             for st in list(self._admitting):
                 while budget > 0 and st["p"] < st["P"]:
-                    t_pf = time.monotonic_ns()
                     s, p = st["slot"], st["p"]
                     final = p + C >= st["P"]
+                    if rec is not None:
+                        sp = begin("serve.prefill_chunk",
+                                   st.get("trace", _NO_TRACE)[0], iter=it,
+                                   slot=s, pos=p, final=bool(final))
                     # last REAL token's offset within this chunk (only
                     # meaningful on the final chunk; intermediate chunks
                     # are all real tokens and their logits are unused)
@@ -2409,8 +2482,8 @@ class _ContinuousLoop:
                             np.asarray([p], np.int32))
                     st["p"] = p + C
                     budget -= C
-                    self._span(rec, "serve.prefill_chunk", t_pf, slot=s,
-                               pos=p, final=bool(final))
+                    if rec is not None:
+                        sp.end()
                     progressed = True
                     if final:
                         if fw.nan_guard and \
@@ -2434,7 +2507,6 @@ class _ContinuousLoop:
                                          meta=dict(st["meta"])),
                                     error=err, stage="llm.serve")
                             metrics.count("llm.serve.poisoned")
-                            _tr(f"poisoned prompt quarantined slot {s}")
                             self._admitting.remove(st)
                             reject(st["meta"], st["emit"], "poison")
                             retire(s)
@@ -2495,7 +2567,6 @@ class _ContinuousLoop:
                         newly_live.append(st)
                         self._admitting.remove(st)
                         metrics.gauge(f"llm.serve.slot{s}.occupied", 1.0)
-                        _tr(f"prefill complete slot {s}")
                         break
 
             # 3. dispatch one chunk of per-row paged decode for the live
@@ -2526,13 +2597,11 @@ class _ContinuousLoop:
                         params, tok, tok_prev, props_dev, dprobs_dev,
                         pool, tables.copy(), pos_dev, keys_dev)
                     metrics.count("llm.serve.spec_rounds")
-                    _tr("spec round dispatched")
                 else:
                     toks_dev, tok, pool = self._decode(
                         params, tok, pool, tables.copy(), pos.copy(),
                         keys_dev, length=fw.chunk)
                     pos[live] += fw.chunk  # parked rows stay parked
-                    _tr("chunk dispatched")
                 progressed = True
             metrics.gauge("llm.serve.occupancy", float(live.sum()))
             metrics.gauge("llm.serve.free_blocks", float(len(free)))
@@ -2545,14 +2614,28 @@ class _ContinuousLoop:
             # dispatch (not one drained queue) after submit.
             for st in newly_live:
                 s = st["slot"]
-                _tr(f"first-token sync begins slot {s}")
+                if rec is not None:
+                    tid, t_adm, p0 = st.get("trace", _NO_TRACE)
+                    sp = begin("serve.first_token", tid, iter=it, slot=s)
                 first = int(np.asarray(st["first"]))
-                _tr(f"first-token synced slot {s}")
                 tok_h[s] = first
                 first_last = st["n"] == 1 or first == eos
                 self._emit_token(st["emit"], st["meta"], first, 0,
                                  first_last)
                 mark_emit(s)
+                if rec is not None:
+                    sp.end()
+                    if t_adm is not None:
+                        # admission -> first token left the loop, from the
+                        # stamps the histogram llm.serve.prefill_ms
+                        # observes at retirement; starts where serve.queue
+                        # ended
+                        rec.record("serve.prefill", _SERVE_STAGE, tid,
+                                   t_adm, max(0, int(
+                                       self._slot_time[s]["first"] * 1e9)
+                                       - t_adm),
+                                   tid=tid, slot=s,
+                                   chunks=(st["P"] - p0) // C)
                 if first_last:
                     # n==1 or EOS on token 0: the in-flight chunk's row
                     # decodes garbage that step 5 skips via remaining==0
@@ -2560,15 +2643,17 @@ class _ContinuousLoop:
 
             # 5. deliver the chunk's tokens
             if toks_dev is not None:
+                if rec is not None:
+                    sp = begin("serve.decode.wait", iter=it)
                 host = np.asarray(toks_dev)  # ONE roundtrip per chunk
-                # the decode span closes HERE, at materialization: the
-                # jit call above only enqueued the async dispatch, so a
-                # span closed there would time host dispatch (~us) and
-                # hide the actual device time — the number the trace
-                # exists to attribute
-                self._span(rec, "serve.decode", t_dec,
-                           occupancy=int(live.sum()), chunk=fw.chunk)
-                _tr("chunk materialized")
+                if rec is not None:
+                    # the decode span closes HERE, at materialization:
+                    # the jit call above only enqueued the async
+                    # dispatch, so a span closed there would time host
+                    # dispatch (~us) and hide the actual device time —
+                    # the number the trace exists to attribute
+                    sp = decode_closed("serve.decode", sp, chunk=fw.chunk)
+                    n_tok0, n_ret0 = delivered(), n_retired
                 for j in range(host.shape[1]):
                     for s in np.flatnonzero(live):
                         if remaining[s] == 0:
@@ -2585,6 +2670,9 @@ class _ContinuousLoop:
                         remaining[s] -= 1
                         if last:
                             retire(int(s))
+                if rec is not None:
+                    sp.end(tokens=delivered() - n_tok0,
+                           retired=n_retired - n_ret0)
 
             # 5b. speculative emit: the fused verify already accepted
             # and COMMITTED on device (tok/tok_prev/pos_dev rebound at
@@ -2598,11 +2686,14 @@ class _ContinuousLoop:
             # Host mirrors (tok_h/tok_prev_h/pos) update from the same
             # values, so drain snapshots stay exact.
             if em_dev is not None:
+                if rec is not None:
+                    sp = begin("serve.spec_verify.wait", iter=it)
                 em_host = np.asarray(em_dev)    # [B, k+1]
                 acc_host = np.asarray(acc_dev)  # [B] — one sync
-                self._span(rec, "serve.spec_verify", t_dec,
-                           occupancy=int(live.sum()), k=fw.spec_k)
-                _tr("spec round materialized")
+                if rec is not None:
+                    sp = decode_closed("serve.spec_verify", sp,
+                                       k=fw.spec_k)
+                    n_tok0, n_ret0 = delivered(), n_retired
                 K = fw.spec_k
                 for s in np.flatnonzero(live):
                     s = int(s)
@@ -2651,6 +2742,19 @@ class _ContinuousLoop:
                         seq = [int(tok_h[s])] + emitted
                         tok_h[s] = seq[-1]
                         tok_prev_h[s] = seq[-2]
+                if rec is not None:
+                    sp.end(tokens=delivered() - n_tok0,
+                           retired=n_retired - n_ret0)
+
+            if rec is not None:
+                sp_iter.end(hold=True, live=int(live.sum()),
+                            waiting=len(self._waiting)
+                            + len(self._admitting))
+                if progressed:
+                    n_iter = it
+                    sp_iter.commit()
+                    sp_intake.commit()
+                    sp_admit.commit()
 
             if not progressed:
                 with self._idle_lock:

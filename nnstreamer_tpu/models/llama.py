@@ -812,7 +812,13 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
     overrides plain causal attention (ring attention under shard_map);
     ``paged_tables`` ([B, max_blocks] int32) switches ``kv`` to the
     block-pool layout ([n_blocks, bs, Hkv, hd] per layer) with per-row
-    positions — the continuous-serving paged path."""
+    positions — the continuous-serving paged path.
+
+    The sections carry ``jax.named_scope`` names (``attention``,
+    ``kv_write``, ``mlp``): metadata only — the scope lands in every
+    operation's ``op_name`` in the HLO, so a device trace can be summed
+    by section instead of recognised by result shape (PERF.md §3)."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -846,25 +852,27 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         k_pool, v_pool = kv  # [n_blocks, bs, Hkv, hd]
         n_blocks, bs = k_pool.shape[0], k_pool.shape[1]
         max_blocks = paged_tables.shape[1]
-        idx = pos_offset[:, None] + jnp.arange(T)[None, :]  # [B, T]
-        valid = (idx >= 0) & (idx < max_blocks * bs)
-        slot_blk = jnp.clip(idx // bs, 0, max_blocks - 1)
-        blk = jnp.where(
-            valid,
-            jnp.take_along_axis(paged_tables, slot_blk, axis=1),
-            n_blocks)  # sentinel -> dropped scatter
-        off = idx % bs
-        k_pool = k_pool.at[blk, off].set(k.astype(k_pool.dtype),
-                                         mode="drop")
-        v_pool = v_pool.at[blk, off].set(v.astype(v_pool.dtype),
-                                         mode="drop")
+        with jax.named_scope("kv_write"):
+            idx = pos_offset[:, None] + jnp.arange(T)[None, :]  # [B, T]
+            valid = (idx >= 0) & (idx < max_blocks * bs)
+            slot_blk = jnp.clip(idx // bs, 0, max_blocks - 1)
+            blk = jnp.where(
+                valid,
+                jnp.take_along_axis(paged_tables, slot_blk, axis=1),
+                n_blocks)  # sentinel -> dropped scatter
+            off = idx % bs
+            k_pool = k_pool.at[blk, off].set(k.astype(k_pool.dtype),
+                                             mode="drop")
+            v_pool = v_pool.at[blk, off].set(v.astype(v_pool.dtype),
+                                             mode="drop")
         # context = everything written so far incl. this suffix; a parked
         # row (pos >= max_blocks*bs) gets len 0 — the paged kernel then
         # issues ZERO block DMAs for it, which is the whole traffic story
-        lens = jnp.where(pos_offset + T <= max_blocks * bs,
-                         pos_offset + T, 0).astype(jnp.int32)
-        attn = paged_attention(q, k_pool, v_pool, paged_tables,
-                               lens).astype(dt)
+        with jax.named_scope("attention"):
+            lens = jnp.where(pos_offset + T <= max_blocks * bs,
+                             pos_offset + T, 0).astype(jnp.int32)
+            attn = paged_attention(q, k_pool, v_pool, paged_tables,
+                                   lens).astype(dt)
         kv = (k_pool, v_pool)
         # falls through to the shared wo/residual/MLP tail below
     elif kv is not None:
@@ -908,7 +916,9 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
     if paged_tables is not None:
         pass  # paged attention computed above; shared tail below
     elif attn_fn is not None:
-        attn = attn_fn(q, _repeat_kv(k_all, H // Hkv), _repeat_kv(v_all, H // Hkv))
+        with jax.named_scope("attention"):
+            attn = attn_fn(q, _repeat_kv(k_all, H // Hkv),
+                           _repeat_kv(v_all, H // Hkv))
     elif kv is None or prefill:
         # Blockwise flash kernel (Pallas; falls back to plain XLA attention
         # internally when T doesn't tile into its blocks).  K/V go in
@@ -916,33 +926,36 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         # query-head group, and the XLA fallback repeats internally.
         from ..ops.attention import flash_attention
 
-        attn = flash_attention(q, k, v, causal=True)
+        with jax.named_scope("attention"):
+            attn = flash_attention(q, k, v, causal=True)
     else:
-        kr = _repeat_kv(k_all, H // Hkv)
-        vr = _repeat_kv(v_all, H // Hkv)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
-                       preferred_element_type=jnp.float32)
-        s = s * (1.0 / np.sqrt(hd))
-        s = jnp.where(mask, s, jnp.float32(-1e30))
-        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-        p = p / jnp.sum(p, axis=-1, keepdims=True)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), vr)
+        with jax.named_scope("attention"):
+            kr = _repeat_kv(k_all, H // Hkv)
+            vr = _repeat_kv(v_all, H // Hkv)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
+                           preferred_element_type=jnp.float32)
+            s = s * (1.0 / np.sqrt(hd))
+            s = jnp.where(mask, s, jnp.float32(-1e30))
+            p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            p = p / jnp.sum(p, axis=-1, keepdims=True)
+            attn = jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), vr)
 
     out = _mm(attn.reshape(B, T, H * hd), lp, "wo", dt)
     x = x + out
 
-    h = _rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
     import jax.nn as jnn
 
-    if "wgu_p" in lp:  # int4 fused gate|up
-        F = lp["wgu_p"].shape[-1] // 2
-        gu = _mm(h, lp, "wgu", dt)
-        gate = jnn.silu(gu[..., :F])
-        up = gu[..., F:]
-    else:
-        gate = jnn.silu(_mm(h, lp, "w_gate", dt))
-        up = _mm(h, lp, "w_up", dt)
-    x = x + _mm(gate * up, lp, "w_down", dt)
+    with jax.named_scope("mlp"):
+        h = _rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
+        if "wgu_p" in lp:  # int4 fused gate|up
+            F = lp["wgu_p"].shape[-1] // 2
+            gu = _mm(h, lp, "wgu", dt)
+            gate = jnn.silu(gu[..., :F])
+            up = gu[..., F:]
+        else:
+            gate = jnn.silu(_mm(h, lp, "w_gate", dt))
+            up = _mm(h, lp, "w_up", dt)
+        x = x + _mm(gate * up, lp, "w_down", dt)
     return x, kv
 
 

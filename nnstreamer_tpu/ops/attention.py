@@ -279,6 +279,7 @@ def flash_attention(
             (None, block_q, grp, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hkv, sq, grp, d), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, hkv, sq, grp, d).transpose(0, 2, 1, 3, 4).reshape(
         b, sq, h, d)
@@ -479,5 +480,6 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(tbl, context_lens.astype(jnp.int32), q[:, 0], k_pool, v_pool)
     return out[:, None]

@@ -226,6 +226,7 @@ def matmul_int4(h, packed, scale, *, block_d2: Optional[int] = None,
         out_shape=jax.ShapeDtypeStruct((B, F), odt),
         scratch_shapes=[pltpu.VMEM((B, fb), jnp.float32)],
         interpret=interpret,
+        name="int4_matmul",
     )(ha, hb, packed, scale)
     # the -8 * rowsum(h_lo) bias correction, applied at full precision
     # outside the kernel (a [B,1] x [1,F] outer product is negligible)

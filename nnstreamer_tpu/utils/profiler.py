@@ -5,10 +5,9 @@ properties plus GStreamer tracers / gst-shark for deeper dives.  TPU
 equivalents:
 
 * :func:`trace` — context manager around ``jax.profiler`` producing an
-  xplane trace viewable in TensorBoard/XProf (device timelines, HBM);
-  the per-buffer flight recorder (``utils/tracing.py``, Chrome
-  trace-event JSON for Perfetto) covers the pipeline layer —
-  docs/OBSERVABILITY.md;
+  xplane trace viewable in TensorBoard/XProf (device timelines, HBM)
+  that also holds the flight recorder's spans as host annotations
+  (``utils/tracing.py span``) — docs/OBSERVABILITY.md "One timeline";
 * :func:`metrics_text` — the process metrics in Prometheus text format:
   counters, sampler-fed gauges (queue depth, staleness watermark), REAL
   cumulative histograms with explicit buckets for every
@@ -37,11 +36,23 @@ log = logger(__name__)
 @contextlib.contextmanager
 def trace(logdir: str):
     """Capture a device trace for the enclosed block (no-op if the jax
-    profiler is unavailable on this backend)."""
+    profiler is unavailable on this backend).
+
+    The host tracer runs at level 1 — the lowest that records
+    ``TraceAnnotation``s, so the flight recorder's spans
+    (``tracing.span``) sit on the profiler's clock beside the device
+    planes and ``python -m nnstreamer_tpu.tools.trace gaps`` can say what
+    the host did in every device idle gap — and the Python tracer is
+    off: the default levels record every Python call and every futex of
+    a busy pipeline, and stopping such a profile took minutes (PERF.md
+    §6, PR 25)."""
     import jax
 
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 1
+    opts.python_tracer_level = 0
     try:
-        jax.profiler.start_trace(logdir)
+        jax.profiler.start_trace(logdir, profiler_options=opts)
         started = True
     except (RuntimeError, NotImplementedError) as e:  # pragma: no cover
         log.warning("jax profiler unavailable: %s", e)

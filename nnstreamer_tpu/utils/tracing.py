@@ -24,10 +24,14 @@ Three trace modes (``Config.trace_mode`` / ``Pipeline(trace_mode=...)``):
 
 Exports: :func:`to_chrome` renders Chrome trace-event JSON (one track per
 stage, flow arrows binding batch dispatch spans to every member row's
-trace id) loadable in Perfetto / ``chrome://tracing`` alongside the
-``utils.profiler.trace`` xplane; :func:`dump_recent_to_log` formats the
-last K seconds for crash reports; ``python -m nnstreamer_tpu.tools.trace``
-validates/summarizes dumps.  See docs/OBSERVABILITY.md.
+trace id) loadable in Perfetto / ``chrome://tracing``;
+:func:`dump_recent_to_log` formats the last K seconds for crash reports;
+``python -m nnstreamer_tpu.tools.trace`` validates/summarizes dumps.
+:class:`span` is the one helper with two sinks: the ring, and a
+``jax.profiler.TraceAnnotation`` of the same name on the profiler's clock,
+so a device profile (``utils.profiler.trace``) shows what the host was
+doing between device programs (``tools.trace gaps``).  See
+docs/OBSERVABILITY.md.
 
 nns-weave (docs/OBSERVABILITY.md "Distributed tracing") extends the
 recorder across processes: trace ids carry a random per-process **epoch**
@@ -74,17 +78,56 @@ SPAN_KINDS: Dict[str, str] = {
                     "fetches; CONCURRENCY is bounded by fetch_depth, the "
                     "backlog only by queue capacity — docs/FETCH.md)",
     "e2e": "source ingress -> sink delivery for one buffer",
+    "serve.iter": "continuous LLM serving: one iteration of the serve "
+                  "loop that progressed, loop top to the end of token "
+                  "delivery — the PARENT of the phase spans below, which "
+                  "lie inside it and (bar serve.decode) do not overlap; "
+                  "its self time is the loop's bookkeeping (args: iter = "
+                  "running number shared by every span of the iteration, "
+                  "live, waiting)",
+    "serve.intake": "continuous LLM serving: hand-off queue drained, "
+                    "control commands run, cancelled streams reaped "
+                    "(args: iter, n = prompts taken)",
+    "serve.admit_pass": "continuous LLM serving: the whole admission pass "
+                        "— cancellation/oversize checks, quota, prefix "
+                        "hashing + lookup, block reservation for every "
+                        "waiting prompt looked at (args: iter, looked, "
+                        "admitted)",
     "serve.admit": "continuous LLM serving: prompt admitted into a slot "
-                   "(args: slot, tokens, blocks reserved)",
+                   "— the tail of its admission, inside serve.admit_pass "
+                   "(tid = the request's trace id; args: iter, tid, slot, "
+                   "tokens, blocks reserved, shared)",
+    "serve.queue": "continuous LLM serving: one request's wait from "
+                   "submit() to admission, recorded at admission from "
+                   "the loop's stamps (tid = request trace id; args: "
+                   "tid, slot, tokens, blocks, shared)",
+    "serve.prefill": "continuous LLM serving: one request's admission -> "
+                     "first token left the loop, recorded at its first "
+                     "emission; starts where its serve.queue ends (tid = "
+                     "request trace id; args: tid, slot, chunks)",
     "serve.prefill_chunk": "continuous LLM serving: one chunked-prefill "
                            "step written into the slot's pool blocks "
-                           "(args: slot, pos, final; times the ASYNC "
+                           "(tid = request trace id; args: iter, tid, "
+                           "slot, pos, final; times the ASYNC "
                            "dispatch — device time overlaps the decode "
                            "chunk by design)",
     "serve.decode": "continuous LLM serving: one paged decode chunk over "
-                    "the live slots (args: occupancy, chunk; closes at "
-                    "chunk materialization, so it covers the device "
-                    "time)",
+                    "the live slots (args: iter, occupancy, chunk, "
+                    "wait_ns = how long the host blocked on the chunk's "
+                    "tokens; opens at dispatch and closes at chunk "
+                    "materialization, so it covers the device time and "
+                    "overlaps serve.first_token; its profiler "
+                    "annotation, serve.decode.wait, covers the blocking "
+                    "wait alone)",
+    "serve.first_token": "continuous LLM serving: a newly live stream's "
+                         "first sampled id synced to the host and "
+                         "emitted, under the decode chunk in flight (tid "
+                         "= request trace id; args: iter, tid, slot)",
+    "serve.emit": "continuous LLM serving: the delivery loop after chunk "
+                  "materialization — every token of the chunk pushed "
+                  "downstream one by one, and the retirements it caused "
+                  "(args: iter, tokens, retired; nothing is recorded "
+                  "per token)",
     "serve.prefix_hit": "continuous LLM serving: an admitted prompt's "
                         "leading blocks matched the prefix cache and "
                         "mapped copy-on-write into its table (instant; "
@@ -96,8 +139,9 @@ SPAN_KINDS: Dict[str, str] = {
                       "move, no program touched)",
     "serve.spec_verify": "continuous LLM serving: one speculative round "
                          "(draft propose + k+1-wide target verify; "
-                         "args: occupancy, k; closes at round "
-                         "materialization like serve.decode)",
+                         "args: iter, occupancy, k, wait_ns; closes at "
+                         "round materialization like serve.decode; "
+                         "annotation serve.spec_verify.wait)",
     "admit.shed": "query-server admission shed a request under backlog "
                   "(instant; args: tenant, msg, backlog — the victim's "
                   "trace id is the span tid, minted at shed when the "
@@ -296,10 +340,13 @@ class FlightRecorder:
         return self
 
     # -- hot path ----------------------------------------------------------
-    def record(self, kind: str, stage: str, tid: Optional[int],
+    def record(self, kind: str, stage: str, tid: Optional[int], /,
                ts_ns: int, dur_ns: int, **args) -> None:
         """Append one span.  No lock: deque.append is GIL-atomic and the
-        ring's maxlen does the eviction."""
+        ring's maxlen does the eviction.  ``kind``/``stage``/``tid`` are
+        positional-only so ``args`` may carry a ``tid`` of its own (a
+        request-bound span repeats its trace id there: consumers that
+        are handed args alone still see which request it was)."""
         self._ring.append(
             Span(ts_ns, dur_ns, kind, stage, tid, args or None))
 
@@ -349,6 +396,79 @@ class FlightRecorder:
 #: the process-wide recorder (one per process, like ``core.log.metrics``);
 #: ``Pipeline(trace_mode=...)`` configures it, runners hold it (or None)
 recorder = FlightRecorder()
+
+
+# -- one span, two sinks ------------------------------------------------------
+
+class span:
+    """One span, two sinks: a ring :class:`Span` on ``time.monotonic_ns``
+    and a ``jax.profiler.TraceAnnotation`` of the same name, start and
+    duration — a host span on the PROFILER's clock, visible in a
+    ``utils.profiler.trace`` capture next to the device planes and free
+    while no profile is being taken.  For a LIVE recorder only — a site
+    whose pipeline runs with ``trace_mode=off`` holds ``None`` and never
+    gets here::
+
+        with tracing.span(rec, "serve.emit", "llm.serve", iter=7) as sp:
+            ...
+            sp.note(tokens=256)          # args known only at the end
+
+    Hot paths that may not pay a ``with`` when tracing is off open and
+    close it by hand, one pointer test each::
+
+        if rec is not None:
+            sp = tracing.span(rec, kind, stage, iter=7).begin()
+        ...
+        if rec is not None:
+            sp.end(tokens=256)
+
+    ``end(hold=True)`` closes the annotation and the clock but leaves the
+    ring alone until :meth:`commit` — for a span whose worth is known
+    later (an iteration that turns out to have done nothing records
+    nothing)."""
+
+    __slots__ = ("rec", "kind", "stage", "tid", "args", "ts", "dur", "_ann")
+
+    def __init__(self, rec: FlightRecorder, kind: str, stage: str,
+                 tid: Optional[int] = None, /, **args):
+        self.rec, self.kind, self.stage, self.tid = rec, kind, stage, tid
+        if tid is not None:
+            args["tid"] = tid  # request-bound: the id rides args too
+        self.args = args
+        self.ts = self.dur = 0
+        self._ann = None
+
+    def begin(self) -> "span":
+        # imported on use: this module stays importable without jax
+        from jax.profiler import TraceAnnotation
+
+        self._ann = TraceAnnotation(self.kind, **self.args)
+        self._ann.__enter__()
+        self.ts = time.monotonic_ns()
+        return self
+
+    def note(self, **args) -> None:
+        """Add args learned inside the span to both sinks."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def end(self, hold: bool = False, **args) -> "span":
+        if args:
+            self.note(**args)
+        self.dur = time.monotonic_ns() - self.ts
+        self._ann.__exit__(None, None, None)
+        if not hold:
+            self.commit()
+        return self
+
+    def commit(self) -> None:
+        self.rec.record(self.kind, self.stage, self.tid, self.ts, self.dur,
+                        **self.args)
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> None:
+        self.end()
 
 
 # -- Chrome trace-event export ----------------------------------------------

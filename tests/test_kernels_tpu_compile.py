@@ -37,11 +37,16 @@ def v5e():
         shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
+def _compile(name, fn, *args):
     lowered = jax.jit(fn).lower(*args)
-    assert "tpu_custom_call" in lowered.as_text(), \
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text, \
         "lowered without the Pallas kernel (a shape gate took the reference)"
+    # the kernel's own name: what a device trace shows it under (the HLO
+    # instruction becomes %<name>.N) instead of %closed_call.N
+    assert f'kernel_name = "{name}"' in text
     lowered.compile()  # Mosaic runs here; a refused kernel raises
+    return lowered
 
 
 @pytest.mark.parametrize("hkv", HEADS)
@@ -49,6 +54,7 @@ def _compile(fn, *args):
 def test_paged_attention_compiles(v5e, hkv, slots):
     bs, n_blocks, max_blocks = 16, 256, 16
     _compile(
+        "paged_attention",
         lambda q, k, v, t, n: A.paged_attention(q, k, v, t, n,
                                                 interpret=False),
         v5e((slots, 1, H, D), jnp.bfloat16),
@@ -61,6 +67,7 @@ def test_paged_attention_compiles(v5e, hkv, slots):
 @pytest.mark.parametrize("seq", [128, 512])
 def test_flash_attention_compiles(v5e, hkv, seq):
     _compile(
+        "flash_attention",
         lambda q, k, v: A.flash_attention(q, k, v, causal=True,
                                           interpret=False),
         v5e((1, seq, H, D), jnp.bfloat16),
@@ -75,6 +82,7 @@ def test_flash_attention_compiles(v5e, hkv, seq):
 @pytest.mark.parametrize("rows", [1, 16])
 def test_matmul_int4_compiles(v5e, din, fout, rows):
     _compile(
+        "int4_matmul",
         lambda h, p, s: I4.matmul_int4(h, p, s, interpret=False),
         v5e((rows, din), jnp.bfloat16), v5e((din // 2, fout), jnp.int8),
         v5e((1, fout), jnp.float32))
@@ -98,8 +106,15 @@ def test_serve_decode_step_engages_paged_kernel(v5e, monkeypatch):
 
     params = abstract(lambda: llama.init_params_int8(cfg, 0, "bfloat16"))
     pool = abstract(lambda: llama.init_paged_cache(cfg, n_blocks, bs))
-    _compile(
+    lowered = _compile(
+        "paged_attention",
         lambda p, tok, pool, tables, pos: llama.forward_paged(
             p, tok, pool, tables, pos, cfg),
         params, v5e((slots, 1), jnp.int32), pool,
         v5e((slots, max_blocks), jnp.int32), v5e((slots,), jnp.int32))
+    # the block's sections are named scopes: every operation's op_name
+    # carries its section, so a device trace sums by section, not by shape
+    text = lowered.as_text(debug_info=True)
+    for scope in ("attention", "kv_write", "mlp"):
+        assert f'loc("{scope}/' in text, scope
+    assert "attention/paged_attention" in text

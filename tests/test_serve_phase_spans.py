@@ -1,0 +1,402 @@
+"""Phase spans of the continuous serve loop (ISSUE 26): one tracing
+system with two sinks.  With ``trace_mode=ring`` every iteration that
+progressed records a ``serve.iter`` whose children tile it, request-bound
+spans carry the request's trace id, and ``serve.queue`` ends where
+``serve.prefill`` starts; with ``trace_mode=off`` nothing is recorded and
+no profiler annotation is even constructed.  Toy decoder, CPU.
+"""
+
+import os
+import time
+
+import numpy as np
+import pytest
+
+import nnstreamer_tpu as nt
+from nnstreamer_tpu.utils import tracing
+from nnstreamer_tpu.utils.tracing import FlightRecorder, recorder
+
+STAGE = "llm.serve"
+#: the children that tile a serve.iter; serve.decode by design spans
+#: dispatch -> materialization and overlaps serve.first_token
+TILING = {"serve.intake", "serve.admit_pass", "serve.prefill_chunk",
+          "serve.first_token", "serve.emit"}
+PROMPTS = [np.array([1, 5, 9, 2], np.int32),
+           np.arange(1, 20, dtype=np.int32),       # three prefill chunks
+           np.array([7, 7, 3], np.int32)]          # waits for a free slot
+MAX_NEW = 6
+
+
+def _desc(extra=""):
+    return ("appsrc name=src ! tensor_filter framework=llm "
+            f"model=llama_tiny custom=max_new:{MAX_NEW},serve:continuous,"
+            "slots:2,temperature:0.0,block_size:8,prefill_chunk:8,"
+            f"stream_chunk:2{extra} invoke-dynamic=true ! "
+            "tensor_sink name=out")
+
+
+def _serve(trace_mode, extra="", idle_s=0.2):
+    """Three prompts through two slots; returns (pulled buffers, serve
+    spans at the last token, serve spans after ``idle_s`` more — the
+    last token is pulled while the loop is still inside the delivery
+    that pushed it, so only the later list holds that iteration whole)."""
+    recorder.configure("off")
+    recorder.clear()
+    p = nt.Pipeline(_desc(extra), trace_mode=trace_mode)
+    with p:
+        for x in PROMPTS:
+            p.push("src", x)
+        bufs = [p.pull("out", timeout=120)
+                for _ in range(MAX_NEW * len(PROMPTS))]
+        at_last = [e for e in recorder.events() if e.stage == STAGE]
+        time.sleep(idle_s)
+        after = [e for e in recorder.events() if e.stage == STAGE]
+        p.eos("src")
+        p.wait(timeout=120)
+    recorder.configure("off")
+    recorder.clear()
+    return bufs, at_last, after
+
+
+@pytest.fixture(scope="module")
+def traced():
+    bufs, _at_last, spans = _serve("ring")
+    return bufs, spans
+
+
+def _by_iter(spans):
+    out = {}
+    for e in spans:
+        if e.args and "iter" in e.args:
+            out.setdefault(e.args["iter"], []).append(e)
+    return out
+
+
+def test_every_iteration_holds_its_children_without_overlap(traced):
+    _bufs, spans = traced
+    iters = _by_iter(spans)
+    assert len(iters) >= 3
+    for it, group in iters.items():
+        parents = [e for e in group if e.kind == "serve.iter"]
+        assert len(parents) == 1, (it, [e.kind for e in group])
+        lo, hi = parents[0].ts, parents[0].ts + parents[0].dur
+        kids = sorted((e for e in group if e.kind != "serve.iter"),
+                      key=lambda e: e.ts)
+        assert {e.kind for e in kids} >= {"serve.intake",
+                                          "serve.admit_pass"}
+        for e in kids:
+            assert lo <= e.ts and e.ts + e.dur <= hi, (it, e)
+        tiles = [e for e in kids if e.kind in TILING]
+        for a, b in zip(tiles, tiles[1:]):
+            assert a.ts + a.dur <= b.ts, (it, a, b)
+        # serve.admit is the tail of one prompt's admission, inside the pass
+        passes = [e for e in kids if e.kind == "serve.admit_pass"]
+        for e in kids:
+            if e.kind == "serve.admit":
+                assert passes[0].ts <= e.ts and \
+                    e.ts + e.dur <= passes[0].ts + passes[0].dur
+
+
+def test_children_sum_to_no_more_than_the_parent(traced):
+    _bufs, spans = traced
+    for it, group in _by_iter(spans).items():
+        parent = next(e for e in group if e.kind == "serve.iter")
+        assert sum(e.dur for e in group if e.kind in TILING) <= parent.dur
+
+
+def test_iter_is_monotonic_dense_and_shared(traced):
+    _bufs, spans = traced
+    parents = [e for e in spans if e.kind == "serve.iter"]
+    nums = [e.args["iter"] for e in sorted(parents, key=lambda e: e.ts)]
+    assert nums == list(range(1, len(nums) + 1))
+    for e in spans:
+        if e.kind in TILING | {"serve.decode", "serve.admit"}:
+            assert e.args["iter"] in nums, e
+    # an iteration that decoded delivered: decode and emit share its number
+    dec = {e.args["iter"] for e in spans if e.kind == "serve.decode"}
+    assert dec and dec == {e.args["iter"] for e in spans
+                           if e.kind == "serve.emit"}
+    assert set(parents[-1].args) == {"iter", "live", "waiting"}
+
+
+def test_each_request_has_one_queue_and_one_prefill_that_meet(traced):
+    bufs, spans = traced
+    tids = {b.meta[tracing.META_TRACE_ID] for b in bufs}
+    assert len(tids) == len(PROMPTS)
+    for tid in tids:
+        q = [e for e in spans if e.kind == "serve.queue" and e.tid == tid]
+        pf = [e for e in spans if e.kind == "serve.prefill"
+              and e.tid == tid]
+        assert len(q) == 1 and len(pf) == 1, tid
+        assert q[0].ts + q[0].dur == pf[0].ts
+        assert q[0].args["tid"] == tid == pf[0].args["tid"]
+        assert set(q[0].args) == {"tid", "slot", "tokens", "blocks",
+                                  "shared"}
+        assert set(pf[0].args) == {"tid", "slot", "chunks"}
+        # the request's other spans carry the same id, in args too
+        mine = {e.kind for e in spans if e.tid == tid}
+        assert mine >= {"serve.admit", "serve.prefill_chunk",
+                        "serve.first_token"}
+        assert all(e.args["tid"] == tid for e in spans if e.tid == tid)
+    chunks = sorted(e.args["chunks"] for e in spans
+                    if e.kind == "serve.prefill")
+    assert chunks == [1, 1, 3]
+    # the third prompt found both slots taken: it waited at least the
+    # iteration that freed one
+    waits = sorted(e.dur for e in spans if e.kind == "serve.queue")
+    assert waits[-1] > waits[0]
+
+
+def test_decode_wait_is_part_of_the_decode_span(traced):
+    _bufs, spans = traced
+    dec = [e for e in spans if e.kind == "serve.decode"]
+    assert dec
+    for e in dec:
+        assert 0 <= e.args["wait_ns"] <= e.dur
+        assert e.args["chunk"] == 2 and e.args["occupancy"] >= 1
+    # the wait is an annotation alone: the ring keeps the whole decode
+    assert not [e for e in spans if e.kind.endswith(".wait")]
+    emits = [e for e in spans if e.kind == "serve.emit"]
+    assert sum(e.args["tokens"] for e in emits) == \
+        (MAX_NEW - 1) * len(PROMPTS)
+    assert sum(e.args["retired"] for e in emits) == len(PROMPTS)
+
+
+def test_an_idle_loop_records_nothing():
+    _bufs, at_last, after = _serve("ring", idle_s=0.4)
+    # twenty spins of the idle loop later, at most the iteration that
+    # was closing when the last token was pulled has been added
+    n0 = sum(1 for e in at_last if e.kind == "serve.iter")
+    n1 = sum(1 for e in after if e.kind == "serve.iter")
+    assert n1 - n0 <= 1
+    last = max(after, key=lambda e: e.ts + e.dur)
+    assert last.kind == "serve.iter" and last.args["live"] >= 0
+
+
+def test_span_kinds_names_every_kind_the_loop_records(traced):
+    _bufs, spans = traced
+    kinds = {e.kind for e in spans}
+    assert kinds >= TILING | {"serve.iter", "serve.decode", "serve.admit",
+                              "serve.queue", "serve.prefill"}
+    assert kinds <= set(tracing.SPAN_KINDS), kinds - set(tracing.SPAN_KINDS)
+
+
+def test_tracing_switched_on_between_admission_and_first_token(monkeypatch):
+    """A request admitted while the recorder was off carries no admission
+    stamp: its later spans have no id, it gets no ``serve.prefill``, and
+    the loop runs on."""
+    from nnstreamer_tpu.filters import llm
+
+    count = llm.metrics.count
+    admissions = []
+
+    def switch_on_at_second_admission(name, *a, **kw):
+        if name == "llm.serve.prefill_tokens":   # counted mid-admission
+            admissions.append(name)
+            if len(admissions) == 2:
+                recorder.configure("ring")
+        return count(name, *a, **kw)
+
+    n_new = 48  # the first stream is still decoding when the second comes
+    recorder.configure("off")
+    recorder.clear()
+    p = nt.Pipeline(_desc().replace(f"max_new:{MAX_NEW}",
+                                    f"max_new:{n_new}"), trace_mode="ring")
+    with p:
+        recorder.configure("off")
+        monkeypatch.setattr(llm.metrics, "count",
+                            switch_on_at_second_admission)
+        p.push("src", PROMPTS[0])
+        bufs = [p.pull("out", timeout=120)]
+        p.push("src", PROMPTS[1])    # three iterations of prefill
+        bufs += [p.pull("out", timeout=120) for _ in range(2 * n_new - 1)]
+        time.sleep(0.2)
+        spans = [e for e in recorder.events() if e.stage == STAGE]
+        p.eos("src")
+        p.wait(timeout=120)
+    recorder.configure("off")
+    recorder.clear()
+    assert len(bufs) == 2 * n_new and len(admissions) == 2
+    kinds = [e.kind for e in spans]
+    assert "serve.queue" not in kinds and "serve.prefill" not in kinds
+    # its first chunk ran untraced, in the iteration that admitted it
+    assert kinds.count("serve.prefill_chunk") == 2
+    assert kinds.count("serve.first_token") == 1
+    assert all(e.tid is None for e in spans)
+    emits = [e for e in spans if e.kind == "serve.emit"]
+    assert sum(e.args["retired"] for e in emits) == 2
+
+
+class _Counting:
+    """Stands in for ``jax.profiler.TraceAnnotation``."""
+    made = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, dict(kw)
+        _Counting.made.append(self)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **kw):
+        self.kw.update(kw)
+
+
+def test_off_mode_records_nothing_and_constructs_no_annotation(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Counting)
+    _Counting.made = []
+    bufs, _at_last, spans = _serve("off")
+    assert len(bufs) == MAX_NEW * len(PROMPTS)
+    assert spans == [] and len(recorder) == 0
+    assert _Counting.made == []
+    assert all(tracing.META_TRACE_ID not in b.meta for b in bufs)
+    # and the same run traced does construct them, under the spans' names
+    _serve("ring")
+    names = {a.name for a in _Counting.made}
+    assert names >= TILING | {"serve.iter", "serve.admit",
+                              "serve.decode.wait"}
+    emit = next(a for a in _Counting.made if a.name == "serve.emit")
+    assert set(emit.kw) == {"iter", "tokens", "retired"}
+
+
+def test_speculative_rounds_carry_iter_and_wait():
+    _bufs, _at_last, spans = _serve("ring", ",draft:llama_tiny,spec_k:2")
+    rounds = [e for e in spans if e.kind == "serve.spec_verify"]
+    assert rounds and not [e for e in spans if e.kind == "serve.decode"]
+    for e in rounds:
+        assert 0 <= e.args["wait_ns"] <= e.dur and e.args["k"] == 2
+    assert {e.args["iter"] for e in rounds} == \
+        {e.args["iter"] for e in spans if e.kind == "serve.emit"}
+    assert sum(e.args["tokens"] for e in spans if e.kind == "serve.emit") \
+        == (MAX_NEW - 1) * len(PROMPTS)
+
+
+def test_span_helper_feeds_both_sinks(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Counting)
+    _Counting.made = []
+    rec = FlightRecorder("full")
+    with tracing.span(rec, "serve.emit", "s", iter=3) as sp:
+        sp.note(tokens=4)
+    held = tracing.span(rec, "serve.intake", "s", 77, iter=3).begin()
+    held.end(hold=True, n=1)
+    assert len(rec) == 1                      # held: not in the ring yet
+    held.commit()
+    a, b = rec.events()
+    assert (a.kind, a.tid, a.args) == ("serve.emit", None,
+                                       {"iter": 3, "tokens": 4})
+    assert (b.kind, b.tid, b.args) == ("serve.intake", 77,
+                                       {"iter": 3, "n": 1, "tid": 77})
+    assert a.dur > 0 and b.ts >= a.ts + a.dur
+    assert [(x.name, x.kw) for x in _Counting.made] == [
+        ("serve.emit", {"iter": 3, "tokens": 4}),
+        ("serve.intake", {"iter": 3, "n": 1, "tid": 77})]
+
+
+def test_profiler_trace_asks_for_annotations_not_python_calls(
+        monkeypatch, tmp_path):
+    import jax
+
+    from nnstreamer_tpu.utils import profiler
+
+    seen = {}
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, profiler_options=None: seen.update(
+                            d=d, o=profiler_options))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: seen.update(stopped=True))
+    with profiler.trace(str(tmp_path)):
+        pass
+    assert seen["stopped"] and seen["d"] == str(tmp_path)
+    assert seen["o"].host_tracer_level == 1
+    assert seen["o"].python_tracer_level == 0
+
+
+#: a profile as plain data: three programs on the device, the serve
+#: thread's annotations on the host, both on one clock (ns)
+PLANES = {
+    "/device:TPU:0": {
+        "XLA Modules": [("jit_decode_chunk(11)", 0, 1000),
+                        ("jit_prefill_step(22)", 1400, 300),
+                        ("jit_decode_chunk(11)", 2000, 1000),
+                        ("jit_decode_chunk(11)", 3500, 100)],
+        "XLA Ops": [("%fusion.1 = bf16[8] fusion(...)", 0, 900)],
+    },
+    "/host:CPU": {
+        "python": [("serve.iter", 900, 1500), ("serve.emit", 1000, 700),
+                   ("serve.admit_pass", 1750, 100),
+                   ("serve.decode.wait", 2450, 500),
+                   ("PjitFunction(decode_chunk)", 1900, 50)],
+        "other": [("serve.iter", 0, 10)],
+    },
+}
+
+
+def test_gaps_are_named_by_the_innermost_annotation():
+    from nnstreamer_tpu.tools import trace as cli
+
+    r = cli.gaps_by_phase(PLANES)
+    assert r["devices"] == 1 and r["host_line"] == ("/host:CPU", "python", 4)
+    assert r["window_s"] == pytest.approx(3600e-9)
+    assert r["idle_s"] == pytest.approx((400 + 300 + 500) * 1e-9)
+    # 1000-1400 lies wholly inside serve.emit; 1700-2000 is split between
+    # serve.iter's own time (1700-1750, 1850-2000) and serve.admit_pass;
+    # 3000-3500 is after every annotation closed
+    assert r["by_next"]["before jit_prefill_step"] == \
+        pytest.approx({"serve.emit": 400e-9})
+    assert r["by_next"]["before jit_decode_chunk"] == pytest.approx(
+        {"serve.iter": 200e-9, "serve.admit_pass": 100e-9,
+         cli.UNNAMED: 500e-9})
+    assert r["by_phase"] == pytest.approx(
+        {"serve.emit": 400e-9, "serve.iter": 200e-9,
+         "serve.admit_pass": 100e-9, cli.UNNAMED: 500e-9})
+    assert sum(r["by_phase"].values()) == pytest.approx(r["idle_s"])
+    assert cli.gaps_by_phase({"/host:CPU": PLANES["/host:CPU"]}) == {
+        "devices": 0, "host_line": ("/host:CPU", "python", 4)}
+
+
+def test_gaps_cli_reads_a_profile_taken_here(tmp_path, capsys):
+    """A profile of a traced toy run, taken with ``profiler.trace``, holds
+    the serve annotations; on the CPU there is no TPU plane, which the
+    subcommand says instead of printing an empty table."""
+    import glob
+
+    from nnstreamer_tpu.tools import trace as cli
+    from nnstreamer_tpu.utils import profiler
+
+    with profiler.trace(str(tmp_path)):
+        _serve("ring")
+    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert files
+    names = {name for lines in cli.load_xplane(files[0]).values()
+             for evs in lines.values() for name, _s, _d in evs}
+    assert names >= TILING | {"serve.iter", "serve.decode.wait"}
+    assert cli.main(["gaps", str(tmp_path)]) == 1
+    assert "no device plane" in capsys.readouterr().err
+    assert cli.main(["gaps", str(tmp_path / "nothing")]) == 1
+
+
+def test_the_second_tracer_is_gone():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    word = "NNSTPU_" + "SERVE_TRACE"
+    hits = []
+    for top in ("nnstreamer_tpu", "tests", "tools", "docs", "benchmark",
+                "examples"):
+        for base, _dirs, files in os.walk(os.path.join(root, top)):
+            for name in files:
+                if name.endswith((".py", ".md", ".json", ".txt", ".ini")):
+                    with open(os.path.join(base, name),
+                              errors="replace") as f:
+                        if word in f.read():
+                            hits.append(os.path.join(base, name))
+    assert hits == []
+    with open(os.path.join(root, "nnstreamer_tpu", "filters",
+                           "llm.py")) as f:
+        src = f.read()
+    assert "_tr(" not in src and "self._span(" not in src
