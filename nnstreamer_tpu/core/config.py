@@ -153,7 +153,8 @@ class Config:
     #: ``full`` = unbounded capture for short profiling runs
     trace_mode: str = "off"
     #: span capacity of the ``ring`` trace mode
-    trace_ring_capacity: int = 65536
+    #: (``utils.tracing.DEFAULT_RING_CAPACITY`` says why this many)
+    trace_ring_capacity: int = 262144
     #: nns-xray predicted-vs-actual reconciliation (utils/xray.py,
     #: docs/OBSERVABILITY.md "Predicted vs actual"): register every jit
     #: entry point's compiles with the live program census, attribute

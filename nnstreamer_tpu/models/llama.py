@@ -11,7 +11,11 @@ C++ runtime.  Here the whole decode loop is a JAX program designed for TPU:
   compile time stays flat as the model deepens, and remat slots in cleanly.
 * **KV cache as a functional carry**: ``[L, B, S_max, H_kv, D]`` bf16
   buffers updated with ``lax.dynamic_update_slice`` at the decode position;
-  one fused XLA program per decode step, weights resident in HBM.
+  one fused XLA program per decode step, weights resident in HBM.  The
+  serving path's block pool (``[L, n_blocks, bs, H_kv, D]``,
+  :func:`forward_paged`) is a carry of the LAYER scan too: each layer
+  scatters its rows into the whole pool in place and attends over it in
+  HBM, so no layer is ever sliced out of the pool or written back.
 * **GQA** (n_kv_heads <= n_heads), **RoPE**, **RMSNorm**, **SwiGLU** — the
   Llama-2/3 block, dims kept multiples of 128 so matmuls tile onto the MXU.
 * **TP via GSPMD**: ``param_pspecs`` shard attention heads and FFN hidden
@@ -806,13 +810,14 @@ def _repeat_kv(x, n_rep: int):
 
 
 def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
-           attn_fn=None, paged_tables=None):
+           attn_fn=None, paged_tables=None, layer=None):
     """One transformer block.  ``kv=(k_cache, v_cache)`` enables cached
     decode (x is the new suffix, written at ``pos_offset``); ``attn_fn``
     overrides plain causal attention (ring attention under shard_map);
-    ``paged_tables`` ([B, max_blocks] int32) switches ``kv`` to the
-    block-pool layout ([n_blocks, bs, Hkv, hd] per layer) with per-row
-    positions — the continuous-serving paged path.
+    ``paged_tables`` ([B, max_blocks] int32) switches ``kv`` to the WHOLE
+    block pool ([L, n_blocks, bs, Hkv, hd], every layer) with per-row
+    positions, of which this block writes and reads layer ``layer`` (a
+    traced scalar) — the continuous-serving paged path.
 
     The sections carry ``jax.named_scope`` names (``attention``,
     ``kv_write``, ``mlp``): metadata only — the scope lands in every
@@ -841,39 +846,51 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
 
     mask = None
     if paged_tables is not None:
-        # Block-pool write + paged attention.  Writes scatter each new
-        # K/V row into (pool block, offset) looked up through the row's
-        # block table; a parked/overshooting position resolves to the
-        # n_blocks sentinel and the write DROPS — idle slots decode
+        # Block-pool write + paged attention, on the whole pool viewed
+        # flat as [L * n_blocks, bs, Hkv, hd] (a reshape of the leading
+        # dims, no data moves): layer l's block j is flat block
+        # l * n_blocks + j, so neither the write nor the read takes a
+        # layer out of the pool (forward_paged says why).  Writes scatter
+        # each new K/V row into (flat block, offset) looked up through the
+        # row's block table; a parked/overshooting position, or a table
+        # entry outside the layer's own [0, n_blocks), resolves to the
+        # L * n_blocks sentinel and the write DROPS — idle slots decode
         # garbage without touching live blocks, recycled blocks can't be
-        # written through a stale (cleared) table.
+        # written through a stale (cleared) table, and nothing lands in
+        # the NEXT layer's blocks, which is where n_blocks would point.
         from ..ops.attention import paged_attention
 
-        k_pool, v_pool = kv  # [n_blocks, bs, Hkv, hd]
-        n_blocks, bs = k_pool.shape[0], k_pool.shape[1]
+        k_pool, v_pool = kv  # [L, n_blocks, bs, Hkv, hd]
+        pool_shape = k_pool.shape
+        n_layers, n_blocks, bs = pool_shape[:3]
+        flat = (n_layers * n_blocks,) + pool_shape[2:]
+        base = layer * n_blocks
         max_blocks = paged_tables.shape[1]
         with jax.named_scope("kv_write"):
             idx = pos_offset[:, None] + jnp.arange(T)[None, :]  # [B, T]
-            valid = (idx >= 0) & (idx < max_blocks * bs)
             slot_blk = jnp.clip(idx // bs, 0, max_blocks - 1)
-            blk = jnp.where(
-                valid,
-                jnp.take_along_axis(paged_tables, slot_blk, axis=1),
-                n_blocks)  # sentinel -> dropped scatter
+            entry = jnp.take_along_axis(paged_tables, slot_blk, axis=1)
+            valid = ((idx >= 0) & (idx < max_blocks * bs)
+                     & (entry >= 0) & (entry < n_blocks))
+            blk = jnp.where(valid, base + entry,
+                            n_layers * n_blocks)  # sentinel -> dropped
             off = idx % bs
-            k_pool = k_pool.at[blk, off].set(k.astype(k_pool.dtype),
-                                             mode="drop")
-            v_pool = v_pool.at[blk, off].set(v.astype(v_pool.dtype),
-                                             mode="drop")
+            k_flat = k_pool.reshape(flat).at[blk, off].set(
+                k.astype(k_pool.dtype), mode="drop")
+            v_flat = v_pool.reshape(flat).at[blk, off].set(
+                v.astype(v_pool.dtype), mode="drop")
         # context = everything written so far incl. this suffix; a parked
         # row (pos >= max_blocks*bs) gets len 0 — the paged kernel then
         # issues ZERO block DMAs for it, which is the whole traffic story
         with jax.named_scope("attention"):
             lens = jnp.where(pos_offset + T <= max_blocks * bs,
                              pos_offset + T, 0).astype(jnp.int32)
-            attn = paged_attention(q, k_pool, v_pool, paged_tables,
+            # sentinel entries clip inside the layer BEFORE the offset:
+            # clipped after it they would name another layer's block
+            tables = base + jnp.clip(paged_tables, 0, n_blocks - 1)
+            attn = paged_attention(q, k_flat, v_flat, tables,
                                    lens).astype(dt)
-        kv = (k_pool, v_pool)
+        kv = (k_flat.reshape(pool_shape), v_flat.reshape(pool_shape))
         # falls through to the shared wo/residual/MLP tail below
     elif kv is not None:
         k_cache, v_cache = kv  # [B, S_max, Hkv, hd]
@@ -1003,7 +1020,14 @@ def init_paged_cache(cfg: LlamaConfig, n_blocks: int, block_size: int,
     continuous serving: streams own BLOCKS (via a per-slot block table),
     not S_max rows, so per-decode-step HBM traffic scales with the sum of
     live sequence lengths (ops/attention.py paged kernel) and a short
-    stream stops paying for the longest one."""
+    stream stops paying for the longest one.
+
+    Layers lead and blocks follow, so the two leading dims merge into
+    one flat block axis without moving data: :func:`forward_paged`
+    addresses layer ``l``'s block ``j`` as flat block ``l * n_blocks +
+    j`` and never takes a layer out of the pool.  Host code indexes
+    ``pool["k"][:, ids]`` (every layer of a block: CoW fork, drain,
+    adopt)."""
     import jax.numpy as jnp
 
     shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads,
@@ -1144,6 +1168,21 @@ def forward_paged(params, tokens, pool, block_tables, pos,
     stream join/leave/retire only changes VALUES, which is what pins the
     continuous loop at zero recompiles.
 
+    The pool is a CARRY of the layer scan, next to ``x``, and the scan
+    yields no per-layer outputs: layer ``l`` scatters its new rows into
+    the whole pool and attends over the whole pool, addressing its own
+    blocks through the flat view ``[L * n_blocks, bs, H_kv, hd]`` at
+    ``l * n_blocks + block`` (:func:`_block`).  A scan's outputs are a
+    fresh buffer, so a pool scanned in as ``xs`` and collected as ``ys``
+    is rebuilt on every call: each layer sliced out, 64 KB written into
+    that copy, the layer written into a second pool, and the second pool
+    copied back over the caller's carry — 2.28 GB moved per decode step
+    in the 7B serving cell (PERF.md §6, PR 27).  Carried, the donated
+    pool is updated in place from the caller's argument to its result;
+    the returned pytree has the argument's shapes and
+    :func:`paged_cache_pspecs` (the reshape merges the two unsharded
+    leading dims only).
+
     ``logit_off`` (traced scalar): return logits for ONLY that suffix
     position — [B, 1, vocab].  A chunked-prefill step needs one
     position's logits (the last REAL token; pad rows fill the chunk
@@ -1158,14 +1197,18 @@ def forward_paged(params, tokens, pool, block_tables, pos,
     x = jnp.asarray(params["embed"]).astype(dt)[tokens]
     positions = pos[:, None] + jnp.arange(T)[None, :]
 
-    def body(x, layer):
-        lp, kc, vc = layer
+    def body(carry, layer):
+        x, kc, vc = carry
+        lp, l = layer
         x, (kc, vc) = _block(cfg, lp, x, positions, kv=(kc, vc),
-                             pos_offset=pos, paged_tables=block_tables)
-        return x, (kc, vc)
+                             pos_offset=pos, paged_tables=block_tables,
+                             layer=l)
+        return (x, kc, vc), None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["layers"], pool["k"], pool["v"]))
+    n_layers = pool["k"].shape[0]
+    (x, k_new, v_new), _ = jax.lax.scan(
+        body, (x, pool["k"], pool["v"]),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
     x = _rmsnorm(x, params["ln_out"], cfg.norm_eps)
     if logit_off is not None:
         x = lax.dynamic_slice_in_dim(x, logit_off, 1, axis=1)

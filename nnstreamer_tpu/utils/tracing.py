@@ -232,7 +232,13 @@ SPAN_KINDS: Dict[str, str] = {
 # are declared in core/meta_keys.py — the shared protocol registry —
 # and re-exported above for the existing importers.
 
-DEFAULT_RING_CAPACITY = 65536
+#: A traced serving pipeline records about four spans for every token it
+#: delivers, so this is what a 7B loop at 1,000 tokens/s on one chip writes
+#: in a minute (at 65,536 the ring held its last 16 s, less than the
+#: benchmark's 45 s window, and the ring readers lost the stretch the
+#: device profile is taken in — PERF.md §6, PR 27).  ≈ 0.4 KB a span, so
+#: 100 MB of host memory when full.
+DEFAULT_RING_CAPACITY = 262144
 
 #: random 31-bit process epoch: the high half of every trace id minted by
 #: this process, so ids from different processes (a query client and its

@@ -37,6 +37,13 @@ def v5e():
         shape, dtype, sharding=sharding)
 
 
+def _abstract(v5e, make):
+    """``make()``'s pytree as shapes placed on the described chip (nothing
+    is built: there is no device to hold an array)."""
+    return jax.tree_util.tree_map(
+        lambda x: v5e(x.shape, x.dtype), jax.eval_shape(make))
+
+
 def _compile(name, fn, *args):
     lowered = jax.jit(fn).lower(*args)
     text = lowered.as_text()
@@ -100,12 +107,9 @@ def test_serve_decode_step_engages_paged_kernel(v5e, monkeypatch):
     cfg = dataclasses.replace(CFG, n_layers=2, max_seq=256)
     slots, bs, n_blocks, max_blocks = 4, 16, 24, 16
 
-    def abstract(make):
-        return jax.tree_util.tree_map(
-            lambda x: v5e(x.shape, x.dtype), jax.eval_shape(make))
-
-    params = abstract(lambda: llama.init_params_int8(cfg, 0, "bfloat16"))
-    pool = abstract(lambda: llama.init_paged_cache(cfg, n_blocks, bs))
+    params = _abstract(
+        v5e, lambda: llama.init_params_int8(cfg, 0, "bfloat16"))
+    pool = _abstract(v5e, lambda: llama.init_paged_cache(cfg, n_blocks, bs))
     lowered = _compile(
         "paged_attention",
         lambda p, tok, pool, tables, pos: llama.forward_paged(
@@ -118,3 +122,86 @@ def test_serve_decode_step_engages_paged_kernel(v5e, monkeypatch):
     for scope in ("attention", "kv_write", "mlp"):
         assert f'loc("{scope}/' in text, scope
     assert "attention/paged_attention" in text
+
+
+# -- the KV pool is carried, not moved (PR 27) ------------------------------
+
+#: the benchmark's serving cell: Mistral-7B geometry (GQA 32/8, ffn 14336),
+#: 32 slots, a pool of 1088 blocks of 16, tables of 4096 / 16 entries;
+#: depth cut to 4
+POOL_CFG = dict(n_layers=4, n_kv_heads=8, ffn_hidden=14336, max_seq=4096)
+POOL_SLOTS, POOL_BS, POOL_BLOCKS, POOL_MAX_BLOCKS = 32, 16, 1088, 256
+
+
+def _decode_program(cfg, v5e):
+    """``filters/llm.py decode_chunk``'s shape: 8 decode steps, the pool
+    carried through the step scan as well."""
+    def decode_chunk(params, pool, tok, tables, pos):
+        def step(carry, _):
+            tok, pool, p = carry
+            logits, pool = llama.forward_paged(
+                params, tok[:, None], pool, tables, p, cfg)
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return (nxt, pool, p + 1), nxt
+
+        (tok, pool, _), toks = jax.lax.scan(
+            step, (tok, pool, pos), None, length=8)
+        return toks, tok, pool
+
+    return decode_chunk, (
+        v5e((POOL_SLOTS,), jnp.int32),
+        v5e((POOL_SLOTS, POOL_MAX_BLOCKS), jnp.int32),
+        v5e((POOL_SLOTS,), jnp.int32))
+
+
+def _prefill_program(cfg, v5e):
+    """``filters/llm.py prefill_step``'s shape: one [1, 32] chunk."""
+    def prefill_step(params, pool, toks, table, pos0, logit_off):
+        logits, pool = llama.forward_paged(
+            params, toks, pool, table, pos0, cfg, logit_off=logit_off)
+        return logits[:, 0], pool
+
+    return prefill_step, (
+        v5e((1, 32), jnp.int32), v5e((1, POOL_MAX_BLOCKS), jnp.int32),
+        v5e((1,), jnp.int32), v5e((), jnp.int32))
+
+
+@pytest.mark.parametrize("program", [_decode_program, _prefill_program],
+                         ids=["decode_chunk", "prefill_step"])
+def test_serve_program_never_moves_the_pool(v5e, monkeypatch, program):
+    """The compiled serve programs write the new K/V rows into the donated
+    pool in place: no operation copies the pool, slices a layer out of it
+    or writes a layer back, and the program holds no second pool among its
+    temporaries.  (A pool scanned as the layer loop's inputs and outputs
+    makes both programs do all three: 2.28 GB of copies per decode step
+    at full depth.)"""
+    import dataclasses
+    import math
+    import re
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(CFG, **POOL_CFG)
+    params = _abstract(
+        v5e, lambda: llama.init_params_int8(cfg, 0, "bfloat16"))
+    pool = _abstract(
+        v5e, lambda: llama.init_paged_cache(cfg, POOL_BLOCKS, POOL_BS))
+    fn, args = program(cfg, v5e)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile()
+
+    layer_elems = math.prod(pool["k"].shape[1:])
+    moved = {layer_elems, cfg.n_layers * layer_elems}
+    # every instruction line, the bodies of fusions included, so that a
+    # fusion whose root (or whose operand of a bitcast root) moves the
+    # pool is found like a bare operation
+    inst = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                      r"(copy|dynamic-slice|dynamic-update-slice)\(")
+    movers = [line.strip()[:160] for line in compiled.as_text().splitlines()
+              for m in [inst.search(line)] if m
+              and math.prod(map(int, m.group(1).split(","))) in moved]
+    assert not movers, "the pool is moved:\n" + "\n".join(movers)
+
+    mem = compiled.memory_analysis()
+    layer_bytes = 2 * layer_elems * pool["k"].dtype.itemsize  # K + V
+    assert mem.temp_size_in_bytes < layer_bytes
+    assert mem.alias_size_in_bytes >= cfg.n_layers * layer_bytes

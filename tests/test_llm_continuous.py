@@ -269,6 +269,144 @@ class TestPagedForward:
         np.testing.assert_array_equal(after[:, mask], before[:, mask])
 
 
+def _forward_paged_by_layer(params, tokens, pool, tables, pos, cfg,
+                            logit_off=None):
+    """The paged forward as it was before the pool became the layer
+    loop's carry, kept here as the plain reference: the pool's layers
+    are the scan's INPUTS and the written layers its OUTPUTS, each layer
+    sees only its own ``[n_blocks, bs, Hkv, hd]`` blocks, and a dropped
+    write aims at ``n_blocks``, one past the layer's own end."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from nnstreamer_tpu.ops.attention import paged_attention
+
+    dt = jnp.float32
+    B, T = tokens.shape
+    H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    x = jnp.asarray(params["embed"]).astype(dt)[tokens]
+    positions = pos[:, None] + jnp.arange(T)[None, :]
+    max_blocks = tables.shape[1]
+
+    def body(x, layer):
+        lp, k_pool, v_pool = layer
+        n_blocks, bs = k_pool.shape[:2]
+        h = llama._rmsnorm(x, lp["ln_attn"], cfg.norm_eps)
+        q = llama._mm(h, lp, "wq", dt).reshape(B, T, H, hd)
+        k = llama._mm(h, lp, "wk", dt).reshape(B, T, Hkv, hd)
+        v = llama._mm(h, lp, "wv", dt).reshape(B, T, Hkv, hd)
+        q = llama._rope(q, positions, cfg.rope_theta)
+        k = llama._rope(k, positions, cfg.rope_theta)
+        idx = pos[:, None] + jnp.arange(T)[None, :]
+        valid = (idx >= 0) & (idx < max_blocks * bs)
+        slot_blk = jnp.clip(idx // bs, 0, max_blocks - 1)
+        blk = jnp.where(
+            valid, jnp.take_along_axis(tables, slot_blk, axis=1), n_blocks)
+        k_pool = k_pool.at[blk, idx % bs].set(k, mode="drop")
+        v_pool = v_pool.at[blk, idx % bs].set(v, mode="drop")
+        lens = jnp.where(pos + T <= max_blocks * bs, pos + T,
+                         0).astype(jnp.int32)
+        attn = paged_attention(q, k_pool, v_pool, tables, lens)
+        x = x + llama._mm(attn.reshape(B, T, H * hd), lp, "wo", dt)
+        h = llama._rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
+        gate = jax.nn.silu(llama._mm(h, lp, "w_gate", dt))
+        x = x + llama._mm(gate * llama._mm(h, lp, "w_up", dt), lp,
+                          "w_down", dt)
+        return x, (k_pool, v_pool)
+
+    x, (k_new, v_new) = lax.scan(
+        body, x, (params["layers"], pool["k"], pool["v"]))
+    x = llama._rmsnorm(x, params["ln_out"], cfg.norm_eps)
+    if logit_off is not None:
+        x = lax.dynamic_slice_in_dim(x, logit_off, 1, axis=1)
+    return llama._lm_head(params, x, dt), {"k": k_new, "v": v_new}
+
+
+class TestPoolCarriedThroughLayers:
+    """``forward_paged`` carries the whole pool through its layer scan
+    and addresses layer l's block j as flat block ``l * n_blocks + j``.
+    It must stay the SAME function as the per-layer formulation, bit for
+    bit, on the logits and on every block of every layer — above all
+    where a write is dropped (a parked row, an unallocated table entry):
+    the per-layer sentinel ``n_blocks`` is block 0 of the NEXT layer in
+    the flat view."""
+
+    BS, MAX_BLOCKS, N_BLOCKS = 4, 8, 12
+
+    def _cfg(self, n_kv_heads):
+        import dataclasses
+
+        # three layers: a middle one has a neighbour on both sides
+        return dataclasses.replace(llama.PRESETS["llama_tiny"], n_layers=3,
+                                   n_kv_heads=n_kv_heads)
+
+    def _pool(self, cfg, rng):
+        import jax.numpy as jnp
+
+        shape = (cfg.n_layers, self.N_BLOCKS, self.BS, cfg.n_kv_heads,
+                 cfg.head_dim)
+        # a pool already full of other streams' rows: a stray write shows
+        return {"k": jnp.asarray(rng.standard_normal(shape), jnp.float32),
+                "v": jnp.asarray(rng.standard_normal(shape), jnp.float32)}
+
+    def _same(self, cfg, tokens, pool, tables, pos, wrote, logit_off=None):
+        """Logits and the whole returned pool equal the reference's, and
+        of the pool's blocks exactly ``wrote`` changed, in EVERY layer."""
+        import jax
+        import jax.numpy as jnp
+
+        params = llama.init_params(cfg, seed=3)
+        args = (params, jnp.asarray(tokens), pool, jnp.asarray(tables),
+                jnp.asarray(pos))
+        ref_lg, ref_pool = jax.jit(
+            lambda *a: _forward_paged_by_layer(*a, cfg, logit_off))(*args)
+        lg, got = jax.jit(lambda *a: llama.forward_paged(
+            *a, cfg, compute_dtype="float32", logit_off=logit_off))(*args)
+        np.testing.assert_array_equal(np.asarray(lg), np.asarray(ref_lg))
+        for name in ("k", "v"):
+            assert got[name].shape == pool[name].shape
+            np.testing.assert_array_equal(np.asarray(got[name]),
+                                          np.asarray(ref_pool[name]))
+        after, before = np.asarray(got["k"]), np.asarray(pool["k"])
+        hit = np.zeros(self.N_BLOCKS, bool)
+        hit[wrote] = True
+        assert (after[:, hit] != before[:, hit]).any(axis=(2, 3, 4)).all()
+        np.testing.assert_array_equal(after[:, ~hit], before[:, ~hit])
+
+    @pytest.mark.parametrize("n_kv_heads", [4, 2], ids=["mha", "gqa"])
+    def test_decode_step_matches_per_layer_reference(self, n_kv_heads):
+        cfg = self._cfg(n_kv_heads)
+        rng = np.random.default_rng(27)
+        pool = self._pool(cfg, rng)
+        S = self.N_BLOCKS  # the unallocated-entry sentinel
+        tables = np.full((4, self.MAX_BLOCKS), S, np.int32)
+        tables[0, :1] = [5]          # row 0 at depth 2: block 5
+        tables[1, :3] = [0, 9, 3]    # row 1 at depth 9: its third block
+        tables[2, :2] = [7, 1]       # row 2 parked: must write nothing
+        tables[3, :1] = [11]         # row 3 at depth 6: a SENTINEL entry
+        pos = np.array([2, 9, self.MAX_BLOCKS * self.BS, 6], np.int32)
+        tokens = rng.integers(1, cfg.vocab, (4, 1), np.int32)
+        # only rows 0 and 1 wrote, each in its own block; the parked row
+        # and the sentinel entry changed no block at all — not block 0 of
+        # the next layer, where n_blocks points in the flat view
+        self._same(cfg, tokens, pool, tables, pos, wrote=[5, 3])
+
+    @pytest.mark.parametrize("n_kv_heads", [4, 2], ids=["mha", "gqa"])
+    def test_prefill_chunk_matches_per_layer_reference(self, n_kv_heads):
+        cfg = self._cfg(n_kv_heads)
+        rng = np.random.default_rng(28)
+        pool = self._pool(cfg, rng)
+        # an 8-token chunk from position 4 crosses blocks 1 and 2 of the
+        # row; its last block is NOT allocated (sentinel): those two rows
+        # of the chunk drop
+        tables = np.full((1, self.MAX_BLOCKS), self.N_BLOCKS, np.int32)
+        tables[0, :2] = [10, 6]
+        tokens = rng.integers(1, cfg.vocab, (1, 8), np.int32)
+        self._same(cfg, tokens, pool, tables, np.array([4], np.int32),
+                   wrote=[6], logit_off=5)
+
+
 class TestPagedAttentionKernel:
     def _case(self, rng, B=4, H=4, hkv=2, D=16, bs=8, n_blocks=16,
               max_blocks=4, lens=(1, 5, 8, 29)):
