@@ -1,7 +1,9 @@
 """Where the benchmark touches the program: the two pipeline strings, and
 the zoo hook through which benchmark-made weights enter as a checkpoint's
 tree would.  Everything else under ``benchmark/`` is the yardstick and
-imports nothing of ``nnstreamer_tpu``.
+imports nothing of ``nnstreamer_tpu``, but for the ``register`` function
+of a model's module (``manifest.py``): a new architecture's registration
+lives there, these two stay here.
 """
 
 from __future__ import annotations
@@ -55,19 +57,21 @@ def forget(name: str) -> None:
 
 
 def serve_pipeline(name: str, cfg: dict, *, max_new: int, kv_blocks: int,
-                   traced: bool):
+                   traced: bool, options: list):
     """The serving path a user types.  Only what sizes the deployment is
-    passed; ``prefill_chunk``, ``prefill_budget`` and ``stream_chunk``
-    stay at the program's defaults, so a PR that finds better ones is
-    measured."""
+    passed, and ``options``, what the model's module says the string has
+    to state of the model (its weight quantization); ``prefill_chunk``,
+    ``prefill_budget`` and ``stream_chunk`` stay at the program's
+    defaults, so a PR that finds better ones is measured."""
     import nnstreamer_tpu as nt
 
     s = cfg["serve"]
-    custom = (f"max_new:{max_new},max_seq:{s['max_seq']},"
-              f"dtype:{cfg['precision']['compute']},quant:int8,"
-              f"serve:continuous,slots:{s['slots']},"
-              f"block_size:{s['block_size']},kv_blocks:{kv_blocks},"
-              "temperature:0.0")
+    custom = ",".join([
+        f"max_new:{max_new}", f"max_seq:{s['max_seq']}",
+        f"dtype:{cfg['precision']['compute']}", *options,
+        "serve:continuous", f"slots:{s['slots']}",
+        f"block_size:{s['block_size']}", f"kv_blocks:{kv_blocks}",
+        "temperature:0.0"])
     return nt.Pipeline(
         f"appsrc name=src ! tensor_filter framework=llm model={name} "
         f"custom={custom} invoke-dynamic=true name=f ! tensor_sink name=out",

@@ -3,7 +3,8 @@
 size: for each seed one run of the cell (its own load, a short window),
 the program's number as the run compares it, and the control's — the
 reference put in the program's place at the next precision below the one
-the configuration states (int4 weights for int8; float8 for bfloat16).
+the configuration states (the ``CONTROL`` of the configuration's model
+module: int4 weights for int8; float8 for bfloat16).
 
     python3 benchmark/control.py --workload <cell> --seeds 1,2,3 --seconds 25
 
@@ -30,8 +31,8 @@ from benchmark.manifest import Manifest  # noqa: E402
 
 def read_seed(manifest, workload: str, seed: int, seconds: float) -> dict:
     cell = manifest.cell(workload)
-    ctx = run.Context(cell, manifest.config(cell), manifest.mix(cell), seed,
-                      seconds, False, manifest.root, time.perf_counter())
+    ctx = run.Context(manifest, cell, seed, seconds, False,
+                      time.perf_counter())
     ctx.compiles.start()
     driver = run.make_driver(ctx)
     driver.load()

@@ -1,1 +1,2 @@
-"""One driver per configuration ``kind``; ``run.py`` picks by that key."""
+"""One driver per configuration ``kind``: ``drivers/<kind>.py`` holds a
+class ``Driver``, which ``manifest.py`` finds by that key."""

@@ -18,12 +18,10 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from .. import adapter, flops, weights
-from ..reference import dense_decoder
-from ..stats import percentile
-from ..traffic import Request, ServeTraffic, rng_for
+from benchmark import adapter
+from benchmark.stats import percentile
+from benchmark.traffic import Request, ServeTraffic, rng_for
 
-MODEL_NAME = "bench_decoder"
 #: how long after the window's close a request due inside it may take to
 #: show its first token before it counts as never answered
 LATE_GRACE_S = 60.0
@@ -40,10 +38,14 @@ class Served:
     client: Optional[int] = None
 
 
-class ServeDriver:
+class Driver:
+    """``ctx.model`` supplies the weights, the registration, the pipeline
+    string's model options and the FLOPs; ``ctx.reference`` the gaps."""
+
     def __init__(self, ctx):
         self.ctx = ctx
         self.cfg, self.mix = ctx.cfg, ctx.mix
+        self.model, self.reference = ctx.model, ctx.reference
         self.traffic = ServeTraffic(self.mix, self.cfg["vocab_size"],
                                     ctx.seed, ctx.seconds)
         self.max_new = self.traffic.max_new
@@ -60,12 +62,16 @@ class ServeDriver:
 
     # -- set-up -------------------------------------------------------------
     def load(self):
-        self.tree = weights.decoder_tree(self.cfg, self.ctx.seed)
-        adapter.register_decoder(MODEL_NAME, self.cfg, self.tree)
-        self.p = adapter.serve_pipeline(
-            MODEL_NAME, self.cfg, max_new=self.max_new,
-            kv_blocks=self.kv_blocks, traced=self.ctx.trace)
+        self.tree = self.model.weights(self.cfg, self.ctx.seed)
+        self.model.register(self.model.ZOO_NAME, self.cfg, self.tree)
+        self.p = self.pipeline()
         self.p.start()
+
+    def pipeline(self):
+        return adapter.serve_pipeline(
+            self.model.ZOO_NAME, self.cfg, max_new=self.max_new,
+            kv_blocks=self.kv_blocks, traced=self.ctx.trace,
+            options=self.model.pipeline_options(self.cfg))
 
     # -- the two threads ----------------------------------------------------
     def _send(self, req: Request, client: Optional[int] = None) -> Served:
@@ -246,12 +252,11 @@ class ServeDriver:
                 out_tokens += 1
                 if i == 0:
                     # its prompt was prefilled just before: charge it here
-                    flop_sum += sum(flops.decoder_flops_per_token(
+                    flop_sum += sum(self.model.flops_per_token(
                         self.cfg, j + 1) for j in range(T))
                 else:
                     gaps.append((t - s.times[i - 1]) * 1e3)
-                    flop_sum += flops.decoder_flops_per_token(self.cfg,
-                                                              T + i)
+                    flop_sum += self.model.flops_per_token(self.cfg, T + i)
                     decoded.append((t, T + i))
             if not closed and s.sent is not None and \
                     w0 <= w0 + s.req.due_s < w1:
@@ -324,7 +329,7 @@ class ServeDriver:
             return checks
         toks, mask = self.token_matrix(sample)
         self._compared = (toks, mask)
-        gap, _ = dense_decoder.served_gaps(self.tree, toks, self.cfg)
+        gap, _ = self.reference.served_gaps(self.tree, toks, self.cfg)
         gap = np.asarray(gap)
         self.ctx.note(f"reference replayed {len(sample)} requests, "
                       f"{int(mask.sum())} served tokens; exact agreement "
@@ -335,14 +340,16 @@ class ServeDriver:
 
     def close(self):
         """Frees the weights (for a process that reads several seeds)."""
-        adapter.forget(MODEL_NAME)
+        adapter.forget(self.model.ZOO_NAME)
         self.tree = self._compared = None
 
     def control_reading(self) -> dict:
         """The control on the prompts and tokens the last run compared:
-        the widest gap of the token that int4 weights put first."""
+        the widest gap of the token that the lower precision (int4 weights
+        for int8) puts first."""
         toks, mask = self._compared
-        gap, _ = dense_decoder.control_gaps(self.tree, toks, self.cfg, 4)
+        gap, _ = self.reference.control_gaps(self.tree, toks, self.cfg,
+                                             **self.model.CONTROL)
         gap = np.asarray(gap)[mask]
         return {"logit_gap_max": float(gap.max()),
                 "exact_agreement": float((gap <= 0).mean())}

@@ -11,19 +11,21 @@ from typing import List
 
 import numpy as np
 
-from .. import adapter, flops, weights
-from ..reference import mobilenet_v1 as reference
-from ..stats import percentile
-from ..traffic import rng_for, stream_frames
+from benchmark import adapter
+from benchmark.stats import percentile
+from benchmark.traffic import rng_for, stream_frames
 
-MODEL_NAME = "bench_mobilenet_v1"
 WARM_BATCHES = 2
 
 
-class StreamDriver:
+class Driver:
+    """``ctx.model`` supplies the weights, the registration and the
+    FLOPs; ``ctx.reference`` the logits."""
+
     def __init__(self, ctx):
         self.ctx = ctx
         self.cfg, self.mix = ctx.cfg, ctx.mix
+        self.model, self.reference = ctx.model, ctx.reference
         self.batch = int(self.mix["batch"])
         self._stop = threading.Event()
         self._errors: List[Exception] = []
@@ -33,12 +35,16 @@ class StreamDriver:
         self.p = None
 
     def load(self):
-        self.tree = weights.mobilenet_v1_tree(self.cfg, self.ctx.seed)
-        adapter.register_mobilenet_v1(MODEL_NAME, self.cfg, self.tree)
-        self.p = adapter.stream_pipeline(MODEL_NAME, self.cfg, self.mix)
+        self.tree = self.model.weights(self.cfg, self.ctx.seed)
+        self.model.register(self.model.ZOO_NAME, self.cfg, self.tree)
+        self.p = self.pipeline()
         self.pool = stream_frames(self.mix, self.cfg["image_size"],
                                   self.ctx.seed)
         self.p.start()
+
+    def pipeline(self):
+        return adapter.stream_pipeline(self.model.ZOO_NAME, self.cfg,
+                                       self.mix)
 
     def _push_loop(self):
         try:
@@ -93,7 +99,7 @@ class StreamDriver:
         observed = {
             "cfg": self.cfg, "window_s": ctx.seconds,
             "flops_in_window": frames
-            * flops.mobilenet_v1_flops_per_frame(self.cfg),
+            * self.model.flops_per_frame(self.cfg),
             "batch_ms": lat, "spans": [],
             "window_ns": ctx.window_ns,
         }
@@ -110,14 +116,19 @@ class StreamDriver:
         """Every answer of the window against the float32 reference's
         logits for the frames it labels, on rows drawn from the seed.
 
-        ``score_err_spread``: the score an answer carries is its class's
+        ``score_err_within``: the score an answer carries is its class's
         logit; its signed distance from the reference's logit for that
-        class is taken over all rows of all answers, and the number is
-        that distance's standard deviation as a share of the mean logit
-        range.  The spread, not the mean: random weights give every seed
-        an offset of its own, common to all its frames (up to 0.2 % of the
-        range in sound runs, as large as the control's), while the
-        frame-to-frame part is what the precision sets."""
+        class is taken over all rows of all answers, each class's mean
+        distance is taken off, and the number is the standard deviation
+        of what is left, as a share of the mean logit range.  The spread,
+        not the mean: random weights give every class of every seed an
+        offset of its own, common to all the frames it labels (up to
+        0.3 % of the range in sound runs, as large as the control's),
+        while the frame-to-frame part is what the precision sets.  Taken
+        over all classes at once, as until PR 28, the spread also holds
+        the distance between the classes' offsets: 0.00293 on a seed whose
+        frames split between two large classes, 0.00103 inside them
+        (PERF.md section 6, PR 28)."""
         limits = self.ctx.limits
         checks = [("answers_malformed", bad_shape,
                    limits["answers_malformed"]),
@@ -129,52 +140,70 @@ class StreamDriver:
         rows = np.sort(rng_for(self.ctx.seed, "check").choice(
             self.batch, min(int(self.mix["check_rows"]), self.batch),
             replace=False))
-        ref = [reference.logits_in_blocks(self.tree, frames[rows])
+        ref = [self.reference.logits_in_blocks(self.tree, frames[rows])
                for frames in self.pool]
         self._compared = (rows, ref)
-        gap_w, signed, agree = 0.0, [], 0
+        gap_w, signed, answered, agree = 0.0, [], [], 0
         for k, ids, scores in answers:
             gap, err = compare(ref[k], ids[rows], scores[rows])
             gap_w = max(gap_w, float(gap.max()))
             signed.append(err)
+            answered.append(ids[rows])
             agree += int((gap <= 0).sum())
         signed = np.concatenate(signed)
+        within = within_classes(signed, np.concatenate(answered))
         classes = len({int(c) for lg in ref for c in lg.argmax(axis=1)})
         self.ctx.note(f"reference labelled {len(rows)} rows of "
                       f"{len(self.pool)} batches into {classes} classes; "
                       f"{len(answers)} answers compared; exact agreement "
                       f"{agree / len(signed):.4f}; widest label gap "
-                      f"{gap_w:.5f} and mean score offset "
-                      f"{float(signed.mean()):+.5f} of the logit range")
-        checks.append(("score_err_spread", _finite(signed.std()),
-                       limits["score_err_spread"]))
+                      f"{gap_w:.5f}, mean score offset "
+                      f"{float(signed.mean()):+.5f} and score spread over "
+                      f"all classes {_finite(signed.std()):.5f} of the "
+                      f"logit range")
+        checks.append(("score_err_within", _finite(within.std()),
+                       limits["score_err_within"]))
         return checks
 
     def close(self):
         """Frees the weights (for a process that reads several seeds)."""
-        adapter.forget(MODEL_NAME)
+        adapter.forget(self.model.ZOO_NAME)
         self.tree = self._compared = None
 
     def control_reading(self) -> dict:
         """The control on the rows the last run compared: the answers
-        float8 compute would give."""
+        the lower precision (float8 compute for bfloat16) would give."""
         rows, ref = self._compared
-        gap_w, signed = 0.0, []
+        gap_w, signed, answered = 0.0, [], []
         for frames, lg in zip(self.pool, ref):
-            low = reference.logits_in_blocks(self.tree, frames[rows],
-                                             compute="float8")
+            low = self.reference.logits_in_blocks(
+                self.tree, frames[rows], **self.model.CONTROL)
             ids = low.argmax(axis=1)
             gap, err = compare(lg, ids, low[np.arange(len(ids)), ids])
             gap_w = max(gap_w, float(gap.max()))
             signed.append(err)
+            answered.append(ids)
         signed = np.concatenate(signed)
-        return {"score_err_spread": _finite(signed.std()),
+        within = within_classes(signed, np.concatenate(answered))
+        return {"score_err_within": _finite(within.std()),
+                "score_err_spread": _finite(signed.std()),
                 "label_gap_max": gap_w,
                 "score_offset": float(signed.mean())}
 
 
 def _finite(x) -> float:
     return float(x) if np.isfinite(x) else 1e9
+
+
+def within_classes(err, ids):
+    """``err`` with the mean over the rows of each answered class taken
+    off; a class with an infinite distance in it stays not finite."""
+    out = np.array(err, np.float64)
+    with np.errstate(invalid="ignore"):
+        for c in np.unique(ids):
+            rows = ids == c
+            out[rows] -= out[rows].mean()
+    return out
 
 
 def compare(ref_logits, ids, scores):

@@ -2,7 +2,13 @@
 steps in the traced span x the int8 bytes of the matrices over the HBM
 peak, divided by the device time of that program's executions.  The true
 bytes are more (cache, activations), so this is a floor and cannot pass
-100 %."""
+100 %.
+
+Tied to ``models/dense_decoder.py``: the bytes are that architecture's
+(``flops.decoder_weight_bytes``: seven int8 matrices a block and the
+head, every one read by every token), 7.11 GB for ``mistral_7b``.  A
+configuration of another architecture is not added to this reader's
+``workloads``; it brings a reader with counts of its own."""
 
 from benchmark import flops
 from benchmark.trace import program_totals

@@ -7,7 +7,13 @@ The profiler's clock is not the host's, so the two ends of the traced span
 are not cut on the token log.  Instead the bytes per decode call are taken
 from the tokens pulled and the ``serve.decode`` spans closed while the
 profiler ran (host clock, same stretch of the window, same contexts), and
-multiplied by the decode calls the trace holds."""
+multiplied by the decode calls the trace holds.
+
+Tied to ``models/dense_decoder.py``: ``flops.kv_bytes_per_token`` counts
+every layer's K and V alike, at every context, and ``is_kernel`` finds the
+kernel by that architecture's shapes.  A configuration whose layers keep
+different state (a window, a latent, a recurrent state) is not added to
+this reader's ``workloads``; it brings a reader of its own."""
 
 from benchmark import flops
 from benchmark.trace import program_totals

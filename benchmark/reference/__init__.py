@@ -1,2 +1,3 @@
-"""Plain float32 references, one per configuration kind, written from the
-published equations.  They import nothing of the program."""
+"""Plain float32 references, one per architecture, written from the
+published equations and found by the ``reference`` key of a
+configuration's file.  They import nothing of the program."""

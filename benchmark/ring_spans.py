@@ -3,21 +3,25 @@
 the serve-loop readers under ``layer_metrics/``.  Everything is cut on the
 measured window ``obs["window_ns"]``, on the spans' own clock.
 
-The ring is bounded (65,536 spans) and a traced pipeline also records
-several spans for every token it delivers, so by the time a 45 s window
-is read its first part has been evicted (PERF.md §6, PR 26: the ring held
-the last 63 iterations of ≈ 130).  A share is therefore taken of the part
-of the window the ring still COVERS, never of the whole window.
+The ring is bounded (262,144 spans since PR 27; 65,536 before) and a
+traced pipeline also records several spans for every token it delivers,
+so by the time a 45 s window is read its first part may have been evicted
+(PERF.md §6, PR 26: the ring of 65,536 held the last 63 iterations of
+≈ 130; the ring of 262,144 holds a whole window up to ≈ 1,400 tokens/s).
+A share is therefore taken of the part of the window the ring still
+COVERS, never of the whole window.
 
-**What that part is.**  In a ``--trace 1`` run the covered part (≈ 22–45 s
-of the window) is the stretch in which ``benchmark/trace.py``'s
+**What that part is.**  In a ``--trace 1`` run whose ring has lost the
+window's first half, the covered part (≈ 22–45 s of the window) is the
+stretch in which ``benchmark/trace.py``'s
 ``stop_trace()`` converts the device trace (24–39 s), and the serve
 loop's Python runs about half as slow again meanwhile: median
 ``serve.emit`` 48 ms against 32 ms in an undisturbed profile, shares of
 11.9–12.5 where the profile says 9.6 (PERF.md §6, PR 26, chip runs).
-Every reader built on this module therefore reads the SLOWED second half
-of the window, on parent and change alike: compare its readings with each
-other, not with an untraced run, and read the baselines again once
+Every reader built on this module then reads the SLOWED second half of the
+window; with the ring of 262,144 it reads the whole window, of which that
+half is still slowed.  Compare readings taken over the same part with
+each other, not with an untraced run, and read the baselines again once
 ``stop_trace`` has moved out of the window (ROADMAP W11f)."""
 
 from __future__ import annotations

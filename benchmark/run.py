@@ -63,11 +63,14 @@ class CompileClock:
 class Context:
     """What a driver needs of the run it belongs to."""
 
-    def __init__(self, cell, cfg, mix, seed, seconds, traced, root,
+    def __init__(self, manifest, cell, seed, seconds, traced,
                  t_start=_T_START):
-        self.cell, self.cfg, self.mix = cell, cfg, mix
+        self.manifest, self.cell = manifest, cell
+        self.cfg, self.mix = manifest.config(cell), manifest.mix(cell)
+        self.model = manifest.model(self.cfg)
+        self.reference = manifest.reference(self.cfg)
         self.seed, self.seconds, self.trace = seed, float(seconds), traced
-        self.limits = cfg["limits"]
+        self.limits = self.cfg["limits"]
         self.first_run_budget_s = FIRST_RUN_BUDGET_S
         self.t_start = t_start
         self.window = None
@@ -78,7 +81,7 @@ class Context:
         if traced:
             span = min(TRACE_SECONDS, self.seconds / 3)
             self.tracer = trace.DeviceTrace(
-                os.path.join(root, ".bench_trace", cell["name"]),
+                os.path.join(manifest.root, ".bench_trace", cell["name"]),
                 at_s=(self.seconds - span) / 2, for_s=span)
 
     def note(self, msg: str):
@@ -142,16 +145,8 @@ def find_chips(need: int) -> dict:
 
 
 def make_driver(ctx):
-    kind = ctx.cfg["kind"]
-    if kind == "serve":
-        from benchmark.drivers.serve import ServeDriver
-
-        return ServeDriver(ctx)
-    if kind == "stream":
-        from benchmark.drivers.stream import StreamDriver
-
-        return StreamDriver(ctx)
-    raise SystemExit(f"benchmark: no driver for configuration kind {kind!r}")
+    """The ``Driver`` of ``drivers/<kind>.py`` over this run."""
+    return ctx.manifest.driver(ctx.cfg)(ctx)
 
 
 def run_cell(manifest: Manifest, workload: str, seed: int, seconds: float,
@@ -159,8 +154,7 @@ def run_cell(manifest: Manifest, workload: str, seed: int, seconds: float,
     """Everything of a run after the look for a chip; returns the result
     line's object."""
     cell = manifest.cell(workload)
-    ctx = Context(cell, manifest.config(cell), manifest.mix(cell), seed,
-                  seconds, traced, manifest.root, t_start)
+    ctx = Context(manifest, cell, seed, seconds, traced, t_start)
     ctx.compiles.start()
     t_enter = time.perf_counter()
     driver = make_driver(ctx)

@@ -21,6 +21,7 @@ import pytest  # noqa: E402
 
 TOY_DECODER = {
     "name": "toy_decoder", "kind": "serve", "source": "a toy for tests",
+    "model": "dense_decoder", "reference": "dense_decoder",
     "hidden_size": 128, "intermediate_size": 256, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
     "vocab_size": 512, "rms_norm_eps": 1e-05, "rope_theta": 10000.0,
@@ -32,12 +33,20 @@ TOY_DECODER = {
 }
 TOY_CLASSIFIER = {
     "name": "toy_mobilenet", "kind": "stream", "source": "a toy for tests",
+    "model": "mobilenet_v1", "reference": "mobilenet_v1",
     "width_multiplier": 1.0, "image_size": 32, "num_classes": 1001,
     "reduced": [], "assumed": {},
     "precision": {"weights": "float32", "compute": "bfloat16"},
-    "limits": {"score_err_spread": 0.0028, "answers_malformed": 0,
+    "limits": {"score_err_within": 0.0028, "answers_malformed": 0,
                "compiles_in_window": 0},
 }
+#: an architecture the benchmark does not know: its model module and its
+#: reference come with it, from ``tests/toy/``
+TOY_TWO_BRANCH = dict(TOY_DECODER, name="toy_two_branch",
+                      model="two_branch_decoder",
+                      reference="two_branch_decoder")
+#: readers that count one architecture's bytes (their docstrings say so)
+TIED_TO_DENSE_DECODER = ("decode_weight_roofline", "paged_attn_roofline")
 TOY_MIXES = {
     "toy_closed": {"kind": "serve",
                    "arrival": {"mode": "closed", "clients": 4},
@@ -56,13 +65,20 @@ TOY_MIXES = {
 
 
 def add_toy_cells(root: str) -> None:
-    """Adds two configurations, three mixes and three cells to the copy
-    of the benchmark at ``root`` — as files and entries only."""
+    """Adds three configurations, one of them of a new architecture with
+    its model module and its reference, three mixes and four cells to the
+    copy of the benchmark at ``root`` — as files and entries only."""
     bench = os.path.join(root, "benchmark")
     path = os.path.join(root, "BENCHMARK.json")
     with open(path) as f:
         doc = json.load(f)
-    for cfg in (TOY_DECODER, TOY_CLASSIFIER):
+    toy = os.path.join(os.path.dirname(os.path.abspath(__file__)), "toy")
+    for group, key in (("models", "model"), ("reference", "reference")):
+        name = TOY_TWO_BRANCH[key] + ".py"
+        with open(os.path.join(toy, group, name)) as src, \
+                open(os.path.join(bench, group, name), "x") as dst:
+            dst.write(src.read())
+    for cfg in (TOY_DECODER, TOY_CLASSIFIER, TOY_TWO_BRANCH):
         rel = f"benchmark/configs/{cfg['name']}.json"
         with open(os.path.join(root, rel), "x") as f:
             json.dump(cfg, f)
@@ -71,16 +87,24 @@ def add_toy_cells(root: str) -> None:
     for name, mix in TOY_MIXES.items():
         with open(os.path.join(bench, "traffic", name + ".json"), "x") as f:
             json.dump(mix, f)
-        config = ("toy_mobilenet" if mix["kind"] == "stream"
-                  else "toy_decoder")
-        cell = f"{config}.{name}"
-        doc["workloads"].append({"name": cell, "config": config,
-                                 "traffic": name, "chips": 1, "why": "toy"})
-        family = "mobilenet_v1" if mix["kind"] == "stream" else "mistral_7b"
-        for m in doc["end_to_end"] + doc["per_layer"]:
-            if any(w.startswith(family + ".") for w in m.get("workloads",
-                                                             [])):
-                m["workloads"].append(cell)
+        if mix["kind"] == "stream":
+            configs, family = ["toy_mobilenet"], "mobilenet_v1"
+        else:
+            configs, family = ["toy_decoder"], "mistral_7b"
+            if name == "toy_closed":
+                configs.append("toy_two_branch")
+        for config in configs:
+            cell = f"{config}.{name}"
+            doc["workloads"].append({"name": cell, "config": config,
+                                     "traffic": name, "chips": 1,
+                                     "why": "toy"})
+            for m in doc["end_to_end"] + doc["per_layer"]:
+                if config == "toy_two_branch" and \
+                        m["name"] in TIED_TO_DENSE_DECODER:
+                    continue
+                if any(w.startswith(family + ".")
+                       for w in m.get("workloads", [])):
+                    m["workloads"].append(cell)
     with open(path, "w") as f:
         json.dump(doc, f)
 

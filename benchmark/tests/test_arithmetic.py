@@ -3,6 +3,7 @@ percentile and the trace reduction."""
 
 import json
 import os
+import sys
 
 import pytest
 
@@ -112,3 +113,21 @@ def test_a_real_trace_loads(tmp_path):
     r = t.read()
     assert r["devices"] == 0 and r["host_span"][1] > r["host_span"][0]
     assert not os.path.exists(str(tmp_path / "tr"))
+
+
+def test_within_classes_takes_each_class_offset_off():
+    """Two classes, each with an offset of its own and the same scatter:
+    what is left is the scatter; an impossible answer stays not finite."""
+    import numpy as np
+
+    from benchmark.manifest import Manifest
+
+    driver = Manifest().driver({"kind": "stream"})
+    within_classes = sys.modules[driver.__module__].within_classes
+    scatter = np.tile([-0.001, 0.001], 50)
+    ids = np.repeat([7, 9], 50)
+    err = scatter + np.where(ids == 7, 0.004, -0.002)
+    assert err.std() > 0.003
+    assert within_classes(err, ids).std() == pytest.approx(0.001)
+    err[3] = np.inf
+    assert not np.isfinite(within_classes(err, ids).std())

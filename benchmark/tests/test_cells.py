@@ -18,7 +18,7 @@ def _check(result, name):
 
 @pytest.mark.parametrize("cell,number", [(SERVE, "logit_gap_max"),
                                          (OPEN, "logit_gap_max"),
-                                         (STREAM, "score_err_spread")])
+                                         (STREAM, "score_err_within")])
 def test_program_agrees_with_the_reference(toy_root, cell, number):
     r = run_toy(toy_root, cell, seed=2**31 + 5)
     assert r["correct"], r["checks"]
@@ -53,10 +53,10 @@ def test_int4_control_is_not_correct(toy_root, seed):
 @pytest.mark.parametrize("seed", [21, 23, 24])
 def test_float8_control_is_not_correct(toy_root, seed):
     row = _control(toy_root, STREAM, seed)
-    assert row["program"]["score_err_spread"] <= 0.0028
-    assert row["control"]["score_err_spread"] > 0.0028
-    assert row["control"]["score_err_spread"] > 3 * row["program"][
-        "score_err_spread"]
+    assert row["program"]["score_err_within"] <= 0.0028
+    assert row["control"]["score_err_within"] > 0.0028
+    assert row["control"]["score_err_within"] > 3 * row["program"][
+        "score_err_within"]
 
 
 def test_an_altered_token_is_not_correct(toy_root, monkeypatch):
@@ -97,7 +97,7 @@ def test_an_altered_answer_is_not_correct(toy_root, monkeypatch):
     assert adapter.register_mobilenet_v1
     r = run_toy(toy_root, STREAM, seed=32)
     assert not r["correct"]
-    assert not _check(r, "score_err_spread")["ok"]
+    assert not _check(r, "score_err_within")["ok"]
 
 
 def test_a_stall_counts_against_every_metric(toy_root, monkeypatch):

@@ -2,6 +2,7 @@
 the rule that a later PR adds files and entries and edits none."""
 
 import hashlib
+import inspect
 import json
 import os
 import re
@@ -10,7 +11,8 @@ import sys
 
 import pytest
 
-from conftest import ROOT, add_toy_cells, copy_benchmark, run_toy
+from conftest import (ROOT, TOY_TWO_BRANCH, add_toy_cells, copy_benchmark,
+                      run_toy)
 
 from benchmark.manifest import Manifest
 
@@ -107,18 +109,96 @@ def _digest(root):
 
 
 def test_a_cell_is_added_as_files_and_entries_only(tmp_path):
-    """A copy of the benchmark gains two configurations, three mixes and
-    three cells; no file that was there changes but BENCHMARK.json, and
-    the new cells are found and run."""
+    """A copy of the benchmark gains three configurations, three mixes and
+    four cells, and with one configuration an architecture it did not
+    know (its model module, its reference); no file that was there changes
+    but BENCHMARK.json, and the new cells are found and run."""
     root = copy_benchmark(str(tmp_path))
     before = _digest(root)
     add_toy_cells(root)
     after = _digest(root)
     changed = {k for k in before if before[k] != after[k]}
     assert changed == {"BENCHMARK.json"}
-    assert len(after) == len(before) + 5
+    assert len(after) == len(before) + 8
     r = run_toy(root, "toy_decoder.toy_closed", seed=3, seconds=1.0)
     assert r["correct"] and r["metrics"]["serve_tok_s"]["value"] > 0
+    cell = "toy_two_branch.toy_closed"
+    r = run_toy(root, cell, seed=3, seconds=1.0)
+    assert r["correct"], r["checks"]
+    assert r["metrics"]["serve_tok_s"]["value"] > 0
+    assert {m["name"] for m in Manifest(root).per_layer(cell)} \
+        >= {"step_mfu.serve", "device_idle.serve", "serve_live_slots"}
+    # against the reference of another architecture it is not correct
+    with open(os.path.join(root, "benchmark/configs/toy_two_branch.json"),
+              "w") as f:
+        json.dump(dict(TOY_TWO_BRANCH, reference="dense_decoder"), f)
+    r = run_toy(root, cell, seed=3, seconds=1.0)
+    assert not r["correct"]
+    assert [c["name"] for c in r["checks"] if not c["ok"]] == [
+        "logit_gap_max"]
+
+
+@pytest.mark.parametrize("look_up,key,group", [
+    ("model", "model", "models"), ("reference", "reference", "reference"),
+    ("driver", "kind", "drivers")])
+def test_a_name_with_no_file_raises_with_those_that_exist(look_up, key,
+                                                          group):
+    m = Manifest(ROOT)
+    with pytest.raises(KeyError) as e:
+        getattr(m, look_up)({key: "nonesuch"})
+    assert "nonesuch" in str(e.value)
+    assert m.names(group) and all(n in str(e.value)
+                                  for n in m.names(group))
+
+
+def test_every_configuration_names_files_that_are_there(doc):
+    """``kind``, ``model`` and ``reference`` of every configuration's file
+    resolve, and each module has what the driver of that kind calls."""
+    m = Manifest(ROOT)
+    asked = {"serve": (("ZOO_NAME", "CONTROL", "weights", "register",
+                        "pipeline_options", "flops_per_token"),
+                       ("served_gaps", "control_gaps")),
+             "stream": (("ZOO_NAME", "CONTROL", "weights", "register",
+                         "flops_per_frame"), ("logits_in_blocks",))}
+    for c in doc["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert isinstance(m.driver(cfg), type)
+        of_model, of_reference = asked[cfg["kind"]]
+        assert all(hasattr(m.model(cfg), n) for n in of_model), c["name"]
+        assert all(hasattr(m.reference(cfg), n) for n in of_reference)
+
+
+def test_run_py_and_the_drivers_name_no_model():
+    """What belongs to one architecture reaches a driver through
+    ``ctx.model`` and ``ctx.reference`` alone: ``run.py`` and the files
+    under ``drivers/`` name no model, no reference, and nothing that
+    ``weights.py`` or ``flops.py`` defines or ``adapter.py`` registers."""
+    from benchmark import adapter, flops, weights
+
+    m = Manifest(ROOT)
+    words = set(m.names("models")) | set(m.names("reference")) | {
+        n for mod in (weights, flops) for n in vars(mod)
+        if not n.startswith("_") and getattr(vars(mod)[n], "__module__",
+                                             None) in (None, mod.__name__)
+        and not inspect.ismodule(vars(mod)[n])
+    } | {n for n in vars(adapter) if n.startswith("register_")}
+    assert {"dense_decoder", "mobilenet_v1", "decoder_tree",
+            "register_decoder", "decoder_flops_per_token",
+            "MOBILENET_V1_BLOCKS"} <= words
+    files = [os.path.join(ROOT, "benchmark", "run.py")] + [
+        os.path.join(ROOT, "benchmark", "drivers", n + ".py")
+        for n in m.names("drivers")]
+    for path in files:
+        with open(path) as f:
+            text = f.read()
+        assert not [w for w in words
+                    if re.search(rf"\b{re.escape(w)}\b", text)], path
+        assert not re.search(
+            r"^\s*(from|import)\s.*\b(weights|flops|reference|models)\b",
+            text, re.M), path
+    with open(files[0]) as f:
+        assert not re.search(r"\bif kind\b|\bkind ==", f.read())
 
 
 def _run(args, cwd, env_extra):
