@@ -39,6 +39,7 @@ token like the reference sub-plugin.
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from typing import Dict, Iterator, List, Optional, Sequence
@@ -207,6 +208,15 @@ def serving_plan(cfg, *, slots: int, block_size: int = 16,
       ``temperature > 0``; 0 for greedy loops.  Tiny, but the xray HBM
       ledger reconciles measured-vs-predicted by category, so an
       unpriced resident buffer is a drift seed.
+    * ``win_ring`` / ``win_blocks`` — a model with window layers keeps
+      their K/V in a second pool that no allocator touches: every slot
+      owns a RING of ``win_ring`` blocks
+      (:func:`~nnstreamer_tpu.models.llama.window_ring_blocks`: the
+      window, one prefill chunk, one block of slack), ``win_blocks =
+      slots * win_ring`` in all; both 0 without window layers.
+      ``pool_bytes`` counts both pools, and
+      ``decode_bytes_per_ctx_token`` only the full-attention layers (a
+      window layer reads at most its window, whatever the context).
     * ``programs`` — compiled XLA signatures the standing loop ever
       uses.  Without speculation: the ``[slots]``-row paged decode
       chunk, the ``[1, prefill_chunk]`` prefill step, and the slot-token
@@ -230,7 +240,8 @@ def serving_plan(cfg, *, slots: int, block_size: int = 16,
     bs = max(1, int(block_size))
     C = max(1, int(prefill_chunk))
     itemsize = 2 if str(dtype) in ("bfloat16", "float16") else 4
-    hd = cfg.dim // cfg.n_heads
+    hd = cfg.head_dim
+    win_ring = _llama.window_ring_blocks(cfg, bs, C)
     pad_max = math.ceil((cfg.max_seq - 1) / C) * C
     # Speculation: the final rounds dispatch the fixed [slots, k+1]-wide
     # verify (and the k-step propose scan) even when fewer tokens remain,
@@ -248,8 +259,11 @@ def serving_plan(cfg, *, slots: int, block_size: int = 16,
     return {
         "max_blocks": max_blocks,
         "n_blocks": n_blocks,
-        "pool_bytes": _llama.paged_cache_bytes(cfg, n_blocks, bs,
-                                               dtype=dtype),
+        "pool_bytes": _llama.paged_cache_bytes(
+            cfg, n_blocks, bs, dtype=dtype,
+            win_blocks=int(slots) * win_ring),
+        "win_ring": win_ring,
+        "win_blocks": int(slots) * win_ring,
         "draft_pool_bytes": (
             _llama.paged_cache_bytes(draft_cfg, n_blocks, bs, dtype=dtype)
             if draft_cfg is not None else 0),
@@ -257,7 +271,7 @@ def serving_plan(cfg, *, slots: int, block_size: int = 16,
         # per-context-token decode read (ops/attention.py shares each
         # block DMA across the whole query-head group)
         "decode_bytes_per_ctx_token": (
-            2 * cfg.n_layers * cfg.n_kv_heads * hd * itemsize),
+            2 * cfg.n_full_layers * cfg.n_kv_heads * hd * itemsize),
         "kv_groups": cfg.n_heads // cfg.n_kv_heads,
         "prng_state_bytes": (int(slots) * 2 * 4
                              if float(temperature) > 0.0 else 0),
@@ -427,6 +441,23 @@ class LLMFramework(Framework):
             )
         self.draft_bundle = None
         self.draft_cfg = None
+        if self.cfg.patterned:
+            # what is not built for a patterned model (layer pattern,
+            # sparse experts, q/k norm) refuses here, with the reason,
+            # instead of serving it wrong (docs/SERVING.md §4e)
+            if not self.continuous:
+                raise FrameworkError(
+                    "a patterned model is served by serve:continuous "
+                    "only: the per-request stream path keeps a dense "
+                    "per-slot cache (forward_cached), which computes the "
+                    "one-kind decoder")
+            if self.draft_name:
+                raise FrameworkError(
+                    "draft: with a patterned target is not built: the "
+                    "k+1-wide verify step writes k+1 positions into a "
+                    "window layer's ring, and a rejected tail would have "
+                    "overwritten rows the window still needs "
+                    "(self-drafting from a prediction head: ROADMAP M1)")
         if self.draft_name:
             if not self.continuous:
                 raise FrameworkError(
@@ -684,9 +715,19 @@ class LLMFramework(Framework):
         pure function of (framework seed, admission number) and every
         draw folds in the absolute token position, never the slot or
         wall-clock step (docs/SERVING.md §4d)."""
+        self._refuse_two_pool("drain_stream")
         if self._serve is None:
             raise FrameworkError("no continuous serve loop is running")
         return self._serve.drain_stream(int(stream_id), timeout)
+
+    def _refuse_two_pool(self, what: str) -> None:
+        if self.cfg is not None and self.cfg.n_window_layers:
+            raise FrameworkError(
+                f"{what} of a two-pool slot is not built: a snapshot "
+                "carries the full-attention layers' blocks only, and a "
+                "window layer's ring (its last window of K/V, per slot) "
+                "has no place in it; continuing from such a snapshot "
+                "would attend an empty window")
 
     def snapshot_problems(self, snapshot: Dict) -> List[str]:
         """Compatibility problems adopting ``snapshot`` here (empty =
@@ -734,6 +775,7 @@ class LLMFramework(Framework):
         remaining tokens with ``stream_index`` continuing where the
         drained pipeline stopped.  Returns the stream id (stable across
         the handover unless it collides with a live local id)."""
+        self._refuse_two_pool("adopt_stream")
         problems = self.snapshot_problems(snapshot)
         if problems:
             raise FrameworkError(
@@ -988,6 +1030,13 @@ class _ContinuousLoop:
                             temperature=temperature)
         self.max_blocks = plan["max_blocks"]
         self.n_blocks = plan["n_blocks"]
+        #: window layers: blocks of a slot's ring, and of the whole
+        #: window pool (0 = the model has no window layer, one pool)
+        self.win_ring = plan["win_ring"]
+        self.win_blocks = plan["win_blocks"]
+        #: whether the decode chunk returns the expert layers' routing
+        #: counts, as extra rows of its token matrix
+        self._moe = cfg.experts is not None
         self.sentinel = self.n_blocks  # unallocated table entry
         self.park = self.max_blocks * bs  # idle-slot position
         self._pending: "_q.Queue" = _q.Queue()
@@ -1054,19 +1103,26 @@ class _ContinuousLoop:
             the per-slot key folds are dead code XLA drops."""
             def step(carry, _):
                 tok, pool, p = carry
-                logits, pool = llama.forward_paged(
+                logits, pool, stats = llama.forward_paged(
                     params, tok[:, None], pool, tables, p, cfg,
-                    compute_dtype=fw.dtype)
+                    compute_dtype=fw.dtype, with_stats=True)
                 with jax.named_scope("sampler"):
                     kstep = slot_keys(keys, p + 1, TAG_SAMPLE)
                     nxt = llama.sample_token_per_slot(
                         logits[:, -1], kstep, temperature, fw.top_k,
                         fw.top_p)
-                return (nxt, pool, p + 1), nxt
+                return (nxt, pool, p + 1), (nxt, stats)
 
-            (tok, pool, _), toks = lax.scan(
+            (tok, pool, _), (toks, stats) = lax.scan(
                 step, (tok, pool, pos), None, length=length)
-            return jnp.moveaxis(toks, 0, 1), tok, pool
+            toks = jnp.moveaxis(toks, 0, 1)  # [B, length]
+            if stats is not None:
+                # the expert layers' counts of each step ride home as
+                # three extra rows of the token matrix: the fetch that
+                # brings the chunk's tokens brings them, no sync of
+                # their own
+                toks = jnp.concatenate([toks, stats.T], axis=0)
+            return toks, tok, pool
 
         self._decode = jax.jit(
             decode_chunk, static_argnames=("length",), donate_argnums=(2,))
@@ -1298,6 +1354,7 @@ class _ContinuousLoop:
         self._stop.set()
         self._wake.set()
         self._thread.join(timeout=30)
+        gc.unfreeze()  # _run_inner froze what was alive at warm-up
         # control callers blocked on a drain/adopt that raced the stop
         # get a prompt named error instead of riding out their timeout
         while self._ctl:
@@ -1349,6 +1406,11 @@ class _ContinuousLoop:
             "blocks_total": self.n_blocks,
             "blocks_free": self.n_blocks if free is None else len(free),
             "live_streams": sum(1 for s in slots if s is not None),
+            # window layers' pool: `win_ring` blocks a slot, for good
+            "win_blocks_total": self.win_blocks,
+            "win_ring": self.win_ring,
+            "win_blocks_live": getattr(self, "_win_blocks_live",
+                                       lambda: 0)(),
             # prefix-sharing accounting: blocks whose content + chain
             # hash are indexed (many resting in the free list at
             # refcount 0), and blocks currently mapped by >1 stream
@@ -1499,7 +1561,8 @@ class _ContinuousLoop:
         B, bs, C = fw.slots, fw.block_size, fw.prefill_chunk
         params = fw.bundle.params
         pool = llama.init_paged_cache(cfg, self.n_blocks, bs,
-                                      dtype=fw.dtype)
+                                      dtype=fw.dtype,
+                                      win_blocks=self.win_blocks)
         d_params = draft_pool = None
         if self._spec:
             d_params = fw.draft_bundle.params
@@ -1606,6 +1669,22 @@ class _ContinuousLoop:
 
         pos = np.full((B,), self.park, np.int32)  # parked = idle
         tables = np.full((B, self.max_blocks), self.sentinel, np.int32)
+        #: window layers: slot s owns blocks [s * ring, (s + 1) * ring)
+        #: of the window pool for good — logical block j of its stream
+        #: lives at ring entry j % ring, so the table never changes and
+        #: a window layer holds `ring` blocks a slot whatever the context
+        win_tables = (np.arange(B, dtype=np.int32)[:, None] * self.win_ring
+                      + np.arange(self.win_ring, dtype=np.int32)[None, :])
+
+        def tabs(rows=slice(None)):
+            """The table argument of a program for the slots ``rows``: a
+            copy of the block table (dispatch is asynchronous, see
+            above), with the ring table beside it where the model has
+            window layers."""
+            if not self.win_ring:
+                return tables[rows].copy()
+            return {"full": tables[rows].copy(), "win": win_tables[rows]}
+
         free = list(range(self.n_blocks))  # host free list (block ids)
         slot_blocks: list = [[] for _ in range(B)]
         #: per-block reference counts: 0 = on the free list, 1 = one
@@ -1633,6 +1712,11 @@ class _ContinuousLoop:
         self._free, self._slot_blocks = free, slot_blocks
         self._ref, self._prefix_index = ref, prefix_index
         self._block_hash = block_hash
+        #: a window layer's K/V live in the ring of the slot that wrote
+        #: them and nowhere else, so on a model with window layers no
+        #: other stream can resume from a cached prefix: every lookup
+        #: is a miss and nothing is indexed (docs/SERVING.md §4e)
+        share_prefix = fw.prefix_cache and not self.win_ring
         remaining = np.zeros((B,), np.int64)
         sidx = np.zeros((B,), np.int64)
         slots: list = [None] * B  # (meta, emit) per live slot
@@ -1796,6 +1880,17 @@ class _ContinuousLoop:
         def delivered() -> int:
             return n_banked + int(sidx.sum())
 
+        def win_blocks_live() -> int:
+            """Ring entries that hold rows of a live stream: a slot at
+            position p has written ``ceil(p / bs)`` logical blocks, of
+            which its ring keeps the last ``ring``."""
+            if not self.win_ring:
+                return 0
+            p = pos[pos < self.park]
+            return int(np.minimum(-(-p // bs), self.win_ring).sum())
+
+        self._win_blocks_live = win_blocks_live
+
         def retire(s: int) -> None:
             nonlocal pos_dev, n_retired, n_banked
             if rec is not None:
@@ -1901,7 +1996,7 @@ class _ContinuousLoop:
         warm_blocks = alloc(min(C, self.n_blocks * bs))
         tables[0, :len(warm_blocks)] = warm_blocks
         logits_w, pool = self._prefill(
-            params, jnp.zeros((1, C), jnp.int32), pool, tables[:1].copy(),
+            params, jnp.zeros((1, C), jnp.int32), pool, tabs(slice(0, 1)),
             pos[:1] * 0, np.int32(C - 1))
         key, sub = jax.random.split(key)
         first_w = llama.sample_token(logits_w, sub, fw.temperature,
@@ -1925,11 +2020,21 @@ class _ContinuousLoop:
             np.asarray(em_w)
         else:
             toks_w, tok, pool = self._decode(
-                params, tok, pool, tables.copy(), pos.copy(), keys_dev,
+                params, tok, pool, tabs(), pos.copy(), keys_dev,
                 length=fw.chunk)
             np.asarray(toks_w)
         release(warm_blocks)
         tables[0, :] = self.sentinel
+
+        # Everything alive now lives as long as the loop does: modules,
+        # jax's internals, the compiled programs.  A full collection
+        # walks all of it — 50-160 ms a pause on the serving cells, two
+        # to seven of them in a 45 s window, each inside the delivery
+        # loop with nothing queued on the chip (my chip runs, PR 29) —
+        # while the garbage the loop makes is young and small.  Frozen,
+        # those objects are walked no more; shutdown() thaws them.
+        gc.collect()
+        gc.freeze()
 
         n_iter = 0  # iterations that progressed: the spans' `iter`
         while not self._stop.is_set():
@@ -2349,7 +2454,7 @@ class _ContinuousLoop:
                 # simply re-prefilled into fresh private blocks.
                 hashes: list = []
                 matched_ids: list = []
-                if fw.prefix_cache:
+                if share_prefix:
                     hashes = chain_cache.get(sid)
                     if hashes is None:
                         hashes = chain_cache[sid] = chain_hashes(
@@ -2468,7 +2573,7 @@ class _ContinuousLoop:
                     off = np.int32(st["T"] - 1 - p if final else 0)
                     logits, pool = self._prefill(
                         params, jnp.asarray(st["prompt"][:, p:p + C]),
-                        pool, tables[s:s + 1].copy(),
+                        pool, tabs(slice(s, s + 1)),
                         np.asarray([p], np.int32), off)
                     if self._spec:
                         # the draft's prefill twin writes the chunk's
@@ -2548,7 +2653,7 @@ class _ContinuousLoop:
                         # this prefill).  Forked/shared blocks' hashes
                         # are already present — only fresh tails
                         # register.
-                        if fw.prefix_cache:
+                        if share_prefix:
                             for j, h in enumerate(st["hashes"]):
                                 if h not in prefix_index:
                                     bid = slot_blocks[s][j]
@@ -2599,7 +2704,7 @@ class _ContinuousLoop:
                     metrics.count("llm.serve.spec_rounds")
                 else:
                     toks_dev, tok, pool = self._decode(
-                        params, tok, pool, tables.copy(), pos.copy(),
+                        params, tok, pool, tabs(), pos.copy(),
                         keys_dev, length=fw.chunk)
                     pos[live] += fw.chunk  # parked rows stay parked
                 progressed = True
@@ -2646,13 +2751,24 @@ class _ContinuousLoop:
                 if rec is not None:
                     sp = begin("serve.decode.wait", iter=it)
                 host = np.asarray(toks_dev)  # ONE roundtrip per chunk
+                moe_args = {}
+                if self._moe:
+                    # rows B..B+2: per step, over the expert layers and
+                    # the live rows — routed pairs the held experts
+                    # computed, held experts hit, most pairs on one
+                    moe = host[B:]
+                    host = host[:B]
+                    moe_args = {"moe_pairs": int(moe[0].sum()),
+                                "moe_experts_hit": int(moe[1].sum()),
+                                "moe_max_per_expert": int(moe[2].max())}
                 if rec is not None:
                     # the decode span closes HERE, at materialization:
                     # the jit call above only enqueued the async
                     # dispatch, so a span closed there would time host
                     # dispatch (~us) and hide the actual device time —
                     # the number the trace exists to attribute
-                    sp = decode_closed("serve.decode", sp, chunk=fw.chunk)
+                    sp = decode_closed("serve.decode", sp, chunk=fw.chunk,
+                                       **moe_args)
                     n_tok0, n_ret0 = delivered(), n_retired
                 for j in range(host.shape[1]):
                     for s in np.flatnonzero(live):
@@ -2747,9 +2863,13 @@ class _ContinuousLoop:
                            retired=n_retired - n_ret0)
 
             if rec is not None:
+                # blocks live in each pool: what the allocator has handed
+                # out, and the ring entries that hold a live stream's rows
                 sp_iter.end(hold=True, live=int(live.sum()),
                             waiting=len(self._waiting)
-                            + len(self._admitting))
+                            + len(self._admitting),
+                            full_blocks=self.n_blocks - len(free),
+                            win_blocks=win_blocks_live())
                 if progressed:
                     n_iter = it
                     sp_iter.commit()

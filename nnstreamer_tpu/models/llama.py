@@ -39,7 +39,65 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..core.types import TensorFormat, TensorsSpec
+from .moe import ExpertsConfig
 from .zoo import ModelBundle, register_model
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer is: its attention (``window`` 0 = causal over all
+    positions, w = over the last w, the token itself included; ``rope``:
+    whether q and k are rotated) and its feed-forward (``dense`` SwiGLU
+    of ``ffn_hidden``, or ``experts``: ``LlamaConfig.experts``)."""
+
+    window: int = 0
+    rope: bool = True
+    ffn: str = "dense"
+
+    def __post_init__(self):
+        if self.ffn not in ("dense", "experts"):
+            raise ValueError(f"ffn kind {self.ffn!r} (dense, experts)")
+        if self.window < 0:
+            raise ValueError(f"window {self.window}")
+
+    @property
+    def name(self) -> str:
+        """The key of this kind's stack under ``params["layers"]``."""
+        return ".".join([f"window{self.window}" if self.window else "full",
+                         "rope" if self.rope else "nope", self.ffn])
+
+
+@dataclasses.dataclass(frozen=True)
+class WalkPlan:
+    """How the layer walk is laid out in a program: the first ``prefix``
+    layers one by one, then ``n_periods`` turns of a scan whose body
+    holds ``period`` layers — ``prefix + period`` copies of the block,
+    however deep the model."""
+
+    prefix: int
+    period: int
+    n_periods: int
+
+
+def walk_plan(kinds) -> WalkPlan:
+    """The plan with the fewest block copies: the shortest prefix +
+    period such that the layers after the prefix repeat with that period
+    (leading layers of another kind, say one dense layer before the
+    sparse ones, go in the prefix with the rest of their period)."""
+    L = len(kinds)
+    best = None
+    for s in range(L + 1):
+        rest = L - s
+        for p in range(1, rest + 1):
+            if rest % p or any(kinds[s + i] != kinds[s + i % p]
+                               for i in range(rest)):
+                continue
+            if best is None or (s + p, p) < best[0]:
+                best = ((s + p, p), WalkPlan(s, p, rest // p))
+            break
+    if best is None or best[1].n_periods == 1:
+        return WalkPlan(L, 0, 0)   # nothing repeats: all in the prefix
+    return best[1]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,10 +111,79 @@ class LlamaConfig:
     max_seq: int = 4096
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
+    #: head width where it is not ``dim / n_heads`` (0 = derived)
+    head_size: int = 0
+    #: RMSNorm over each head of q and k (a learned gain of head width),
+    #: before the rotation
+    qk_norm: bool = False
+    #: one :class:`LayerKind` a layer; () = every layer full, rotated,
+    #: dense — the program such a config compiles to is the one it
+    #: compiled to before patterns existed (a pattern of that one kind
+    #: is normalised to ())
+    pattern: Tuple[LayerKind, ...] = ()
+    experts: Optional[ExpertsConfig] = None
+
+    def __post_init__(self):
+        pat = tuple(self.pattern)
+        if pat and len(pat) != self.n_layers:
+            raise ValueError(f"pattern names {len(pat)} layers, the "
+                             f"model has {self.n_layers}")
+        if pat and all(k == LayerKind() for k in pat):
+            pat = ()
+        object.__setattr__(self, "pattern", pat)
+        if any(k.ffn == "experts" for k in pat) and self.experts is None:
+            raise ValueError("a layer of ffn kind 'experts' needs "
+                             "LlamaConfig.experts")
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_size or self.dim // self.n_heads
+
+    @property
+    def kinds(self) -> Tuple[LayerKind, ...]:
+        return self.pattern or (LayerKind(),) * self.n_layers
+
+    @property
+    def n_window_layers(self) -> int:
+        return sum(1 for k in self.pattern if k.window)
+
+    @property
+    def n_full_layers(self) -> int:
+        return self.n_layers - self.n_window_layers
+
+    @property
+    def max_window(self) -> int:
+        return max((k.window for k in self.pattern), default=0)
+
+    @property
+    def patterned(self) -> bool:
+        """Whether the layers are walked by :func:`_walk_pattern` over
+        per-kind stacks (a pattern, or q/k norm, which the one-kind
+        layout has no leaves for)."""
+        return bool(self.pattern) or self.qk_norm
+
+
+def refuse_pattern(cfg: LlamaConfig, what: str) -> None:
+    """One wording for every path that computes only the one-kind
+    decoder: it refuses a patterned model instead of serving it wrong."""
+    if cfg.patterned:
+        raise NotImplementedError(
+            f"{what} computes the one-kind decoder only (every layer "
+            "full attention, rotated, dense FFN, no q/k norm); this "
+            "model has a layer pattern — serve it with serve:continuous "
+            "or run llama.forward")
+
+
+def window_ring_blocks(cfg: LlamaConfig, block_size: int,
+                       prefill_chunk: int) -> int:
+    """Blocks a slot's ring holds for the window layers (0 without any):
+    the window, one prefill chunk written ahead of it, and one block
+    for where the window starts inside a block.  A window layer never
+    holds more for a slot, whatever the context (docs/SERVING.md)."""
+    if not cfg.max_window:
+        return 0
+    bs = max(1, int(block_size))
+    return (-(-cfg.max_window // bs) + -(-int(prefill_chunk) // bs) + 1)
 
 
 #: Named size presets.  ``llama2_7b`` is the reference benchmark config #5
@@ -70,6 +197,21 @@ PRESETS: Dict[str, LlamaConfig] = {
     "llama_small": LlamaConfig(
         vocab=2048, dim=512, n_layers=4, n_heads=8, n_kv_heads=4,
         ffn_hidden=1024, max_seq=1024,
+    ),
+    # the patterned walk at toy size: window-window-window-full twice,
+    # layer 0 dense and the rest sparse (16 experts, 4 a token, a shared
+    # one, this process holding experts 4..7), heads wider than dim/heads,
+    # q/k norm, no rotation on the full layers
+    "hybrid_moe_tiny": LlamaConfig(
+        vocab=512, dim=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        ffn_hidden=192, max_seq=256, rope_theta=1e6, head_size=32,
+        qk_norm=True,
+        pattern=tuple(
+            LayerKind(window=0 if l % 4 == 3 else 8, rope=l % 4 != 3,
+                      ffn="dense" if l == 0 else "experts")
+            for l in range(8)),
+        experts=ExpertsConfig(n_experts=16, top_k=4, hidden=32, shared=1,
+                              scale=2.5, held_first=4, held_count=4),
     ),
 }
 
@@ -97,6 +239,13 @@ def init_params(cfg: LlamaConfig, seed: int = 0, dtype="float32") -> Dict:
     L, D, H, Hkv, F = (cfg.n_layers, cfg.dim, cfg.n_heads, cfg.n_kv_heads,
                        cfg.ffn_hidden)
     hd = cfg.head_dim
+    if cfg.patterned:
+        return {
+            "embed": norm_init(k_embed, (cfg.vocab, D), D) * 0.5,
+            "layers": _init_stacks(cfg, k_layers, norm_init),
+            "ln_out": np.ones((D,), np.float32),
+            "lm_head": norm_init(k_out, (D, cfg.vocab), D),
+        }
     ks = jax.random.split(k_layers, 7)
     layers = {
         "wq": norm_init(ks[0], (L, D, H * hd), D),
@@ -117,6 +266,74 @@ def init_params(cfg: LlamaConfig, seed: int = 0, dtype="float32") -> Dict:
     }
 
 
+def stack_shapes(cfg: LlamaConfig, kind: LayerKind) -> Dict[str, tuple]:
+    """Leaf name -> shape of ONE layer of ``kind`` (a stack adds the
+    leading axis): the checkpoint layout of a patterned model.  Matrices
+    are ``[in, out]``; ``ln_*``, ``q_norm``/``k_norm``, ``w_router`` and
+    ``router_bias`` are float32 whatever the weights' type."""
+    D, H, Hkv, hd = cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    out = {"wq": (D, H * hd), "wk": (D, Hkv * hd), "wv": (D, Hkv * hd),
+           "wo": (H * hd, D), "ln_attn": (D,), "ln_mlp": (D,)}
+    if cfg.qk_norm:
+        out.update(q_norm=(hd,), k_norm=(hd,))
+    if kind.ffn == "dense":
+        F = cfg.ffn_hidden
+        out.update(w_gate=(D, F), w_up=(D, F), w_down=(F, D))
+    else:
+        ex = cfg.experts
+        E, Fe, Fs = ex.n_held, ex.hidden, ex.shared * ex.hidden
+        out.update(w_router=(D, ex.n_experts),
+                   router_bias=(ex.n_experts,),
+                   we_gate=(E, D, Fe), we_up=(E, D, Fe), we_down=(E, Fe, D))
+        if Fs:
+            out.update(ws_gate=(D, Fs), ws_up=(D, Fs), ws_down=(Fs, D))
+    return out
+
+
+#: leaves kept in float32 (gains, and the router: see models/moe.py)
+F32_LEAVES = ("ln_attn", "ln_mlp", "q_norm", "k_norm", "w_router",
+              "router_bias")
+
+
+def kind_layers(cfg: LlamaConfig) -> Dict[str, List[int]]:
+    """Kind name -> the layers of that kind, in order: layer ``l`` is
+    entry ``kind_layers(cfg)[cfg.kinds[l].name].index(l)`` of its
+    kind's stack."""
+    out: Dict[str, List[int]] = {}
+    for l, kind in enumerate(cfg.kinds):
+        out.setdefault(kind.name, []).append(l)
+    return out
+
+
+def _init_stacks(cfg: LlamaConfig, key, norm_init) -> Dict:
+    """``params["layers"]`` of a patterned model: one stack a kind,
+    ``{kind.name: {leaf: [n_layers_of_kind, ...]}}``."""
+    import jax
+
+    by_name = {k.name: k for k in cfg.kinds}
+    stacks = {}
+    for i, (name, layers) in enumerate(sorted(kind_layers(cfg).items())):
+        shapes = stack_shapes(cfg, by_name[name])
+        ks = jax.random.split(jax.random.fold_in(key, i), len(shapes))
+        n = len(layers)
+        stack = {}
+        for k, (leaf, shape) in zip(ks, sorted(shapes.items())):
+            if leaf == "router_bias":
+                # small and non-zero, so the choice is seen to use it
+                stack[leaf] = 0.02 * jax.random.normal(
+                    k, (n,) + shape, "float32")
+            elif leaf == "w_router":
+                stack[leaf] = jax.random.normal(
+                    k, (n,) + shape, "float32") * np.float32(
+                        shape[0] ** -0.5)
+            elif leaf in F32_LEAVES:
+                stack[leaf] = np.ones((n,) + shape, np.float32)
+            else:
+                stack[leaf] = norm_init(k, (n,) + shape, shape[-2])
+        stacks[name] = stack
+    return stacks
+
+
 def _init_params_quant(cfg: LlamaConfig, seed: int, gen_dtype,
                        qmat, q2d, suffix: str, groups=None) -> Dict:
     """Generate-then-quantize one matrix at a time.
@@ -135,6 +352,7 @@ def _init_params_quant(cfg: LlamaConfig, seed: int, gen_dtype,
     import jax
     import jax.numpy as jnp
 
+    _refuse_quant(cfg)
     dt = jnp.dtype(gen_dtype)
     k_embed, k_layers, k_out = jax.random.split(jax.random.PRNGKey(seed), 3)
 
@@ -657,6 +875,14 @@ def quantize_int8(params: Dict) -> Dict:
     }
 
 
+def _refuse_quant(cfg: LlamaConfig) -> None:
+    if cfg.patterned:
+        raise ValueError(
+            "quant:int8|int4 is weight-only quantization of the one-kind "
+            "decoder's seven matrices; a patterned model's stacks "
+            "(expert matrices among them) have no quantized layout yet")
+
+
 def _apply_quant(params: Dict, opts: Dict) -> Dict:
     """Shared ``custom=quant:...`` handling for the zoo builders."""
     quant = str(opts.get("quant", "")).lower()
@@ -810,7 +1036,8 @@ def _repeat_kv(x, n_rep: int):
 
 
 def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
-           attn_fn=None, paged_tables=None, layer=None):
+           attn_fn=None, paged_tables=None, layer=None, kind=None,
+           park=None, live=None, stats_out=None):
     """One transformer block.  ``kv=(k_cache, v_cache)`` enables cached
     decode (x is the new suffix, written at ``pos_offset``); ``attn_fn``
     overrides plain causal attention (ring attention under shard_map);
@@ -822,7 +1049,15 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
     The sections carry ``jax.named_scope`` names (``attention``,
     ``kv_write``, ``mlp``): metadata only — the scope lands in every
     operation's ``op_name`` in the HLO, so a device trace can be summed
-    by section instead of recognised by result shape (PERF.md §3)."""
+    by section instead of recognised by result shape (PERF.md §3).
+
+    ``kind`` (a :class:`LayerKind`; None = full, rotated, dense) is what
+    THIS layer is in a patterned model.  A window layer's paged cache is
+    a ring: ``paged_tables`` is then the slots' ring table and ``park``
+    the position from which a row is parked (the full table's span —
+    a ring has no such edge of its own).  An expert layer appends its
+    routing counts (models/moe.py ``moe_ffn``, over the rows ``live``
+    marks) to ``stats_out``."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -830,6 +1065,9 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
     B, T, D = x.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = x.dtype
+    window = kind.window if kind is not None else 0
+    attn_scope = ("attn.window" if window else "attn.full") \
+        if kind is not None else None
 
     h = _rmsnorm(x, lp["ln_attn"], cfg.norm_eps)
     if "wqkv_p" in lp:  # int4 fused q|k|v (one kernel call per layer)
@@ -841,8 +1079,12 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         q = _mm(h, lp, "wq", dt).reshape(B, T, H, hd)
         k = _mm(h, lp, "wk", dt).reshape(B, T, Hkv, hd)
         v = _mm(h, lp, "wv", dt).reshape(B, T, Hkv, hd)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    if cfg.qk_norm:
+        q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+        k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+    if kind is None or kind.rope:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
 
     mask = None
     if paged_tables is not None:
@@ -866,11 +1108,16 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         flat = (n_layers * n_blocks,) + pool_shape[2:]
         base = layer * n_blocks
         max_blocks = paged_tables.shape[1]
+        ring = park is not None
+        # positions from `edge` on are parked; a ring's table says
+        # nothing of it, so its caller passes the full table's span
+        edge = park if ring else max_blocks * bs
         with jax.named_scope("kv_write"):
             idx = pos_offset[:, None] + jnp.arange(T)[None, :]  # [B, T]
-            slot_blk = jnp.clip(idx // bs, 0, max_blocks - 1)
+            slot_blk = (idx // bs) % max_blocks if ring \
+                else jnp.clip(idx // bs, 0, max_blocks - 1)
             entry = jnp.take_along_axis(paged_tables, slot_blk, axis=1)
-            valid = ((idx >= 0) & (idx < max_blocks * bs)
+            valid = ((idx >= 0) & (idx < edge)
                      & (entry >= 0) & (entry < n_blocks))
             blk = jnp.where(valid, base + entry,
                             n_layers * n_blocks)  # sentinel -> dropped
@@ -883,13 +1130,19 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         # row (pos >= max_blocks*bs) gets len 0 — the paged kernel then
         # issues ZERO block DMAs for it, which is the whole traffic story
         with jax.named_scope("attention"):
-            lens = jnp.where(pos_offset + T <= max_blocks * bs,
+            lens = jnp.where(pos_offset + T <= edge,
                              pos_offset + T, 0).astype(jnp.int32)
             # sentinel entries clip inside the layer BEFORE the offset:
             # clipped after it they would name another layer's block
             tables = base + jnp.clip(paged_tables, 0, n_blocks - 1)
-            attn = paged_attention(q, k_flat, v_flat, tables,
-                                   lens).astype(dt)
+            if kind is None:
+                attn = paged_attention(q, k_flat, v_flat, tables,
+                                       lens).astype(dt)
+            else:
+                with jax.named_scope(attn_scope):
+                    attn = paged_attention(
+                        q, k_flat, v_flat, tables, lens, window=window,
+                        ring=ring).astype(dt)
         kv = (k_flat.reshape(pool_shape), v_flat.reshape(pool_shape))
         # falls through to the shared wo/residual/MLP tail below
     elif kv is not None:
@@ -936,6 +1189,11 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         with jax.named_scope("attention"):
             attn = attn_fn(q, _repeat_kv(k_all, H // Hkv),
                            _repeat_kv(v_all, H // Hkv))
+    elif window:
+        from ..ops.attention import attention_reference
+
+        with jax.named_scope("attention"), jax.named_scope(attn_scope):
+            attn = attention_reference(q, k, v, causal=True, window=window)
     elif kv is None or prefill:
         # Blockwise flash kernel (Pallas; falls back to plain XLA attention
         # internally when T doesn't tile into its blocks).  K/V go in
@@ -964,6 +1222,13 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
 
     with jax.named_scope("mlp"):
         h = _rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
+        if kind is not None and kind.ffn == "experts":
+            from .moe import moe_ffn
+
+            y, stats = moe_ffn(h, lp, cfg.experts, dt, live=live)
+            if stats_out is not None:
+                stats_out.append(stats)
+            return x + y, kv
         if "wgu_p" in lp:  # int4 fused gate|up
             F = lp["wgu_p"].shape[-1] // 2
             gu = _mm(h, lp, "wgu", dt)
@@ -986,6 +1251,14 @@ def forward(params, tokens, cfg: LlamaConfig, compute_dtype="bfloat16"):
     x = jnp.asarray(params["embed"]).astype(dt)[tokens]
     positions = jnp.arange(T)
 
+    if cfg.patterned:
+        def step(x, lp, kind, _slot):
+            return _block(cfg, lp, x, positions, kind=kind)[0]
+
+        x = _walk_pattern(cfg, params["layers"], x, step)
+        x = _rmsnorm(x, params["ln_out"], cfg.norm_eps)
+        return _lm_head(params, x, dt)
+
     def body(x, lp):
         x, _ = _block(cfg, lp, x, positions)
         return x, None
@@ -993,6 +1266,66 @@ def forward(params, tokens, cfg: LlamaConfig, compute_dtype="bfloat16"):
     x, _ = jax.lax.scan(body, x, params["layers"])
     x = _rmsnorm(x, params["ln_out"], cfg.norm_eps)
     return _lm_head(params, x, dt)
+
+
+def _walk_pattern(cfg: LlamaConfig, stacks, carry, step):
+    """Threads ``carry`` through every layer of a patterned model:
+    ``step(carry, lp, kind, slot) -> carry`` with ``lp`` the layer's
+    leaves taken out of its kind's stack and ``slot`` the layer's index
+    among the layers of its attention class (full or window) — its layer
+    in that class's cache pool.  Laid out by :func:`walk_plan`: the
+    prefix one by one, then a scan over the periods, so a deep model is
+    ``prefix + period`` copies of the block, not ``n_layers``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    kinds, plan = cfg.kinds, walk_plan(cfg.kinds)
+    # where[l]: layer l's entry in its kind's stack, and its layer in its
+    # attention class's pool
+    where, seen = [], {}
+    for kind in kinds:
+        pair = []
+        for key in (kind.name, bool(kind.window)):
+            pair.append(seen.get(key, 0))
+            seen[key] = pair[-1] + 1
+        where.append(tuple(pair))
+
+    def take(name, i):
+        """Layer ``i`` of a kind's stack; the expert matrices stay whole
+        and go with the index (models/moe.py says why)."""
+        from .moe import STACKED_LEAVES
+
+        def one(a):
+            if isinstance(i, int):
+                return a[i]
+            return lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+
+        lp = {leaf: a if leaf in STACKED_LEAVES else one(a)
+              for leaf, a in stacks[name].items()}
+        lp["_layer"] = i
+        return lp
+
+    s0, p = plan.prefix, plan.period
+    for kind, (i, slot) in zip(kinds[:s0], where[:s0]):
+        carry = step(carry, take(kind.name, i), kind, slot)
+    if not plan.n_periods:
+        return carry
+
+    def body(carry, t):
+        # layer j of period t: its place in the first period, plus t
+        # times what one period adds (n_periods >= 2: the second period
+        # is there to read that off)
+        for j in range(p):
+            (i0, slot0), (i1, slot1) = where[s0 + j], where[s0 + p + j]
+            kind = kinds[s0 + j]
+            carry = step(carry, take(kind.name, i0 + t * (i1 - i0)), kind,
+                         slot0 + t * (slot1 - slot0))
+        return carry, None
+
+    carry, _ = lax.scan(body, carry,
+                        jnp.arange(plan.n_periods, dtype=jnp.int32))
+    return carry
 
 
 def init_cache(cfg: LlamaConfig, batch: int, dtype="bfloat16"):
@@ -1013,7 +1346,7 @@ def cache_pspecs() -> Dict:
 # -- block-paged KV cache (continuous serving) ------------------------------
 
 def init_paged_cache(cfg: LlamaConfig, n_blocks: int, block_size: int,
-                     dtype="bfloat16"):
+                     dtype="bfloat16", win_blocks: int = 0):
     """Block-pool KV cache: k/v of [L, n_blocks, block_size, H_kv, head_dim].
 
     The pool replaces the dense per-slot [L, B, S_max, ...] cache for
@@ -1027,12 +1360,23 @@ def init_paged_cache(cfg: LlamaConfig, n_blocks: int, block_size: int,
     addresses layer ``l``'s block ``j`` as flat block ``l * n_blocks +
     j`` and never takes a layer out of the pool.  Host code indexes
     ``pool["k"][:, ids]`` (every layer of a block: CoW fork, drain,
-    adopt)."""
+    adopt).
+
+    A model with window layers gets a pool a layer CLASS: ``k``/``v``
+    hold the full-attention layers (``n_blocks`` blocks each, handed out
+    by the allocator as before), ``k_win``/``v_win`` the window layers
+    (``win_blocks`` blocks each: ``slots`` rings of
+    :func:`window_ring_blocks`, which no allocator touches)."""
     import jax.numpy as jnp
 
-    shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads,
-             cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    tail = (block_size, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_full_layers, n_blocks) + tail
+    pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if cfg.n_window_layers:
+        wshape = (cfg.n_window_layers, max(1, int(win_blocks))) + tail
+        pool["k_win"] = jnp.zeros(wshape, dtype)
+        pool["v_win"] = jnp.zeros(wshape, dtype)
+    return pool
 
 
 def paged_cache_pspecs() -> Dict:
@@ -1064,6 +1408,11 @@ def tp_divisibility_problems(cfg: LlamaConfig, tp: int) -> List[str]:
     static ``model-divisibility`` diagnostic must agree on."""
     if tp <= 1:
         return []
+    if cfg.patterned:
+        return ["a patterned model (layer pattern, sparse experts, q/k "
+                "norm) has no tensor-parallel layout: its experts divide "
+                "by expert parallelism (ROADMAP M2), its stacks have no "
+                "param_pspecs"]
     probs: List[str] = []
     if (cfg.n_heads * cfg.head_dim) % tp:
         probs.append(f"attention out dim n_heads*head_dim="
@@ -1082,13 +1431,17 @@ def tp_divisibility_problems(cfg: LlamaConfig, tp: int) -> List[str]:
 
 
 def paged_cache_bytes(cfg: LlamaConfig, n_blocks: int, block_size: int,
-                      dtype="bfloat16") -> int:
-    """Static HBM footprint of :func:`init_paged_cache` (k + v), without
-    building anything — the deep-lint resource report prices the pool
-    through this, so the arithmetic lives next to the allocation."""
+                      dtype="bfloat16", win_blocks: int = 0) -> int:
+    """Static HBM footprint of :func:`init_paged_cache` (k + v, both
+    layer classes), without building anything — the deep-lint resource
+    report prices the pool through this, so the arithmetic lives next to
+    the allocation."""
     itemsize = 2 if str(dtype) in ("bfloat16", "float16") else 4
-    return (2 * cfg.n_layers * n_blocks * block_size * cfg.n_kv_heads
-            * cfg.head_dim * itemsize)
+    blocks = cfg.n_full_layers * n_blocks
+    if cfg.n_window_layers:
+        blocks += cfg.n_window_layers * max(1, int(win_blocks))
+    return (2 * blocks * block_size * cfg.n_kv_heads * cfg.head_dim
+            * itemsize)
 
 
 def resolve_config(model: str, opts: Dict) -> Optional[LlamaConfig]:
@@ -1114,6 +1467,16 @@ def param_bytes_estimate(cfg: LlamaConfig, quant: str = "",
     (no weights built): the seven big layer mats + lm_head at the quant
     width (int8 1 B + f32 scales, int4 0.5 B + scales, else the param
     dtype's width), embed at param dtype, norms f32."""
+    if cfg.patterned:
+        if str(quant):
+            _refuse_quant(cfg)
+        itemsize = 2 if str(param_dtype) in ("bfloat16", "float16") else 4
+        total = 2 * cfg.vocab * cfg.dim * itemsize + 4 * cfg.dim
+        for kind in cfg.kinds:
+            for leaf, shape in stack_shapes(cfg, kind).items():
+                total += int(np.prod(shape)) * (
+                    4 if leaf in F32_LEAVES else itemsize)
+        return total
     L, D, H, Hkv, F = (cfg.n_layers, cfg.dim, cfg.n_heads, cfg.n_kv_heads,
                        cfg.ffn_hidden)
     hd = cfg.head_dim
@@ -1155,7 +1518,7 @@ def param_bytes_split(cfg: LlamaConfig, quant: str = "",
 
 def forward_paged(params, tokens, pool, block_tables, pos,
                   cfg: LlamaConfig, compute_dtype="bfloat16",
-                  logit_off=None):
+                  logit_off=None, with_stats=False):
     """Forward a suffix against the block-paged KV pool.
 
     ``tokens``: [B, T] (T == 1 for the continuous decode step, B == 1 with
@@ -1187,7 +1550,11 @@ def forward_paged(params, tokens, pool, block_tables, pos,
     position — [B, 1, vocab].  A chunked-prefill step needs one
     position's logits (the last REAL token; pad rows fill the chunk
     tail), and slicing before the lm_head keeps the vocab matmul at one
-    row instead of T."""
+    row instead of T.
+
+    ``with_stats``: also return the expert layers' routing counts of
+    this step, int32 [3] (models/moe.py; None for a model without
+    experts)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -1196,6 +1563,42 @@ def forward_paged(params, tokens, pool, block_tables, pos,
     B, T = tokens.shape
     x = jnp.asarray(params["embed"]).astype(dt)[tokens]
     positions = pos[:, None] + jnp.arange(T)[None, :]
+
+    if cfg.patterned:
+        # A pool and a table a layer class: ``block_tables`` is then
+        # ``{"full": [B, max_blocks], "win": [B, ring]}`` (the array
+        # alone where no layer has a window).  Both pools are carries of
+        # the walk, like the one pool below.
+        from .moe import merge_stats
+
+        tabs = block_tables if isinstance(block_tables, dict) \
+            else {"full": block_tables}
+        park = tabs["full"].shape[1] * pool["k"].shape[2]
+        live = pos < park
+
+        def step(carry, lp, kind, slot):
+            x, pl, stats = carry
+            suffix = "_win" if kind.window else ""
+            got: list = []
+            x, (kc, vc) = _block(
+                cfg, lp, x, positions,
+                kv=(pl["k" + suffix], pl["v" + suffix]), pos_offset=pos,
+                paged_tables=tabs["win" if kind.window else "full"],
+                layer=slot, kind=kind, park=park if kind.window else None,
+                live=live, stats_out=got)
+            pl = dict(pl, **{"k" + suffix: kc, "v" + suffix: vc})
+            if got:
+                stats = merge_stats(stats, got[0])
+            return x, pl, stats
+
+        stats0 = jnp.zeros((3,), jnp.int32) if cfg.experts else None
+        x, pool, stats = _walk_pattern(
+            cfg, params["layers"], (x, dict(pool), stats0), step)
+        x = _rmsnorm(x, params["ln_out"], cfg.norm_eps)
+        if logit_off is not None:
+            x = lax.dynamic_slice_in_dim(x, logit_off, 1, axis=1)
+        out = _lm_head(params, x, dt), pool
+        return out + (stats,) if with_stats else out
 
     def body(carry, layer):
         x, kc, vc = carry
@@ -1212,7 +1615,8 @@ def forward_paged(params, tokens, pool, block_tables, pos,
     x = _rmsnorm(x, params["ln_out"], cfg.norm_eps)
     if logit_off is not None:
         x = lax.dynamic_slice_in_dim(x, logit_off, 1, axis=1)
-    return _lm_head(params, x, dt), {"k": k_new, "v": v_new}
+    out = _lm_head(params, x, dt), {"k": k_new, "v": v_new}
+    return out + (None,) if with_stats else out
 
 
 def forward_cached(params, tokens, cache, pos_offset, cfg: LlamaConfig,
@@ -1226,6 +1630,7 @@ def forward_cached(params, tokens, cache, pos_offset, cfg: LlamaConfig,
     import jax
     import jax.numpy as jnp
 
+    refuse_pattern(cfg, "forward_cached (the dense per-slot cache)")
     dt = jnp.dtype(compute_dtype)
     B, T = tokens.shape
     x = jnp.asarray(params["embed"]).astype(dt)[tokens]
@@ -1261,6 +1666,7 @@ def forward_seq_parallel(mesh, params, tokens, cfg: LlamaConfig,
 
     from ..parallel.ring import ring_attention_local
 
+    refuse_pattern(cfg, "forward_seq_parallel (ring attention)")
     n_seq = int(mesh.shape.get("seq", 1))
     if n_seq <= 1:
         return forward(params, tokens, cfg, compute_dtype)
@@ -1416,6 +1822,8 @@ def _build(preset: str, opts: Dict[str, str]) -> ModelBundle:
     # device (required to fit 7B in one chip's HBM); default float32 keeps
     # the test presets' numerics unchanged.
     quant = str(opts.get("quant", "")).lower()
+    if quant and cfg.patterned:
+        _refuse_quant(cfg)
     if quant in ("int8", "int4"):
         # per-mat generate+quantize+donate: the full-precision tree is
         # never resident, so quantized 7B fits where generate-everything-
@@ -1438,7 +1846,10 @@ def _build(preset: str, opts: Dict[str, str]) -> ModelBundle:
         format=TensorFormat.FLEXIBLE)
     bundle = ModelBundle(
         apply_fn=apply_fn, params=params, in_spec=in_spec, out_spec=out_spec,
-        param_pspecs=param_pspecs(quant=quant), name=preset,
+        # a patterned model's stacks have no TP layout
+        # (tp_divisibility_problems refuses model_parallel > 1)
+        param_pspecs=None if cfg.patterned else param_pspecs(quant=quant),
+        name=preset,
     )
     bundle.config = cfg  # used by the llm framework for the decode loop
     return bundle

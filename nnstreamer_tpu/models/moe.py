@@ -1,0 +1,187 @@
+"""Sparse-expert feed-forward layer that knows which experts it holds.
+
+A router scores every token against ALL ``n_experts`` and picks
+``top_k`` of them; this process holds the contiguous share
+``[held_first, held_first + held_count)`` of the routed experts (its
+rank's share under expert parallelism) plus the shared expert, which
+every rank holds.  The layer returns
+
+    sum over chosen experts i that are HELD of w_i * E_i(h)  +  E_shared(h)
+
+— the PARTIAL result of this rank.  What the experts held elsewhere
+would have added is added by the exchange between ranks (``parallel/``,
+not built yet: ROADMAP M2); on one chip the layer runs without it and
+nothing here stands in for the absent ranks.  With the whole set held
+(``held_count == n_experts``) the partial result is the layer.
+
+Routing is DROPLESS: there is no capacity factor and no token is ever
+skipped.  Inside the serve loop's fixed shapes the routed (token,
+expert) pairs — ``tokens * top_k`` rows, a static bound — are sorted by
+expert, pairs of experts held elsewhere last, and the three expert
+matrices are applied as a grouped product (``jax.lax.ragged_dot``) whose
+group sizes are VALUES: how the tokens spread over the experts changes
+no shape, so nothing recompiles.  Rows past the held groups belong to
+no expert; what the product leaves there is not read.
+
+Weights of one layer (``lp``): ``w_router`` [D, E] and ``router_bias``
+[E] float32 (the router runs in float32: a choice among near-equal
+scores must not depend on bf16 rounding); ``we_gate``, ``we_up`` [held,
+D, F], ``we_down`` [held, F, D]; ``ws_gate``, ``ws_up`` [D, Fs],
+``ws_down`` [Fs, D] with ``Fs = shared * F``.
+
+**The expert matrices are never sliced out of their stack.**  A grouped
+product is a custom call, and a slice that feeds one is materialized:
+taking layer ``l``'s ``[held, D, F]`` out of a kind's ``[n, held, D,
+F]`` stack copied 0.4 GB a matrix a layer in the decode program (4.2 GB
+of temporaries at 8 layers; chipless v5e compile, PR 29).  So the walk
+hands the WHOLE stack over (``STACKED_LEAVES``) with the layer's index
+``lp["_layer"]``, the stack is viewed as ``n * held`` groups (a reshape
+of the leading dims, no data moves), and the layer's group sizes are
+written at ``[l * held, (l + 1) * held)`` of a vector that is zero
+elsewhere: groups of size zero own no row and are not read.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+
+#: leaves :func:`moe_ffn` takes as the kind's whole stack (see above)
+STACKED_LEAVES = ("we_gate", "we_up", "we_down")
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertsConfig:
+    """The sparse FFN of a model: ``n_experts`` routed experts of width
+    ``hidden``, ``top_k`` a token, ``shared`` always-on experts of the
+    same width; scores are sigmoids, with a per-expert correction bias
+    added FOR THE CHOICE ONLY (``scoring`` names the one kind built, so a
+    config that states another is refused); ``norm_topk`` divides the
+    chosen weights by their sum; ``scale`` multiplies them.
+    ``held_first``/``held_count`` name this process's share
+    (``held_count`` 0 = all)."""
+
+    n_experts: int
+    top_k: int
+    hidden: int
+    shared: int = 0
+    scoring: str = "sigmoid"
+    norm_topk: bool = True
+    scale: float = 1.0
+    held_first: int = 0
+    held_count: int = 0
+
+    def __post_init__(self):
+        if self.scoring != "sigmoid":
+            raise ValueError(f"expert scoring {self.scoring!r}: only "
+                             "sigmoid scores are built")
+        if not 0 < self.top_k <= self.n_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.n_experts} experts")
+        if self.held_first < 0 or \
+                self.held_first + self.n_held > self.n_experts:
+            raise ValueError(
+                f"held experts [{self.held_first}, "
+                f"{self.held_first + self.n_held}) outside the "
+                f"{self.n_experts} routed")
+
+    @property
+    def n_held(self) -> int:
+        return self.held_count or self.n_experts
+
+
+def route(h, lp, ex: ExpertsConfig):
+    """The router over ALL experts: ``h`` [N, D] -> (``idx`` [N, k] the
+    chosen experts, ``w`` [N, k] float32 their weights, normalised over
+    all k chosen wherever they live)."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("moe.router"):
+        logits = jnp.dot(h.astype(jnp.float32),
+                         lp["w_router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + lp["router_bias"].astype(jnp.float32),
+                               ex.top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if ex.norm_topk:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return idx, w * ex.scale
+
+
+def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
+    """``h`` [B, T, D] (already normed) -> (this rank's partial FFN
+    output [B, T, D], ``stats``).  ``stats`` is int32 [3] — routed pairs
+    computed by held experts, held experts hit, most pairs on one expert
+    — over the rows ``live`` [B] marks (all when None); it is what the
+    serve loop's ``serve.decode`` span reports."""
+    import jax
+    import jax.nn as jnn
+    import jax.numpy as jnp
+
+    B, T, D = h.shape
+    N, k, E = B * T, ex.top_k, ex.n_held
+    x = h.reshape(N, D)
+    idx, w = route(x, lp, ex)
+
+    with jax.named_scope("moe.experts"):
+        # pairs sorted by local expert; an expert held elsewhere sorts
+        # last (local id E) and belongs to no group
+        local = idx - ex.held_first
+        held = (local >= 0) & (local < E)
+        local = jnp.where(held, local, E).reshape(N * k)
+        order = jnp.argsort(local, stable=True)
+        tok = order // k
+        counts = jnp.zeros((E + 1,), jnp.int32).at[local].add(1)
+        sizes = groups = counts[:E]
+        we = {leaf: lp[leaf] for leaf in STACKED_LEAVES}
+        if we["we_gate"].ndim == 4:   # the kind's stack: [n, E, ., .]
+            n = we["we_gate"].shape[0]
+            we = {leaf: a.reshape((n * E,) + a.shape[2:])
+                  for leaf, a in we.items()}
+            groups = jax.lax.dynamic_update_slice(
+                jnp.zeros((n * E,), jnp.int32), sizes,
+                (jnp.asarray(lp["_layer"], jnp.int32) * E,))
+        xs = x[tok].astype(dt)
+        gate = jax.lax.ragged_dot(xs, we["we_gate"].astype(dt), groups)
+        up = jax.lax.ragged_dot(xs, we["we_up"].astype(dt), groups)
+        y = jax.lax.ragged_dot((jnn.silu(gate) * up).astype(dt),
+                               we["we_down"].astype(dt), groups,
+                               preferred_element_type=jnp.float32)
+        # rows past the groups belong to no expert: whatever the grouped
+        # product left there is not read
+        hs = held.reshape(N * k)[order]
+        wp = w.reshape(N * k)[order]
+        routed = jnp.zeros((N, D), jnp.float32).at[tok].add(
+            jnp.where(hs[:, None], y * wp[:, None], 0.0))
+
+    with jax.named_scope("moe.shared"):
+        out = routed
+        if ex.shared:
+            g = jnn.silu(x.astype(dt) @ lp["ws_gate"].astype(dt))
+            u = x.astype(dt) @ lp["ws_up"].astype(dt)
+            out = out + ((g * u) @ lp["ws_down"].astype(dt)).astype(
+                jnp.float32)
+
+    # what the span reports, over live rows only: a parked slot decodes
+    # garbage whose routing nobody asked for
+    if live is None:
+        lcounts = sizes
+    else:
+        lheld = held & jnp.repeat(live, T)[:, None]
+        lcounts = jnp.zeros((E + 1,), jnp.int32).at[
+            jnp.where(lheld, idx - ex.held_first, E).reshape(N * k)
+        ].add(1)[:E]
+    stats = jnp.stack([lcounts.sum(), (lcounts > 0).sum(),
+                       lcounts.max()]).astype(jnp.int32)
+    return out.astype(dt).reshape(B, T, D), stats
+
+
+def merge_stats(a: Optional[object], b):
+    """Sums pairs and experts hit, keeps the largest per-expert load."""
+    import jax.numpy as jnp
+
+    if a is None:
+        return b
+    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2])])

@@ -80,8 +80,12 @@ def _repeat_kv_heads(x, n_rep: int):
             b, s, hkv * n_rep, d)
 
 
-def attention_reference(q, k, v, *, causal: bool = False, scale: Optional[float] = None):
+def attention_reference(q, k, v, *, causal: bool = False,
+                        scale: Optional[float] = None, window: int = 0):
     """Plain-XLA attention (the flash kernel's semantics, materialized).
+
+    ``window`` > 0 (causal only): query at position p attends positions
+    ``p - window + 1 .. p``, the token itself included.
 
     Accepts grouped K/V (``k.shape[2]`` dividing ``q.shape[2]``) and
     repeats internally — XLA fuses the broadcast into the einsum, so the
@@ -101,7 +105,10 @@ def attention_reference(q, k, v, *, causal: bool = False, scale: Optional[float]
         # kv may be longer than q (prefix/cache): align q to the BACK of kv.
         qpos = jnp.arange(sq)[:, None] + (sk - sq)
         kpos = jnp.arange(sk)[None, :]
-        s = jnp.where(kpos <= qpos, s, -jnp.inf)
+        keep = kpos <= qpos
+        if window:
+            keep &= kpos > qpos - window
+        s = jnp.where(keep, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
@@ -290,7 +297,8 @@ def flash_attention(
 # ---------------------------------------------------------------------------
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
-                              *, scale: Optional[float] = None):
+                              *, scale: Optional[float] = None,
+                              window: int = 0, ring: bool = False):
     """Plain-XLA paged attention (the kernel's semantics, materialized).
 
     ``q``: [B, T, H, D] query suffix (T=1 decode, T=C prefill chunk);
@@ -307,6 +315,13 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
     the path that only touches live blocks) and applies EXACTLY the dense
     masked-decode formulation from models/llama.py so paged and dense
     caches emit identical greedy tokens.
+
+    ``window`` > 0: query at position p attends ``p - window + 1 .. p``
+    only.  ``ring``: the table is a ring of ``R = max_blocks`` entries —
+    logical block j lives at entry ``j % R`` and an entry holds the
+    LATEST logical block written to it; the caller sizes R so that every
+    position a query of this call may attend is still there (models/
+    llama.py ``window_ring_blocks``).
     """
     B, T, H, D = q.shape
     n_blocks, bs, hkv, _ = k_pool.shape
@@ -325,8 +340,23 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
         v_all = jnp.broadcast_to(
             v_all[:, :, :, None, :], (B, S, hkv, rep, D)).reshape(B, S, H, D)
     q_pos = (context_lens[:, None] - T) + jnp.arange(T)[None, :]  # [B, T]
-    k_pos = jnp.arange(k_all.shape[1])
-    mask = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None]
+    if ring:
+        # entry r holds logical block hi - ((hi - r) mod R), hi the block
+        # of the row's last written position; negative = never written
+        R = block_tables.shape[1]
+        hi = (jnp.maximum(context_lens, 1) - 1) // bs  # [B]
+        r = jnp.arange(R)[None, :]
+        held = hi[:, None] - jnp.mod(hi[:, None] - r, R)  # [B, R]
+        k_pos = (held[:, :, None] * bs
+                 + jnp.arange(bs)[None, None, :]).reshape(B, -1)[:, None, :]
+    else:
+        k_pos = jnp.arange(k_all.shape[1])[None, None, :]
+    mask = k_pos <= q_pos[:, :, None]
+    if ring:
+        mask &= k_pos >= 0
+    if window:
+        mask &= k_pos > q_pos[:, :, None] - window
+    mask = mask[:, None]  # [B, 1, T, S]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k_all,
                    preferred_element_type=jnp.float32) * scale_v
     s = jnp.where(mask, s, jnp.float32(-1e30))
@@ -336,7 +366,7 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
 
 
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
-                  scale: float):
+                  scale: float, window: int = 0, ring: int = 0):
     """One stream (batch row) per grid cell.
 
     The whole point of paging: the kv stream for row ``b`` is
@@ -346,6 +376,11 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
     scalar-prefetched SMEM (available before the body runs, so the block
     ids can steer the DMAs); k/v pools stay in HBM (ANY) and blocks
     stream through a 2-slot VMEM scratch like the flash kernel above.
+
+    ``window`` > 0 starts the stream at the block that holds position
+    ``L - window``: a window layer reads ``ceil(window/bs) + 1`` blocks
+    at most, whatever the context.  ``ring`` > 0 is the table's width
+    when it is a ring: logical block i lives at entry ``i % ring``.
     """
     H, D = q_ref.shape
     bs = k_hbm.shape[1]
@@ -354,6 +389,12 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
     b = pl.program_id(0)
     L = len_ref[b]
     nb = (L + bs - 1) // bs  # live blocks only — the traffic contract
+    # first position attended, and the block that holds it
+    lo = jnp.maximum(L - window, 0) if window else 0
+    b0 = lo // bs if window else 0
+
+    def entry(i):
+        return tbl_ref[b, jax.lax.rem(i, ring) if ring else i]
 
     q = q_ref[:].astype(jnp.float32) * scale  # [H, D]
 
@@ -371,16 +412,16 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
     def scoped(kbuf, vbuf, ksem, vsem):
         def kdma(slot, i):
             return pltpu.make_async_copy(
-                k_hbm.at[tbl_ref[b, i]], kbuf.at[slot], ksem.at[slot])
+                k_hbm.at[entry(i)], kbuf.at[slot], ksem.at[slot])
 
         def vdma(slot, i):
             return pltpu.make_async_copy(
-                v_hbm.at[tbl_ref[b, i]], vbuf.at[slot], vsem.at[slot])
+                v_hbm.at[entry(i)], vbuf.at[slot], vsem.at[slot])
 
-        @pl.when(nb > 0)
+        @pl.when(nb > b0)
         def _():
-            kdma(0, 0).start()
-            vdma(0, 0).start()
+            kdma(jax.lax.rem(b0, 2) if window else 0, b0).start()
+            vdma(jax.lax.rem(b0, 2) if window else 0, b0).start()
 
         def body(i, carry):
             m, l, acc = carry
@@ -402,7 +443,10 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
             # the final block is partially valid: the single query sits
             # at position L-1 and attends positions < L
             pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, H), 0)
-            s = jnp.where(pos < L, s, -jnp.inf)
+            keep = pos < L
+            if window:
+                keep &= pos >= lo
+            s = jnp.where(keep, s, -jnp.inf)
             m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
             shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
             p = jnp.exp(s - shift)
@@ -415,7 +459,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
         m0 = jnp.full((1, H), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((1, H), jnp.float32)
         acc0 = jnp.zeros((H, D), jnp.float32)
-        m, l, acc = jax.lax.fori_loop(0, nb, body, (m0, l0, acc0))
+        m, l, acc = jax.lax.fori_loop(b0, nb, body, (m0, l0, acc0))
         # L == 0 (idle slot): l stays 0 and the row emits zeros — finite
         # garbage the serve loop never reads
         o_ref[:] = (acc / jnp.maximum(l.reshape(H, 1), 1e-30)).astype(
@@ -432,7 +476,8 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
 
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                     scale: Optional[float] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    window: int = 0, ring: bool = False):
     """Attention over a block-paged KV pool (continuous LLM serving).
 
     Shapes as in :func:`paged_attention_reference`.  The Pallas kernel
@@ -441,7 +486,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     (T > 1) and non-TPU backends take the reference path.  Per-row HBM
     traffic on the kernel path is ``ceil(context_len / block_size)``
     blocks — the reason paged decode scales with the sum of live
-    sequence lengths instead of B x S_max.
+    sequence lengths instead of B x S_max; with ``window`` > 0 it is the
+    blocks that intersect ``[p - window + 1, p]`` (``ring``: looked up
+    through a ring table, :func:`paged_attention_reference`).
     """
     B, T, H, D = q.shape
     n_blocks, bs, hkv, _ = k_pool.shape
@@ -450,7 +497,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
         interpret = False
         if jax.default_backend() != "tpu":
             return paged_attention_reference(
-                q, k_pool, v_pool, block_tables, context_lens, scale=scale_v)
+                q, k_pool, v_pool, block_tables, context_lens, scale=scale_v,
+                window=window, ring=ring)
     if (
         not paged_kernel_enabled()  # TP traces need the shardable path
         or T != 1
@@ -460,7 +508,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
         or (not interpret and D % 128)
     ):
         return paged_attention_reference(
-            q, k_pool, v_pool, block_tables, context_lens, scale=scale_v)
+            q, k_pool, v_pool, block_tables, context_lens, scale=scale_v,
+            window=window, ring=ring)
 
     # sentinel entries must not index past the pool when a DMA is (never)
     # issued for them; clip on host side of the call
@@ -476,7 +525,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
         out_specs=pl.BlockSpec((None, H, D), lambda b, *_: (b, 0, 0)),
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale_v),
+        functools.partial(_paged_kernel, scale=scale_v, window=int(window),
+                          ring=block_tables.shape[1] if ring else 0),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,

@@ -205,3 +205,78 @@ def test_serve_program_never_moves_the_pool(v5e, monkeypatch, program):
     layer_bytes = 2 * layer_elems * pool["k"].dtype.itemsize  # K + V
     assert mem.temp_size_in_bytes < layer_bytes
     assert mem.alias_size_in_bytes >= cfg.n_layers * layer_bytes
+
+
+# -- window and full attention in one paged cache, sparse experts (PR 29) ---
+
+#: the benchmark's patterned serving cell: 64 slots, 64 query heads over 8
+#: KV heads of 128, window 128, blocks of 16, a ring of 8 + 2 + 1 blocks
+HYB_SLOTS, HYB_H, HYB_HKV, HYB_RING = 64, 64, 8, 11
+
+
+@pytest.mark.parametrize("window,ring", [(0, False), (128, False),
+                                         (128, True)],
+                         ids=["full", "window", "window_ring"])
+def test_paged_attention_compiles_with_a_window(v5e, window, ring):
+    """q [64, 64, 128] over 8 KV heads: without a window, with one over an
+    ordinary table, and with one over a slot's ring (the table's width is
+    the modulus)."""
+    bs, n_blocks = 16, HYB_SLOTS * HYB_RING
+    width = HYB_RING if ring else 256
+    _compile(
+        "paged_attention",
+        lambda q, k, v, t, n: A.paged_attention(
+            q, k, v, t, n, interpret=False, window=window, ring=ring),
+        v5e((HYB_SLOTS, 1, HYB_H, D), jnp.bfloat16),
+        v5e((n_blocks, bs, HYB_HKV, D), jnp.bfloat16),
+        v5e((n_blocks, bs, HYB_HKV, D), jnp.bfloat16),
+        v5e((HYB_SLOTS, width), jnp.int32), v5e((HYB_SLOTS,), jnp.int32))
+
+
+def test_patterned_decode_step_compiles_at_published_widths(v5e,
+                                                            monkeypatch):
+    """One period of the benchmark's patterned configuration — hidden 6144,
+    64/8 heads of 128, a dense layer of 18432, three sparse layers of 16
+    held experts of 2048 out of 128 routed, window 128 — as the decode
+    step of 64 slots: both pools carried, the paged kernel in it twice
+    over (window layers through their ring), the grouped expert product a
+    custom call, and NO expert matrix taken out of its stack (a slice that
+    feeds a custom call is materialized: 0.4 GB a matrix a layer, which is
+    why ``moe_ffn`` takes the whole stack)."""
+    import math
+
+    from nnstreamer_tpu.models.moe import ExpertsConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    L = 4
+    cfg = llama.LlamaConfig(
+        vocab=19200, dim=6144, n_layers=L, n_heads=HYB_H,
+        n_kv_heads=HYB_HKV, ffn_hidden=18432, max_seq=4096, rope_theta=1e6,
+        head_size=128, qk_norm=True,
+        pattern=tuple(llama.LayerKind(
+            window=0 if l % 4 == 3 else 128, rope=l % 4 != 3,
+            ffn="dense" if l == 0 else "experts") for l in range(L)),
+        experts=ExpertsConfig(n_experts=128, top_k=8, hidden=2048, shared=1,
+                              scale=2.5, held_first=0, held_count=16))
+    bs, n_blocks = 16, HYB_SLOTS * 50
+    params = _abstract(v5e, lambda: llama.init_params(cfg, 0, "bfloat16"))
+    pool = _abstract(v5e, lambda: llama.init_paged_cache(
+        cfg, n_blocks, bs, win_blocks=HYB_SLOTS * HYB_RING))
+    assert llama.window_ring_blocks(cfg, bs, 32) == HYB_RING
+    tables = {"full": v5e((HYB_SLOTS, 256), jnp.int32),
+              "win": v5e((HYB_SLOTS, HYB_RING), jnp.int32)}
+    compiled = jax.jit(
+        lambda p, tok, pool, tb, pos: llama.forward_paged(
+            p, tok, pool, tb, pos, cfg, with_stats=True),
+        donate_argnums=(2,)).lower(
+            params, v5e((HYB_SLOTS, 1), jnp.int32), pool, tables,
+            v5e((HYB_SLOTS,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("%paged_attention") >= L
+    assert "%ragged-dot" in text
+    stack = 3 * 16 * 6144 * 2048 * 2   # one layer's experts, one matrix
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < stack, mem.temp_size_in_bytes
+    pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                     for x in jax.tree_util.tree_leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes   # both pools in place

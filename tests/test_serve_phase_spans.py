@@ -116,7 +116,12 @@ def test_iter_is_monotonic_dense_and_shared(traced):
     dec = {e.args["iter"] for e in spans if e.kind == "serve.decode"}
     assert dec and dec == {e.args["iter"] for e in spans
                            if e.kind == "serve.emit"}
-    assert set(parents[-1].args) == {"iter", "live", "waiting"}
+    # since PR 29 the parent also gauges the blocks live in each pool (the
+    # window pool's stay 0 on a model without window layers)
+    assert set(parents[-1].args) == {"iter", "live", "waiting",
+                                     "full_blocks", "win_blocks"}
+    assert all(e.args["win_blocks"] == 0 for e in parents)
+    assert max(e.args["full_blocks"] for e in parents) > 0
 
 
 def test_each_request_has_one_queue_and_one_prefill_that_meet(traced):
