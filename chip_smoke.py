@@ -356,13 +356,21 @@ def serving_leg(model: str = "llama2_7b", n_layers: int = 0,
 
 def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
                dim: int = 4096, ffn: int = 11008, slots: int = 4,
-               block_size: int = 16, seq: int = 128, context: int = 94,
-               interpret=None) -> dict:
+               block_size: int = 16, seq: int = 128,
+               paged_heads=((32, 32), (32, 8), (64, 8)),
+               paged_lens=(0, 1, 94, 128, 129, 300, 544, 800),
+               window: int = 128, interpret=None) -> dict:
     """flash_attention, paged_attention and matmul_int4 at the serving
     leg's shapes, called as models/llama.py calls them (``interpret`` left
     at None on the chip; the CPU dry run passes True).  The lowered text
     must hold ``tpu_custom_call`` — the kernel engaged, the shape gates did
-    not route to the reference — and the outputs match the references."""
+    not route to the reference — and the outputs match the references.
+
+    The paged kernel runs at both serving cells' head counts (32/8, 64/8)
+    and at MHA, without a window and with ``window`` over a slot's ring,
+    on rows that are idle (length 0), end mid-block, end mid-wave (the
+    kernel streams 1024 // (block_size * kv_heads) blocks a wave), fill a
+    wave exactly and pass it by one token."""
     import jax
     import jax.numpy as jnp
 
@@ -392,8 +400,6 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
         errs[name] = round(err, 5)
 
     kw = {} if interpret is None else {"interpret": interpret}
-    max_blocks = -(-context // block_size) + 1
-    n_blocks = slots * max_blocks
     for hkv in n_kv_heads:
         tag = f"{n_heads}/{hkv}"
         q = arr((1, seq, n_heads, head_dim))
@@ -403,25 +409,36 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
             lambda q, k, v: A.attention_reference(q, k, v, causal=True),
             (q, k, v), 0.05)
 
-        qd = arr((slots, 1, n_heads, head_dim))
-        kp, vp = (arr((n_blocks, block_size, hkv, head_dim))
-                  for _ in range(2))
-        # every slot at its own depth, one of them idle (length 0) and one
-        # ending mid-block; unused table entries hold the sentinel
-        lens = np.linspace(0, context, slots).astype(np.int32)
-        tbl = np.full((slots, max_blocks), n_blocks, np.int32)
-        perm = rng.permutation(n_blocks)
-        for s in range(slots):
-            nb = -(-int(lens[s]) // block_size)
-            tbl[s, :nb] = perm[s * max_blocks:s * max_blocks + nb]
-        live = jnp.asarray((lens > 0).reshape(slots, 1, 1, 1))
-        run(f"paged_attention {tag}",
-            # idle rows emit garbage the serve loop never reads: mask them
-            lambda q, kp, vp, t, n: jnp.where(
-                live, A.paged_attention(q, kp, vp, t, n, **kw), 0),
-            lambda q, kp, vp, t, n: jnp.where(
-                live, A.paged_attention_reference(q, kp, vp, t, n), 0),
-            (qd, kp, vp, jnp.asarray(tbl), jnp.asarray(lens)), 0.05)
+    # every row at its own depth; a full table names a row's blocks in
+    # order (unused entries hold the sentinel), a ring's entry j % width
+    # holds logical block j, as models/llama.py fills them
+    lens = np.asarray(paged_lens, np.int32)
+    rows = len(lens)
+    live = jnp.asarray((lens > 0).reshape(rows, 1, 1, 1))
+    for heads, hkv in paged_heads:
+        for ring in (False, True):
+            width = (window // block_size + 3 if ring
+                     else -(-int(lens.max()) // block_size) + 1)
+            n_blocks = rows * width
+            qd = arr((rows, 1, heads, head_dim))
+            kp, vp = (arr((n_blocks, block_size, hkv, head_dim))
+                      for _ in range(2))
+            tbl = rng.permutation(n_blocks).astype(np.int32).reshape(
+                rows, width)
+            if not ring:
+                used = -(-lens // block_size)
+                tbl[np.arange(width)[None, :] >= used[:, None]] = n_blocks
+            opts = {"window": window, "ring": True} if ring else {}
+            run(f"paged_attention {heads}/{hkv}"
+                + (f" window {window} ring {width}" if ring else ""),
+                # idle rows emit garbage the serve loop never reads
+                lambda q, kp, vp, t, n, opts=opts: jnp.where(
+                    live, A.paged_attention(q, kp, vp, t, n, **opts, **kw),
+                    0),
+                lambda q, kp, vp, t, n, opts=opts: jnp.where(
+                    live, A.paged_attention_reference(q, kp, vp, t, n,
+                                                      **opts), 0),
+                (qd, kp, vp, jnp.asarray(tbl), jnp.asarray(lens)), 0.05)
 
     h_kv = n_kv_heads[0] * head_dim
     for din, fout in ((dim, dim + 2 * h_kv), (dim, dim), (dim, 2 * ffn),

@@ -943,8 +943,9 @@ class _ContinuousLoop:
     slot block table ``[slots, max_blocks]`` whose entries map a stream's
     logical block j to a pool block (``n_blocks`` = unallocated
     sentinel).  The paged decode step (``forward_paged`` →
-    ops/attention.py ``paged_attention``) gathers ONLY each stream's live
-    blocks, so per-step HBM traffic scales with the *sum of live sequence
+    ops/attention.py ``paged_attention``) streams ONLY each stream's live
+    blocks (several a DMA wave, scores and P x V on the MXU), so per-step
+    HBM traffic scales with the *sum of live sequence
     lengths* instead of ``slots × max_seq`` — a short stream stops paying
     cache bandwidth for the longest one, which is what lets full-
     occupancy throughput keep scaling past 8 streams.

@@ -365,8 +365,28 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), v_all)
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
-                  scale: float, window: int = 0, ring: int = 0):
+def _paged_wave_blocks(bs: int, hkv: int, d: int, itemsize: int,
+                       window: int = 0) -> int:
+    """Blocks of K (and of V) in flight per buffer slot.
+
+    A block ``[bs, hkv, D]`` is the matrix ``[bs * hkv, D]`` it already is
+    in memory, and a wave of W of them is one ``[W * bs * hkv, D]`` MXU
+    operand.  Aim at ~1024 score columns a wave (8 blocks of 16 x 8 heads,
+    0.5 MB of K + V outstanding per slot in bf16), never more than 16
+    places, and keep K + V x two slots inside 4 MiB of VMEM.  A window
+    that fits those limits is ONE wave a row: ``window`` positions touch
+    ``ceil(window / bs) + 1`` blocks at most."""
+    rows = bs * hkv
+    most = min(16, (4 << 20) // (4 * rows * d * itemsize))
+    span = -(-window // bs) + 1
+    if window and span <= most:
+        return span
+    return max(1, min(1024 // rows, most))
+
+
+def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  kbuf, vbuf, sem, state, *, scale: float, hkv: int,
+                  window: int = 0, ring: int = 0):
     """One stream (batch row) per grid cell.
 
     The whole point of paging: the kv stream for row ``b`` is
@@ -374,8 +394,24 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
     beyond their own live prefix, so per-step HBM traffic is the SUM of
     live lengths, not B x S_max.  ``tbl_ref``/``len_ref`` are
     scalar-prefetched SMEM (available before the body runs, so the block
-    ids can steer the DMAs); k/v pools stay in HBM (ANY) and blocks
-    stream through a 2-slot VMEM scratch like the flash kernel above.
+    ids can steer the DMAs); k/v pools stay in HBM (ANY), each block read
+    as the ``[bs * hkv, D]`` matrix it is in memory.
+
+    Blocks stream in WAVES of ``W = kbuf.shape[1]`` through a 2-slot VMEM
+    scratch: the next wave's W copies of K and W of V are in flight while
+    this one is computed on, and a row's LAST wave overlaps the next
+    row's first (the scratch, its semaphores and ``state`` — the slot
+    that wave went to, and whether it was started — outlive a grid step;
+    the grid runs in order).  A place of a row's last wave whose block is
+    not live for the row issues no copy: it keeps whatever an earlier
+    wave left there and its columns are masked by position.
+
+    Per wave the scores of ALL query heads against ALL of the wave's rows
+    are one MXU product ``q[H, D] x K[W * bs * hkv, D]^T``: column ``c``
+    is token ``c // hkv`` of the wave under KV head ``c % hkv``, and head
+    ``h`` keeps only the columns of its own KV head ``h // G`` — no
+    per-head broadcast of the block; the other columns ride MXU rows that
+    were idle anyway and enter P x V (the second product) as exact zeros.
 
     ``window`` > 0 starts the stream at the block that holds position
     ``L - window``: a window layer reads ``ceil(window/bs) + 1`` blocks
@@ -383,95 +419,111 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, *,
     when it is a ring: logical block i lives at entry ``i % ring``.
     """
     H, D = q_ref.shape
-    bs = k_hbm.shape[1]
-    hkv = k_hbm.shape[2]
+    _, W, rows, _ = kbuf.shape  # rows = bs * hkv
+    bs = rows // hkv
     G = H // hkv
+    cols = W * rows
     b = pl.program_id(0)
-    L = len_ref[b]
-    nb = (L + bs - 1) // bs  # live blocks only — the traffic contract
-    # first position attended, and the block that holds it
-    lo = jnp.maximum(L - window, 0) if window else 0
-    b0 = lo // bs if window else 0
+    B = pl.num_programs(0)
 
-    def entry(i):
-        return tbl_ref[b, jax.lax.rem(i, ring) if ring else i]
+    def stream(row):
+        # (first block, one past the last) that `row` attends, its
+        # length and the first position attended
+        L = len_ref[row]
+        nb = (L + bs - 1) // bs  # live blocks only: the traffic contract
+        lo = jnp.maximum(L - window, 0) if window else 0
+        return (lo // bs if window else 0), nb, L, lo
 
-    q = q_ref[:].astype(jnp.float32) * scale  # [H, D]
+    b0, nb, L, lo = stream(b)
+    n_waves = (nb - b0 + W - 1) // W  # 0 for an idle row (L == 0)
 
-    def heads(blk):
-        # [bs, hkv, D] -> [bs, H, D]: query head h reads kv head h // G.
-        # Flat heads keep D in the lane dim and H in the sublane dim for
-        # every value below — the grouped [bs, hkv, G] statistics put G in
-        # the lane position, which Mosaic cannot reduce over axis 0.
-        blk = blk.astype(jnp.float32)
-        if G == 1:
-            return blk
-        return jnp.broadcast_to(
-            blk[:, :, None, :], (bs, hkv, G, D)).reshape(bs, H, D)
+    def each_live_place(row, slot, first, end, op):
+        # place w of the slot <-> logical block first + w of `row`, for
+        # the places whose block is live
+        def place(w, carry):
+            i = first + w
+            e = tbl_ref[row, jax.lax.rem(i, ring) if ring else i]
+            op(pltpu.make_async_copy(
+                k_hbm.at[e], kbuf.at[slot, w], sem.at[0, slot]))
+            op(pltpu.make_async_copy(
+                v_hbm.at[e], vbuf.at[slot, w], sem.at[1, slot]))
+            return carry
 
-    def scoped(kbuf, vbuf, ksem, vsem):
-        def kdma(slot, i):
-            return pltpu.make_async_copy(
-                k_hbm.at[entry(i)], kbuf.at[slot], ksem.at[slot])
+        jax.lax.fori_loop(0, jnp.clip(end - first, 0, W), place, None)
 
-        def vdma(slot, i):
-            return pltpu.make_async_copy(
-                v_hbm.at[entry(i)], vbuf.at[slot], vsem.at[slot])
+    def start(c):
+        c.start()
 
-        @pl.when(nb > b0)
-        def _():
-            kdma(jax.lax.rem(b0, 2) if window else 0, b0).start()
-            vdma(jax.lax.rem(b0, 2) if window else 0, b0).start()
+    def wait(c):
+        c.wait()
 
-        def body(i, carry):
-            m, l, acc = carry
-            slot = jax.lax.rem(i, 2)
-            nxt = jax.lax.rem(i + 1, 2)
+    @pl.when(b == 0)
+    def _():
+        # a place no copy has filled yet meets p == 0 in P x V, and
+        # 0 x whatever VMEM held is NaN where that was NaN; K needs no
+        # such care, its stale columns are masked before the max
+        vbuf[...] = jnp.zeros_like(vbuf)
+        state[0] = 0  # the slot this row's first wave goes to
+        state[1] = 0  # ... and whether the row before has started it
 
-            @pl.when(i + 1 < nb)
-            def _():  # prefetch the next live block while computing
-                kdma(nxt, i + 1).start()
-                vdma(nxt, i + 1).start()
+    slot0 = state[0]
 
-            kdma(slot, i).wait()
-            vdma(slot, i).wait()
-            kblk = heads(kbuf[slot])  # [bs, H, D]
-            vblk = heads(vbuf[slot])
-            # decode GEMV: VPU mul-reduce (no transposes — Mosaic keeps
-            # the 128-lane minor dim intact); scores [bs, H]
-            s = jnp.sum(q[None] * kblk, axis=-1)
-            # the final block is partially valid: the single query sits
-            # at position L-1 and attends positions < L
-            pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, H), 0)
-            keep = pos < L
-            if window:
-                keep &= pos >= lo
-            s = jnp.where(keep, s, -jnp.inf)
-            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-            shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            p = jnp.exp(s - shift)
-            alpha = jnp.exp(jnp.where(jnp.isfinite(m), m, shift) - shift)
-            l_new = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-            acc_new = acc * alpha.reshape(H, 1) + jnp.sum(
-                p[:, :, None] * vblk, axis=0)
-            return m_new, l_new, acc_new
+    @pl.when(state[1] == 0)
+    def _():
+        each_live_place(b, slot0, b0, nb, start)
 
-        m0 = jnp.full((1, H), -jnp.inf, jnp.float32)
-        l0 = jnp.zeros((1, H), jnp.float32)
-        acc0 = jnp.zeros((H, D), jnp.float32)
-        m, l, acc = jax.lax.fori_loop(b0, nb, body, (m0, l0, acc0))
-        # L == 0 (idle slot): l stays 0 and the row emits zeros — finite
-        # garbage the serve loop never reads
-        o_ref[:] = (acc / jnp.maximum(l.reshape(H, 1), 1e-30)).astype(
-            o_ref.dtype)
+    nxt = jnp.minimum(b + 1, B - 1)
+    nxt_b0, nxt_nb, _, _ = stream(nxt)
+    nxt_nb = jnp.where(b + 1 < B, nxt_nb, 0)
 
-    pl.run_scoped(
-        scoped,
-        kbuf=pltpu.VMEM((2,) + k_hbm.shape[1:], k_hbm.dtype),
-        vbuf=pltpu.VMEM((2,) + v_hbm.shape[1:], v_hbm.dtype),
-        ksem=pltpu.SemaphoreType.DMA((2,)),
-        vsem=pltpu.SemaphoreType.DMA((2,)),
-    )
+    q = q_ref[...]  # [H, D], the compute dtype (the reference's own)
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, cols), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (H, cols), 0)
+    own = (col % hkv) == (head // G)  # head h reads kv head h // G
+    tok = col // hkv  # the column's token within the wave
+
+    def body(j, carry):
+        m, l, acc = carry
+        slot = jax.lax.rem(slot0 + j, 2)
+        # what streams in while this wave is computed on: the row's next
+        # wave, or after its last the next row's first
+        more = j + 1 < n_waves
+        each_live_place(jnp.where(more, b, nxt), 1 - slot,
+                        jnp.where(more, b0 + (j + 1) * W, nxt_b0),
+                        jnp.where(more, nb, nxt_nb), start)
+        first = b0 + j * W
+        each_live_place(b, slot, first, nb, wait)
+        s = jax.lax.dot_general(
+            q, kbuf[slot].reshape(cols, D).astype(q.dtype),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, cols]
+        # the single query sits at position L-1 and attends positions
+        # < L (>= lo under a window); the final block is partially valid
+        keep = own & (tok < L - first * bs)
+        if window:
+            keep &= tok >= lo - first * bs
+        s = jnp.where(keep, s, -jnp.inf)
+        # every wave run holds a live position, so m_new is finite
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot_general(
+            p.astype(q.dtype), vbuf[slot].reshape(cols, D).astype(q.dtype),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    m0 = jnp.full((H, 1), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((H, 1), jnp.float32)
+    acc0 = jnp.zeros((H, D), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(0, n_waves, body, (m0, l0, acc0))
+    # L == 0 (idle slot): l stays 0 and the row emits zeros — finite
+    # garbage the serve loop never reads
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    # an idle row starts nothing for the row after it, which then starts
+    # its own first wave, in the slot this one would have used
+    state[0] = jax.lax.rem(slot0 + n_waves, 2)
+    state[1] = (n_waves > 0).astype(jnp.int32)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
@@ -488,7 +540,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     blocks — the reason paged decode scales with the sum of live
     sequence lengths instead of B x S_max; with ``window`` > 0 it is the
     blocks that intersect ``[p - window + 1, p]`` (``ring``: looked up
-    through a ring table, :func:`paged_attention_reference`).
+    through a ring table, :func:`paged_attention_reference`).  Products
+    are in the query's dtype with float32 accumulation, the softmax
+    statistics in float32: the reference's own precision.
     """
     B, T, H, D = q.shape
     n_blocks, bs, hkv, _ = k_pool.shape
@@ -499,13 +553,15 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
             return paged_attention_reference(
                 q, k_pool, v_pool, block_tables, context_lens, scale=scale_v,
                 window=window, ring=ring)
+    itemsize = k_pool.dtype.itemsize
     if (
         not paged_kernel_enabled()  # TP traces need the shardable path
         or T != 1
         or H % hkv
         or k_pool.shape != v_pool.shape
-        # Mosaic DMA lane tiling (the flash kernel's constraint)
-        or (not interpret and D % 128)
+        # Mosaic DMA tiling: lanes (the flash kernel's constraint), and a
+        # block's rows must fill whole sublane tiles of the wave buffer
+        or (not interpret and (D % 128 or (bs * hkv) % (32 // itemsize)))
     ):
         return paged_attention_reference(
             q, k_pool, v_pool, block_tables, context_lens, scale=scale_v,
@@ -514,6 +570,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     # sentinel entries must not index past the pool when a DMA is (never)
     # issued for them; clip on host side of the call
     tbl = jnp.clip(block_tables, 0, n_blocks - 1).astype(jnp.int32)
+    wave = _paged_wave_blocks(bs, hkv, D, itemsize, int(window))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
@@ -523,13 +580,28 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((None, H, D), lambda b, *_: (b, 0, 0)),
+        # two slots of one wave each; scratch outlives a grid step
+        scratch_shapes=[
+            pltpu.VMEM((2, wave, bs * hkv, D), k_pool.dtype),
+            pltpu.VMEM((2, wave, bs * hkv, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+        ],
     )
+    # [n_blocks, bs, hkv, D] is row-major [n_blocks, bs * hkv, D]: a
+    # bitcast, the pool is not moved
+    flat = (n_blocks, bs * hkv, D)
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale_v, window=int(window),
+        functools.partial(_paged_kernel, scale=scale_v, hkv=hkv,
+                          window=int(window),
                           ring=block_tables.shape[1] if ring else 0),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        # a row's last wave overlaps the next row's first: rows in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(tbl, context_lens.astype(jnp.int32), q[:, 0], k_pool, v_pool)
+    )(tbl, context_lens.astype(jnp.int32), q[:, 0],
+      k_pool.reshape(flat), v_pool.reshape(flat))
     return out[:, None]

@@ -44,10 +44,14 @@ def test_serving_leg_cuts_only_depth():
 def test_kernel_leg_toy_interpreted():
     facts = chip_smoke.kernel_leg(n_heads=4, n_kv_heads=(4, 2), head_dim=64,
                                   dim=256, ffn=512, slots=3, block_size=8,
-                                  seq=128, context=30, interpret=True)
+                                  seq=128, paged_heads=((4, 4), (8, 2)),
+                                  paged_lens=(0, 5, 30, 128, 129, 200),
+                                  window=32, interpret=True)
     names = " ".join(facts["rel_err"])
     for kernel in ("flash_attention", "paged_attention", "matmul_int4"):
         assert kernel in names
+    # the paged kernel ran without a window and with one over a ring
+    assert "paged_attention 8/2 window 32 ring 7" in facts["rel_err"]
 
 
 def test_main_refuses_cpu(capsys):

@@ -4,20 +4,35 @@ The grouped kernels (ops/attention.py) take K/V UNREPEATED at
 ``[*, Hkv, *]`` and share each streamed block across the whole
 query-head group.  Three things must hold, and each gets pinned here:
 
-1. **Values**: the grouped layout is bit-identical to feeding the SAME
-   kernel a pre-repeated ``Hkv == H`` layout (the pre-refactor data
-   path) at every ratio, including MQA — the refactor moved bytes, not
-   math.  (Vs the materialized XLA reference it is allclose, not
-   bitwise: blockwise online softmax re-associates the reduction.)
+1. **Values**: the grouped layout equals feeding the SAME kernel a
+   pre-repeated ``Hkv == H`` layout (the pre-refactor data path) at
+   every ratio, including MQA — bit for bit in the flash kernel: the
+   refactor moved bytes, not math.  (Vs the materialized XLA reference
+   it is allclose, not bitwise: blockwise online softmax re-associates
+   the reduction.)
 2. **Stream count**: the flash grid is ``(B * Hkv, Sq / block_q)`` —
    one K/V stream per (batch, KV head), NOT per query head — and the
    paged grid is ``(B,)``; K/V operands ride ANY memory space (the
    kernel's own DMAs stream them), so HBM reads scale with ``Hkv``.
-3. **DMA structure**: each grid cell issues exactly one double-buffered
-   K stream and one V stream (6 ``make_async_copy`` call sites: 2 warm
-   starts + 2 prefetches + 2 waits), with NO per-query-head DMA loop —
-   the count is invariant in H/Hkv.  Interpret mode traces the cell
-   body once, so call-site counting is exact.
+3. **DMA structure**: a flash grid cell issues exactly one
+   double-buffered K stream and one V stream (6 ``make_async_copy`` call
+   sites: 2 warm starts + 2 prefetches + 2 waits), with NO
+   per-query-head DMA loop — the count is invariant in H/Hkv.  Interpret
+   mode traces the cell body once, so call-site counting is exact.  The
+   paged kernel streams a row's blocks in waves of W (up to W K copies
+   and W V copies a buffer slot, two slots), each copy a whole
+   ``[bs * Hkv, D]`` block, one per place whose block is live for the
+   row: what is pinned is what runs — a row STARTS two copies per live
+   block, an idle row none, each is waited for once — and that every
+   copy is Hkv-sized.
+
+The paged kernel computes a wave's scores and its P x V as one MXU
+product each over all of the wave's ``[bs * Hkv]`` rows (a head keeps the
+columns of its own KV head), so the grouped and the repeated layout sum
+in different orders: they agree to the tolerance each holds against the
+XLA reference, not bitwise.  ``TestPagedWaves`` runs it on bf16 pools at
+the serving cells' shapes around every wave boundary, with every block
+that is not live filled with NaN.
 
 Plus the prediction side: ``serving_plan``'s
 ``decode_bytes_per_ctx_token`` must price the pool at ``n_kv_heads``
@@ -26,6 +41,7 @@ over-prediction nns-xray's reconciliation flagged.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -59,16 +75,36 @@ def _flash_inputs(hkv, *, b=2, s=256, d=32, seed=0):
 
 class _PallasCapture:
     """Wrap ``pl.pallas_call`` (and ``pltpu.make_async_copy``) through the
-    module under test, recording the grid actually launched and the
-    number of DMA call sites traced."""
+    module under test, recording the grid actually launched, the number
+    of DMA call sites traced, and — counted as the interpreted kernel
+    RUNS, through a debug callback beside each ``start()`` and ``wait()``
+    — the source shape of every copy a grid cell really started and how
+    many it waited for."""
 
     def __init__(self):
         self.grids = []
         self.dma_calls = 0
+        self.started = []
+        self.waited = 0
 
     def install(self, monkeypatch):
         real_call = A.pl.pallas_call
         real_dma = A.pltpu.make_async_copy
+        cap = self
+
+        class Copy:
+            def __init__(self, src, dst, sem):
+                self.copy, self.src = real_dma(src, dst, sem), src.shape
+
+            def start(self):
+                jax.debug.callback(lambda: cap.started.append(self.src))
+                return self.copy.start()
+
+            def wait(self):
+                def count():
+                    cap.waited += 1
+                jax.debug.callback(count)
+                return self.copy.wait()
 
         def spy_call(*args, **kw):
             if "grid" in kw:
@@ -79,7 +115,7 @@ class _PallasCapture:
 
         def spy_dma(*args, **kw):
             self.dma_calls += 1
-            return real_dma(*args, **kw)
+            return Copy(*args, **kw)
 
         monkeypatch.setattr(A.pl, "pallas_call", spy_call)
         monkeypatch.setattr(A.pltpu, "make_async_copy", spy_dma)
@@ -118,13 +154,14 @@ class TestFlashGrouped:
 
 
 class TestPagedGrouped:
-    def _pool_case(self, hkv, *, b=3, d=32, bs=16, n_blocks=24, seed=1):
+    def _pool_case(self, hkv, *, d=32, bs=16, n_blocks=32, seed=1):
         kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+        lens = jnp.asarray([5, 0, bs * 3, bs * 8], jnp.int32)  # one idle
+        b = lens.shape[0]
         q = jax.random.normal(kq, (b, 1, H, d), jnp.float32)
         k_pool = jax.random.normal(kk, (n_blocks, bs, hkv, d), jnp.float32)
         v_pool = jax.random.normal(kv, (n_blocks, bs, hkv, d), jnp.float32)
         tbl = jnp.arange(b * 8, dtype=jnp.int32).reshape(b, 8) % n_blocks
-        lens = jnp.asarray([5, bs * 3, bs * 8], jnp.int32)[:b]
         return q, k_pool, v_pool, tbl, lens
 
     def _repeat_pool(self, pool, rep):
@@ -134,28 +171,154 @@ class TestPagedGrouped:
                 n, bs, hkv * rep, d)
 
     @pytest.mark.parametrize("rep", RATIOS)
-    def test_bit_identical_to_repeated_pool(self, rep):
+    def test_equal_to_repeated_pool(self, rep):
         hkv = H // rep
         q, kp, vp, tbl, lens = self._pool_case(hkv)
         grouped = A.paged_attention(q, kp, vp, tbl, lens, interpret=True)
         repeated = A.paged_attention(
             q, self._repeat_pool(kp, rep), self._repeat_pool(vp, rep),
             tbl, lens, interpret=True)
-        assert np.array_equal(np.asarray(grouped), np.asarray(repeated))
-        ref = A.paged_attention_reference(q, kp, vp, tbl, lens)
+        # a wave is W * bs * Hkv score columns wide, so the two layouts
+        # re-associate the sums: equal to the tolerance, not the bit
         np.testing.assert_allclose(
-            np.asarray(grouped), np.asarray(ref), atol=2e-5, rtol=2e-5)
+            np.asarray(grouped), np.asarray(repeated), atol=2e-5, rtol=2e-5)
+        ref = A.paged_attention_reference(q, kp, vp, tbl, lens)
+        live = np.asarray(lens) > 0  # an idle row emits zeros, unread
+        np.testing.assert_allclose(
+            np.asarray(grouped)[live], np.asarray(ref)[live],
+            atol=2e-5, rtol=2e-5)
+        assert not np.asarray(grouped)[~live].any()
 
     @pytest.mark.parametrize("rep", RATIOS)
     def test_one_stream_per_row(self, rep, monkeypatch):
         hkv = H // rep
         cap = _PallasCapture().install(monkeypatch)
         q, kp, vp, tbl, lens = self._pool_case(hkv)
-        A.paged_attention(q, kp, vp, tbl, lens, interpret=True)
-        # one grid cell per batch row regardless of head layout; the
-        # row streams ceil(len/bs) blocks of its OWN Hkv-sized pool
+        bs, d = kp.shape[1], kp.shape[3]
+        jax.block_until_ready(
+            A.paged_attention(q, kp, vp, tbl, lens, interpret=True))
+        jax.effects_barrier()
+        # one grid cell per batch row regardless of head layout
         assert cap.grids == [(q.shape[0],)]
+        # the row streams ceil(len/bs) blocks of K and as many of V: the
+        # idle row starts no copy, no row one for a block it does not hold
+        live_blocks = int(np.sum(-(-np.asarray(lens) // bs)))
+        assert len(cap.started) == 2 * live_blocks
+        # and every copy is a whole block of its OWN Hkv-sized pool: the
+        # traffic scales with Hkv, no copy is per query head
+        assert set(cap.started) == {(bs * hkv, d)}
+        # every copy started is waited for once (a row's first wave is
+        # started by the row before it, unless that one was idle)
+        assert cap.waited == len(cap.started)
+        # three copy SITES for K and three for V — a row's own first
+        # wave, the wave streamed in behind the compute, the wait — each
+        # run once per live place: none per query head
         assert cap.dma_calls == 6
+
+
+#: the serving cells' geometry: Mistral-7B (32/8 heads) and K-EXAONE
+#: (64/8), head_dim 128, blocks of 16: a block is a [128, 128] matrix and
+#: a wave is 8 of them = 128 tokens
+CELL_BS, CELL_D, CELL_WAVE = 16, 128, 128
+CELL_MODES = {"full": (0, False), "window": (128, False),
+              "window_ring": (128, True)}
+
+
+@functools.partial(jax.jit, static_argnames=("window", "ring"))
+def _cell_kernel(q, kp, vp, tbl, lens, window, ring):
+    return A.paged_attention(q, kp, vp, tbl, lens, interpret=True,
+                             window=window, ring=ring)
+
+
+def _cell_case(heads, mode, lens, seed):
+    """bf16 query, pools and a shuffled table (a ring where the mode has
+    one) for rows at ``lens``; the table as numpy, to say which blocks
+    are live."""
+    h, hkv = heads
+    window, ring = CELL_MODES[mode]
+    b = len(lens)
+    width = window // CELL_BS + 3 if ring else 64
+    n_blocks = b * width
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (b, 1, h, CELL_D)).astype(jnp.bfloat16)
+    kp, vp = (jax.random.normal(
+        k, (n_blocks, CELL_BS, hkv, CELL_D)).astype(jnp.bfloat16)
+        for k in ks[1:])
+    tbl = np.random.RandomState(seed).permutation(n_blocks).astype(
+        np.int32).reshape(b, width)
+    return q, kp, vp, tbl, np.asarray(lens, np.int32), window, ring
+
+
+def _assert_is_reference(got, q, kp, vp, tbl, lens, window, ring):
+    ref = np.asarray(A.paged_attention_reference(
+        q, kp, vp, jnp.asarray(tbl), jnp.asarray(lens), window=window,
+        ring=ring), np.float32)
+    rows = lens > 0
+    # one bf16 step of an output of size ~1 is 0.0078
+    np.testing.assert_allclose(got[rows], ref[rows], atol=2e-2)
+    assert not got[~rows].any()  # an idle row emits zeros
+
+
+class TestPagedWaves:
+    """The wave pipeline at the cells' shapes, bf16 pools, interpreted."""
+
+    @pytest.mark.parametrize("ctx", [1, 15, 16, 17, CELL_WAVE - 1, CELL_WAVE,
+                                     CELL_WAVE + 1, 544, 800])
+    @pytest.mark.parametrize("mode", list(CELL_MODES))
+    @pytest.mark.parametrize("heads", [(32, 8), (64, 8)],
+                             ids=["mistral32x8", "exaone64x8"])
+    def test_matches_reference_and_skips_dead_blocks(self, heads, mode, ctx):
+        """Contexts on both sides of a block's and of a wave's edge, and
+        at the cells' deepest, against the XLA reference; then the same
+        call on a pool whose every block that no row holds live is NaN:
+        the last wave's spare places (and a window's blocks before its
+        start) must neither be fetched nor reach the result."""
+        bs = CELL_BS
+        assert A._paged_wave_blocks(bs, heads[1], CELL_D, 2) * bs == CELL_WAVE
+        case = _cell_case(heads, mode, [ctx, 0, 77, ctx], seed=ctx)
+        q, kp, vp, tbl, lens, window, ring = case
+        args = (jnp.asarray(tbl), jnp.asarray(lens), window, ring)
+        clean = np.asarray(_cell_kernel(q, kp, vp, *args), np.float32)
+        _assert_is_reference(clean, *case)
+
+        # the blocks a row attends: those that intersect [lo, L)
+        live = np.zeros(kp.shape[0], bool)
+        for r, L in enumerate(lens):
+            lo = max(L - window, 0) if window else 0
+            for i in range(lo // bs, -(-int(L) // bs)):
+                live[tbl[r, i % tbl.shape[1] if ring else i]] = True
+        dead = jnp.asarray(~live)[:, None, None, None]
+        nan = jnp.asarray(jnp.nan, jnp.bfloat16)
+        dirty = np.asarray(_cell_kernel(
+            q, jnp.where(dead, nan, kp), jnp.where(dead, nan, vp), *args),
+            np.float32)
+        assert np.isfinite(dirty).all()
+        assert np.array_equal(dirty, clean)
+
+    @pytest.mark.parametrize("heads,mode,lens", [
+        ((32, 8), "full", [1, 0, 129, 300, 0, 0, 17, 544, 16]),
+        ((64, 8), "window_ring", [200, 0, 129, 3, 0, 800]),
+        ((32, 8), "window", [0, 0, 5, 400]),
+        ((32, 32), "full", [33, 0, 0, 64, 1])],
+        ids=["full", "window_ring", "window_after_idle", "mha"])
+    def test_pipeline_holds_when_copies_land_late(self, heads, mode, lens):
+        """The TPU interpreter with every copy carried out only when it is
+        WAITED for, scratch that starts as NaN, and its race detector on:
+        a wave (a row's next, or the next row's first, started a grid step
+        early) that is computed on before its own waits, or a place read
+        that nothing ever filled, shows."""
+        from jax._src.pallas.mosaic.interpret import (
+            interpret_pallas_call as tpu_interpret)
+
+        case = _cell_case(heads, mode, lens, seed=0)
+        q, kp, vp, tbl, lens, window, ring = case
+        got = np.asarray(A.paged_attention(
+            q, kp, vp, jnp.asarray(tbl), jnp.asarray(lens), window=window,
+            ring=ring, interpret=A.pltpu.InterpretParams(
+                dma_execution_mode="on_wait", uninitialized_memory="nan",
+                detect_races=True)), np.float32)
+        assert not tpu_interpret.races.races_found
+        _assert_is_reference(got, *case)
 
 
 class TestServingPlanTraffic:

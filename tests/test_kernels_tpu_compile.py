@@ -70,6 +70,23 @@ def test_paged_attention_compiles(v5e, hkv, slots):
         v5e((slots, max_blocks), jnp.int32), v5e((slots,), jnp.int32))
 
 
+def test_paged_attention_compiles_at_the_serving_cells_table(v5e):
+    """32 slots of 32/8 heads over the whole pool (32 layers x 1088
+    blocks) with a table wide enough for 4,096 tokens, the cell's
+    ``max_seq``: a wave of 8 blocks of [128, 128], two slots of K and of
+    V, steered through an SMEM table of 32 x 256 entries."""
+    slots, hkv, bs, n_blocks, max_blocks = 32, 8, 16, 32 * 1088, 256
+    assert A._paged_wave_blocks(bs, hkv, D, 2) == 8
+    _compile(
+        "paged_attention",
+        lambda q, k, v, t, n: A.paged_attention(q, k, v, t, n,
+                                                interpret=False),
+        v5e((slots, 1, H, D), jnp.bfloat16),
+        v5e((n_blocks, bs, hkv, D), jnp.bfloat16),
+        v5e((n_blocks, bs, hkv, D), jnp.bfloat16),
+        v5e((slots, max_blocks), jnp.int32), v5e((slots,), jnp.int32))
+
+
 @pytest.mark.parametrize("hkv", HEADS)
 @pytest.mark.parametrize("seq", [128, 512])
 def test_flash_attention_compiles(v5e, hkv, seq):
