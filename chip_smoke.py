@@ -255,9 +255,9 @@ def serving_leg(model: str = "llama2_7b", n_layers: int = 0,
                 max_new: int = 24, prompt_lens=(19, 70, 7), slots: int = 4,
                 quant: str = "int8", param_dtype: str = "bfloat16",
                 first_token_timeout: float = 900.0) -> dict:
-    """Config #5 continuous serving, the option set ``bench.py --config
-    llm7b --llm-quant int8 --llm-serve continuous`` builds.  ``n_layers``
-    (0 = the preset's full depth) is the ONLY cut allowed."""
+    """Config #5 continuous serving: ``quant`` weights, ``slots`` slots,
+    ``serve:continuous`` over the paged cache.  ``n_layers`` (0 = the
+    preset's full depth) is the ONLY cut allowed."""
     import nnstreamer_tpu as nt
     from nnstreamer_tpu.models import llama
     from nnstreamer_tpu.utils import tracing

@@ -18,7 +18,6 @@ append, window+advance; the continuous-serving 3-program discipline), and
 
 shows the ``agg ring`` bytes inside the budgeted HBM estimate — CI pins
 this via tools/check_tier1.py's MXU gate against tools/asr_deep_baseline.txt.
-bench.py --config asr_stream A/Bs this pipeline host-vs-device.
 """
 import os
 import sys

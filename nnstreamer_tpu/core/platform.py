@@ -1,5 +1,5 @@
-"""Backend/platform helpers for SCRIPT entry points (bench.py,
-chip_smoke.py, tools/smoke_tpu.py) that own their process — the library
+"""Backend/platform helpers for SCRIPT entry points (chip_smoke.py,
+benchmark/run.py's like) that own their process — the library
 itself never mutates global jax config on import, so a user's deliberate
 programmatic settings survive ``import nnstreamer_tpu``.  Importing THIS
 module does not import jax.

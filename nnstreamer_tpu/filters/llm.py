@@ -60,6 +60,7 @@ from ..core.meta_keys import (META_ABORT_REASON, META_QUERY_CONN,
 from ..core.meta_keys import META_TENANT as _META_TENANT
 from .base import (Framework, FrameworkError, parse_custom_options,
                    place_swapped_params)
+from .kv_blocks import BlockManager
 
 #: buffer-meta keys that must NOT ride a drain snapshot: the queue-stamp
 #: map is the source pipeline's tracer plumbing, and the query
@@ -939,10 +940,14 @@ class _ContinuousLoop:
 
     **The pool.**  One thread owns a fixed block pool
     ``[L, n_blocks, block_size, H_kv, hd]`` (models/llama.py
-    ``init_paged_cache``), a host-side free list of block ids, and a per-
-    slot block table ``[slots, max_blocks]`` whose entries map a stream's
+    ``init_paged_cache``) and schedules; its block manager ``self.kv``
+    (filters/kv_blocks.py) owns the host side — a free list of block ids,
+    reference counts, the prefix index and a per-slot block table
+    ``[slots, max_blocks]`` whose entries map a stream's
     logical block j to a pool block (``n_blocks`` = unallocated
-    sentinel).  The paged decode step (``forward_paged`` →
+    sentinel).  Host state in the manager, device state in the loop: a
+    copy-on-write fork is chosen by one and copied by the other.  The
+    paged decode step (``forward_paged`` →
     ops/attention.py ``paged_attention``) streams ONLY each stream's live
     blocks (several a DMA wave, scores and P x V on the MXU), so per-step
     HBM traffic scales with the *sum of live sequence
@@ -1038,8 +1043,37 @@ class _ContinuousLoop:
         #: whether the decode chunk returns the expert layers' routing
         #: counts, as extra rows of its token matrix
         self._moe = cfg.experts is not None
-        self.sentinel = self.n_blocks  # unallocated table entry
-        self.park = self.max_blocks * bs  # idle-slot position
+        #: the host side of the paged cache (filters/kv_blocks.py): free
+        #: list, refcounts, block and ring tables, prefix chain index.
+        #: The scheduler below calls it; the pools stay device state of
+        #: the serve thread.
+        self.kv = BlockManager(
+            slots=fw.slots, block_size=bs, prefill_chunk=fw.prefill_chunk,
+            n_blocks=self.n_blocks, max_blocks=self.max_blocks,
+            win_ring=self.win_ring, win_blocks=self.win_blocks,
+            prefix_cache=fw.prefix_cache, count=metrics.count)
+        self.sentinel = self.kv.sentinel  # unallocated table entry
+        self.park = self.kv.park  # idle-slot position
+        #: per-slot host bookkeeping the serve thread mutates in place
+        #: (it binds them to locals); born here so that pool_stats(),
+        #: stream_table() and the crash terminator read them whenever
+        #: they are called.  Positions: parked = idle.
+        self._pos = np.full((fw.slots,), self.park, np.int32)
+        self._live_slots: list = [None] * fw.slots  # (meta, emit)
+        #: per-slot stream id / tenant / original prompt tokens — the
+        #: elastic surface (cancel lookup, quota accounting, drain
+        #: snapshots); set at admission, cleared by retire()
+        self._slot_sid: list = [None] * fw.slots
+        self._slot_tenant: list = [None] * fw.slots
+        self._slot_prompt: list = [None] * fw.slots
+        #: per-slot serving timeline (docs/OBSERVABILITY.md "Distributed
+        #: tracing"): enqueue/admit/first-token/last-emit stamps
+        #: (monotonic seconds) feeding the TTFT / ITL / phase-split
+        #: histograms.  Values are MILLISECONDS (the ``_ms`` series are
+        #: reservoir-quantile sources; the seconds-scaled fixed bucket
+        #: ladder saturates for them).  None for adopted streams — their
+        #: enqueue happened in another process, so TTFT is unknowable.
+        self._slot_time: list = [None] * fw.slots
         self._pending: "_q.Queue" = _q.Queue()
         self._wake = threading.Event()
         self._stop = threading.Event()
@@ -1401,24 +1435,11 @@ class _ContinuousLoop:
         and total block counts plus live stream count.  Reads host-side
         ints the serve thread mutates — values are a consistent-enough
         snapshot for accounting at quiesce points (post-drain)."""
-        free = getattr(self, "_free", None)
-        slots = getattr(self, "_live_slots", None) or []
         return {
-            "blocks_total": self.n_blocks,
-            "blocks_free": self.n_blocks if free is None else len(free),
-            "live_streams": sum(1 for s in slots if s is not None),
-            # window layers' pool: `win_ring` blocks a slot, for good
-            "win_blocks_total": self.win_blocks,
-            "win_ring": self.win_ring,
-            "win_blocks_live": getattr(self, "_win_blocks_live",
-                                       lambda: 0)(),
-            # prefix-sharing accounting: blocks whose content + chain
-            # hash are indexed (many resting in the free list at
-            # refcount 0), and blocks currently mapped by >1 stream
-            "blocks_cached": len(getattr(self, "_block_hash", {}) or {}),
-            "blocks_shared": int(
-                (np.asarray(getattr(self, "_ref", [])) > 1).sum())
-            if getattr(self, "_ref", None) is not None else 0,
+            **self.kv.stats(),
+            "live_streams": sum(
+                1 for s in self._live_slots if s is not None),
+            "win_blocks_live": self.kv.win_blocks_live(self._pos),
         }
 
     def stream_table(self) -> Dict[int, Dict]:
@@ -1435,17 +1456,15 @@ class _ContinuousLoop:
             if sid is not None:
                 out[sid] = {"state": "admitting", "slot": st["slot"],
                             "blocks": len(
-                                getattr(self, "_slot_blocks",
-                                        [[]])[st["slot"]]),
+                                self.kv.slot_blocks[st["slot"]]),
                             "tenant": st["meta"].get(_META_TENANT)}
-        slots = getattr(self, "_live_slots", None) or []
-        sids = getattr(self, "_slot_sid", None) or []
-        for s, slot in enumerate(slots):
-            if slot is None or s >= len(sids) or sids[s] is None:
+        for s, slot in enumerate(self._live_slots):
+            sid = self._slot_sid[s]
+            if slot is None or sid is None:
                 continue
-            out[sids[s]] = {"state": "live", "slot": s,
-                            "blocks": len(self._slot_blocks[s]),
-                            "tenant": slot[0].get(_META_TENANT)}
+            out[sid] = {"state": "live", "slot": s,
+                        "blocks": len(self.kv.slot_blocks[s]),
+                        "tenant": slot[0].get(_META_TENANT)}
         return out
 
     def _ctl_call(self, cmd: Dict, timeout: float):
@@ -1526,7 +1545,7 @@ class _ContinuousLoop:
             # after the drain.
             import queue as _q
 
-            for slot in list(getattr(self, "_live_slots", []) or []):
+            for slot in list(self._live_slots):
                 if slot is not None:
                     abort(slot[0], slot[1], 1 << 30)
             for st in list(self._admitting):
@@ -1552,7 +1571,6 @@ class _ContinuousLoop:
     def _run_inner(self) -> None:
         import dataclasses as _dc
         import functools as _ft
-        import math
         import queue as _q
 
         import jax
@@ -1668,74 +1686,17 @@ class _ContinuousLoop:
             adm_no += 1
             return k
 
-        pos = np.full((B,), self.park, np.int32)  # parked = idle
-        tables = np.full((B, self.max_blocks), self.sentinel, np.int32)
-        #: window layers: slot s owns blocks [s * ring, (s + 1) * ring)
-        #: of the window pool for good — logical block j of its stream
-        #: lives at ring entry j % ring, so the table never changes and
-        #: a window layer holds `ring` blocks a slot whatever the context
-        win_tables = (np.arange(B, dtype=np.int32)[:, None] * self.win_ring
-                      + np.arange(self.win_ring, dtype=np.int32)[None, :])
-
-        def tabs(rows=slice(None)):
-            """The table argument of a program for the slots ``rows``: a
-            copy of the block table (dispatch is asynchronous, see
-            above), with the ring table beside it where the model has
-            window layers."""
-            if not self.win_ring:
-                return tables[rows].copy()
-            return {"full": tables[rows].copy(), "win": win_tables[rows]}
-
-        free = list(range(self.n_blocks))  # host free list (block ids)
-        slot_blocks: list = [[] for _ in range(B)]
-        #: per-block reference counts: 0 = on the free list, 1 = one
-        #: private owner, >1 = a prefix-shared block mapped into several
-        #: streams' tables.  A block returns to the free list ONLY at
-        #: refcount 0 (release) — the prefix-sharing invariant the
-        #: property tests in tests/test_spec_decode.py pin.
-        ref = np.zeros((self.n_blocks,), np.int64)
-        #: prefix cache: chain-hash -> pool block id.  Cached blocks with
-        #: refcount 0 LIVE IN THE FREE LIST (content + index intact):
-        #: the cache never shrinks admission capacity, and eviction is
-        #: simply allocation — popping an indexed block drops its entry.
-        prefix_index: Dict[bytes, int] = {}
-        block_hash: Dict[int, bytes] = {}
+        kv = self.kv  # block manager: tables, free list, prefix chain
+        pos = self._pos  # parked = idle
         #: host mirrors of the carried token state (the last committed
         #: token and the one before it) per slot — the speculative
         #: round's accept/commit writes them and rebuilds the device
         #: vectors by value; drain snapshots read tok_prev from here.
         tok_h = np.zeros((B,), np.int32)
         tok_prev_h = np.zeros((B,), np.int32)
-        # Bookkeeping published on self (mutated in place, so the refs
-        # stay live): the leak/contamination tests read them after
-        # drain(), and a post-mortem can see the pool state.
-        self._pos, self._tables = pos, tables
-        self._free, self._slot_blocks = free, slot_blocks
-        self._ref, self._prefix_index = ref, prefix_index
-        self._block_hash = block_hash
-        #: a window layer's K/V live in the ring of the slot that wrote
-        #: them and nowhere else, so on a model with window layers no
-        #: other stream can resume from a cached prefix: every lookup
-        #: is a miss and nothing is indexed (docs/SERVING.md §4e)
-        share_prefix = fw.prefix_cache and not self.win_ring
         remaining = np.zeros((B,), np.int64)
         sidx = np.zeros((B,), np.int64)
-        slots: list = [None] * B  # (meta, emit) per live slot
-        self._live_slots = slots  # visible to the crash terminator
-        #: per-slot stream id / tenant / original prompt tokens — the
-        #: elastic surface (cancel lookup, quota accounting, drain
-        #: snapshots); set at admission, cleared by retire()
-        self._slot_sid: list = [None] * B
-        self._slot_tenant: list = [None] * B
-        self._slot_prompt: list = [None] * B
-        #: per-slot serving timeline (docs/OBSERVABILITY.md "Distributed
-        #: tracing"): enqueue/admit/first-token/last-emit stamps
-        #: (monotonic seconds) feeding the TTFT / ITL / phase-split
-        #: histograms.  Values are MILLISECONDS (the ``_ms`` series are
-        #: reservoir-quantile sources; the seconds-scaled fixed bucket
-        #: ladder saturates for them).  None for adopted streams — their
-        #: enqueue happened in another process, so TTFT is unknowable.
-        self._slot_time: list = [None] * B
+        slots = self._live_slots  # (meta, emit) per live slot
         eos = getattr(fw.tokenizer, "eos", -1) if fw.stop_eos else -1
 
         def begin(kind: str, tid=None, /, **args):
@@ -1757,74 +1718,12 @@ class _ContinuousLoop:
                        **args)
             return begin("serve.emit", iter=it)
 
-        def take_blocks(need: int) -> list:
-            """Allocate ``need`` private blocks (refcount 1) off the
-            free list, preferring blocks that do NOT hold a cached
-            prefix; when only cached blocks remain, the oldest-released
-            ones are evicted (their index entries dropped) — eviction
-            IS allocation, so the prefix cache can never make admission
-            defer.
-
-            O(need * len(free)) from the head-pops — per ADMISSION,
-            not per token; at the worst-case bench pool (64 7B
-            streams, ~4.6k blocks) that is ~1 ms of host time under
-            the prefill dispatch it precedes.  Revisit with a deque +
-            free-set if pools grow past that."""
-            got: list = []
-            cached: list = []
-            while free and len(got) < need:
-                b = free.pop(0)
-                (cached if b in block_hash else got).append(b)
-            while cached and len(got) < need:
-                b = cached.pop(0)
-                del prefix_index[block_hash.pop(b)]
-                metrics.count("llm.serve.prefix_evictions")
-                got.append(b)
-            free[0:0] = cached  # skipped cached blocks keep their place
-            if len(got) < need:
-                # every caller pre-checks capacity (admission counts
-                # resting matched blocks on top of phys; adopt checks
-                # len(free)); a shortfall here is an allocator-invariant
-                # bug — fail LOUDLY instead of handing back a short
-                # list that becomes a silently truncated block table
-                # and bit-wrong output
-                free[0:0] = got
-                for b in got:
-                    ref[b] = 0
-                raise RuntimeError(
-                    f"KV allocator invariant violated: asked for {need} "
-                    f"blocks, only {len(got)} allocatable")
-            for b in got:
-                ref[b] = 1
-            return got
-
-        def alloc(n_tokens: int) -> list:
-            return take_blocks(math.ceil(n_tokens / bs))
-
-        def release(blocks) -> None:
-            """Drop one reference per block; a block returns to the
-            free list ONLY at refcount 0 (prefix-shared blocks stay
-            resident for their other holders; cached content + index
-            survive until eviction-by-allocation)."""
-            for b in blocks:
-                ref[b] -= 1
-                if ref[b] <= 0:
-                    ref[b] = 0
-                    free.append(b)
-
-        def map_shared(bid: int) -> None:
-            """Take one more reference on a cached/shared block — off
-            the free list if it was resting there at refcount 0."""
-            if ref[bid] == 0:
-                free.remove(bid)
-            ref[bid] += 1
-
-        def cow_fork(src: int, rec=None) -> int:
-            """Copy-on-write fork: a stream about to WRITE into a block
-            it shares gets a private copy first (target AND draft pool
-            rows — an eager value move like adopt's scatter; none of
-            the compiled programs is touched).  The source keeps its
-            other holders' references.
+        def cow_copy(src: int, dst: int) -> None:
+            """The device half of a copy-on-write fork the manager chose
+            at admission: block ``src``'s rows (target AND draft pool)
+            copied into the private block ``dst`` — an eager value move
+            like adopt's scatter; none of the compiled programs is
+            touched.
 
             Trade-off (shared with adopt): the eager ``.at[].set`` holds
             the old pool alive across the update, so XLA materializes a
@@ -1834,9 +1733,8 @@ class _ContinuousLoop:
             census (serving_plan/tracecheck/xray) would have to price;
             revisit if silicon pools sized to the HBM edge OOM here."""
             sp = begin("serve.cow_fork") if rec is not None else None
-            new = take_blocks(1)[0]
             src_i = np.asarray([src], np.int32)
-            new_i = np.asarray([new], np.int32)
+            new_i = np.asarray([dst], np.int32)
             pool["k"] = pool["k"].at[:, new_i].set(pool["k"][:, src_i])
             pool["v"] = pool["v"].at[:, new_i].set(pool["v"][:, src_i])
             if draft_pool is not None:
@@ -1844,34 +1742,8 @@ class _ContinuousLoop:
                     draft_pool["k"][:, src_i])
                 draft_pool["v"] = draft_pool["v"].at[:, new_i].set(
                     draft_pool["v"][:, src_i])
-            metrics.count("llm.serve.cow_forks")
             if sp is not None:
-                sp.end(src=int(src), dst=int(new))
-            return new
-
-        def chain_hashes(row: np.ndarray, full: int) -> list:
-            """Token-block chain hashes: hash j commits to ALL tokens
-            of blocks 0..j, so two prompts share block j only when
-            their entire prefixes match — which is exactly when the
-            cached K/V rows (position-dependent through RoPE) are
-            bit-valid for both."""
-            import hashlib
-
-            h = b"nns-prefix-v1"
-            out = []
-            for j in range(full):
-                h = hashlib.sha1(
-                    h + row[j * bs:(j + 1) * bs].tobytes()).digest()
-                out.append(h)
-            return out
-
-        #: sid -> chain_hashes(prompt) memo for WAITING prompts: a
-        #: capacity-deferred entry is re-scanned every loop iteration,
-        #: and its prompt is immutable after submit — re-hashing a long
-        #: prompt per spin would burn serve-thread time exactly when
-        #: the system is saturated.  Pruned against the live waiting
-        #: set each admission phase, so no path can leak entries.
-        chain_cache: Dict[int, list] = {}
+                sp.end(src=int(src), dst=int(dst))
 
         #: while tracing is on retire() banks the retiring stream's count
         #: of delivered tokens, so serve.emit reads `tokens` and `retired`
@@ -1881,25 +1753,12 @@ class _ContinuousLoop:
         def delivered() -> int:
             return n_banked + int(sidx.sum())
 
-        def win_blocks_live() -> int:
-            """Ring entries that hold rows of a live stream: a slot at
-            position p has written ``ceil(p / bs)`` logical blocks, of
-            which its ring keeps the last ``ring``."""
-            if not self.win_ring:
-                return 0
-            p = pos[pos < self.park]
-            return int(np.minimum(-(-p // bs), self.win_ring).sum())
-
-        self._win_blocks_live = win_blocks_live
-
         def retire(s: int) -> None:
             nonlocal pos_dev, n_retired, n_banked
             if rec is not None:
                 n_retired += 1
                 n_banked += int(sidx[s])
-            release(slot_blocks[s])
-            slot_blocks[s] = []
-            tables[s, :] = self.sentinel
+            kv.release(s)
             pos[s] = self.park
             if pos_dev is not None:
                 # re-park the device twin too: the fused verify carries
@@ -1960,6 +1819,12 @@ class _ContinuousLoop:
                     tenant=self._slot_tenant[s])
                 tt["last"] = now
 
+        def free_slots() -> list:
+            """Slots no stream holds: not live, not mid-prefill."""
+            busy = {st["slot"] for st in self._admitting}
+            return [s for s in range(B) if slots[s] is None
+                    and remaining[s] == 0 and s not in busy]
+
         def slot_of(sid) -> Optional[int]:
             if sid is None:
                 return None
@@ -1984,21 +1849,16 @@ class _ContinuousLoop:
                 self._owned_sids.discard(sid)
                 self._cancelled.pop(sid, None)
 
-        def tenant_blocks(tenant) -> int:
-            return sum(len(slot_blocks[s]) for s in range(B)
-                       if self._slot_tenant[s] == tenant)
-
         # Warm EVERY program the loop uses before admitting real work:
         # first-use costs (trace + compile + program upload) land on the
         # first requests' critical path otherwise.  llama.cpp servers warm up
         # the same way.  Warmup allocates real blocks (exercising the
         # allocator), writes garbage through them, and frees them —
         # nothing real can attend it (the slot re-parks).
-        warm_blocks = alloc(min(C, self.n_blocks * bs))
-        tables[0, :len(warm_blocks)] = warm_blocks
+        kv.reserve(0, min(C, self.n_blocks * bs))
         logits_w, pool = self._prefill(
-            params, jnp.zeros((1, C), jnp.int32), pool, tabs(slice(0, 1)),
-            pos[:1] * 0, np.int32(C - 1))
+            params, jnp.zeros((1, C), jnp.int32), pool,
+            kv.tabs(slice(0, 1)), pos[:1] * 0, np.int32(C - 1))
         key, sub = jax.random.split(key)
         first_w = llama.sample_token(logits_w, sub, fw.temperature,
                                      fw.top_k, fw.top_p)[0]
@@ -2011,21 +1871,20 @@ class _ContinuousLoop:
             # rows through untouched).
             draft_pool = self._draft_prefill(
                 d_params, jnp.zeros((1, C), jnp.int32), draft_pool,
-                tables[:1].copy(), pos[:1] * 0)
+                kv.tabs(slice(0, 1)), pos[:1] * 0)
             props_w, dprobs_w, draft_pool = self._propose(
-                d_params, tok_prev, tok, draft_pool, tables.copy(),
+                d_params, tok_prev, tok, draft_pool, kv.tabs(),
                 pos_dev, keys_dev)
             em_w, acc_w, tok, tok_prev, pos_dev, pool = self._verify(
                 params, tok, tok_prev, props_w, dprobs_w, pool,
-                tables.copy(), pos_dev, keys_dev)
+                kv.tabs(), pos_dev, keys_dev)
             np.asarray(em_w)
         else:
             toks_w, tok, pool = self._decode(
-                params, tok, pool, tabs(), pos.copy(), keys_dev,
+                params, tok, pool, kv.tabs(), pos.copy(), keys_dev,
                 length=fw.chunk)
             np.asarray(toks_w)
-        release(warm_blocks)
-        tables[0, :] = self.sentinel
+        kv.release(0)
 
         # Everything alive now lives as long as the loop does: modules,
         # jax's internals, the compiled programs.  A full collection
@@ -2092,13 +1951,8 @@ class _ContinuousLoop:
                     if s is not None:
                         sp = (begin("elastic.drain")
                               if rec is not None else None)
-                        n_used = math.ceil(int(pos[s]) / bs)
-                        ids = np.asarray(slot_blocks[s][:n_used],
-                                         np.int32)
+                        ids, n_shared = kv.used(s, int(pos[s]))
                         meta, _emit_cb = slots[s]
-                        n_shared = sum(
-                            1 for b in slot_blocks[s][:n_used]
-                            if ref[b] > 1)
                         cmd["result"] = {
                             # v2: adds tok_prev (the speculative
                             # refresh step's input) + shared_blocks;
@@ -2133,7 +1987,7 @@ class _ContinuousLoop:
                             "blocks_k": np.asarray(pool["k"][:, ids]),
                             "blocks_v": np.asarray(pool["v"][:, ids]),
                         }
-                        nb = len(slot_blocks[s])
+                        nb = len(kv.slot_blocks[s])
                         retire(s)
                         if sp is not None:
                             sp.end(stream_id=sid, state="live", blocks=nb)
@@ -2199,31 +2053,23 @@ class _ContinuousLoop:
                     p_next = int(snap["pos"])
                     rem = int(snap["remaining"])
                     need_tok = p_next + rem
-                    freeslots = [
-                        s for s in range(B)
-                        if slots[s] is None and remaining[s] == 0
-                        and not any(st["slot"] == s
-                                    for st in self._admitting)]
+                    need = kv.blocks_for(need_tok)
+                    freeslots = free_slots()
                     if not freeslots:
                         cmd["error"] = "no free slot to adopt into"
-                    elif math.ceil(need_tok / bs) > self.max_blocks:
-                        cmd["error"] = (
-                            f"stream needs {math.ceil(need_tok / bs)} "
-                            f"blocks > table span {self.max_blocks}")
-                    elif len(free) * bs < need_tok:
+                    elif need > self.max_blocks:
+                        cmd["error"] = (f"stream needs {need} blocks > "
+                                        f"table span {self.max_blocks}")
+                    elif len(kv.free) < need:
                         cmd["error"] = (
                             f"insufficient free KV blocks "
-                            f"({len(free)} free, "
-                            f"{math.ceil(need_tok / bs)} needed)")
+                            f"({len(kv.free)} free, {need} needed)")
                     else:
                         sp = (begin("elastic.adopt")
                               if rec is not None else None)
                         s = freeslots[0]
-                        blocks = alloc(need_tok)
-                        slot_blocks[s] = blocks
-                        tables[s, :len(blocks)] = blocks
-                        n_used = math.ceil(p_next / bs)
-                        ids = np.asarray(blocks[:n_used], np.int32)
+                        blocks = kv.reserve(s, need_tok)
+                        ids, _ = kv.used(s, p_next)
                         # eager scatter of the snapshot's cache rows
                         # into the newly reserved pool blocks (a value
                         # move — the compiled census is untouched)
@@ -2345,7 +2191,7 @@ class _ContinuousLoop:
                         sp = (begin("serve.reap", slot=s, stream_id=sid,
                                     reason=reason)
                               if rec is not None else None)
-                        nb = len(slot_blocks[s])
+                        nb = len(kv.slot_blocks[s])
                         live_slot = slots[s] is not None
                         meta, emit_cb = (slots[s] if live_slot
                                          else (st["meta"], st["emit"]))
@@ -2381,12 +2227,8 @@ class _ContinuousLoop:
             # abort instead of wedging every tenant queued behind it,
             # and a tenant over its kv-block quota is SKIPPED — tenant-
             # attributed deferral must not head-of-line-block the rest.
-            if chain_cache:
-                waiting_sids = {e[1].get(elastic.META_STREAM_ID)
-                                for e in self._waiting}
-                for k in [k for k in chain_cache
-                          if k not in waiting_sids]:
-                    del chain_cache[k]
+            kv.prune(e[1].get(elastic.META_STREAM_ID)
+                     for e in self._waiting)
             wi = 0
             while wi < len(self._waiting):
                 prompt, meta, emit, t_enq = self._waiting[wi]
@@ -2430,9 +2272,10 @@ class _ContinuousLoop:
                 # free nor double-charges the physical pool (the free-
                 # list check below is the physical side and charges the
                 # non-shared suffix only).
-                logical = math.ceil((T + n) / bs)
-                if quota is not None and \
-                        tenant_blocks(tenant) + logical > quota:
+                logical = kv.blocks_for(T + n)
+                if quota is not None and logical + kv.held(
+                        s for s in range(B)
+                        if self._slot_tenant[s] == tenant) > quota:
                     if overdue:
                         self._waiting.pop(wi)
                         metrics.count("llm.serve.admit_timeouts")
@@ -2442,48 +2285,12 @@ class _ContinuousLoop:
                     metrics.count("llm.serve.quota_deferred")
                     wi += 1  # skip: quota deferral is tenant-scoped
                     continue
-                # Prefix lookup BEFORE the capacity check: a cache hit
-                # shrinks the PHYSICAL reservation to ~the non-shared
-                # suffix, so a hit prompt admits where a cold one
-                # defers.  The suffix prefill starts at p0 — the
-                # largest prefill_chunk multiple not past the shared
-                # extent (or the last real token): chunk ends stay on
-                # the cold path's grid, so the table-span arithmetic in
-                # serving_plan() is untouched.  A matched block
-                # straddling p0 is copy-on-write FORKED (the chunk
-                # rewrites part of it); matched blocks past p0 are
-                # simply re-prefilled into fresh private blocks.
-                hashes: list = []
-                matched_ids: list = []
-                if share_prefix:
-                    hashes = chain_cache.get(sid)
-                    if hashes is None:
-                        hashes = chain_cache[sid] = chain_hashes(
-                            prompt[0], T // bs)
-                    for h in hashes:
-                        bid = prefix_index.get(h)
-                        if bid is None:
-                            break
-                        matched_ids.append(bid)
-                s0 = len(matched_ids) * bs
-                p0 = min(s0 // C, (T - 1) // C) * C if s0 else 0
-                shared = p0 // bs
-                fork = 1 if p0 % bs else 0
-                phys = logical - shared
-                # matched blocks RESTING in the free list (refcount 0,
-                # cached content) still count as free right now, but
-                # map_shared pulls each one OUT of the list below — the
-                # capacity check must demand phys blocks ON TOP of
-                # them, or take_blocks comes up short and the stream
-                # gets a silently truncated table
-                resting = sum(1 for b in matched_ids[:shared]
-                              if ref[b] == 0)
-                freeslots = np.flatnonzero(remaining == 0)
-                freeslots = [int(s) for s in freeslots
-                             if slots[s] is None and not any(
-                                 st["slot"] == s
-                                 for st in self._admitting)]
-                if not freeslots or len(free) < phys + resting:
+                # the prefix lookup comes BEFORE the capacity check: a
+                # cache hit shrinks the physical reservation to about
+                # the non-shared suffix, prefilled from plan.p0
+                plan = kv.lookup(sid, prompt[0], T, n)
+                freeslots = free_slots()
+                if not freeslots or not kv.fits(plan):
                     if overdue:
                         # head-of-line fix: a wedged/dead/huge stream at
                         # the queue head times out instead of blocking
@@ -2504,30 +2311,23 @@ class _ContinuousLoop:
                     t_admit = time.monotonic_ns()
                 self._waiting.pop(wi)
                 s = freeslots[0]
-                blocks = list(matched_ids[:shared])
-                for bid in blocks:
-                    map_shared(bid)
-                if fork:
-                    blocks.append(cow_fork(matched_ids[shared], rec=rec))
-                blocks.extend(take_blocks(phys - fork))
-                slot_blocks[s] = blocks
-                tables[s, :len(blocks)] = blocks
+                p0, shared, phys = plan.p0, plan.shared, plan.phys
+                forked = kv.admit(s, plan)
+                if forked is not None:
+                    cow_copy(*forked)
                 self._slot_sid[s] = sid
                 self._slot_tenant[s] = tenant
                 self._slot_prompt[s] = prompt[:, :T].copy()
                 self._slot_time[s] = {"enq": t_enq, "admit": t_admit / 1e9,
                                       "first": None, "last": None}
-                if shared:
-                    metrics.count("llm.serve.prefix_hits")
-                    metrics.count("llm.serve.prefix_hit_blocks", shared)
-                    if rec is not None:
-                        rec.record("serve.prefix_hit", _SERVE_STAGE, None,
-                                   t_admit, time.monotonic_ns() - t_admit,
-                                   slot=s, blocks=shared, tokens=p0)
+                if shared and rec is not None:
+                    rec.record("serve.prefix_hit", _SERVE_STAGE, None,
+                               t_admit, time.monotonic_ns() - t_admit,
+                               slot=s, blocks=shared, tokens=p0)
                 # chunk-multiple padding (replaces the old power-of-two
                 # prompt bucketing on this path: waste < one chunk);
                 # only the suffix [p0, P) is prefilled
-                P = p0 + math.ceil((T - p0) / C) * C
+                P = p0 + -(-(T - p0) // C) * C
                 if P > T:
                     prompt = np.pad(prompt, ((0, 0), (0, P - T)))
                 metrics.count("llm.serve.prefill_tokens", P - p0)
@@ -2535,7 +2335,7 @@ class _ContinuousLoop:
                 self._admitting.append({
                     "slot": s, "prompt": prompt.astype(np.int32), "T": T,
                     "P": P, "p": p0, "n": n, "meta": meta, "emit": emit,
-                    "first": None, "hashes": hashes,
+                    "first": None, "hashes": plan.hashes,
                     "last_tok": int(prompt[0, T - 1])})
                 if rec is not None:
                     # what this request's later spans need of its admission
@@ -2574,7 +2374,7 @@ class _ContinuousLoop:
                     off = np.int32(st["T"] - 1 - p if final else 0)
                     logits, pool = self._prefill(
                         params, jnp.asarray(st["prompt"][:, p:p + C]),
-                        pool, tabs(slice(s, s + 1)),
+                        pool, kv.tabs(slice(s, s + 1)),
                         np.asarray([p], np.int32), off)
                     if self._spec:
                         # the draft's prefill twin writes the chunk's
@@ -2584,7 +2384,7 @@ class _ContinuousLoop:
                         draft_pool = self._draft_prefill(
                             d_params,
                             jnp.asarray(st["prompt"][:, p:p + C]),
-                            draft_pool, tables[s:s + 1].copy(),
+                            draft_pool, kv.tabs(slice(s, s + 1)),
                             np.asarray([p], np.int32))
                     st["p"] = p + C
                     budget -= C
@@ -2651,15 +2451,8 @@ class _ContinuousLoop:
                         # register the prompt's full blocks in the
                         # prefix index (content is in-flight on device;
                         # pool donation chains order any reader after
-                        # this prefill).  Forked/shared blocks' hashes
-                        # are already present — only fresh tails
-                        # register.
-                        if share_prefix:
-                            for j, h in enumerate(st["hashes"]):
-                                if h not in prefix_index:
-                                    bid = slot_blocks[s][j]
-                                    prefix_index[h] = bid
-                                    block_hash[bid] = h
+                        # this prefill)
+                        kv.register(s, st["hashes"])
                         pos[s] = st["T"]
                         remaining[s] = st["n"] - 1
                         sidx[s] = 1
@@ -2696,21 +2489,21 @@ class _ContinuousLoop:
                     # Step 4's retires re-park pos_dev AFTER this
                     # rebind, so a first-token EOS still wins.
                     props_dev, dprobs_dev, draft_pool = self._propose(
-                        d_params, tok_prev, tok, draft_pool, tables.copy(),
+                        d_params, tok_prev, tok, draft_pool, kv.tabs(),
                         pos_dev, keys_dev)
                     (em_dev, acc_dev, tok, tok_prev, pos_dev,
                      pool) = self._verify(
                         params, tok, tok_prev, props_dev, dprobs_dev,
-                        pool, tables.copy(), pos_dev, keys_dev)
+                        pool, kv.tabs(), pos_dev, keys_dev)
                     metrics.count("llm.serve.spec_rounds")
                 else:
                     toks_dev, tok, pool = self._decode(
-                        params, tok, pool, tabs(), pos.copy(),
+                        params, tok, pool, kv.tabs(), pos.copy(),
                         keys_dev, length=fw.chunk)
                     pos[live] += fw.chunk  # parked rows stay parked
                 progressed = True
             metrics.gauge("llm.serve.occupancy", float(live.sum()))
-            metrics.gauge("llm.serve.free_blocks", float(len(free)))
+            metrics.gauge("llm.serve.free_blocks", float(len(kv.free)))
             metrics.gauge("llm.serve.waiting",
                           float(len(self._waiting) + len(self._admitting)))
 
@@ -2869,8 +2662,8 @@ class _ContinuousLoop:
                 sp_iter.end(hold=True, live=int(live.sum()),
                             waiting=len(self._waiting)
                             + len(self._admitting),
-                            full_blocks=self.n_blocks - len(free),
-                            win_blocks=win_blocks_live())
+                            full_blocks=self.n_blocks - len(kv.free),
+                            win_blocks=kv.win_blocks_live(pos))
                 if progressed:
                     n_iter = it
                     sp_iter.commit()

@@ -846,8 +846,8 @@ def quantize_int8(params: Dict) -> Dict:
     mats + lm_head as int8 halves bytes/token vs bf16.  Consumption is
     scale-AFTER-dot (see :func:`_mm`): the int8->bf16 convert fuses into
     the dot's operand read so dequant costs no extra HBM traffic, which
-    premultiplying the scale would break (tools/probe_int8_dot.py).  Norms and the embedding table (gather — tiny
-    per-token traffic) stay full precision.
+    premultiplying the scale would break.  Norms and the embedding table
+    (gather — tiny per-token traffic) stay full precision.
 
     Quantization runs ON DEVICE via jit: 7B params are materialized in
     HBM (13.5 GB bf16) and must never round-trip to the host — a numpy
@@ -904,8 +904,7 @@ def _mm(h, lp: Dict, key: str, dt):
     fuses into the dot's operand read, so the weights stream through the
     MXU at 1 byte/param; premultiplying the scale instead
     (``h @ (q.astype(dt) * s)``) forces XLA to materialize a full bf16
-    copy of every mat in HBM — measured 4x slower per mat on v5e
-    (tools/probe_int8_dot.py).  int8 values are integers <= 127, exactly
+    copy of every mat in HBM.  int8 values are integers <= 127, exactly
     representable in bf16, so postscale is also the more accurate order.
     """
     if key + "_q" in lp:

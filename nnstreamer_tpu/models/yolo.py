@@ -122,7 +122,7 @@ def _poly_coeffs(g: int, n_out: int, n_anchor: int, box_a):
     head, out = A*sigmoid(raw)^2 + B*sigmoid(raw) + C over the flattened
     [N_s, n_out] scale block — the whole box decode as ONE lane-friendly
     pass (the textbook slice/meshgrid/stack form builds minor-dim-3/4
-    tensors that TPU pads to 128 lanes; ROADMAP S5).  ``box_a``: [n_anchor, 2] quadratic
+    tensors that TPU pads to 128 lanes).  ``box_a``: [n_anchor, 2] quadratic
     coefficients for the w/h channels (4*anchor, already in the head's
     output units).  Channels: 0/1 affine cell-centers, 2/3 quadratic
     w/h, the rest identity (scores)."""

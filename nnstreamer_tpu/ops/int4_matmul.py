@@ -1,7 +1,7 @@
 """Weight-only int4 (w4a16) matmul as a Pallas TPU kernel.
 
 Why a kernel: the 7B decode step is bound by streaming the weights from
-HBM (ROADMAP S3), so bytes/token is the lever.
+HBM, so bytes/token is the lever.
 Nibble-packing weights halves bytes, but XLA cannot consume a packed
 buffer in one pass — the natural two-dot formulation fuses each nibble's
 unpack into its own dot and reads every packed byte TWICE (measured

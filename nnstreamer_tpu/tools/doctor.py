@@ -18,8 +18,8 @@ verdict into one report with a machine-readable JSON twin.
     # CI gate mode: deterministic verdict lines (tools/xray_baseline.txt)
     python -m nnstreamer_tpu.tools.doctor --gate
 
-    # bench mode: xray-off vs xray-on wall-time A/B (the bench_all
-    # `doctor_overhead` sentinel row's {"metric": ...} contract)
+    # bench mode: xray-off vs xray-on wall-time A/B, printed as one
+    # {"metric": "doctor_overhead", ...} JSON line
     python -m nnstreamer_tpu.tools.doctor --bench
 
 See docs/OBSERVABILITY.md "Predicted vs actual".
@@ -32,9 +32,8 @@ import json
 import sys
 import time
 
-#: the built-in bench pipeline: the adaptive-batching bench's shape
-#: (bench.py --config batching) at doctor scale — a backlogged device
-#: filter whose bucket ladder, single-buffer program, and activation
+#: the built-in bench pipeline: a backlogged small-model batching
+#: pipeline at doctor scale — a device filter whose bucket ladder, single-buffer program, and activation
 #: window all exercise the census + ledger
 BENCH_DIMS = 64
 BENCH_DESC = (
@@ -111,7 +110,7 @@ def main(argv=None) -> int:
                          "on drift")
     ap.add_argument("--bench", action="store_true",
                     help="xray-off vs xray-on wall A/B; prints the "
-                         "bench_all {\"metric\": ...} JSON line")
+                         "one {\"metric\": ...} JSON line")
     args = ap.parse_args(argv)
 
     from ..core.log import metrics
@@ -149,7 +148,7 @@ def main(argv=None) -> int:
                     "must be 0",
         }))
         # the advertised pin: a bench row with live census drift is a
-        # regression, not a measurement (bench_all fails the row on rc)
+        # regression, not a measurement (the exit code says so)
         return 0 if drift == 0 else 1
 
     metrics.reset()

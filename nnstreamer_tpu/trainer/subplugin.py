@@ -17,8 +17,7 @@ TPU-first design (docs/TRAINING.md):
   (``dynamic_update_slice`` at a traced index — the device-aggregator
   ring's exact move) and a full window dispatches one update step; the
   host never holds an epoch of samples.  ``host-accumulate=true`` keeps
-  the legacy stack-the-epoch path for A/B comparison
-  (``bench.py --config train_stream``).
+  the legacy stack-the-epoch path for A/B comparison.
 * **Closed census.**  The stage compiles exactly
   :data:`TRAINER_PROGRAMS` programs for its lifetime — append, step,
   eval — with every shape static (a partial tail window steps through
@@ -739,7 +738,7 @@ class JaxTrainer(TrainerSubplugin):
     def _train_host(self, train) -> None:
         """Legacy host-accumulated epoch (``host-accumulate=true``): the
         whole epoch stacks on host, minibatches slice from the stack.
-        Kept as the ``bench.py --config train_stream`` A/B baseline; the
+        Kept as the A/B baseline of the streaming window; the
         step program is SHARED with the streaming path (same masked
         signature), so the census stays closed either way."""
         bs = max(1, self.batch_size)

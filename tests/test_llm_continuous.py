@@ -125,9 +125,9 @@ class TestBlockAllocator:
             got = _serve_tokens(fw, prompts)
             assert all(len(v) == 5 for v in got.values())
             serve = fw._serve
-            assert sorted(serve._free) == list(range(serve.n_blocks))
-            assert (serve._tables == serve.sentinel).all()
-            assert all(not b for b in serve._slot_blocks)
+            assert sorted(serve.kv.free) == list(range(serve.n_blocks))
+            assert (serve.kv.tables == serve.sentinel).all()
+            assert all(not b for b in serve.kv.slot_blocks)
             assert (serve._pos == serve.park).all()
         finally:
             fw.close()

@@ -1,26 +1,21 @@
 """Entry-point device discipline (core/platform.py): a measurement script
-refuses a non-TPU platform unless ``JAX_PLATFORMS`` pins ``cpu``, every
-bench row names the device it ran on, and the compile cache is placed
-from outside when ``JAX_COMPILATION_CACHE_DIR`` is set."""
+refuses a non-TPU platform unless ``JAX_PLATFORMS`` pins ``cpu``, stamps
+the device it ran on, and the compile cache is placed from outside when
+``JAX_COMPILATION_CACHE_DIR`` is set."""
 
-import json
 import os
-import sys
 
 import jax
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import bench  # noqa: E402
-from nnstreamer_tpu.core import platform  # noqa: E402
+from nnstreamer_tpu.core import platform
 
 
 def test_require_tpu_refuses_unpinned_cpu(monkeypatch):
     # a host with no chip and no JAX_PLATFORMS resolves to CPU silently
     monkeypatch.delenv("JAX_PLATFORMS")
     with pytest.raises(SystemExit) as e:
-        platform.require_tpu("bench.py")
+        platform.require_tpu("chip_smoke.py")
     assert "needs a TPU" in str(e.value) and "'cpu'" in str(e.value)
 
 
@@ -28,32 +23,14 @@ def test_require_tpu_refuses_cpu_listed_second(monkeypatch):
     # "tpu,cpu" asks for a TPU; landing on the CPU is not what was asked
     monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
     with pytest.raises(SystemExit):
-        platform.require_tpu("bench.py")
+        platform.require_tpu("chip_smoke.py")
 
 
 def test_require_tpu_allows_pinned_cpu_and_stamps_device(monkeypatch):
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    dev = platform.require_tpu("bench.py")
+    dev = platform.require_tpu("chip_smoke.py")
     assert dev == {"platform": "cpu", "device_kind": "cpu",
                    "device_count": len(jax.devices())}
-
-
-def test_bench_main_exits_nonzero_without_tpu(monkeypatch, capsys):
-    monkeypatch.delenv("JAX_PLATFORMS")
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--config", "link"])
-    with pytest.raises(SystemExit) as e:
-        bench.main()
-    assert e.value.code not in (0, None)
-    assert capsys.readouterr().out.strip() == ""  # no row, no 0.0 record
-
-
-def test_bench_rows_name_their_device(monkeypatch, capsys):
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--config", "link"])
-    assert bench.main() == 0
-    row = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert row["platform"] == "cpu" and row["device_kind"] == "cpu"
-    assert row["device_count"] == len(jax.devices())
 
 
 @pytest.fixture

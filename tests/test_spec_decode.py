@@ -144,11 +144,11 @@ class TestRefcountAllocator:
         assert stats["live_streams"] == 2
         assert stats["blocks_shared"] >= 4, stats  # 32-token prefix / 8
         shared_ids = [b for b in range(serve.n_blocks)
-                      if serve._ref[b] > 1]
+                      if serve.kv.ref[b] > 1]
         assert fw.drain(180)
         # retired: every shared block released down to 0 and free again
-        assert sorted(serve._free) == list(range(serve.n_blocks))
-        assert (np.asarray(serve._ref) == 0).all()
+        assert sorted(serve.kv.free) == list(range(serve.n_blocks))
+        assert (np.asarray(serve.kv.ref) == 0).all()
         assert shared_ids, "expected shared blocks while both live"
         fw.close()
         del short
@@ -176,7 +176,7 @@ class TestRefcountAllocator:
             # the (unperturbed) cached blocks
             got = _serve_tokens(fw, [p])
             assert got[0] == want
-            assert sorted(fw._serve._free) == \
+            assert sorted(fw._serve.kv.free) == \
                 list(range(fw._serve.n_blocks))
         finally:
             fw.close()
@@ -198,12 +198,12 @@ class TestRefcountAllocator:
             for i in range(len(prompts)):
                 assert got[i] == want[i], f"stream {i} after recycle"
             serve = fw._serve
-            assert sorted(serve._free) == list(range(serve.n_blocks))
-            assert (np.asarray(serve._ref) == 0).all()
+            assert sorted(serve.kv.free) == list(range(serve.n_blocks))
+            assert (np.asarray(serve.kv.ref) == 0).all()
             assert _metric("llm.serve.prefix_evictions") > e0
             # index never points at an unindexed block and vice versa
-            assert set(serve._prefix_index.values()) == \
-                set(serve._block_hash.keys())
+            assert set(serve.kv.prefix_index.values()) == \
+                set(serve.kv.block_hash.keys())
         finally:
             fw.close()
 
@@ -325,7 +325,7 @@ class TestPrefixSharing:
             assert _metric("llm.serve.prefix_hits") > h0
             assert got[2] == want_b, (got[2], want_b)
             serve = fw._serve
-            assert sorted(serve._free) == list(range(serve.n_blocks))
+            assert sorted(serve.kv.free) == list(range(serve.n_blocks))
         finally:
             fw.close()
 
@@ -364,7 +364,7 @@ class TestPrefixSharing:
                 time.sleep(0.01)
             assert fw._serve.pool_stats()["live_streams"] == 1
             # plenty of PHYSICAL space all along
-            assert len(fw._serve._free) > 2
+            assert len(fw._serve.kv.free) > 2
             # stream 1 admits after stream 0 retires
             assert fw.drain(180)
             assert len(got[1]) == 24
@@ -524,7 +524,7 @@ class TestSpeculativeDecoding:
             got = _serve_staggered(fw, [pa, pb])
             assert got[0] == want[0] and got[1] == want[1]
             assert _metric("llm.serve.prefix_hits") > h0
-            assert sorted(fw._serve._free) == \
+            assert sorted(fw._serve.kv.free) == \
                 list(range(fw._serve.n_blocks))
         finally:
             fw.close()

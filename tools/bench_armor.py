@@ -16,8 +16,8 @@ page-cache write + an amortized fsync, not a per-request fsync).
 Row 2, ``yank_process``: tools/soak.py --yank in a subprocess — the
 kill -9 / journal-replay exactly-once demonstration (see soak.py).
 
-The stdout tail is one {"metric": ...} JSON line so tools/bench_all.py
-ingests the overhead number as a sweep row.
+The stdout tail is one {"metric": ...} JSON line carrying the overhead
+number.
 """
 
 from __future__ import annotations

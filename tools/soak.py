@@ -66,8 +66,8 @@ journal entry at the kill is re-admitted and answered (acked) exactly
 once by the restarted process, the journal ends fully answered, and no
 client loses a request.
 
-The stdout tail is one JSON line carrying ``"metric"`` so
-``tools/bench_all.py`` ingests the result as a sweep row.
+The stdout tail is one JSON line carrying ``"metric"``: the run's
+summary for whatever collects it.
 """
 
 from __future__ import annotations
@@ -1481,7 +1481,7 @@ def main() -> int:
         json.dump(doc, f, indent=1)
     total_fps = sum(t.get("sustained_fps", 0.0)
                     for r in rows for t in r.get("tenants", {}).values())
-    # the bench_all-ingestable summary line (last JSON line with "metric")
+    # the summary line (last JSON line with "metric")
     print(json.dumps({
         "metric": "soak_sustained_fps_sum", "value": round(total_fps, 2),
         "unit": "fps",

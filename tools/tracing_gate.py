@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Tracing gate (ISSUE 5 / docs/OBSERVABILITY.md), run by check_tier1.py:
 
-1. **ring e2e**: a backlogged batching pipeline (the bench.py
-   ``--config batching`` shape) runs with ``trace_mode=ring``; the dumped
+1. **ring e2e**: a backlogged batching pipeline (a small model behind
+   a deep queue) runs with ``trace_mode=ring``; the dumped
    Chrome JSON must schema-validate (monotonic ts), contain at least one
    batched dispatch span LINKING >1 member-row trace ids, and
    ``metrics_text()`` must expose bucketed histogram series (with
@@ -101,8 +101,7 @@ HOOKS_PER_BUFFER = 20
 
 def measure_guard_ns(iters: int = 500_000) -> float:
     """Cost of ONE off-mode hook: the ``is not None`` pointer check every
-    instrumentation site reduces to (same microbench bench.py records as
-    ``trace_off_guard_ns``).  Empty-loop baseline subtracted."""
+    instrumentation site reduces to.  Empty-loop baseline subtracted."""
     tr = None
     t0 = time.perf_counter()
     for _ in range(iters):

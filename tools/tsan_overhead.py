@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""nns-tsan off-mode overhead sentinel (ISSUE 17), a bench_all.py row.
+"""nns-tsan off-mode overhead sentinel (ISSUE 17).
 
 With ``NNS_TPU_TSAN`` unset the lock factories in
 ``nnstreamer_tpu.utils.locks`` return PLAIN ``threading`` primitives and
@@ -22,8 +22,8 @@ Two pins, both required for a passing row:
 2. **arithmetic**: guard_ns × HOOKS_PER_BUFFER ≤ 2% of per-buffer
    service time.
 
-Prints the one-line ``{"metric": ...}`` JSON contract bench_all.py
-rows use; exits non-zero if either pin fails.
+Prints one ``{"metric": ...}`` JSON line; exits non-zero if either pin
+fails.
 """
 
 from __future__ import annotations
