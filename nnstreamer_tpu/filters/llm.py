@@ -81,6 +81,52 @@ _NO_TRACE = (None, None, 0)
 log = logger(__name__)
 
 
+class _Tail:
+    """What one stream is still owed of a settled decode chunk, BY VALUE:
+    its slot may be seated again before these tokens have left."""
+
+    __slots__ = ("meta", "emit", "start", "toks", "last", "time", "sid",
+                 "tenant", "done")
+
+    def __init__(self, meta, emit, start, toks, last, sid, tenant,
+                 time_rec):
+        self.meta, self.emit = meta, emit
+        self.start = start    # stream index of toks[0]
+        self.toks = toks      # the chunk's tokens this stream emits
+        self.last = last      # toks[-1] ends the stream
+        self.sid, self.tenant = sid, tenant
+        self.time = time_rec  # the slot's timeline record (or None)
+        self.done = 0         # tokens of toks that have left
+
+
+class _Settled:
+    """One decode chunk after settling (``_ContinuousLoop`` step 5): the
+    host's books are closed on it, its tokens have yet to leave."""
+
+    __slots__ = ("iter", "rows", "retired")
+
+    def __init__(self, it, rows):
+        self.iter, self.rows = it, rows
+        self.retired = sum(1 for r in rows if r.last)
+
+    def order(self, ending_first: bool):
+        """``(tail, j, may_wait)`` for every token still owed, in the
+        order they leave: token j of every stream before token j+1 of
+        any; with ``ending_first`` the streams that end here go before
+        that, each whole, and ``may_wait`` marks what follows them and
+        every other stream's first token of the chunk.  Lazy: the caller
+        advances ``tail.done`` as it goes and may stop."""
+        if ending_first:
+            for r in self.rows:
+                if r.last:
+                    for j in range(r.done, len(r.toks)):
+                        yield r, j, False
+        for j in range(max((len(r.toks) for r in self.rows), default=0)):
+            for r in self.rows:
+                if r.done == j and j < len(r.toks):
+                    yield r, j, ending_first and j > 0
+
+
 def _next_bucket(t: int) -> int:
     """Smallest power-of-two >= t (min 32): bounds distinct prefill
     compilations at log2(max_seq) programs for arbitrary prompt mixes."""
@@ -1092,6 +1138,12 @@ class _ContinuousLoop:
         #: abort it instead of stranding its client
         self._waiting: list = []
         self._admitting: list = []
+        #: the decode chunk that is settled (slots, blocks and counters
+        #: say it happened) while some of its tokens have not left yet:
+        #: set from settling to the end of its delivery, which may be put
+        #: off until the next chunk is dispatched (step 5).  Crash-visible
+        #: like the two above: a stream that retired in it is in no slot.
+        self._undelivered: Optional[_Settled] = None
         # -- elastic serving state (docs/SERVING.md "Elastic serving") --
         #: control commands (drain/adopt) from app threads, processed at
         #: chunk boundaries; each is a dict with an Event the caller
@@ -1520,6 +1572,92 @@ class _ContinuousLoop:
               np.frombuffer(piece, np.uint8).copy()], out_meta)
         metrics.count("llm.tokens")
 
+    @staticmethod
+    def _mark_emit(tt: Optional[Dict], tenant) -> None:
+        """One emitted token's wall stamp on its stream's timeline record
+        ``tt``: first emission observes TTFT (enqueue → first token, the
+        client-visible number), later ones observe the inter-token gap.
+        Chunked decode materializes a whole chunk at once, so intra-chunk
+        ITL samples are ~0 and the chunk boundary carries the gap — that
+        IS the emission timeline a streaming client sees."""
+        if tt is None:
+            return  # adopted stream (or warmup): no local enqueue
+        now = time.monotonic()
+        if tt["first"] is None:
+            tt["first"] = tt["last"] = now
+            metrics.observe_latency(
+                "llm.serve.ttft_ms", (now - tt["enq"]) * 1e3, tenant=tenant)
+        else:
+            metrics.observe_latency(
+                "llm.serve.itl_ms", (now - tt["last"]) * 1e3, tenant=tenant)
+            tt["last"] = now
+
+    def _close_stream(self, sid, tenant, tt: Optional[Dict]) -> None:
+        """The delivering half of a retirement (the settling half is
+        ``vacate`` in ``_run_inner``): the stream's last token has left,
+        so it leaves the registry and its phase splits are observed from
+        the stamps delivery took."""
+        if sid is not None:
+            elastic.unregister_stream(sid)
+            self._owned_sids.discard(sid)
+            self._cancelled.pop(sid, None)
+        if tt is not None and tt["first"] is not None:
+            # time queued, time from admission to first token (prefill +
+            # first dispatch), time spent decoding
+            metrics.observe_latency(
+                "llm.serve.queue_ms", (tt["admit"] - tt["enq"]) * 1e3,
+                tenant=tenant)
+            metrics.observe_latency(
+                "llm.serve.prefill_ms", (tt["first"] - tt["admit"]) * 1e3,
+                tenant=tenant)
+            metrics.observe_latency(
+                "llm.serve.decode_ms", (tt["last"] - tt["first"]) * 1e3,
+                tenant=tenant)
+
+    def _deliver(self, rec, ahead: bool, until: int = 0) -> None:
+        """Hand the settled chunk's tokens downstream and close the books
+        of the streams that end in it.  ``ahead``: the next decode chunk
+        was dispatched before this began, so the chip works underneath.
+
+        ``until`` > 0 is the delivery made with NOTHING queued on the
+        chip, because streams ended in the chunk and no request was
+        waiting.  The streams that end leave first, each whole — their
+        callers' next requests are what the idle chip is waiting for —
+        then every other stream's first token of the chunk, so that no
+        stream's gap at the boundary grows by the admission to come; from
+        there on the delivery stops as soon as ``until`` requests are
+        queued (as many as streams ended: the freed callers have all
+        answered) and leaves the rest for after the next dispatch.
+        Without ``until``: token j of every stream before token j+1 of
+        any, to the end, picking up where an earlier delivery stopped
+        (``_Tail.done``) — also what the crash terminator relies on."""
+        ch = self._undelivered
+        if ch is None:
+            return
+        sp = None
+        if rec is not None:
+            sp = tracing.span(rec, "serve.emit", _SERVE_STAGE, None,
+                              iter=ch.iter).begin()
+        n_tok = n_end = 0
+        queued = self._pending.qsize
+        for r, j, may_wait in ch.order(ending_first=until > 0):
+            if may_wait and queued() >= until:
+                break
+            last = r.last and j + 1 == len(r.toks)
+            self._emit_token(r.emit, r.meta, r.toks[j], r.start + j, last)
+            r.done = j + 1
+            n_tok += 1
+            self._mark_emit(r.time, r.tenant)
+            if last:
+                n_end += 1
+                self._close_stream(r.sid, r.tenant, r.time)
+        else:
+            self._undelivered = None
+        if ahead:
+            metrics.count("llm.serve.deliver_ahead")
+        if sp is not None:
+            sp.end(tokens=n_tok, retired=n_end, ahead=int(ahead))
+
     def _run(self) -> None:
         try:
             self._run_inner()
@@ -1545,6 +1683,19 @@ class _ContinuousLoop:
             # after the drain.
             import queue as _q
 
+            # A settled chunk first: the streams in it get the tokens they
+            # are still owed, in order and before any terminator; one that
+            # retired in it is in no slot below, so if its tail cannot
+            # leave either it gets its abort here.
+            ch = self._undelivered
+            if ch is not None:
+                try:
+                    self._deliver(None, False)
+                except Exception:  # noqa: BLE001 - downstream may be gone
+                    self._undelivered = None
+                for r in ch.rows:
+                    if r.last and r.done < len(r.toks):
+                        abort(r.meta, r.emit, r.start + r.done)
             for slot in list(self._live_slots):
                 if slot is not None:
                     abort(slot[0], slot[1], 1 << 30)
@@ -1706,17 +1857,16 @@ class _ContinuousLoop:
             return tracing.span(rec, kind, _SERVE_STAGE, tid,
                                 **args).begin()
 
-        def decode_closed(kind: str, sp_wait, **args):
+        def decode_closed(kind: str, sp_wait, **args) -> None:
             """The chunk (or speculative round) materialized: its ring
             span opened at the dispatch in step 3, so it is recorded from
             stamps; the blocking wait alone was its profiler annotation
-            (held: never a ring span of its own).  Delivery starts."""
+            (held: never a ring span of its own)."""
             sp_wait.end(hold=True)
             rec.record(kind, _SERVE_STAGE, None, t_dec,
                        sp_wait.ts + sp_wait.dur - t_dec, iter=it,
                        occupancy=int(live.sum()), wait_ns=sp_wait.dur,
                        **args)
-            return begin("serve.emit", iter=it)
 
         def cow_copy(src: int, dst: int) -> None:
             """The device half of a copy-on-write fork the manager chose
@@ -1745,19 +1895,16 @@ class _ContinuousLoop:
             if sp is not None:
                 sp.end(src=int(src), dst=int(dst))
 
-        #: while tracing is on retire() banks the retiring stream's count
-        #: of delivered tokens, so serve.emit reads `tokens` and `retired`
-        #: as deltas around the delivery loop: nothing is counted per token
-        n_retired = n_banked = 0
+        def holder(s: int) -> tuple:
+            """``(stream id, tenant, timeline record)`` of the stream in
+            slot ``s``: what ``_close_stream`` needs of it."""
+            return (self._slot_sid[s], self._slot_tenant[s],
+                    self._slot_time[s])
 
-        def delivered() -> int:
-            return n_banked + int(sidx.sum())
-
-        def retire(s: int) -> None:
-            nonlocal pos_dev, n_retired, n_banked
-            if rec is not None:
-                n_retired += 1
-                n_banked += int(sidx[s])
+        def vacate(s: int) -> None:
+            """The settling half of a retirement: slot ``s`` and its
+            blocks are free for the next admission."""
+            nonlocal pos_dev
             kv.release(s)
             pos[s] = self.park
             if pos_dev is not None:
@@ -1771,53 +1918,59 @@ class _ContinuousLoop:
             slots[s] = None
             remaining[s] = 0
             sidx[s] = 0
-            sid = self._slot_sid[s]
-            if sid is not None:
-                elastic.unregister_stream(sid)
-                self._owned_sids.discard(sid)
-                self._cancelled.pop(sid, None)
-            tt = self._slot_time[s]
-            if tt is not None and tt["first"] is not None:
-                # per-stream phase splits at retirement: time queued,
-                # time from admission to first token (prefill + first
-                # dispatch), time spent decoding
-                ten = self._slot_tenant[s]
-                metrics.observe_latency(
-                    "llm.serve.queue_ms",
-                    (tt["admit"] - tt["enq"]) * 1e3, tenant=ten)
-                metrics.observe_latency(
-                    "llm.serve.prefill_ms",
-                    (tt["first"] - tt["admit"]) * 1e3, tenant=ten)
-                metrics.observe_latency(
-                    "llm.serve.decode_ms",
-                    (tt["last"] - tt["first"]) * 1e3, tenant=ten)
             self._slot_time[s] = None
             self._slot_sid[s] = None
             self._slot_tenant[s] = None
             self._slot_prompt[s] = None
+            # here and not with the books: the slot may be seated again,
+            # and gauged 1, before its old stream's tail has left
             metrics.gauge(f"llm.serve.slot{s}.occupied", 0.0)
 
-        def mark_emit(s: int) -> None:
-            """One emitted token's wall stamp: first emission observes
-            TTFT (enqueue → first token, the client-visible number),
-            later ones observe the inter-token gap.  Chunked decode
-            materializes a whole chunk at once, so intra-chunk ITL
-            samples are ~0 and the chunk boundary carries the gap —
-            that IS the emission timeline a streaming client sees."""
-            tt = self._slot_time[s]
-            if tt is None:
-                return  # adopted stream (or warmup): no local enqueue
-            now = time.monotonic()
-            if tt["first"] is None:
-                tt["first"] = tt["last"] = now
-                metrics.observe_latency(
-                    "llm.serve.ttft_ms", (now - tt["enq"]) * 1e3,
-                    tenant=self._slot_tenant[s])
-            else:
-                metrics.observe_latency(
-                    "llm.serve.itl_ms", (now - tt["last"]) * 1e3,
-                    tenant=self._slot_tenant[s])
-                tt["last"] = now
+        def retire(s: int) -> None:
+            """Both halves at once, for a stream that is owed nothing."""
+            who = holder(s)
+            vacate(s)
+            self._close_stream(*who)
+
+        def settle(host: np.ndarray) -> _Settled:
+            """The settling half of step 5, over the materialized chunk
+            ``host[B, chunk]``: everything the host must know before it
+            can dispatch again, as vector tests — for every row that
+            decoded, how many of the chunk's tokens it emits (what it had
+            left, cut at the first ``eos``) and whether that ends it; from
+            that ``remaining``, ``sidx``, the last two tokens a row keeps,
+            and for a row that ends the slot and its blocks.  What is left
+            per TOKEN — the emission itself, its stamp, the registry and
+            the phase histograms of a stream that ended — is the record
+            returned, by value."""
+            # remaining == 0 on a dispatched row: retired at its first
+            # token in step 4, the chunk's row is garbage
+            rows = np.flatnonzero(live & (remaining > 0))
+            mine = host[rows]
+            n = np.minimum(remaining[rows], mine.shape[1])
+            ends = n == remaining[rows]
+            if eos >= 0:
+                hit = mine == eos
+                cut = np.where(hit.any(axis=1), hit.argmax(axis=1) + 1,
+                               mine.shape[1] + 1)
+                ends |= cut <= n
+                n = np.minimum(n, cut)
+            at = np.arange(len(rows))
+            tok_prev_h[rows] = np.where(
+                n > 1, mine[at, np.maximum(n - 2, 0)], tok_h[rows])
+            tok_h[rows] = mine[at, n - 1]
+            start = sidx[rows]
+            sidx[rows] += n
+            remaining[rows] -= n
+            tails = []
+            for s, n_s, first, end, toks in zip(
+                    rows.tolist(), n.tolist(), start.tolist(),
+                    ends.tolist(), mine.tolist()):
+                tails.append(_Tail(*slots[s], first, toks[:n_s], end,
+                                   *holder(s)))
+                if end:
+                    vacate(s)
+            return _Settled(it, tails)
 
         def free_slots() -> list:
             """Slots no stream holds: not live, not mid-prefill."""
@@ -1906,10 +2059,10 @@ class _ContinuousLoop:
             # before the iteration knows whether it will do anything are
             # HELD and reach the ring only if it progressed — an idle
             # loop spinning at 50 Hz must not evict the flight recorder.
+            it = n_iter + 1
             rec = getattr(fw, "_trace_rec", None)
             if rec is not None:
                 if rec.active:
-                    it = n_iter + 1
                     sp_iter = begin("serve.iter", iter=it)
                     sp_phase = begin("serve.intake", iter=it)
                     n_taken = -len(self._waiting)
@@ -1931,6 +2084,11 @@ class _ContinuousLoop:
             # moves plus eager gather/scatter on the pool — none of the
             # three compiled loop programs is touched, so the census pin
             # holds across drain/adopt (tests/test_elastic.py).
+            # A command ends, moves or reads a stream, so the chunk whose
+            # delivery step 5 put off leaves first: a drain's snapshot
+            # equals what its client has received.
+            if self._ctl:
+                self._deliver(rec, False)
             deferred_cmds = []
             while self._ctl:
                 cmd = self._ctl.popleft()
@@ -2177,6 +2335,8 @@ class _ContinuousLoop:
                         self._cancelled.items()):
                     if now_m < deadline:
                         continue
+                    # the stream's tokens first, then its terminator
+                    self._deliver(rec, False)
                     s = slot_of(sid)
                     st = next(
                         (st for st in self._admitting
@@ -2413,6 +2573,9 @@ class _ContinuousLoop:
                                          meta=dict(st["meta"])),
                                     error=err, stage="llm.serve")
                             metrics.count("llm.serve.poisoned")
+                            # the slot's last stream may still be owed
+                            # its tail (step 5): that leaves first
+                            self._deliver(rec, False)
                             self._admitting.remove(st)
                             reject(st["meta"], st["emit"], "poison")
                             retire(s)
@@ -2521,7 +2684,7 @@ class _ContinuousLoop:
                 first_last = st["n"] == 1 or first == eos
                 self._emit_token(st["emit"], st["meta"], first, 0,
                                  first_last)
-                mark_emit(s)
+                self._mark_emit(self._slot_time[s], self._slot_tenant[s])
                 if rec is not None:
                     sp.end()
                     if t_adm is not None:
@@ -2540,7 +2703,16 @@ class _ContinuousLoop:
                     # decodes garbage that step 5 skips via remaining==0
                     retire(s)
 
-            # 5. deliver the chunk's tokens
+            # 4b. what step 5 put off of the last chunk's delivery (all of
+            # it, or the rest once the freed callers' requests were in)
+            # leaves now: the chip has the next chunk (or, when every row
+            # retired and nothing went live, nothing left to do)
+            if self._undelivered is not None:
+                self._deliver(rec, toks_dev is not None)
+                progressed = True
+
+            # 5. wait for the chunk, settle it, and deliver its tokens now
+            # or under the next chunk
             if toks_dev is not None:
                 if rec is not None:
                     sp = begin("serve.decode.wait", iter=it)
@@ -2561,28 +2733,23 @@ class _ContinuousLoop:
                     # dispatch, so a span closed there would time host
                     # dispatch (~us) and hide the actual device time —
                     # the number the trace exists to attribute
-                    sp = decode_closed("serve.decode", sp, chunk=fw.chunk,
-                                       **moe_args)
-                    n_tok0, n_ret0 = delivered(), n_retired
-                for j in range(host.shape[1]):
-                    for s in np.flatnonzero(live):
-                        if remaining[s] == 0:
-                            continue  # finished mid-chunk: discard
-                        meta, emit = slots[s]
-                        tokid = int(host[s, j])
-                        last = remaining[s] == 1 or tokid == eos
-                        self._emit_token(emit, meta, tokid,
-                                         int(sidx[s]), bool(last))
-                        mark_emit(int(s))
-                        tok_prev_h[s] = tok_h[s]
-                        tok_h[s] = tokid
-                        sidx[s] += 1
-                        remaining[s] -= 1
-                        if last:
-                            retire(int(s))
-                if rec is not None:
-                    sp.end(tokens=delivered() - n_tok0,
-                           retired=n_retired - n_ret0)
+                    decode_closed("serve.decode", sp, chunk=fw.chunk,
+                                  **moe_args)
+                self._undelivered = settled = settle(host)
+                # Delivery is Python per token (33 ms for 255 tokens on
+                # the 7B cell, my chip runs, PR 30) with nothing queued on
+                # the chip, so it waits until the next chunk is dispatched
+                # (step 4b) — unless streams ended here and no request is
+                # queued: then the callers most likely to send the next
+                # one are waiting on this very delivery (a caller whose
+                # next turn follows its last answer), and a chunk
+                # dispatched now would make their requests wait it out.
+                # So they are answered first, and the rest of the delivery
+                # fills the time until their requests are in.
+                if settled.retired and not (
+                        self._waiting or self._admitting
+                        or not self._pending.empty()):
+                    self._deliver(rec, False, until=settled.retired)
 
             # 5b. speculative emit: the fused verify already accepted
             # and COMMITTED on device (tok/tok_prev/pos_dev rebound at
@@ -2601,9 +2768,9 @@ class _ContinuousLoop:
                 em_host = np.asarray(em_dev)    # [B, k+1]
                 acc_host = np.asarray(acc_dev)  # [B] — one sync
                 if rec is not None:
-                    sp = decode_closed("serve.spec_verify", sp,
-                                       k=fw.spec_k)
-                    n_tok0, n_ret0 = delivered(), n_retired
+                    decode_closed("serve.spec_verify", sp, k=fw.spec_k)
+                    sp = begin("serve.emit", iter=it)
+                n_tok = n_ret = 0
                 K = fw.spec_k
                 for s in np.flatnonzero(live):
                     s = int(s)
@@ -2635,7 +2802,8 @@ class _ContinuousLoop:
                         self._emit_token(
                             emit, meta, tokid, int(sidx[s]), bool(last),
                             extra={"spec_draft": 1 if j < acc else 0})
-                        mark_emit(s)
+                        self._mark_emit(self._slot_time[s],
+                                        self._slot_tenant[s])
                         emitted.append(tokid)
                         sidx[s] += 1
                         remaining[s] -= 1
@@ -2647,14 +2815,15 @@ class _ContinuousLoop:
                             retire(s)
                             finished = True
                             break
+                    n_tok += len(emitted)
+                    n_ret += finished
                     if not finished:
                         pos[s] += len(emitted)
                         seq = [int(tok_h[s])] + emitted
                         tok_h[s] = seq[-1]
                         tok_prev_h[s] = seq[-2]
                 if rec is not None:
-                    sp.end(tokens=delivered() - n_tok0,
-                           retired=n_retired - n_ret0)
+                    sp.end(tokens=n_tok, retired=n_ret, ahead=0)
 
             if rec is not None:
                 # blocks live in each pool: what the allocator has handed
@@ -2678,3 +2847,6 @@ class _ContinuousLoop:
                         self._idle.set()
                 self._wake.wait(0.02)
                 self._wake.clear()
+        # stopped with a chunk settled and its delivery put off: the
+        # streams that ended in it are in no slot, their tails leave now
+        self._deliver(None, False)

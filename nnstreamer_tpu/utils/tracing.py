@@ -79,9 +79,11 @@ SPAN_KINDS: Dict[str, str] = {
                     "backlog only by queue capacity — docs/FETCH.md)",
     "e2e": "source ingress -> sink delivery for one buffer",
     "serve.iter": "continuous LLM serving: one iteration of the serve "
-                  "loop that progressed, loop top to the end of token "
-                  "delivery — the PARENT of the phase spans below, which "
-                  "lie inside it and (bar serve.decode) do not overlap; "
+                  "loop that progressed, loop top to the chunk's "
+                  "settling — the PARENT of the phase spans below, which "
+                  "lie inside it (a serve.emit in the iteration it ran "
+                  "in: its own or the next) and, bar serve.decode, do "
+                  "not overlap one another; "
                   "its self time is the loop's bookkeeping (args: iter = "
                   "running number shared by every span of the iteration, "
                   "live, waiting)",
@@ -123,11 +125,13 @@ SPAN_KINDS: Dict[str, str] = {
                          "first sampled id synced to the host and "
                          "emitted, under the decode chunk in flight (tid "
                          "= request trace id; args: iter, tid, slot)",
-    "serve.emit": "continuous LLM serving: the delivery loop after chunk "
-                  "materialization — every token of the chunk pushed "
-                  "downstream one by one, and the retirements it caused "
-                  "(args: iter, tokens, retired; nothing is recorded "
-                  "per token)",
+    "serve.emit": "continuous LLM serving: one delivery — tokens of a "
+                  "settled chunk pushed downstream one by one, and the "
+                  "books of the streams that ended in it (args: iter = "
+                  "the iteration that dispatched the chunk, tokens, "
+                  "retired, ahead = 1 where the next decode chunk was "
+                  "dispatched first, so the span overlaps serve.decode "
+                  "and is not idle time; nothing is recorded per token)",
     "serve.prefix_hit": "continuous LLM serving: an admitted prompt's "
                         "leading blocks matched the prefix cache and "
                         "mapped copy-on-write into its table (instant; "

@@ -569,3 +569,481 @@ if __name__ == "__main__":
     import sys
 
     sys.exit(pytest.main([__file__, "-q"]))
+
+
+# ---------------------------------------------------------------------------
+# PR 32: a decode chunk is SETTLED when it materializes and its tokens are
+# DELIVERED under the next chunk, unless a stream ended in it and nothing
+# is queued (docs/SERVING.md "Settling and delivering")
+# ---------------------------------------------------------------------------
+
+CH = 4
+LONG = 3 * CH + 5       # index 0 at admission, then four chunks exactly
+AHEAD = f"stream_chunk:{CH},temperature:0.0,dtype:float32"
+LOOP = ",serve:continuous,slots:2,block_size:8,prefill_chunk:8"
+
+
+class _Stream:
+    """emit() target for one stream: what its client receives, in order.
+    ``log`` (shared with the wrapped ``_decode``) takes ``(name, index)``
+    per token; ``at`` maps a stream index to a callable run on the serve
+    thread right after that token has been taken."""
+
+    def __init__(self, name="s", log=None, at=None):
+        import threading
+
+        self.name, self.log, self.at = name, log, at or {}
+        self.ids, self.idx, self.metas = [], [], []
+        self.done = threading.Event()
+
+    def __call__(self, tensors, meta):
+        self.ids.append(int(tensors[0][0]))
+        self.idx.append(meta["stream_index"])
+        self.metas.append(dict(meta))
+        if self.log is not None:
+            self.log.append((self.name, meta["stream_index"]))
+        if meta.get("stream_last"):
+            self.done.set()
+        hook = self.at.get(meta["stream_index"])
+        if hook is not None:
+            hook()
+
+    @property
+    def sid(self):
+        return self.metas[0]["stream_id"]
+
+    def terminators(self):
+        return [m for m in self.metas if m.get("stream_last")]
+
+    def whole(self, want):
+        """Exactly ``want``, indices dense from 0, one terminator, last."""
+        assert self.ids == want, (self.name, self.ids, want)
+        assert self.idx == list(range(len(want))), (self.name, self.idx)
+        assert len(self.terminators()) == 1, self.name
+        assert self.metas[-1].get("stream_last") is True
+        assert not self.metas[-1].get("stream_aborted")
+
+
+def _ahead():
+    from nnstreamer_tpu.core.log import metrics as m
+
+    return m.snapshot().get("llm.serve.deliver_ahead", 0.0)
+
+
+def _warm_loop(custom, log=None, fail_at=None):
+    """A loop that has served one request (so it exists and is warm), with
+    ``_decode`` wrapped to log ``("decode", n)`` for its n-th call from
+    here on and to raise on call ``fail_at``."""
+    fw = _fw(custom)
+    _serve_tokens(fw, [np.array([9, 9, 9], np.int32)])
+    serve = fw._serve
+    inner, calls = serve._decode, [0]
+
+    def decode(*a, **kw):
+        calls[0] += 1
+        if log is not None:
+            log.append(("decode", calls[0]))
+        if calls[0] == fail_at:
+            raise RuntimeError("forced: the decode dispatch failed")
+        return inner(*a, **kw)
+
+    serve._decode = decode
+    return fw
+
+
+def _prompts(seed, lengths):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 500, (t,), dtype=np.int32) for t in lengths]
+
+
+def _refs(prompts, max_new):
+    base = f"max_new:{max_new},{AHEAD}"
+    return [_plain_tokens(p, base) for p in prompts]
+
+
+@pytest.fixture(scope="module")
+def abc():
+    """Three prompts and their dense-path streams of LONG tokens."""
+    prompts = _prompts(32, (5, 11, 7))
+    return prompts, _refs(prompts, LONG)
+
+
+class TestSettleThenDeliver:
+    @pytest.mark.parametrize("max_new", [1, CH - 1, CH, CH + 1, LONG])
+    def test_streams_indices_and_last_at_every_length(self, max_new, abc):
+        """Three prompts through two slots (the third is seated in a slot
+        whose old stream's tail may still be owed): every stream is the
+        dense path's, its indices are dense and its one terminator is its
+        last token — whichever side of the next dispatch it left on."""
+        prompts, long_refs = abc
+        # greedy: a shorter max_new is a prefix of the longer stream
+        want = [r[:max_new] for r in long_refs]
+        fw = _fw(f"max_new:{max_new},{AHEAD}{LOOP}")
+        got = [_Stream(str(i)) for i in range(3)]
+        try:
+            for p, g in zip(prompts, got):
+                fw.submit([p], {}, g)
+            assert fw.drain(timeout=300)
+            stats = fw._serve.pool_stats()
+        finally:
+            fw.close()
+        for g, w in zip(got, want):
+            g.whole(w)
+        assert stats["blocks_free"] == stats["blocks_total"]
+        assert stats["live_streams"] == 0
+
+    def test_eos_in_mid_chunk_cuts_the_stream_there(self, monkeypatch, abc):
+        from nnstreamer_tpu.filters import llm
+
+        prompts, refs = abc
+        ref = refs[0]
+        # an index in the middle of a chunk whose token is new there
+        j = next(j for j in (6, 7, 10, 11, 2, 3) if ref[j] not in ref[:j])
+        monkeypatch.setattr(llm.ByteTokenizer, "eos", ref[j])
+        fw = _fw(f"max_new:{LONG},{AHEAD}{LOOP},stop_eos:1")
+        a, b = _Stream("a"), _Stream("b")
+        try:
+            fw.submit([prompts[0]], {}, a)
+            fw.submit([prompts[1]], {}, b)
+            assert fw.drain(timeout=300)
+            stats = fw._serve.pool_stats()
+        finally:
+            fw.close()
+        a.whole(ref[:j + 1])
+        # the other stream is cut at ITS first eos, or runs to its end
+        want_b = refs[1]
+        if ref[j] in want_b:
+            want_b = want_b[:want_b.index(ref[j]) + 1]
+        b.whole(want_b)
+        assert stats["blocks_free"] == stats["blocks_total"]
+
+    def test_prefix_shared_pair(self):
+        rng = np.random.default_rng(33)
+        pre = rng.integers(1, 500, (16,), dtype=np.int32)
+        pa = np.concatenate([pre, rng.integers(1, 500, (3,), np.int32)])
+        pb = np.concatenate([pre, rng.integers(1, 500, (5,), np.int32)])
+        want = _refs([pa, pb], LONG)
+        fw = _fw(f"max_new:{LONG},{AHEAD}{LOOP}")
+        h0 = metrics.snapshot().get("llm.serve.prefix_hits", 0.0)
+        b = _Stream("b")
+        # b joins from the serve thread once a's prompt blocks are indexed
+        a = _Stream("a", at={1: lambda: fw.submit([pb], {}, b)})
+        try:
+            fw.submit([pa], {}, a)
+            assert a.done.wait(300) and b.done.wait(300)
+        finally:
+            fw.close()
+        a.whole(want[0])
+        b.whole(want[1])
+        assert metrics.snapshot().get("llm.serve.prefix_hits", 0.0) > h0
+
+    def test_sampled_stream_is_the_same_alone_and_in_company(self):
+        """The draws stay a pure function of (seed, admission number,
+        positions): a stream decodes the same tokens whether its chunks
+        were delivered at once (alone: nothing to run ahead of at its
+        end) or under the next chunk beside another stream."""
+        custom = (f"max_new:{LONG},stream_chunk:{CH},temperature:0.9,"
+                  f"top_k:40,seed:7,dtype:float32{LOOP}")
+        pa, pb = _prompts(34, (6, 9))
+        runs = []
+        for company in (False, True):
+            fw = _fw(custom)
+            a, b = _Stream("a"), _Stream("b")
+            try:
+                fw.submit([pa], {}, a)
+                if company:
+                    fw.submit([pb], {}, b)
+                assert fw.drain(timeout=300)
+            finally:
+                fw.close()
+            runs.append(a.ids)
+            assert a.idx == list(range(LONG))
+        assert runs[0] == runs[1]
+
+
+class TestDeliveryOrder:
+    """``_decode`` and the streams' emit log one sequence (all of it on
+    the serve thread): where each chunk's tokens left relative to the next
+    dispatch."""
+
+    def test_a_boundary_that_retires_nothing_dispatches_first(self, abc):
+        prompts, refs = abc
+        log = []
+        fw = _warm_loop(f"max_new:{LONG},{AHEAD}{LOOP}", log)
+        a = _Stream("a", log)
+        n0 = _ahead()
+        try:
+            fw.submit([prompts[0]], {}, a)
+            assert a.done.wait(300) and fw.drain(timeout=60)
+        finally:
+            fw.close()
+        a.whole(refs[0])
+        assert [e for e in log if e[0] == "decode"] == \
+            [("decode", n) for n in (1, 2, 3, 4)]
+        for c in (1, 2, 3):
+            # chunk c retired nothing: dispatch c+1 precedes its first token
+            assert log.index(("decode", c + 1)) < \
+                log.index(("a", 1 + (c - 1) * CH)), (c, log)
+        # the last chunk ended the stream with nothing queued: delivered
+        # at once, after its own dispatch and with none to follow
+        assert log[-CH:] == [("a", i) for i in range(LONG - CH, LONG)]
+        assert _ahead() - n0 == 3
+
+    def test_a_retirement_with_nothing_queued_delivers_first(self, abc):
+        prompts, refs = abc
+        log = []
+        fw = _warm_loop(f"max_new:{LONG},{AHEAD}{LOOP}", log)
+        b = _Stream("b", log)
+        a = _Stream("a", log,
+                    at={2: lambda: fw.submit([prompts[1]], {}, b)})
+        try:
+            fw.submit([prompts[0]], {}, a)
+            assert a.done.wait(300) and b.done.wait(300)
+        finally:
+            fw.close()
+        a.whole(refs[0])
+        b.whole(refs[1])
+        # a's last chunk came with dispatch 4; b decodes on: dispatch 5
+        # follows a's last token, so a's client is answered with the loop
+        # idle, as before this change
+        assert log.index(("decode", 4)) < log.index(("a", 13))
+        assert log.index(("a", LONG - 1)) < \
+            log.index(("decode", 5)), log
+        # while b's boundaries that retire nothing still run ahead
+        assert log.index(("decode", 6)) < log.index(("b", 9))
+
+    def test_the_idle_delivery_stops_once_the_freed_caller_is_back(
+            self, abc):
+        """The stream that ends leaves first and whole, then the other
+        streams' first token of the chunk; its caller has answered by then
+        (here: from inside the last token's emit), and with that request
+        queued the rest of the chunk waits for the dispatch."""
+        prompts, refs = abc
+        log = []
+        fw = _warm_loop(f"max_new:{LONG},{AHEAD}{LOOP}", log)
+        b, c = _Stream("b", log), _Stream("c", log)
+        a = _Stream("a", log,
+                    at={2: lambda: fw.submit([prompts[1]], {}, b),
+                        LONG - 1: lambda: fw.submit([prompts[2]], {}, c)})
+        n0 = _ahead()
+        try:
+            fw.submit([prompts[0]], {}, a)
+            assert all(s.done.wait(300) for s in (a, b, c))
+            assert fw.drain(timeout=60)
+        finally:
+            fw.close()
+        for s, w in zip((a, b, c), refs):
+            s.whole(w)
+        # chunk 4: a's tail, its caller's next request, dispatch 5 with
+        # that request seated and answered, then the rest of b's share
+        # (b's long prompt took two iterations to prefill: it went live
+        # with dispatch 4, so its share of chunk 4 is tokens 1 to CH)
+        assert log.index(("decode", 4)) < log.index(("b", 0)) < \
+            log.index(("a", 13))
+        # b's first token of the chunk does not wait for c's admission
+        assert log.index(("a", LONG - 1)) < log.index(("b", 1)) < \
+            log.index(("decode", 5)) < log.index(("c", 0)) < \
+            log.index(("b", 2)), log
+        # both halves of chunk 4 are deliveries; the second ran ahead
+        assert _ahead() - n0 >= 4
+
+    def test_one_answer_is_not_enough_where_two_streams_ended(self):
+        """Two streams end in one chunk and one caller answers: the loop
+        goes on delivering with the chip idle (the second caller may be
+        an instant away), as it did before this change."""
+        pa, pb, pz, pc = _prompts(37, (4, 6, 5, 7))
+        want = _refs([pa, pb, pz, pc], LONG)
+        log = []
+        fw = _warm_loop(f"max_new:{LONG},{AHEAD}{LOOP},slots:3", log)
+        a, b, z, c = (_Stream(n, log) for n in "abzc")
+        a.at = {2: lambda: fw.submit([pz], {}, z),
+                LONG - 1: lambda: fw.submit([pc], {}, c)}
+        try:
+            # a and b are admitted together (nothing decoding: the prefill
+            # budget is waived), so they end in one chunk
+            fw._serve.submit(pa[None], {}, a)
+            fw.submit([pb], {}, b)
+            assert all(s.done.wait(300) for s in (a, b, z, c))
+        finally:
+            fw.close()
+        for s, w in zip((a, b, z, c), want):
+            s.whole(w)
+        # a and b leave first, whole; then z's share of the chunk, all of
+        # it before the next dispatch
+        end_a, end_b = (log.index((n, LONG - 1)) for n in "ab")
+        nxt = next(i for i, e in enumerate(log)
+                   if e[0] == "decode" and i > max(end_a, end_b))
+        zs = [i for i, e in enumerate(log) if e[0] == "z"]
+        between = [i for i in zs if max(end_a, end_b) < i < nxt]
+        assert len(between) == CH, log
+        assert not [i for i in zs if min(end_a, end_b) - CH < i
+                    < max(end_a, end_b)], log
+
+    def test_a_retirement_with_a_request_queued_dispatches_first(self, abc):
+        """...and the queued request is seated in the slot the settling
+        freed while the old stream's tail is still owed."""
+        prompts, refs = abc
+        log = []
+        fw = _warm_loop(f"max_new:{LONG},{AHEAD}{LOOP}", log)
+        b, c = _Stream("b", log), _Stream("c", log)
+        # c arrives during the delivery of a's chunk 3, which lies in the
+        # iteration that settles a's last chunk: both slots are taken
+        a = _Stream("a", log,
+                    at={2: lambda: fw.submit([prompts[1]], {}, b),
+                        12: lambda: fw.submit([prompts[2]], {}, c)})
+        try:
+            fw.submit([prompts[0]], {}, a)
+            assert all(s.done.wait(300) for s in (a, b, c))
+            assert fw.drain(timeout=60)
+            stats = fw._serve.pool_stats()
+        finally:
+            fw.close()
+        for s, w in zip((a, b, c), refs):
+            s.whole(w)
+        tail = log.index(("a", 13))
+        assert log.index(("decode", 5)) < tail, log
+        # c holds a's slot and has its first token before a's tail leaves
+        assert log.index(("c", 0)) < tail
+        assert stats["blocks_free"] == stats["blocks_total"]
+
+
+class TestPendingDeliveryIsFlushed:
+    """Anything that ends, moves or reads a stream meets a delivery that
+    was put off: the tokens leave first."""
+
+    def test_drain_snapshot_equals_what_was_received(self, abc):
+        import threading
+        import time
+
+        prompts, refs = abc
+        custom = f"max_new:{LONG},{AHEAD}{LOOP}"
+        fw = _warm_loop(custom)
+        serve = fw._serve
+        a = _Stream("a")
+
+        class Seen(threading.Event):
+            def set(self):                  # on the serve thread
+                self.received = len(a.ids)
+                super().set()
+
+        cmd = {"kind": "drain", "ev": Seen(),
+               "deadline": time.monotonic() + 60}
+
+        def ask():
+            # from inside chunk 1's delivery: chunk 2 is settled later in
+            # this iteration and retires nothing, so its delivery is
+            # pending when the command is read
+            cmd["sid"] = a.sid
+            serve._ctl.append(cmd)
+
+        a.at[2] = ask
+        fw_b = _fw(custom)
+        cont = _Stream("cont")
+        try:
+            fw.submit([prompts[0]], {}, a)
+            assert cmd["ev"].wait(120) and not cmd.get("error"), cmd
+            snap = cmd["result"]
+            assert snap["sidx"] == cmd["ev"].received == 1 + 2 * CH
+            assert a.idx == list(range(snap["sidx"]))
+            assert not a.terminators()      # moved, not ended
+            fw_b.adopt_stream(snap, cont)
+            assert cont.done.wait(120)
+        finally:
+            fw.close()
+            fw_b.close()
+        assert a.ids + cont.ids == refs[0]
+        assert cont.idx == list(range(snap["sidx"], LONG))
+        assert len(cont.terminators()) == 1
+
+    @pytest.mark.parametrize("ends_in_pending_chunk", [False, True])
+    def test_cancel_sends_the_tokens_then_one_terminator(
+            self, abc, ends_in_pending_chunk):
+        from nnstreamer_tpu.utils import elastic
+
+        prompts, refs = abc
+        fw = _warm_loop(f"max_new:{LONG},{AHEAD}{LOOP},slots:1")
+        a, b = _Stream("a"), _Stream("b")
+
+        def cancel():
+            elastic.cancel_stream(a.sid, "gone", force=True)
+            if ends_in_pending_chunk:
+                # a request queued: the chunk that ends a is put off
+                fw.submit([prompts[1]], {}, b)
+
+        # chunk 3's delivery lies in the iteration that settles the last
+        # chunk; chunk 1's in one that settles a chunk retiring nothing
+        a.at[12 if ends_in_pending_chunk else 2] = cancel
+        try:
+            fw.submit([prompts[0]], {}, a)
+            assert a.done.wait(120)
+            assert fw.drain(timeout=120)
+            stats = fw._serve.pool_stats()
+        finally:
+            fw.close()
+        if ends_in_pending_chunk:
+            a.whole(refs[0])                # its own end came first
+            b.whole(refs[1])
+        else:
+            n = 1 + 2 * CH                  # all of the settled chunk
+            assert a.ids[:n] == refs[0][:n]
+            assert a.idx == list(range(n + 1))
+            assert len(a.terminators()) == 1
+            assert a.metas[-1]["stream_aborted"] is True
+            assert a.metas[-1]["abort_reason"] == "gone"
+        assert stats["blocks_free"] == stats["blocks_total"]
+
+    def test_crash_gives_pending_tokens_then_one_terminator_each(self, abc):
+        prompts, refs = abc
+        fw = _warm_loop(f"max_new:{LONG},{AHEAD}{LOOP}", fail_at=5)
+        b, c = _Stream("b"), _Stream("c")
+        a = _Stream("a",
+                    at={2: lambda: fw.submit([prompts[1]], {}, b),
+                        12: lambda: fw.submit([prompts[2]], {}, c)})
+        try:
+            fw.submit([prompts[0]], {}, a)
+            # dispatch 5 raises with chunk 4 settled and undelivered: it
+            # ended a (in no slot any more), b decodes on, c has a's slot
+            assert all(s.done.wait(120) for s in (a, b, c))
+            assert fw.drain(timeout=60)
+            with pytest.raises(Exception, match="serve loop died"):
+                fw.submit([prompts[0]], {}, _Stream())
+        finally:
+            fw.close()
+        a.whole(refs[0])                    # its tail, and its own end
+        # b's prompt took two iterations to prefill: its first token and
+        # chunk 4, the one that was pending
+        n = 1 + CH
+        assert b.ids[:n] == refs[1][:n] and b.idx[:n] == list(range(n))
+        assert len(b.ids) == n + 1
+        assert c.ids == [0]
+        for s in (b, c):
+            assert len(s.terminators()) == 1, s.name
+            assert s.metas[-1]["stream_aborted"] is True
+
+
+class TestCensusUnderRunAhead:
+    @pytest.mark.parametrize("extra,programs", [
+        ("", ("_decode", "_prefill", "_set_tok")),
+        (",draft:llama_tiny,spec_k:2",
+         ("_propose", "_verify", "_draft_prefill", "_prefill", "_set_tok")),
+    ], ids=["plain3", "speculative5"])
+    def test_no_program_is_added_and_none_compiles_again(self, extra,
+                                                         programs):
+        fw = _fw(f"max_new:{CH + 2},{AHEAD}{LOOP}{extra}")
+        try:
+            _serve_tokens(fw, _prompts(35, (3,)))
+            serve = fw._serve
+            warm = {p: getattr(serve, p)._cache_size() for p in programs}
+            assert warm == {p: 1 for p in programs}
+            n0 = _ahead()
+            # churn: boundaries that run ahead, that do not, and a slot
+            # seated again with a tail owed
+            got = _serve_tokens(fw, _prompts(36, (1, 7, 13, 4, 9)))
+            after = {p: getattr(serve, p)._cache_size() for p in programs}
+            ran_ahead = _ahead() - n0
+        finally:
+            fw.close()
+        assert all(len(v) == CH + 2 for v in got.values())
+        assert after == warm, f"compiled in the loop: {warm} -> {after}"
+        # the speculative round keeps its order; the plain loop ran ahead
+        assert (ran_ahead > 0) == (extra == "")

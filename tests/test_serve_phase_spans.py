@@ -72,36 +72,51 @@ def _by_iter(spans):
     return out
 
 
+def _inside(e, parent):
+    return parent.ts <= e.ts and e.ts + e.dur <= parent.ts + parent.dur
+
+
 def test_every_iteration_holds_its_children_without_overlap(traced):
     _bufs, spans = traced
     iters = _by_iter(spans)
     assert len(iters) >= 3
+    parent_of = {}
     for it, group in iters.items():
         parents = [e for e in group if e.kind == "serve.iter"]
         assert len(parents) == 1, (it, [e.kind for e in group])
-        lo, hi = parents[0].ts, parents[0].ts + parents[0].dur
+        parent_of[it] = parents[0]
+    for it, group in iters.items():
         kids = sorted((e for e in group if e.kind != "serve.iter"),
                       key=lambda e: e.ts)
         assert {e.kind for e in kids} >= {"serve.intake",
                                           "serve.admit_pass"}
         for e in kids:
-            assert lo <= e.ts and e.ts + e.dur <= hi, (it, e)
-        tiles = [e for e in kids if e.kind in TILING]
-        for a, b in zip(tiles, tiles[1:]):
-            assert a.ts + a.dur <= b.ts, (it, a, b)
+            if e.kind == "serve.emit" and not _inside(e, parent_of[it]):
+                # since PR 32 a chunk's delivery may be put off until the
+                # next chunk is dispatched: it then lies in the NEXT
+                # iteration, after that iteration's first tokens
+                assert _inside(e, parent_of[it + 1]), (it, e)
+                continue
+            assert _inside(e, parent_of[it]), (it, e)
         # serve.admit is the tail of one prompt's admission, inside the pass
         passes = [e for e in kids if e.kind == "serve.admit_pass"]
         for e in kids:
             if e.kind == "serve.admit":
-                assert passes[0].ts <= e.ts and \
-                    e.ts + e.dur <= passes[0].ts + passes[0].dur
+                assert _inside(e, passes[0])
+    # the tiles that lie in one iteration, whichever chunk they deliver
+    for parent in parent_of.values():
+        tiles = sorted((e for e in spans
+                        if e.kind in TILING and _inside(e, parent)),
+                       key=lambda e: e.ts)
+        for a, b in zip(tiles, tiles[1:]):
+            assert a.ts + a.dur <= b.ts, (parent, a, b)
 
 
 def test_children_sum_to_no_more_than_the_parent(traced):
     _bufs, spans = traced
-    for it, group in _by_iter(spans).items():
-        parent = next(e for e in group if e.kind == "serve.iter")
-        assert sum(e.dur for e in group if e.kind in TILING) <= parent.dur
+    for parent in (e for e in spans if e.kind == "serve.iter"):
+        assert sum(e.dur for e in spans
+                   if e.kind in TILING and _inside(e, parent)) <= parent.dur
 
 
 def test_iter_is_monotonic_dense_and_shared(traced):
@@ -165,6 +180,32 @@ def test_decode_wait_is_part_of_the_decode_span(traced):
     assert sum(e.args["tokens"] for e in emits) == \
         (MAX_NEW - 1) * len(PROMPTS)
     assert sum(e.args["retired"] for e in emits) == len(PROMPTS)
+
+
+def test_deliveries_under_the_next_chunk_are_marked_and_counted():
+    """``serve.emit`` says on which side of the next dispatch it ran
+    (``ahead``), and ``llm.serve.deliver_ahead`` counts the same chunks."""
+    from nnstreamer_tpu.core.log import metrics
+
+    n0 = metrics.snapshot().get("llm.serve.deliver_ahead", 0.0)
+    _bufs, _at_last, spans = _serve("ring")
+    counted = metrics.snapshot().get("llm.serve.deliver_ahead", 0.0) - n0
+    emits = sorted((e for e in spans if e.kind == "serve.emit"),
+                   key=lambda e: e.ts)
+    assert all(set(e.args) == {"iter", "tokens", "retired", "ahead"}
+               for e in emits)
+    assert {e.args["ahead"] for e in emits} == {0, 1}
+    assert sum(e.args["ahead"] for e in emits) == counted
+    decodes = {e.args["iter"]: e for e in spans if e.kind == "serve.decode"}
+    for e in emits:
+        nxt = decodes.get(e.args["iter"] + 1)
+        if e.args["ahead"]:
+            # the next chunk was dispatched before this delivery began
+            assert nxt is not None and nxt.ts <= e.ts, e
+        else:
+            assert nxt is None or e.ts + e.dur <= nxt.ts, e
+    # the last chunk of all ended a stream with nothing queued
+    assert emits[-1].args["ahead"] == 0 and emits[-1].args["retired"] >= 1
 
 
 def test_an_idle_loop_records_nothing():
@@ -266,7 +307,7 @@ def test_off_mode_records_nothing_and_constructs_no_annotation(monkeypatch):
     assert names >= TILING | {"serve.iter", "serve.admit",
                               "serve.decode.wait"}
     emit = next(a for a in _Counting.made if a.name == "serve.emit")
-    assert set(emit.kw) == {"iter", "tokens", "retired"}
+    assert set(emit.kw) == {"iter", "tokens", "retired", "ahead"}
 
 
 def test_speculative_rounds_carry_iter_and_wait():
