@@ -264,6 +264,11 @@ def serving_plan(cfg, *, slots: int, block_size: int = 16,
       ``pool_bytes`` counts both pools, and
       ``decode_bytes_per_ctx_token`` only the full-attention layers (a
       window layer reads at most its window, whatever the context).
+    * latent layers (``LayerKind.latent``) keep one row a token for all
+      heads, ``latent_width`` values, in the allocator's blocks:
+      ``pool_bytes`` counts them at the pool's padded width (what HBM
+      holds), ``decode_bytes_per_ctx_token`` at ``latent_width`` (what
+      the mathematics reads: the padding is the kernel's cost).
     * ``programs`` — compiled XLA signatures the standing loop ever
       uses.  Without speculation: the ``[slots]``-row paged decode
       chunk, the ``[1, prefill_chunk]`` prefill step, and the slot-token
@@ -318,7 +323,8 @@ def serving_plan(cfg, *, slots: int, block_size: int = 16,
         # per-context-token decode read (ops/attention.py shares each
         # block DMA across the whole query-head group)
         "decode_bytes_per_ctx_token": (
-            2 * cfg.n_full_layers * cfg.n_kv_heads * hd * itemsize),
+            2 * cfg.n_full_layers * cfg.n_kv_heads * hd
+            + cfg.n_latent_layers * cfg.latent_width) * itemsize,
         "kv_groups": cfg.n_heads // cfg.n_kv_heads,
         "prng_state_bytes": (int(slots) * 2 * 4
                              if float(temperature) > 0.0 else 0),
@@ -503,7 +509,10 @@ class LLMFramework(Framework):
                     "draft: with a patterned target is not built: the "
                     "k+1-wide verify step writes k+1 positions into a "
                     "window layer's ring, and a rejected tail would have "
-                    "overwritten rows the window still needs "
+                    "overwritten rows the window still needs; over a "
+                    "latent pool its k+1 queries take the gather "
+                    "reference, not the kernel; this target has "
+                    f"{llama.pattern_traits(self.cfg)} "
                     "(self-drafting from a prediction head: ROADMAP M1)")
         if self.draft_name:
             if not self.continuous:
@@ -1205,7 +1214,7 @@ class _ContinuousLoop:
             toks = jnp.moveaxis(toks, 0, 1)  # [B, length]
             if stats is not None:
                 # the expert layers' counts of each step ride home as
-                # three extra rows of the token matrix: the fetch that
+                # four extra rows of the token matrix: the fetch that
                 # brings the chunk's tokens brings them, no sync of
                 # their own
                 toks = jnp.concatenate([toks, stats.T], axis=0)
@@ -1757,7 +1766,8 @@ class _ContinuousLoop:
                                  llama.paged_cache_pspecs())
         # published like the allocator bookkeeping below: tests and
         # post-mortems read the pool's actual placement off the loop
-        self._pool_sharding = getattr(pool["k"], "sharding", None)
+        self._pool_sharding = getattr(next(iter(pool.values())), "sharding",
+                                      None)
         # the MEASURED pool footprint (global bytes; /M per chip under
         # TP; target + draft pools) — nns-xray's HBM ledger reconciles
         # this against the deep lint's serving_plan pool_bytes +
@@ -1885,13 +1895,11 @@ class _ContinuousLoop:
             sp = begin("serve.cow_fork") if rec is not None else None
             src_i = np.asarray([src], np.int32)
             new_i = np.asarray([dst], np.int32)
-            pool["k"] = pool["k"].at[:, new_i].set(pool["k"][:, src_i])
-            pool["v"] = pool["v"].at[:, new_i].set(pool["v"][:, src_i])
-            if draft_pool is not None:
-                draft_pool["k"] = draft_pool["k"].at[:, new_i].set(
-                    draft_pool["k"][:, src_i])
-                draft_pool["v"] = draft_pool["v"].at[:, new_i].set(
-                    draft_pool["v"][:, src_i])
+            for pl in (pool, draft_pool):
+                # every leaf the allocator's blocks live in: K and V, a
+                # latent class's rows
+                for leaf in llama.allocated_leaves(pl or {}):
+                    pl[leaf] = pl[leaf].at[:, new_i].set(pl[leaf][:, src_i])
             if sp is not None:
                 sp.end(src=int(src), dst=int(dst))
 
@@ -2141,9 +2149,12 @@ class _ContinuousLoop:
                             # valid cache rows [0, pos) gathered to
                             # host, whole blocks at a time — a COPY,
                             # never an alias (np.asarray of a device
-                            # gather materializes)
-                            "blocks_k": np.asarray(pool["k"][:, ids]),
-                            "blocks_v": np.asarray(pool["v"][:, ids]),
+                            # gather materializes); one entry a pool
+                            # leaf: blocks_k and blocks_v, or a latent
+                            # class's blocks_c
+                            **{"blocks_" + leaf: np.asarray(
+                                pool[leaf][:, ids])
+                               for leaf in llama.allocated_leaves(pool)},
                         }
                         nb = len(kv.slot_blocks[s])
                         retire(s)
@@ -2231,10 +2242,10 @@ class _ContinuousLoop:
                         # eager scatter of the snapshot's cache rows
                         # into the newly reserved pool blocks (a value
                         # move — the compiled census is untouched)
-                        pool["k"] = pool["k"].at[:, ids].set(
-                            jnp.asarray(np.asarray(snap["blocks_k"])))
-                        pool["v"] = pool["v"].at[:, ids].set(
-                            jnp.asarray(np.asarray(snap["blocks_v"])))
+                        for leaf in llama.allocated_leaves(pool):
+                            pool[leaf] = pool[leaf].at[:, ids].set(
+                                jnp.asarray(np.asarray(
+                                    snap["blocks_" + leaf])))
                         # jnp.asarray: the jit fast path keys on arg
                         # TYPE, not just aval — a raw numpy scalar here
                         # would mint a 4th signature and break the
@@ -2719,14 +2730,16 @@ class _ContinuousLoop:
                 host = np.asarray(toks_dev)  # ONE roundtrip per chunk
                 moe_args = {}
                 if self._moe:
-                    # rows B..B+2: per step, over the expert layers and
+                    # rows B..B+3: per step, over the expert layers and
                     # the live rows — routed pairs the held experts
-                    # computed, held experts hit, most pairs on one
+                    # computed, held experts hit, most pairs on one,
+                    # identity pairs (no expert computed them)
                     moe = host[B:]
                     host = host[:B]
                     moe_args = {"moe_pairs": int(moe[0].sum()),
                                 "moe_experts_hit": int(moe[1].sum()),
-                                "moe_max_per_expert": int(moe[2].max())}
+                                "moe_max_per_expert": int(moe[2].max()),
+                                "moe_zero_pairs": int(moe[3].sum())}
                 if rec is not None:
                     # the decode span closes HERE, at materialization:
                     # the jit call above only enqueued the async

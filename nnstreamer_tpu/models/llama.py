@@ -47,24 +47,53 @@ from .zoo import ModelBundle, register_model
 class LayerKind:
     """What one layer is: its attention (``window`` 0 = causal over all
     positions, w = over the last w, the token itself included; ``rope``:
-    whether q and k are rotated) and its feed-forward (``dense`` SwiGLU
-    of ``ffn_hidden``, or ``experts``: ``LlamaConfig.experts``)."""
+    whether q and k are rotated; ``latent``: the cache holds one
+    compressed row a token for all heads, ``LlamaConfig``'s latent
+    sizes) and its feed-forward (``dense`` SwiGLU of ``ffn_hidden``, or
+    ``experts``: ``LlamaConfig.experts``).
+
+    ``shortcut`` names the two ends of an expert branch that runs BESIDE
+    the layers between them: a kind that ``open``s takes the branch off
+    its post-attention norm (``LlamaConfig.experts`` on the same input
+    its dense FFN reads), a kind that ``close``s adds the branch's
+    result to its output.  A published layer of two attention blocks
+    and two dense FFNs with its experts across them is two such
+    sub-layers, and the walk carries the open branch's tensor from one
+    to the other."""
 
     window: int = 0
     rope: bool = True
     ffn: str = "dense"
+    latent: bool = False
+    shortcut: str = ""
 
     def __post_init__(self):
         if self.ffn not in ("dense", "experts"):
             raise ValueError(f"ffn kind {self.ffn!r} (dense, experts)")
         if self.window < 0:
             raise ValueError(f"window {self.window}")
+        if self.shortcut not in ("", "open", "close"):
+            raise ValueError(f"shortcut {self.shortcut!r} (open, close)")
+        if self.latent and (self.window or not self.rope):
+            raise ValueError("latent attention is built causal over all "
+                             "positions with its shared key rotated")
+        if self.shortcut and self.ffn != "dense":
+            raise ValueError("a shortcut's ends are dense-FFN layers: the "
+                             "expert branch is the shortcut itself")
 
     @property
     def name(self) -> str:
         """The key of this kind's stack under ``params["layers"]``."""
-        return ".".join([f"window{self.window}" if self.window else "full",
-                         "rope" if self.rope else "nope", self.ffn])
+        attn = "latent" if self.latent else \
+            f"window{self.window}" if self.window else "full"
+        return ".".join([attn, "rope" if self.rope else "nope", self.ffn]
+                        + ([self.shortcut] if self.shortcut else []))
+
+    @property
+    def cache(self) -> str:
+        """The class of paged cache this layer's state lives in: one pool
+        (and one numbering of layers) a class."""
+        return "latent" if self.latent else "win" if self.window else "full"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,6 +151,16 @@ class LlamaConfig:
     #: is normalised to ())
     pattern: Tuple[LayerKind, ...] = ()
     experts: Optional[ExpertsConfig] = None
+    #: latent attention (``LayerKind.latent``): the query's and the
+    #: cache's compressed widths, a head's unrotated, rotated and value
+    #: widths, and what the two normed compressions are multiplied by
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    q_lora_scale: float = 1.0
+    kv_lora_scale: float = 1.0
 
     def __post_init__(self):
         pat = tuple(self.pattern)
@@ -131,9 +170,28 @@ class LlamaConfig:
         if pat and all(k == LayerKind() for k in pat):
             pat = ()
         object.__setattr__(self, "pattern", pat)
-        if any(k.ffn == "experts" for k in pat) and self.experts is None:
-            raise ValueError("a layer of ffn kind 'experts' needs "
-                             "LlamaConfig.experts")
+        if any(k.ffn == "experts" or k.shortcut == "open" for k in pat) \
+                and self.experts is None:
+            raise ValueError("a layer of ffn kind 'experts', or one that "
+                             "opens a shortcut, needs LlamaConfig.experts")
+        if any(k.latent for k in pat) and not (
+                self.q_lora_rank > 0 and self.kv_lora_rank > 0
+                and self.qk_nope_dim > 0 and self.v_head_dim > 0
+                and self.qk_rope_dim > 0 and self.qk_rope_dim % 2 == 0):
+            raise ValueError("a latent-attention layer needs q_lora_rank, "
+                             "kv_lora_rank, qk_nope_dim, qk_rope_dim "
+                             "(even) and v_head_dim")
+        is_open = False
+        for l, k in enumerate(pat):
+            if not k.shortcut:
+                continue
+            if (k.shortcut == "open") == is_open:
+                raise ValueError(
+                    f"layer {l} would {k.shortcut} a shortcut that is "
+                    f"{'open already' if is_open else 'not open'}")
+            is_open = not is_open
+        if is_open:
+            raise ValueError("the last shortcut opened is never closed")
 
     @property
     def head_dim(self) -> int:
@@ -148,8 +206,22 @@ class LlamaConfig:
         return sum(1 for k in self.pattern if k.window)
 
     @property
+    def n_latent_layers(self) -> int:
+        return sum(1 for k in self.pattern if k.latent)
+
+    @property
     def n_full_layers(self) -> int:
-        return self.n_layers - self.n_window_layers
+        return self.n_layers - self.n_window_layers - self.n_latent_layers
+
+    @property
+    def has_shortcut(self) -> bool:
+        return any(k.shortcut for k in self.pattern)
+
+    @property
+    def latent_width(self) -> int:
+        """Values a latent layer caches a token: the normed latent and
+        the one rotated key all heads share."""
+        return self.kv_lora_rank + self.qk_rope_dim
 
     @property
     def max_window(self) -> int:
@@ -169,9 +241,23 @@ def refuse_pattern(cfg: LlamaConfig, what: str) -> None:
     if cfg.patterned:
         raise NotImplementedError(
             f"{what} computes the one-kind decoder only (every layer "
-            "full attention, rotated, dense FFN, no q/k norm); this "
-            "model has a layer pattern — serve it with serve:continuous "
-            "or run llama.forward")
+            "full attention over K and V per head, rotated, dense FFN, "
+            f"no q/k norm); this model has {pattern_traits(cfg)} — "
+            "serve it with serve:continuous or run llama.forward")
+
+
+def pattern_traits(cfg: LlamaConfig) -> str:
+    """What makes ``cfg`` a patterned model, for a refusal's reason."""
+    kinds = cfg.pattern
+    traits = [name for name, has in (
+        ("window layers", any(k.window for k in kinds)),
+        ("unrotated layers", any(not k.rope for k in kinds)),
+        ("sparse experts", cfg.experts is not None),
+        ("latent attention (one cache row for all heads)",
+         any(k.latent for k in kinds)),
+        ("a shortcut expert branch across sub-layers", cfg.has_shortcut),
+        ("q/k norm", cfg.qk_norm)) if has]
+    return "a layer pattern: " + ", ".join(traits or ["mixed layers"])
 
 
 def window_ring_blocks(cfg: LlamaConfig, block_size: int,
@@ -212,6 +298,23 @@ PRESETS: Dict[str, LlamaConfig] = {
             for l in range(8)),
         experts=ExpertsConfig(n_experts=16, top_k=4, hidden=32, shared=1,
                               scale=2.5, held_first=4, held_count=4),
+    ),
+    # latent attention and a shortcut expert branch at toy size: two
+    # published layers = four sub-layers of period 2 (open, close), a
+    # softmax router over 16 routed + 8 identity experts, 4 a token,
+    # this process holding experts 4..7
+    "latent_shortcut_tiny": LlamaConfig(
+        vocab=512, dim=64, n_layers=4, n_heads=4, n_kv_heads=1,
+        ffn_hidden=128, max_seq=256, rope_theta=1e7,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, q_lora_scale=2.0 ** 0.5, kv_lora_scale=2.0 ** 0.5,
+        pattern=tuple(
+            LayerKind(latent=True, shortcut="close" if l % 2 else "open")
+            for l in range(4)),
+        experts=ExpertsConfig(n_experts=16, top_k=4, hidden=32,
+                              scoring="softmax", norm_topk=False,
+                              scale=6.0, zero_experts=8, held_first=4,
+                              held_count=4),
     ),
 }
 
@@ -272,18 +375,29 @@ def stack_shapes(cfg: LlamaConfig, kind: LayerKind) -> Dict[str, tuple]:
     are ``[in, out]``; ``ln_*``, ``q_norm``/``k_norm``, ``w_router`` and
     ``router_bias`` are float32 whatever the weights' type."""
     D, H, Hkv, hd = cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    out = {"wq": (D, H * hd), "wk": (D, Hkv * hd), "wv": (D, Hkv * hd),
-           "wo": (H * hd, D), "ln_attn": (D,), "ln_mlp": (D,)}
-    if cfg.qk_norm:
-        out.update(q_norm=(hd,), k_norm=(hd,))
+    out = {"ln_attn": (D,), "ln_mlp": (D,)}
+    if kind.latent:
+        # a latent layer: the query and the cache each go through a
+        # normed compression; ``wkv_a`` makes the latent and the one
+        # rotated key, ``wkv_b`` expands the latent to every head's
+        # unrotated key and value
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        out.update(wq_a=(D, rq), q_a_norm=(rq,), wq_b=(rq, H * (dn + dr)),
+                   wkv_a=(D, rkv + dr), kv_a_norm=(rkv,),
+                   wkv_b=(rkv, H * (dn + dv)), wo=(H * dv, D))
+    else:
+        out.update(wq=(D, H * hd), wk=(D, Hkv * hd), wv=(D, Hkv * hd),
+                   wo=(H * hd, D))
+        if cfg.qk_norm:
+            out.update(q_norm=(hd,), k_norm=(hd,))
     if kind.ffn == "dense":
         F = cfg.ffn_hidden
         out.update(w_gate=(D, F), w_up=(D, F), w_down=(F, D))
-    else:
+    if kind.ffn == "experts" or kind.shortcut == "open":
         ex = cfg.experts
         E, Fe, Fs = ex.n_held, ex.hidden, ex.shared * ex.hidden
-        out.update(w_router=(D, ex.n_experts),
-                   router_bias=(ex.n_experts,),
+        out.update(w_router=(D, ex.n_router), router_bias=(ex.n_router,),
                    we_gate=(E, D, Fe), we_up=(E, D, Fe), we_down=(E, Fe, D))
         if Fs:
             out.update(ws_gate=(D, Fs), ws_up=(D, Fs), ws_down=(Fs, D))
@@ -291,8 +405,8 @@ def stack_shapes(cfg: LlamaConfig, kind: LayerKind) -> Dict[str, tuple]:
 
 
 #: leaves kept in float32 (gains, and the router: see models/moe.py)
-F32_LEAVES = ("ln_attn", "ln_mlp", "q_norm", "k_norm", "w_router",
-              "router_bias")
+F32_LEAVES = ("ln_attn", "ln_mlp", "q_norm", "k_norm", "q_a_norm",
+              "kv_a_norm", "w_router", "router_bias")
 
 
 def kind_layers(cfg: LlamaConfig) -> Dict[str, List[int]]:
@@ -458,6 +572,8 @@ def load_checkpoint(path: str, cfg: Optional[LlamaConfig] = None,
 
     from . import checkpoint as ckpt
 
+    if cfg is not None:
+        refuse_pattern(cfg, "load_checkpoint (the HF/gguf name mapping)")
     dt = _resolve_param_dtype(dtype)
     if path.endswith(".gguf"):
         params, cfg, _tok = _load_gguf(path, cfg, dt)
@@ -880,7 +996,8 @@ def _refuse_quant(cfg: LlamaConfig) -> None:
         raise ValueError(
             "quant:int8|int4 is weight-only quantization of the one-kind "
             "decoder's seven matrices; a patterned model's stacks "
-            "(expert matrices among them) have no quantized layout yet")
+            "(expert matrices, latent projections) have no quantized "
+            f"layout yet, and this model has {pattern_traits(cfg)}")
 
 
 def _apply_quant(params: Dict, opts: Dict) -> Dict:
@@ -996,11 +1113,16 @@ def param_pspecs(quant: bool = False) -> Dict:
     }
 
 
-def _rmsnorm(x, w, eps):
+def _rmsnorm(x, w, eps, scale: float = 1.0):
+    """``scale`` (a latent layer's ``*_lora_scale``) multiplies in float32,
+    before the cast: in bfloat16 sqrt(12) would sit 0.13 % high on every
+    cached row."""
     import jax.numpy as jnp
 
     x32 = x.astype(jnp.float32)
     inv = jnp.reciprocal(jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps))
+    if scale != 1.0:
+        inv = inv * jnp.float32(scale)
     return (x32 * inv).astype(x.dtype) * w.astype(x.dtype)
 
 
@@ -1034,9 +1156,133 @@ def _repeat_kv(x, n_rep: int):
     ).reshape(B, T, Hkv * n_rep, D)
 
 
+def _paged_rows(pool_shape, paged_tables, pos_offset, T, layer, park):
+    """Where the ``T`` new rows of each sequence go in a pool viewed flat
+    as ``[L * n_blocks, bs, ...]``: ``(flat block [B, T], offset in the
+    block [B, T])``, looked up through the row's block table.  A parked
+    or overshooting position, or a table entry outside the layer's own
+    ``[0, n_blocks)``, resolves to the ``L * n_blocks`` sentinel and the
+    write DROPS.  ``park`` is given for a ring table, which has no such
+    edge of its own."""
+    import jax.numpy as jnp
+
+    n_layers, n_blocks, bs = pool_shape[:3]
+    max_blocks = paged_tables.shape[1]
+    ring = park is not None
+    edge = park if ring else max_blocks * bs
+    idx = pos_offset[:, None] + jnp.arange(T)[None, :]  # [B, T]
+    slot_blk = (idx // bs) % max_blocks if ring \
+        else jnp.clip(idx // bs, 0, max_blocks - 1)
+    entry = jnp.take_along_axis(paged_tables, slot_blk, axis=1)
+    valid = ((idx >= 0) & (idx < edge)
+             & (entry >= 0) & (entry < n_blocks))
+    blk = jnp.where(valid, layer * n_blocks + entry,
+                    n_layers * n_blocks)  # sentinel -> dropped
+    return blk, idx % bs
+
+
+def _paged_view(pool_shape, paged_tables, pos_offset, T, layer, park):
+    """What a layer attends in the flat pool: ``(context lengths [B],
+    flat tables [B, max_blocks])``.  Context = everything written so far
+    incl. this suffix; a parked row (pos >= the edge) gets len 0 — the
+    paged kernels then issue ZERO block DMAs for it, which is the whole
+    traffic story.  Sentinel entries clip inside the layer BEFORE the
+    offset: clipped after it they would name another layer's block."""
+    import jax.numpy as jnp
+
+    _, n_blocks, bs = pool_shape[:3]
+    edge = park if park is not None else paged_tables.shape[1] * bs
+    lens = jnp.where(pos_offset + T <= edge,
+                     pos_offset + T, 0).astype(jnp.int32)
+    return lens, layer * n_blocks + jnp.clip(paged_tables, 0, n_blocks - 1)
+
+
+def _latent_attention(cfg: LlamaConfig, lp, h, positions, pool=None,
+                      pos_offset=None, paged_tables=None, layer=None):
+    """Latent attention of one layer on the normed input ``h`` [B, T, D]
+    -> (the heads' outputs [B, T, H * v_head_dim], the pool).
+
+    ``c_q = q_lora_scale * N(h wq_a)``, ``q = c_q wq_b`` -> per head an
+    unrotated part ``q_C`` and a rotated ``q_R``; ``[c | k_R] = h wkv_a``,
+    ``c <- kv_lora_scale * N(c)``, ``k_R`` rotated and shared by all
+    heads; ``[k_C | v] = c wkv_b`` per head; scores ``(q_C . k_C + q_R .
+    k_R) / sqrt(nope + rope)``.  **The cache row of a token is ``c | k_R``**
+    (``latent_width`` values, zero-padded to the pool's lanes), written
+    into the whole pool viewed flat like K and V are (:func:`_block`).
+
+    Two forms of the same numbers.  Over the pool (a decode step and a
+    prefill chunk alike) attention stays in the latent space: ``q~_h =
+    q_C,h (wkv_b^K,h)^T`` scores against ``c`` directly and ``o_h = (P c)
+    wkv_b^V,h`` — a decode step's paged kernel streams each cached row
+    once for both products, a chunk takes the kernel's plain reference
+    (ops/attention.py ``paged_latent_attention``).  The cacheless forward
+    EXPANDS: its own rows' ``c`` go through ``wkv_b`` to K and V per head
+    and attention runs per head, the form the model is published in (a
+    query-row pair then costs ``2 (nope + rope + v)`` FLOP a head against
+    the latent space's ``2 (2 r + rope)``, and the expansion is paid once
+    a row: the cheaper form from about a hundred queries a row on, which
+    a chunk of ``prefill_chunk`` queries over a whole block table never
+    is)."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, _ = h.shape
+    dt = h.dtype
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    scale = (dn + dr) ** -0.5
+    cq = _rmsnorm(_mm(h, lp, "wq_a", dt), lp["q_a_norm"], cfg.norm_eps,
+                  cfg.q_lora_scale)
+    q = _mm(cq, lp, "wq_b", dt).reshape(B, T, H, dn + dr)
+    q_c, q_r = q[..., :dn], _rope(q[..., dn:], positions, cfg.rope_theta)
+    kva = _mm(h, lp, "wkv_a", dt)
+    c = _rmsnorm(kva[..., :r], lp["kv_a_norm"], cfg.norm_eps,
+                 cfg.kv_lora_scale)
+    k_r = _rope(kva[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    wkv_b = lp["wkv_b"].astype(dt).reshape(r, H, dn + dv)
+    wk, wv = wkv_b[..., :dn], wkv_b[..., dn:]
+
+    if paged_tables is None:
+        with jax.named_scope("attention"), jax.named_scope("attn.latent"):
+            k_c = jnp.einsum("bsr,rhn->bshn", c, wk)
+            v = jnp.einsum("bsr,rhv->bshv", c, wv)
+            s = jnp.einsum("bqhn,bkhn->bhqk", q_c, k_c,
+                           preferred_element_type=jnp.float32) \
+                + jnp.einsum("bqhd,bkd->bhqk", q_r, k_r,
+                             preferred_element_type=jnp.float32)
+            causal = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+            s = jnp.where(causal[None, None], s * scale, jnp.float32(-1e30))
+            p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            p = p / jnp.sum(p, axis=-1, keepdims=True)
+            attn = jnp.einsum("bhqk,bkhv->bqhv", p.astype(dt), v)
+        return attn.reshape(B, T, H * dv), pool
+
+    from ..ops.attention import paged_latent_attention
+
+    pool_shape = pool.shape  # [L, n_blocks, bs, lanes]
+    flat = (pool_shape[0] * pool_shape[1],) + pool_shape[2:]
+    with jax.named_scope("kv_write"):
+        blk, off = _paged_rows(pool_shape, paged_tables, pos_offset, T,
+                               layer, None)
+        row = jnp.concatenate([c, k_r], axis=-1)
+        row = jnp.pad(row, ((0, 0), (0, 0),
+                            (0, pool_shape[-1] - row.shape[-1])))
+        c_flat = pool.reshape(flat).at[blk, off].set(
+            row.astype(pool.dtype), mode="drop")
+    with jax.named_scope("attention"), jax.named_scope("attn.latent"):
+        lens, tables = _paged_view(pool_shape, paged_tables, pos_offset, T,
+                                   layer, None)
+        q_lat = jnp.einsum("bqhn,rhn->bqhr", q_c, wk)
+        o_lat = paged_latent_attention(
+            jnp.concatenate([q_lat, q_r], axis=-1), c_flat, tables,
+            lens, v_width=r, scale=scale).astype(dt)
+        attn = jnp.einsum("bqhr,rhv->bqhv", o_lat, wv)
+    return attn.reshape(B, T, H * dv), c_flat.reshape(pool_shape)
+
+
 def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
            attn_fn=None, paged_tables=None, layer=None, kind=None,
-           park=None, live=None, stats_out=None):
+           park=None, live=None, stats_out=None, branch_io=None):
     """One transformer block.  ``kv=(k_cache, v_cache)`` enables cached
     decode (x is the new suffix, written at ``pos_offset``); ``attn_fn``
     overrides plain causal attention (ring attention under shard_map);
@@ -1056,7 +1302,11 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
     the position from which a row is parked (the full table's span —
     a ring has no such edge of its own).  An expert layer appends its
     routing counts (models/moe.py ``moe_ffn``, over the rows ``live``
-    marks) to ``stats_out``."""
+    marks) to ``stats_out``.  A latent layer's ``kv`` is its class's one
+    pool, as a 1-tuple (:func:`_latent_attention`).  ``branch_io`` is a
+    one-entry list holding the open shortcut branch's tensor: a kind
+    that opens writes it, a kind that closes reads it (the walk carries
+    it between)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -1069,6 +1319,13 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         if kind is not None else None
 
     h = _rmsnorm(x, lp["ln_attn"], cfg.norm_eps)
+    if kind is not None and kind.latent:
+        attn, c = _latent_attention(
+            cfg, lp, h, positions, kv[0] if kv is not None else None,
+            pos_offset, paged_tables, layer)
+        x = x + _mm(attn, lp, "wo", dt)
+        return _feed_forward(cfg, lp, x, kind, live, stats_out,
+                             branch_io), (c,)
     if "wqkv_p" in lp:  # int4 fused q|k|v (one kernel call per layer)
         qkv = _mm(h, lp, "wqkv", dt)
         q = qkv[..., :H * hd].reshape(B, T, H, hd)
@@ -1103,37 +1360,18 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
 
         k_pool, v_pool = kv  # [L, n_blocks, bs, Hkv, hd]
         pool_shape = k_pool.shape
-        n_layers, n_blocks, bs = pool_shape[:3]
-        flat = (n_layers * n_blocks,) + pool_shape[2:]
-        base = layer * n_blocks
-        max_blocks = paged_tables.shape[1]
+        flat = (pool_shape[0] * pool_shape[1],) + pool_shape[2:]
         ring = park is not None
-        # positions from `edge` on are parked; a ring's table says
-        # nothing of it, so its caller passes the full table's span
-        edge = park if ring else max_blocks * bs
         with jax.named_scope("kv_write"):
-            idx = pos_offset[:, None] + jnp.arange(T)[None, :]  # [B, T]
-            slot_blk = (idx // bs) % max_blocks if ring \
-                else jnp.clip(idx // bs, 0, max_blocks - 1)
-            entry = jnp.take_along_axis(paged_tables, slot_blk, axis=1)
-            valid = ((idx >= 0) & (idx < edge)
-                     & (entry >= 0) & (entry < n_blocks))
-            blk = jnp.where(valid, base + entry,
-                            n_layers * n_blocks)  # sentinel -> dropped
-            off = idx % bs
+            blk, off = _paged_rows(pool_shape, paged_tables, pos_offset, T,
+                                   layer, park)
             k_flat = k_pool.reshape(flat).at[blk, off].set(
                 k.astype(k_pool.dtype), mode="drop")
             v_flat = v_pool.reshape(flat).at[blk, off].set(
                 v.astype(v_pool.dtype), mode="drop")
-        # context = everything written so far incl. this suffix; a parked
-        # row (pos >= max_blocks*bs) gets len 0 — the paged kernel then
-        # issues ZERO block DMAs for it, which is the whole traffic story
         with jax.named_scope("attention"):
-            lens = jnp.where(pos_offset + T <= edge,
-                             pos_offset + T, 0).astype(jnp.int32)
-            # sentinel entries clip inside the layer BEFORE the offset:
-            # clipped after it they would name another layer's block
-            tables = base + jnp.clip(paged_tables, 0, n_blocks - 1)
+            lens, tables = _paged_view(pool_shape, paged_tables, pos_offset,
+                                       T, layer, park)
             if kind is None:
                 attn = paged_attention(q, k_flat, v_flat, tables,
                                        lens).astype(dt)
@@ -1216,18 +1454,37 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
 
     out = _mm(attn.reshape(B, T, H * hd), lp, "wo", dt)
     x = x + out
+    return _feed_forward(cfg, lp, x, kind, live, stats_out, branch_io), kv
 
+
+def _feed_forward(cfg: LlamaConfig, lp, x, kind, live, stats_out,
+                  branch_io):
+    """The second half of a block: ``x + FFN(N(x))``, the FFN dense or
+    sparse experts as ``kind`` says.  A kind that opens a shortcut also
+    sends the SAME normed input through the experts and leaves their
+    result in ``branch_io[0]`` — nothing reads it before the closing
+    kind adds it to its own output, so the branch runs beside whatever
+    lies between."""
+    import jax
     import jax.nn as jnn
 
+    from .moe import moe_ffn
+
+    dt = x.dtype
+    shortcut = kind.shortcut if kind is not None else ""
     with jax.named_scope("mlp"):
         h = _rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
         if kind is not None and kind.ffn == "experts":
-            from .moe import moe_ffn
-
             y, stats = moe_ffn(h, lp, cfg.experts, dt, live=live)
             if stats_out is not None:
                 stats_out.append(stats)
-            return x + y, kv
+            return x + y
+        if shortcut == "open":
+            with jax.named_scope("moe.shortcut"):
+                branch_io[0], stats = moe_ffn(h, lp, cfg.experts, dt,
+                                              live=live)
+            if stats_out is not None:
+                stats_out.append(stats)
         if "wgu_p" in lp:  # int4 fused gate|up
             F = lp["wgu_p"].shape[-1] // 2
             gu = _mm(h, lp, "wgu", dt)
@@ -1237,7 +1494,10 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
             gate = jnn.silu(_mm(h, lp, "w_gate", dt))
             up = _mm(h, lp, "w_up", dt)
         x = x + _mm(gate * up, lp, "w_down", dt)
-    return x, kv
+        if shortcut == "close":
+            with jax.named_scope("moe.shortcut"):
+                x = x + branch_io[0]
+    return x
 
 
 def forward(params, tokens, cfg: LlamaConfig, compute_dtype="bfloat16"):
@@ -1251,10 +1511,14 @@ def forward(params, tokens, cfg: LlamaConfig, compute_dtype="bfloat16"):
     positions = jnp.arange(T)
 
     if cfg.patterned:
-        def step(x, lp, kind, _slot):
-            return _block(cfg, lp, x, positions, kind=kind)[0]
+        def step(carry, lp, kind, _slot):
+            x, branch = carry
+            io = [branch]
+            x, _ = _block(cfg, lp, x, positions, kind=kind, branch_io=io)
+            return x, io[0]
 
-        x = _walk_pattern(cfg, params["layers"], x, step)
+        x, _ = _walk_pattern(cfg, params["layers"], (x, _no_branch(cfg, x)),
+                             step)
         x = _rmsnorm(x, params["ln_out"], cfg.norm_eps)
         return _lm_head(params, x, dt)
 
@@ -1267,12 +1531,25 @@ def forward(params, tokens, cfg: LlamaConfig, compute_dtype="bfloat16"):
     return _lm_head(params, x, dt)
 
 
+def _no_branch(cfg: LlamaConfig, x):
+    """What the walk carries for the shortcut branch before any is open:
+    a tensor of ``x``'s shape where the model has shortcuts (a scan's
+    carry keeps one structure; after a branch closed, its stale value
+    rides on unread until the next one opens), else nothing."""
+    import jax.numpy as jnp
+
+    return jnp.zeros_like(x) if cfg.has_shortcut else None
+
+
 def _walk_pattern(cfg: LlamaConfig, stacks, carry, step):
     """Threads ``carry`` through every layer of a patterned model:
     ``step(carry, lp, kind, slot) -> carry`` with ``lp`` the layer's
     leaves taken out of its kind's stack and ``slot`` the layer's index
-    among the layers of its attention class (full or window) — its layer
-    in that class's cache pool.  Laid out by :func:`walk_plan`: the
+    among the layers of its cache class (``LayerKind.cache``: full,
+    window or latent) — its layer in that class's cache pool.  What one
+    sub-layer opens and a later one closes (a shortcut's branch) is part
+    of ``carry``, beside ``x`` and the pools.  Laid out by
+    :func:`walk_plan`: the
     prefix one by one, then a scan over the periods, so a deep model is
     ``prefix + period`` copies of the block, not ``n_layers``."""
     import jax
@@ -1285,7 +1562,7 @@ def _walk_pattern(cfg: LlamaConfig, stacks, carry, step):
     where, seen = [], {}
     for kind in kinds:
         pair = []
-        for key in (kind.name, bool(kind.window)):
+        for key in (kind.name, "cache:" + kind.cache):
             pair.append(seen.get(key, 0))
             seen[key] = pair[-1] + 1
         where.append(tuple(pair))
@@ -1365,17 +1642,44 @@ def init_paged_cache(cfg: LlamaConfig, n_blocks: int, block_size: int,
     hold the full-attention layers (``n_blocks`` blocks each, handed out
     by the allocator as before), ``k_win``/``v_win`` the window layers
     (``win_blocks`` blocks each: ``slots`` rings of
-    :func:`window_ring_blocks`, which no allocator touches)."""
+    :func:`window_ring_blocks`, which no allocator touches).  Latent
+    layers keep ``c``: ``[latent layers, n_blocks, block_size, lanes]``,
+    one row a token for all heads (:func:`_latent_attention`), in the
+    ALLOCATOR's blocks like ``k``/``v`` (block ``j`` holds the same
+    positions in every leaf but the rings) and merged flat the same way;
+    ``lanes`` is ``latent_width`` padded as the kernel wants it
+    (ops/attention.py ``latent_pool_width``).  A class the model has no
+    layer of has no leaf."""
     import jax.numpy as jnp
 
+    from ..ops.attention import latent_pool_width
+
     tail = (block_size, cfg.n_kv_heads, cfg.head_dim)
-    shape = (cfg.n_full_layers, n_blocks) + tail
-    pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    pool = {}
+    if cfg.n_full_layers:
+        shape = (cfg.n_full_layers, n_blocks) + tail
+        pool.update(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
     if cfg.n_window_layers:
         wshape = (cfg.n_window_layers, max(1, int(win_blocks))) + tail
         pool["k_win"] = jnp.zeros(wshape, dtype)
         pool["v_win"] = jnp.zeros(wshape, dtype)
+    if cfg.n_latent_layers:
+        pool["c"] = jnp.zeros(
+            (cfg.n_latent_layers, n_blocks, block_size,
+             latent_pool_width(cfg.latent_width)), dtype)
     return pool
+
+
+#: cache class (``LayerKind.cache``) -> its leaves of the pool
+POOL_LEAVES = {"full": ("k", "v"), "win": ("k_win", "v_win"),
+               "latent": ("c",)}
+
+
+def allocated_leaves(pool) -> List[str]:
+    """The pool leaves whose blocks the allocator hands out (all but a
+    window class's rings), in a fixed order: what a CoW fork, a drain and
+    an adopt copy block by block."""
+    return sorted(leaf for leaf in pool if not leaf.endswith("_win"))
 
 
 def paged_cache_pspecs() -> Dict:
@@ -1408,10 +1712,15 @@ def tp_divisibility_problems(cfg: LlamaConfig, tp: int) -> List[str]:
     if tp <= 1:
         return []
     if cfg.patterned:
-        return ["a patterned model (layer pattern, sparse experts, q/k "
-                "norm) has no tensor-parallel layout: its experts divide "
-                "by expert parallelism (ROADMAP M2), its stacks have no "
-                "param_pspecs"]
+        probs = ["a patterned model (layer pattern, sparse experts, q/k "
+                 "norm) has no tensor-parallel layout: its experts divide "
+                 "by expert parallelism (ROADMAP M2), its stacks have no "
+                 "param_pspecs"]
+        if cfg.n_latent_layers:
+            probs.append("a latent layer caches ONE row a token for all "
+                         "heads: the pool has no KV-head axis to shard "
+                         "(ROADMAP M5)")
+        return probs
     probs: List[str] = []
     if (cfg.n_heads * cfg.head_dim) % tp:
         probs.append(f"attention out dim n_heads*head_dim="
@@ -1431,16 +1740,21 @@ def tp_divisibility_problems(cfg: LlamaConfig, tp: int) -> List[str]:
 
 def paged_cache_bytes(cfg: LlamaConfig, n_blocks: int, block_size: int,
                       dtype="bfloat16", win_blocks: int = 0) -> int:
-    """Static HBM footprint of :func:`init_paged_cache` (k + v, both
-    layer classes), without building anything — the deep-lint resource
+    """Static HBM footprint of :func:`init_paged_cache` (k + v of the
+    full and window classes, the latent class's rows at their padded
+    width), without building anything — the deep-lint resource
     report prices the pool through this, so the arithmetic lives next to
     the allocation."""
+    from ..ops.attention import latent_pool_width
+
     itemsize = 2 if str(dtype) in ("bfloat16", "float16") else 4
     blocks = cfg.n_full_layers * n_blocks
     if cfg.n_window_layers:
         blocks += cfg.n_window_layers * max(1, int(win_blocks))
-    return (2 * blocks * block_size * cfg.n_kv_heads * cfg.head_dim
-            * itemsize)
+    latent = cfg.n_latent_layers * n_blocks * (
+        latent_pool_width(cfg.latent_width) if cfg.n_latent_layers else 0)
+    return (2 * blocks * cfg.n_kv_heads * cfg.head_dim
+            + latent) * block_size * itemsize
 
 
 def resolve_config(model: str, opts: Dict) -> Optional[LlamaConfig]:
@@ -1515,6 +1829,11 @@ def param_bytes_split(cfg: LlamaConfig, quant: str = "",
     return total - replicated, replicated
 
 
+def block_size_of(pool) -> int:
+    """Positions a block of ``pool`` holds (every leaf's axis 2)."""
+    return next(iter(pool.values())).shape[2]
+
+
 def forward_paged(params, tokens, pool, block_tables, pos,
                   cfg: LlamaConfig, compute_dtype="bfloat16",
                   logit_off=None, with_stats=False):
@@ -1552,7 +1871,7 @@ def forward_paged(params, tokens, pool, block_tables, pos,
     row instead of T.
 
     ``with_stats``: also return the expert layers' routing counts of
-    this step, int32 [3] (models/moe.py; None for a model without
+    this step, int32 [4] (models/moe.py; None for a model without
     experts)."""
     import jax
     import jax.numpy as jnp
@@ -1564,35 +1883,39 @@ def forward_paged(params, tokens, pool, block_tables, pos,
     positions = pos[:, None] + jnp.arange(T)[None, :]
 
     if cfg.patterned:
-        # A pool and a table a layer class: ``block_tables`` is then
-        # ``{"full": [B, max_blocks], "win": [B, ring]}`` (the array
-        # alone where no layer has a window).  Both pools are carries of
+        # A pool a layer class (POOL_LEAVES) and a table for the
+        # allocator's blocks and one for the rings: ``block_tables`` is
+        # then ``{"full": [B, max_blocks], "win": [B, ring]}`` (the array
+        # alone where no layer has a window).  Every pool is a carry of
         # the walk, like the one pool below.
-        from .moe import merge_stats
+        from .moe import N_STATS, merge_stats
 
         tabs = block_tables if isinstance(block_tables, dict) \
             else {"full": block_tables}
-        park = tabs["full"].shape[1] * pool["k"].shape[2]
+        park = tabs["full"].shape[1] * block_size_of(pool)
         live = pos < park
 
         def step(carry, lp, kind, slot):
-            x, pl, stats = carry
-            suffix = "_win" if kind.window else ""
-            got: list = []
-            x, (kc, vc) = _block(
-                cfg, lp, x, positions,
-                kv=(pl["k" + suffix], pl["v" + suffix]), pos_offset=pos,
-                paged_tables=tabs["win" if kind.window else "full"],
-                layer=slot, kind=kind, park=park if kind.window else None,
-                live=live, stats_out=got)
-            pl = dict(pl, **{"k" + suffix: kc, "v" + suffix: vc})
+            x, pl, stats, branch = carry
+            got, io = [], [branch]
+            # a latent layer's rows live in the allocator's blocks, as
+            # the full class's do: one table for both
+            ring = kind.cache == "win"
+            leaves = POOL_LEAVES[kind.cache]
+            x, kv = _block(
+                cfg, lp, x, positions, kv=tuple(pl[n] for n in leaves),
+                pos_offset=pos, paged_tables=tabs["win" if ring else "full"],
+                layer=slot, kind=kind, park=park if ring else None,
+                live=live, stats_out=got, branch_io=io)
+            pl = dict(pl, **dict(zip(leaves, kv)))
             if got:
                 stats = merge_stats(stats, got[0])
-            return x, pl, stats
+            return x, pl, stats, io[0]
 
-        stats0 = jnp.zeros((3,), jnp.int32) if cfg.experts else None
-        x, pool, stats = _walk_pattern(
-            cfg, params["layers"], (x, dict(pool), stats0), step)
+        stats0 = jnp.zeros((N_STATS,), jnp.int32) if cfg.experts else None
+        x, pool, stats, _ = _walk_pattern(
+            cfg, params["layers"],
+            (x, dict(pool), stats0, _no_branch(cfg, x)), step)
         x = _rmsnorm(x, params["ln_out"], cfg.norm_eps)
         if logit_off is not None:
             x = lax.dynamic_slice_in_dim(x, logit_off, 1, axis=1)

@@ -23,8 +23,18 @@ group sizes are VALUES: how the tokens spread over the experts changes
 no shape, so nothing recompiles.  Rows past the held groups belong to
 no expert; what the product leaves there is not read.
 
-Weights of one layer (``lp``): ``w_router`` [D, E] and ``router_bias``
-[E] float32 (the router runs in float32: a choice among near-equal
+**Identity (zero-compute) experts.**  A router may score ``zero_experts``
+further outputs, numbered after the routed ones: a token that chooses one
+gets ``g * h`` for it, its own input scaled by the pair's weight, and no
+matrix is read.  Such a pair needs no dispatch — it is computed where the
+token lives, by every rank alike, and counted ONCE when the ranks' partial
+results are added (as a shared expert is).  The grouped product's rows
+stay ``tokens * top_k``; an identity pair sorts last with the pairs of
+experts held elsewhere.  How many of a token's choices are identity is a
+value: a token computes between 0 and ``top_k`` real experts.
+
+Weights of one layer (``lp``): ``w_router`` [D, E + zero] and ``router_bias``
+[E + zero] float32 (the router runs in float32: a choice among near-equal
 scores must not depend on bf16 rounding); ``we_gate``, ``we_up`` [held,
 D, F], ``we_down`` [held, F, D]; ``ws_gate``, ``ws_up`` [D, Fs],
 ``ws_down`` [Fs, D] with ``Fs = shared * F``.
@@ -49,18 +59,21 @@ from typing import Optional
 
 #: leaves :func:`moe_ffn` takes as the kind's whole stack (see above)
 STACKED_LEAVES = ("we_gate", "we_up", "we_down")
+#: entries of :func:`moe_ffn`'s ``stats``
+N_STATS = 4
 
 
 @dataclasses.dataclass(frozen=True)
 class ExpertsConfig:
     """The sparse FFN of a model: ``n_experts`` routed experts of width
     ``hidden``, ``top_k`` a token, ``shared`` always-on experts of the
-    same width; scores are sigmoids, with a per-expert correction bias
-    added FOR THE CHOICE ONLY (``scoring`` names the one kind built, so a
-    config that states another is refused); ``norm_topk`` divides the
-    chosen weights by their sum; ``scale`` multiplies them.
-    ``held_first``/``held_count`` name this process's share
-    (``held_count`` 0 = all)."""
+    same width; scores are sigmoids or a softmax over all the router's
+    outputs (``scoring``), with a per-expert correction bias added FOR
+    THE CHOICE ONLY; ``norm_topk`` divides the chosen weights by their
+    sum; ``scale`` multiplies them.  ``zero_experts`` identity experts
+    follow the routed ones in the router's outputs (module docstring).
+    ``held_first``/``held_count`` name this process's share of the
+    routed experts (``held_count`` 0 = all)."""
 
     n_experts: int
     top_k: int
@@ -71,13 +84,17 @@ class ExpertsConfig:
     scale: float = 1.0
     held_first: int = 0
     held_count: int = 0
+    zero_experts: int = 0
 
     def __post_init__(self):
-        if self.scoring != "sigmoid":
-            raise ValueError(f"expert scoring {self.scoring!r}: only "
-                             "sigmoid scores are built")
-        if not 0 < self.top_k <= self.n_experts:
-            raise ValueError(f"top_k {self.top_k} of {self.n_experts} experts")
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"expert scoring {self.scoring!r}: sigmoid "
+                             "and softmax scores are built")
+        if self.zero_experts < 0:
+            raise ValueError(f"zero_experts {self.zero_experts}")
+        if not 0 < self.top_k <= self.n_router:
+            raise ValueError(f"top_k {self.top_k} of {self.n_router} "
+                             "router outputs")
         if self.held_first < 0 or \
                 self.held_first + self.n_held > self.n_experts:
             raise ValueError(
@@ -89,11 +106,17 @@ class ExpertsConfig:
     def n_held(self) -> int:
         return self.held_count or self.n_experts
 
+    @property
+    def n_router(self) -> int:
+        """Outputs the router scores: routed, then identity experts."""
+        return self.n_experts + self.zero_experts
+
 
 def route(h, lp, ex: ExpertsConfig):
-    """The router over ALL experts: ``h`` [N, D] -> (``idx`` [N, k] the
-    chosen experts, ``w`` [N, k] float32 their weights, normalised over
-    all k chosen wherever they live)."""
+    """The router over ALL its outputs: ``h`` [N, D] -> (``idx`` [N, k]
+    the chosen experts, ids from ``n_experts`` on being identity experts,
+    ``w`` [N, k] float32 their weights, normalised (where the model
+    does) over all k chosen wherever they live)."""
     import jax
     import jax.numpy as jnp
 
@@ -101,7 +124,8 @@ def route(h, lp, ex: ExpertsConfig):
         logits = jnp.dot(h.astype(jnp.float32),
                          lp["w_router"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        s = jax.nn.sigmoid(logits)
+        s = jax.nn.sigmoid(logits) if ex.scoring == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
         _, idx = jax.lax.top_k(s + lp["router_bias"].astype(jnp.float32),
                                ex.top_k)
         w = jnp.take_along_axis(s, idx, axis=-1)
@@ -112,10 +136,10 @@ def route(h, lp, ex: ExpertsConfig):
 
 def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
     """``h`` [B, T, D] (already normed) -> (this rank's partial FFN
-    output [B, T, D], ``stats``).  ``stats`` is int32 [3] — routed pairs
-    computed by held experts, held experts hit, most pairs on one expert
-    — over the rows ``live`` [B] marks (all when None); it is what the
-    serve loop's ``serve.decode`` span reports."""
+    output [B, T, D], ``stats``).  ``stats`` is int32 [4] — routed pairs
+    computed by held experts, held experts hit, most pairs on one expert,
+    identity pairs — over the rows ``live`` [B] marks (all when None);
+    it is what the serve loop's ``serve.decode`` span reports."""
     import jax
     import jax.nn as jnn
     import jax.numpy as jnp
@@ -156,32 +180,40 @@ def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
         routed = jnp.zeros((N, D), jnp.float32).at[tok].add(
             jnp.where(hs[:, None], y * wp[:, None], 0.0))
 
-    with jax.named_scope("moe.shared"):
-        out = routed
-        if ex.shared:
+    out = routed
+    if ex.shared:
+        with jax.named_scope("moe.shared"):
             g = jnn.silu(x.astype(dt) @ lp["ws_gate"].astype(dt))
             u = x.astype(dt) @ lp["ws_up"].astype(dt)
             out = out + ((g * u) @ lp["ws_down"].astype(dt)).astype(
                 jnp.float32)
+    zero = idx >= ex.n_experts   # [N, k]: the identity pairs
+    if ex.zero_experts:
+        with jax.named_scope("moe.zero"):
+            out = out + x.astype(jnp.float32) * jnp.sum(
+                jnp.where(zero, w, 0.0), axis=-1, keepdims=True)
 
     # what the span reports, over live rows only: a parked slot decodes
     # garbage whose routing nobody asked for
     if live is None:
         lcounts = sizes
     else:
-        lheld = held & jnp.repeat(live, T)[:, None]
+        lrow = jnp.repeat(live, T)[:, None]
+        lheld, zero = held & lrow, zero & lrow
         lcounts = jnp.zeros((E + 1,), jnp.int32).at[
             jnp.where(lheld, idx - ex.held_first, E).reshape(N * k)
         ].add(1)[:E]
-    stats = jnp.stack([lcounts.sum(), (lcounts > 0).sum(),
-                       lcounts.max()]).astype(jnp.int32)
+    stats = jnp.stack([lcounts.sum(), (lcounts > 0).sum(), lcounts.max(),
+                       zero.sum()]).astype(jnp.int32)
     return out.astype(dt).reshape(B, T, D), stats
 
 
 def merge_stats(a: Optional[object], b):
-    """Sums pairs and experts hit, keeps the largest per-expert load."""
+    """Sums pairs, experts hit and identity pairs, keeps the largest
+    per-expert load."""
     import jax.numpy as jnp
 
     if a is None:
         return b
-    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2])])
+    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2]),
+                      a[3] + b[3]])

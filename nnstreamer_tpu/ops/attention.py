@@ -605,3 +605,246 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     )(tbl, context_lens.astype(jnp.int32), q[:, 0],
       k_pool.reshape(flat), v_pool.reshape(flat))
     return out[:, None]
+
+
+# ---------------------------------------------------------------------------
+# Paged latent attention — decode over a pool whose value is a prefix of
+# its key
+# ---------------------------------------------------------------------------
+
+def latent_pool_width(width: int) -> int:
+    """Columns a latent pool's row takes for ``width`` cached values: the
+    next multiple of the 128 lanes a DMA and an MXU operand tile by (576
+    -> 640).  The columns past ``width`` are zero for good — the pool is
+    born zero and every write pads with zeros — so they add nothing to a
+    score; they are the padding the kernel pays for in bytes."""
+    return -(-int(width) // 128) * 128
+
+
+def paged_latent_attention_reference(q, pool, block_tables, context_lens, *,
+                                     v_width: int,
+                                     scale: Optional[float] = None):
+    """Plain-XLA attention in the latent space (the kernel's semantics,
+    materialized).
+
+    ``q``: [B, T, H, Dq] — per head the query already multiplied into the
+    latent space, then its rotated part (``q~ | q_R``); ``pool``:
+    [n_blocks, block_size, W] with ``W >= Dq``: a cached token's row is
+    its normed latent, then the one rotated key all heads share, then
+    zeros (:func:`latent_pool_width`).  Every head scores against the
+    SAME row (one "KV head"), and the value is the row's first
+    ``v_width`` columns.  ``block_tables``/``context_lens`` as in
+    :func:`paged_attention_reference`.  Returns [B, T, H, v_width]."""
+    B, T, H, Dq = q.shape
+    n_blocks, bs, _ = pool.shape
+    scale_v = (Dq ** -0.5) if scale is None else scale
+    dt = q.dtype
+    tbl = jnp.clip(block_tables, 0, n_blocks - 1)
+    rows = pool[tbl].reshape(B, -1, pool.shape[-1])[..., :Dq].astype(dt)
+    q_pos = (context_lens[:, None] - T) + jnp.arange(T)[None, :]  # [B, T]
+    mask = jnp.arange(rows.shape[1])[None, None, :] <= q_pos[:, :, None]
+    s = jnp.einsum("bqhd,bkd->bhqk", q, rows,
+                   preferred_element_type=jnp.float32) * scale_v
+    s = jnp.where(mask[:, None], s, jnp.float32(-1e30))
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    return jnp.einsum("bhqk,bkd->bqhd", p.astype(dt), rows[..., :v_width])
+
+
+def _latent_wave_blocks(bs: int) -> int:
+    """Blocks in flight per buffer slot: ~512 score columns a wave, never
+    more than 32 places (2 slots x 32 x 20 KB of VMEM at block 16, width
+    640).  Twice the K/V kernel's wave: a full wave costs one wait and no
+    loop (:func:`_paged_latent_kernel`), so the longer wave halves what
+    the scalar core spends a cached token; on the chip the cell's mix of
+    contexts reads 151 us a call against 160 at 16 places and the slope
+    0.150 us a token of context against 0.201."""
+    return max(1, min(512 // bs, 32))
+
+
+def _paged_latent_kernel(tbl_ref, len_ref, q_ref, c_hbm, o_ref, cbuf, sem,
+                         state, *, scale: float):
+    """One stream (batch row) per grid cell, as :func:`_paged_kernel`:
+    blocks stream in waves of ``W = cbuf.shape[1]`` through two VMEM
+    slots, a row's last wave overlapping the next row's first, and an
+    idle row (length 0) fetches nothing.
+
+    What differs: ONE pool.  A block is ``[bs, Wd]`` and every query head
+    reads every row of it, so a wave's scores are the plain product
+    ``q[H, Wd] x C[W * bs, Wd]^T`` with no column masked by head, and the
+    value is the same buffer's first ``Dv`` columns: the second product
+    ``P[H, W * bs] x C[:, :Dv]`` reads what the first one's DMA brought."""
+    H, Wd = q_ref.shape
+    Dv = o_ref.shape[-1]
+    _, W, bs, _ = cbuf.shape
+    cols = W * bs
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+
+    def stream(row):
+        L = len_ref[row]
+        return (L + bs - 1) // bs, L  # live blocks only
+
+    nb, L = stream(b)
+    n_waves = (nb + W - 1) // W  # 0 for an idle row
+
+    def copy(row, slot, first, w):
+        return pltpu.make_async_copy(
+            c_hbm.at[tbl_ref[row, first + w]], cbuf.at[slot, w], sem.at[slot])
+
+    def each_live_place(row, slot, first, end, op):
+        def place(w, carry):
+            op(copy(row, slot, first, w))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(end - first, 0, W), place, None)
+
+    # The scalar core issues every copy and every wait, in blocks of code
+    # of their own between the products, so none of it overlaps them:
+    # timed alone, a call's copies took as long as its arithmetic and the
+    # two ADDED.  So a FULL wave (all but a row's last) takes the short
+    # way: its W starts unrolled, with no loop and no bound to clip, and
+    # ONE wait for the whole slot's bytes, which is what W copies on one
+    # semaphore add up to.
+    def start(row, slot, first, end):
+        full = first + W <= end
+
+        @pl.when(full)
+        def _():
+            for w in range(W):
+                copy(row, slot, first, w).start()
+
+        @pl.when(jnp.logical_not(full))
+        def _():
+            each_live_place(row, slot, first, end, lambda c: c.start())
+
+    def wait(row, slot, first, end):
+        full = first + W <= end
+
+        @pl.when(full)
+        def _():
+            # a descriptor of the slot's shape: only its byte count is read
+            pltpu.make_async_copy(c_hbm.at[pl.ds(0, W)], cbuf.at[slot],
+                                  sem.at[slot]).wait()
+
+        @pl.when(jnp.logical_not(full))
+        def _():
+            each_live_place(row, slot, first, end, lambda c: c.wait())
+
+    @pl.when(b == 0)
+    def _():
+        # a place no copy has filled yet meets p == 0 in P x V, and
+        # 0 x whatever VMEM held is NaN where that was NaN
+        cbuf[...] = jnp.zeros_like(cbuf)
+        state[0] = 0  # the slot this row's first wave goes to
+        state[1] = 0  # ... and whether the row before has started it
+
+    slot0 = state[0]
+
+    @pl.when(state[1] == 0)
+    def _():
+        start(b, slot0, 0, nb)
+
+    nxt = jnp.minimum(b + 1, B - 1)
+    nxt_nb, _ = stream(nxt)
+    nxt_nb = jnp.where(b + 1 < B, nxt_nb, 0)
+
+    q = q_ref[...]  # [H, Wd], the compute dtype
+    tok = jax.lax.broadcasted_iota(jnp.int32, (H, cols), 1)
+
+    def body(j, carry):
+        m, l, acc = carry
+        slot = jax.lax.rem(slot0 + j, 2)
+        more = j + 1 < n_waves
+        start(jnp.where(more, b, nxt), 1 - slot,
+              jnp.where(more, (j + 1) * W, 0), jnp.where(more, nb, nxt_nb))
+        first = j * W
+        wait(b, slot, first, nb)
+        rows = cbuf[slot].reshape(cols, Wd).astype(q.dtype)
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, cols]
+        s = jnp.where(tok < L - first * bs, s, -jnp.inf)
+        # every wave run holds a live position, so m_new is finite
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot_general(
+            p.astype(q.dtype), rows[:, :Dv],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    m0 = jnp.full((H, 1), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((H, 1), jnp.float32)
+    acc0 = jnp.zeros((H, Dv), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(0, n_waves, body, (m0, l0, acc0))
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    state[0] = jax.lax.rem(slot0 + n_waves, 2)
+    state[1] = (n_waves > 0).astype(jnp.int32)
+
+
+def paged_latent_attention(q, pool, block_tables, context_lens, *,
+                           v_width: int, scale: Optional[float] = None,
+                           interpret: Optional[bool] = None):
+    """Decode attention over a block-paged LATENT pool (shapes as in
+    :func:`paged_latent_attention_reference`): per cached token one row of
+    ``Dq`` values that is the key of every head and, in its first
+    ``v_width`` columns, the value of every head.
+
+    The Pallas kernel runs on TPU (or under ``interpret=True``) for the
+    decode shape (T == 1) over a pool whose rows are lane-padded
+    (:func:`latent_pool_width`); prefill chunks (T > 1) and non-TPU
+    backends take the reference.  Per-row HBM traffic is
+    ``ceil(context_len / block_size)`` blocks, each read ONCE for both
+    products.  Products in the query's dtype with float32 accumulation,
+    softmax statistics in float32."""
+    B, T, H, Dq = q.shape
+    n_blocks, bs, Wd = pool.shape
+    scale_v = (Dq ** -0.5) if scale is None else scale
+    if interpret is None:
+        interpret = False
+        if jax.default_backend() != "tpu":
+            return paged_latent_attention_reference(
+                q, pool, block_tables, context_lens, v_width=v_width,
+                scale=scale_v)
+    if (
+        not paged_kernel_enabled()
+        or T != 1
+        or (not interpret and (Wd % 128 or v_width % 128
+                               or bs % (32 // pool.dtype.itemsize)))
+    ):
+        return paged_latent_attention_reference(
+            q, pool, block_tables, context_lens, v_width=v_width,
+            scale=scale_v)
+
+    tbl = jnp.clip(block_tables, 0, n_blocks - 1).astype(jnp.int32)
+    # a full wave's wait is sized as a slice of the pool: never wider
+    wave = min(_latent_wave_blocks(bs), n_blocks)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((None, H, Wd), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # the pool stays in HBM
+        ],
+        out_specs=pl.BlockSpec((None, H, v_width), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, wave, bs, Wd), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+        ],
+    )
+    # the query's columns past Dq meet the pool's zero padding
+    qp = jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, Wd - Dq)))
+    out = pl.pallas_call(
+        functools.partial(_paged_latent_kernel, scale=scale_v),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, v_width), q.dtype),
+        # a row's last wave overlaps the next row's first: rows in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_latent_attention",
+    )(tbl, context_lens.astype(jnp.int32), qp, pool)
+    return out[:, None]

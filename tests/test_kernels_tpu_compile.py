@@ -297,3 +297,105 @@ def test_patterned_decode_step_compiles_at_published_widths(v5e,
     pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                      for x in jax.tree_util.tree_leaves(pool))
     assert mem.alias_size_in_bytes >= pool_bytes   # both pools in place
+
+
+# -- latent attention, shortcut experts, identity experts (PR 33) -----------
+
+#: the benchmark's latent serving cell: 64 slots, 64 heads on ONE cache row
+#: of 512 + 64 values, 8 attention blocks (4 published layers of two), a
+#: pool of 64 x ceil(1056 / 16) blocks of 16, tables of 4096 / 16 entries
+LAT_SLOTS, LAT_BLOCKS, LAT_MAX_BLOCKS = 64, 64 * 66, 256
+
+
+def _latent_cfg():
+    from nnstreamer_tpu.models.moe import ExpertsConfig
+
+    return llama.LlamaConfig(
+        vocab=16384, dim=6144, n_layers=8, n_heads=64, n_kv_heads=1,
+        ffn_hidden=12288, max_seq=4096, rope_theta=1e7,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
+        v_head_dim=128, q_lora_scale=2.0, kv_lora_scale=12 ** 0.5,
+        pattern=tuple(llama.LayerKind(
+            latent=True, shortcut="close" if l % 2 else "open")
+            for l in range(8)),
+        experts=ExpertsConfig(n_experts=512, top_k=12, hidden=2048,
+                              scoring="softmax", norm_topk=False, scale=6.0,
+                              zero_experts=256, held_first=0, held_count=16))
+
+
+def test_paged_latent_attention_compiles(v5e):
+    """The latent decode kernel alone at the cell's shapes: 64 query heads
+    of 576 on one pool row padded to 640, the value its first 512 columns."""
+    _compile(
+        "paged_latent_attention",
+        lambda q, pool, tbl, lens: A.paged_latent_attention(
+            q, pool, tbl, lens, v_width=512, scale=192 ** -0.5,
+            interpret=False),
+        v5e((LAT_SLOTS, 1, 64, 576), jnp.bfloat16),
+        v5e((8 * LAT_BLOCKS, 16, A.latent_pool_width(576)), jnp.bfloat16),
+        v5e((LAT_SLOTS, LAT_MAX_BLOCKS), jnp.int32),
+        v5e((LAT_SLOTS,), jnp.int32))
+
+
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_step"])
+def test_latent_serve_programs_compile_at_the_cells_shapes(
+        v5e, monkeypatch, program):
+    """Both programs of the latent cell at its own sizes — published
+    widths, 8 sub-layers walked as a scan of four periods of two, 16 held
+    experts of 512 routed + 256 identity, 64 slots, the latent pool of
+    4,224 blocks carried and updated in place: they fit the chip, the
+    decode step holds the latent kernel (the prefill chunk takes its plain
+    reference and holds none) and the grouped expert product, and neither
+    slices an expert matrix out of its stack.  The bytes go to PERF.md §4
+    (``-rP`` prints them)."""
+    import math
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _latent_cfg()
+    assert llama.walk_plan(cfg.kinds) == llama.WalkPlan(0, 2, 4)
+    params = _abstract(v5e, lambda: llama.init_params(cfg, 0, "bfloat16"))
+    pool = _abstract(v5e, lambda: llama.init_paged_cache(cfg, LAT_BLOCKS, 16))
+    assert {k: v.shape for k, v in pool.items()} == {
+        "c": (8, LAT_BLOCKS, 16, 640)}
+
+    def decode_chunk(params, pool, tok, tables, pos):
+        def step(carry, _):
+            tok, pool, p = carry
+            logits, pool, stats = llama.forward_paged(
+                params, tok[:, None], pool, tables, p, cfg, with_stats=True)
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return (nxt, pool, p + 1), (nxt, stats)
+
+        (tok, pool, _), (toks, stats) = jax.lax.scan(
+            step, (tok, pool, pos), None, length=8)
+        return jnp.concatenate([toks.T, stats.T], axis=0), tok, pool
+
+    def prefill_step(params, pool, toks, table, pos0, logit_off):
+        logits, pool = llama.forward_paged(
+            params, toks, pool, table, pos0, cfg, logit_off=logit_off)
+        return logits[:, 0], pool
+
+    if program == "decode_chunk":
+        fn, args = decode_chunk, (
+            v5e((LAT_SLOTS,), jnp.int32),
+            v5e((LAT_SLOTS, LAT_MAX_BLOCKS), jnp.int32),
+            v5e((LAT_SLOTS,), jnp.int32))
+    else:
+        fn, args = prefill_step, (
+            v5e((1, 32), jnp.int32), v5e((1, LAT_MAX_BLOCKS), jnp.int32),
+            v5e((1,), jnp.int32), v5e((), jnp.int32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile()
+    text = compiled.as_text()
+    assert ("%paged_latent_attention" in text) == (program == "decode_chunk")
+    assert "%ragged-dot" in text
+    mem = compiled.memory_analysis()
+    pool_bytes = math.prod(pool["c"].shape) * 2
+    assert mem.alias_size_in_bytes >= pool_bytes     # the pool in place
+    stack = 4 * 16 * 6144 * 2048 * 2   # the kind's experts, one matrix
+    assert mem.temp_size_in_bytes < stack, mem.temp_size_in_bytes
+    bytes_limit = 16_909_336_064
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < bytes_limit
+    for name in ("argument_size_in_bytes", "temp_size_in_bytes",
+                 "alias_size_in_bytes"):
+        print(program, name, getattr(mem, name))
