@@ -359,22 +359,36 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
                block_size: int = 16, seq: int = 128,
                paged_heads=((32, 32), (32, 8), (64, 8)),
                paged_lens=(0, 1, 94, 128, 129, 300, 544, 800),
-               window: int = 128, interpret=None) -> dict:
-    """flash_attention, paged_attention and matmul_int4 at the serving
-    leg's shapes, called as models/llama.py calls them (``interpret`` left
-    at None on the chip; the CPU dry run passes True).  The lowered text
-    must hold ``tpu_custom_call`` — the kernel engaged, the shape gates did
-    not route to the reference — and the outputs match the references.
+               window: int = 128, grouped_dims=(6144, 2048),
+               grouped=((64, 8, 128, 16, 2), (64, 12, 768, 16, 2)),
+               interpret=None) -> dict:
+    """flash_attention, paged_attention, matmul_int4 and grouped_swiglu at
+    the serving leg's shapes, called as models/llama.py and models/moe.py
+    call them (``interpret`` left at None on the chip; the CPU dry run
+    passes True).  The lowered text must hold ``tpu_custom_call`` — the
+    kernel engaged, the shape gates did not route to the reference — and
+    the outputs match the references.
 
     The paged kernel runs at both serving cells' head counts (32/8, 64/8)
     and at MHA, without a window and with ``window`` over a slot's ring,
     on rows that are idle (length 0), end mid-block, end mid-wave (the
     kernel streams 1024 // (block_size * kv_heads) blocks a wave), fill a
-    wave exactly and pass it by one token."""
+    wave exactly and pass it by one token.
+
+    The grouped expert kernel runs at both sparse cells' shapes
+    (``grouped``: slots, experts a token, router outputs, experts held,
+    layers in the stack, two of the cells' 7 and 4 so that the leg fits
+    beside whatever the legs before it left on the device;
+    ``grouped_dims``: D, F) — the layer's sizes written into the
+    stack-wide vector at the second layer — with
+    the sizes the cell's router gives (every row's choices uniform over
+    the router's outputs) and with skewed ones: half the rows on one
+    expert (several visits), a quarter on another, seven on the last."""
     import jax
     import jax.numpy as jnp
 
     from nnstreamer_tpu.ops import attention as A
+    from nnstreamer_tpu.ops import grouped_ffn as GF
     from nnstreamer_tpu.ops import int4_matmul as I4
 
     rng = np.random.default_rng(0)
@@ -448,6 +462,33 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
         run(f"matmul_int4 {din}x{fout}",
             lambda h, p, s: I4.matmul_int4(h, p, s, **kw),
             I4.matmul_int4_reference, (h, packed, scale), 0.03)
+
+    # the stacks are made on the device: 2.4 GB at the cells' widths
+    D, F = grouped_dims
+    for slots_g, top_k, n_router, held, layers in grouped:
+        M, G = slots_g * top_k, layers * held
+        keys = jax.random.split(jax.random.PRNGKey(M), 4)
+        xs = jax.random.normal(keys[0], (M, D), jnp.bfloat16)
+        ws = tuple(
+            jax.random.normal(k, shape, jnp.bfloat16) * shape[1] ** -0.5
+            for k, shape in zip(keys[1:], ((G, D, F), (G, D, F), (G, F, D))))
+        chosen = rng.integers(0, n_router, M)
+        cell = np.bincount(chosen[chosen < held], minlength=held)
+        skew = np.zeros(held, np.int64)
+        skew[[0, 2, held - 1]] = M // 2, M // 4, 7
+        opts = dict(live=held, expect=M / n_router, **kw)
+        for tag, sizes in (("cell", cell), ("skewed", skew)):
+            groups = np.zeros(G, np.int32)
+            groups[(layers - 1) * held:] = sizes
+            own = jnp.arange(M)[:, None] < int(sizes.sum())
+            run(f"grouped_swiglu {M}x{G} {tag}",
+                # rows past the groups are never read
+                lambda x, g, u, d, n, own=own: jnp.where(
+                    own, GF.grouped_swiglu(x, g, u, d, n, **opts)[0], 0),
+                lambda x, g, u, d, n, own=own: jnp.where(
+                    own, GF.grouped_swiglu_reference(x, g, u, d, n), 0),
+                (xs, *ws, jnp.asarray(groups)), 0.03)
+        del xs, ws
     return {"rel_err": errs}
 
 

@@ -1214,7 +1214,7 @@ class _ContinuousLoop:
             toks = jnp.moveaxis(toks, 0, 1)  # [B, length]
             if stats is not None:
                 # the expert layers' counts of each step ride home as
-                # four extra rows of the token matrix: the fetch that
+                # five extra rows of the token matrix: the fetch that
                 # brings the chunk's tokens brings them, no sync of
                 # their own
                 toks = jnp.concatenate([toks, stats.T], axis=0)
@@ -2730,16 +2730,19 @@ class _ContinuousLoop:
                 host = np.asarray(toks_dev)  # ONE roundtrip per chunk
                 moe_args = {}
                 if self._moe:
-                    # rows B..B+3: per step, over the expert layers and
+                    # rows B..B+4: per step, over the expert layers and
                     # the live rows — routed pairs the held experts
                     # computed, held experts hit, most pairs on one,
-                    # identity pairs (no expert computed them)
+                    # identity pairs (no expert computed them) — and,
+                    # over all rows, the times the grouped kernel
+                    # streamed an expert's matrices
                     moe = host[B:]
                     host = host[:B]
                     moe_args = {"moe_pairs": int(moe[0].sum()),
                                 "moe_experts_hit": int(moe[1].sum()),
                                 "moe_max_per_expert": int(moe[2].max()),
-                                "moe_zero_pairs": int(moe[3].sum())}
+                                "moe_zero_pairs": int(moe[3].sum()),
+                                "moe_weight_passes": int(moe[4].sum())}
                 if rec is not None:
                     # the decode span closes HERE, at materialization:
                     # the jit call above only enqueued the async

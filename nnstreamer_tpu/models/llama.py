@@ -1871,7 +1871,7 @@ def forward_paged(params, tokens, pool, block_tables, pos,
     row instead of T.
 
     ``with_stats``: also return the expert layers' routing counts of
-    this step, int32 [4] (models/moe.py; None for a model without
+    this step, int32 [5] (models/moe.py; None for a model without
     experts)."""
     import jax
     import jax.numpy as jnp

@@ -18,10 +18,13 @@ Routing is DROPLESS: there is no capacity factor and no token is ever
 skipped.  Inside the serve loop's fixed shapes the routed (token,
 expert) pairs — ``tokens * top_k`` rows, a static bound — are sorted by
 expert, pairs of experts held elsewhere last, and the three expert
-matrices are applied as a grouped product (``jax.lax.ragged_dot``) whose
-group sizes are VALUES: how the tokens spread over the experts changes
-no shape, so nothing recompiles.  Rows past the held groups belong to
-no expert; what the product leaves there is not read.
+matrices are applied as a grouped product (``ops/grouped_ffn.py``: a
+Pallas kernel whose row tile fits the handful of rows an expert has at
+decode, XLA's ``ragged_dot`` where an expert expects hundreds; the rule
+reads static shapes only) whose group sizes are VALUES: how the tokens
+spread over the experts changes no shape, so nothing recompiles.  Rows
+past the held groups belong to no expert; what the product leaves there
+is not read.
 
 **Identity (zero-compute) experts.**  A router may score ``zero_experts``
 further outputs, numbered after the routed ones: a token that chooses one
@@ -60,7 +63,7 @@ from typing import Optional
 #: leaves :func:`moe_ffn` takes as the kind's whole stack (see above)
 STACKED_LEAVES = ("we_gate", "we_up", "we_down")
 #: entries of :func:`moe_ffn`'s ``stats``
-N_STATS = 4
+N_STATS = 5
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,13 +139,19 @@ def route(h, lp, ex: ExpertsConfig):
 
 def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
     """``h`` [B, T, D] (already normed) -> (this rank's partial FFN
-    output [B, T, D], ``stats``).  ``stats`` is int32 [4] — routed pairs
+    output [B, T, D], ``stats``).  ``stats`` is int32 [5] — routed pairs
     computed by held experts, held experts hit, most pairs on one expert,
-    identity pairs — over the rows ``live`` [B] marks (all when None);
-    it is what the serve loop's ``serve.decode`` span reports."""
+    identity pairs, all four over the rows ``live`` [B] marks (all when
+    None), and the weight passes the grouped kernel made: how many times
+    an expert's three matrices were streamed, over ALL rows (a parked
+    row's pairs are computed like any other), 0 where ``ragged_dot`` ran;
+    passes / experts hit = 1 says every expert hit was streamed once.
+    It is what the serve loop's ``serve.decode`` span reports."""
     import jax
     import jax.nn as jnn
     import jax.numpy as jnp
+
+    from ..ops.grouped_ffn import grouped_swiglu
 
     B, T, D = h.shape
     N, k, E = B * T, ex.top_k, ex.n_held
@@ -167,12 +176,12 @@ def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
             groups = jax.lax.dynamic_update_slice(
                 jnp.zeros((n * E,), jnp.int32), sizes,
                 (jnp.asarray(lp["_layer"], jnp.int32) * E,))
-        xs = x[tok].astype(dt)
-        gate = jax.lax.ragged_dot(xs, we["we_gate"].astype(dt), groups)
-        up = jax.lax.ragged_dot(xs, we["we_up"].astype(dt), groups)
-        y = jax.lax.ragged_dot((jnn.silu(gate) * up).astype(dt),
-                               we["we_down"].astype(dt), groups,
-                               preferred_element_type=jnp.float32)
+        # at most E groups own a row, and one is expected to own as many
+        # as there are rows to a router output: both static
+        y, passes = grouped_swiglu(
+            x[tok].astype(dt), *(we[leaf].astype(dt)
+                                 for leaf in STACKED_LEAVES), groups,
+            live=E, expect=N * k / ex.n_router)
         # rows past the groups belong to no expert: whatever the grouped
         # product left there is not read
         hs = held.reshape(N * k)[order]
@@ -204,16 +213,16 @@ def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
             jnp.where(lheld, idx - ex.held_first, E).reshape(N * k)
         ].add(1)[:E]
     stats = jnp.stack([lcounts.sum(), (lcounts > 0).sum(), lcounts.max(),
-                       zero.sum()]).astype(jnp.int32)
+                       zero.sum(), passes]).astype(jnp.int32)
     return out.astype(dt).reshape(B, T, D), stats
 
 
 def merge_stats(a: Optional[object], b):
-    """Sums pairs, experts hit and identity pairs, keeps the largest
-    per-expert load."""
+    """Sums pairs, experts hit, identity pairs and weight passes, keeps
+    the largest per-expert load."""
     import jax.numpy as jnp
 
     if a is None:
         return b
     return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2]),
-                      a[3] + b[3]])
+                      a[3] + b[3], a[4] + b[4]])

@@ -46,10 +46,16 @@ def test_kernel_leg_toy_interpreted():
                                   dim=256, ffn=512, slots=3, block_size=8,
                                   seq=128, paged_heads=((4, 4), (8, 2)),
                                   paged_lens=(0, 5, 30, 128, 129, 200),
-                                  window=32, interpret=True)
+                                  window=32, grouped_dims=(256, 128),
+                                  grouped=((16, 8, 32, 8, 2),),
+                                  interpret=True)
     names = " ".join(facts["rel_err"])
-    for kernel in ("flash_attention", "paged_attention", "matmul_int4"):
+    for kernel in ("flash_attention", "paged_attention", "matmul_int4",
+                   "grouped_swiglu"):
         assert kernel in names
+    # the grouped kernel ran on the router's sizes and on skewed ones
+    assert {"grouped_swiglu 128x16 cell", "grouped_swiglu 128x16 skewed"} \
+        <= set(facts["rel_err"])
     # the paged kernel ran without a window and with one over a ring
     assert "paged_attention 8/2 window 32 ring 7" in facts["rel_err"]
 

@@ -1,5 +1,5 @@
-"""Chipless pre-check: the three Pallas kernels must COMPILE for a TPU v5e
-at llama2_7b decode/prefill shapes.
+"""Chipless pre-check: the Pallas kernels must COMPILE for a TPU v5e at
+llama2_7b decode/prefill shapes and at the sparse serving cells' own.
 
 The installed libtpu can compile for a v5e topology without one
 (``jax.experimental.topologies`` — a compile-only client, no device is
@@ -250,6 +250,22 @@ def test_paged_attention_compiles_with_a_window(v5e, window, ring):
         v5e((HYB_SLOTS, width), jnp.int32), v5e((HYB_SLOTS,), jnp.int32))
 
 
+def _hybrid_cfg(L):
+    """The patterned configuration's first ``L`` layers at published
+    widths: layer 0 dense, then 16 held experts of 2048 out of 128."""
+    from nnstreamer_tpu.models.moe import ExpertsConfig
+
+    return llama.LlamaConfig(
+        vocab=19200, dim=6144, n_layers=L, n_heads=HYB_H,
+        n_kv_heads=HYB_HKV, ffn_hidden=18432, max_seq=4096, rope_theta=1e6,
+        head_size=128, qk_norm=True,
+        pattern=tuple(llama.LayerKind(
+            window=0 if l % 4 == 3 else 128, rope=l % 4 != 3,
+            ffn="dense" if l == 0 else "experts") for l in range(L)),
+        experts=ExpertsConfig(n_experts=128, top_k=8, hidden=2048, shared=1,
+                              scale=2.5, held_first=0, held_count=16))
+
+
 def test_patterned_decode_step_compiles_at_published_widths(v5e,
                                                             monkeypatch):
     """One period of the benchmark's patterned configuration — hidden 6144,
@@ -262,19 +278,9 @@ def test_patterned_decode_step_compiles_at_published_widths(v5e,
     why ``moe_ffn`` takes the whole stack)."""
     import math
 
-    from nnstreamer_tpu.models.moe import ExpertsConfig
-
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     L = 4
-    cfg = llama.LlamaConfig(
-        vocab=19200, dim=6144, n_layers=L, n_heads=HYB_H,
-        n_kv_heads=HYB_HKV, ffn_hidden=18432, max_seq=4096, rope_theta=1e6,
-        head_size=128, qk_norm=True,
-        pattern=tuple(llama.LayerKind(
-            window=0 if l % 4 == 3 else 128, rope=l % 4 != 3,
-            ffn="dense" if l == 0 else "experts") for l in range(L)),
-        experts=ExpertsConfig(n_experts=128, top_k=8, hidden=2048, shared=1,
-                              scale=2.5, held_first=0, held_count=16))
+    cfg = _hybrid_cfg(L)
     bs, n_blocks = 16, HYB_SLOTS * 50
     params = _abstract(v5e, lambda: llama.init_params(cfg, 0, "bfloat16"))
     pool = _abstract(v5e, lambda: llama.init_paged_cache(
@@ -323,6 +329,35 @@ def _latent_cfg():
                               zero_experts=256, held_first=0, held_count=16))
 
 
+def _sparse_decode_chunk(cfg):
+    """``filters/llm.py decode_chunk`` for a model with experts: 8 steps,
+    the pools carried, the routing counts riding home under the tokens."""
+    def decode_chunk(params, pool, tok, tables, pos):
+        def step(carry, _):
+            tok, pool, p = carry
+            logits, pool, stats = llama.forward_paged(
+                params, tok[:, None], pool, tables, p, cfg, with_stats=True)
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return (nxt, pool, p + 1), (nxt, stats)
+
+        (tok, pool, _), (toks, stats) = jax.lax.scan(
+            step, (tok, pool, pos), None, length=8)
+        return jnp.concatenate([toks.T, stats.T], axis=0), tok, pool
+
+    return decode_chunk
+
+
+def _the_grouped_kernel_is_the_expert_product(text, rows):
+    """The compiled text holds the expert product as the repo's kernel —
+    a custom call named ``ragged-dot…`` whose result is ``[rows, 6144]``,
+    what both roofline readers look for — and none of XLA's own, whose
+    row tile (``ragged_dot_tiling``) is sized to the static row count."""
+    import re
+
+    assert re.search(rf"%ragged-dot-swiglu[.\d]* = f32\[{rows},6144\]", text)
+    assert "ragged_dot_tiling" not in text
+
+
 def test_paged_latent_attention_compiles(v5e):
     """The latent decode kernel alone at the cell's shapes: 64 query heads
     of 576 on one pool row padded to 640, the value its first 512 columns."""
@@ -358,17 +393,7 @@ def test_latent_serve_programs_compile_at_the_cells_shapes(
     assert {k: v.shape for k, v in pool.items()} == {
         "c": (8, LAT_BLOCKS, 16, 640)}
 
-    def decode_chunk(params, pool, tok, tables, pos):
-        def step(carry, _):
-            tok, pool, p = carry
-            logits, pool, stats = llama.forward_paged(
-                params, tok[:, None], pool, tables, p, cfg, with_stats=True)
-            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            return (nxt, pool, p + 1), (nxt, stats)
-
-        (tok, pool, _), (toks, stats) = jax.lax.scan(
-            step, (tok, pool, pos), None, length=8)
-        return jnp.concatenate([toks.T, stats.T], axis=0), tok, pool
+    decode_chunk = _sparse_decode_chunk(cfg)
 
     def prefill_step(params, pool, toks, table, pos0, logit_off):
         logits, pool = llama.forward_paged(
@@ -394,8 +419,72 @@ def test_latent_serve_programs_compile_at_the_cells_shapes(
     assert mem.alias_size_in_bytes >= pool_bytes     # the pool in place
     stack = 4 * 16 * 6144 * 2048 * 2   # the kind's experts, one matrix
     assert mem.temp_size_in_bytes < stack, mem.temp_size_in_bytes
+    # 64 x top-12 rows a decode step, 32 x 12 a prefill chunk; PERF.md
+    # section 4's temporaries (XLA's grouped product, PR 33) do not grow
+    _the_grouped_kernel_is_the_expert_product(
+        text, 768 if program == "decode_chunk" else 384)
+    assert mem.temp_size_in_bytes <= (
+        511_662_592 if program == "decode_chunk" else 6_582_784)
     bytes_limit = 16_909_336_064
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < bytes_limit
     for name in ("argument_size_in_bytes", "temp_size_in_bytes",
                  "alias_size_in_bytes"):
         print(program, name, getattr(mem, name))
+
+
+# -- the grouped expert kernel whose row tile fits decode (PR 34) -----------
+
+@pytest.mark.parametrize("rows,groups,expect", [
+    (512, 112, 4), (768, 64, 1), (256, 112, 2), (384, 64, 0.5)],
+    ids=["k_exaone_decode", "longcat_decode", "k_exaone_prefill",
+         "longcat_prefill"])
+def test_grouped_swiglu_compiles(v5e, rows, groups, expect):
+    """``ops/grouped_ffn.py`` alone at the four shapes the serve programs
+    of the two sparse cells call it with (64 slots x top-8 / top-12 and a
+    prefill chunk of 32; 7 x 16 / 4 x 16 groups of 6144 x 2048): the rule
+    sends all four to the kernel, Mosaic takes it, and the compiled call
+    is named and shaped as the roofline readers expect."""
+    from nnstreamer_tpu.ops import grouped_ffn as GF
+
+    D, F = 6144, 2048
+    lowered = _compile(
+        "ragged-dot-swiglu",
+        lambda x, g, u, d, n: GF.grouped_swiglu(
+            x, g, u, d, n, live=16, expect=expect, interpret=False),
+        v5e((rows, D), jnp.bfloat16), v5e((groups, D, F), jnp.bfloat16),
+        v5e((groups, D, F), jnp.bfloat16), v5e((groups, F, D), jnp.bfloat16),
+        v5e((groups,), jnp.int32))
+    _the_grouped_kernel_is_the_expert_product(
+        lowered.compile().as_text(), rows)
+
+
+def test_hybrid_decode_chunk_compiles_at_the_cells_shapes(v5e, monkeypatch):
+    """``decode_chunk`` of the patterned cell at its own sizes — 8 layers
+    ``LLLG LLLG``, layer 0 dense, 7 x 16 held experts, 64 slots, 3,200
+    blocks and the rings: the expert product is the grouped kernel over
+    ``[512, 6144]`` rows, none of XLA's is left, both pools are updated in
+    place and the temporaries are no larger than PERF.md section 4's
+    (1.53 GB with XLA's product, chipless compile, PR 29)."""
+    import math
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _hybrid_cfg(8)
+    params = _abstract(v5e, lambda: llama.init_params(cfg, 0, "bfloat16"))
+    pool = _abstract(v5e, lambda: llama.init_paged_cache(
+        cfg, 3200, 16, win_blocks=HYB_SLOTS * HYB_RING))
+    tables = {"full": v5e((HYB_SLOTS, 256), jnp.int32),
+              "win": v5e((HYB_SLOTS, HYB_RING), jnp.int32)}
+    compiled = jax.jit(_sparse_decode_chunk(cfg), donate_argnums=(1,)).lower(
+        params, pool, v5e((HYB_SLOTS,), jnp.int32), tables,
+        v5e((HYB_SLOTS,), jnp.int32)).compile()
+    text = compiled.as_text()
+    _the_grouped_kernel_is_the_expert_product(text, 512)
+    assert "%paged_attention" in text
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                     for x in jax.tree_util.tree_leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes <= 1_530_000_000, mem.temp_size_in_bytes
+    for name in ("argument_size_in_bytes", "temp_size_in_bytes",
+                 "alias_size_in_bytes"):
+        print("decode_chunk", name, getattr(mem, name))

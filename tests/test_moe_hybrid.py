@@ -200,8 +200,9 @@ def test_chunked_prefill_then_paged_decode_is_the_reference(n_layers):
         lg, pool, stats = decode(tree, toks[:, T0 + i][:, None], pool,
                                  {"full": tables, "win": win}, pos + i)
         assert np.abs(np.asarray(lg)[:, 0] - ref[:, T0 + i]).max() < TOL
-    pairs, hit, most, identity = (int(v) for v in stats)
+    pairs, hit, most, identity, passes = (int(v) for v in stats)
     assert identity == 0   # this router has no identity experts
+    assert passes == 0     # the CPU takes ragged_dot: no kernel pass
     sparse = n_layers - 1
     assert 0 < hit <= sparse * 4 and most <= B * 4
     assert hit <= pairs <= sparse * B * 4

@@ -182,6 +182,38 @@ def test_decode_wait_is_part_of_the_decode_span(traced):
     assert sum(e.args["retired"] for e in emits) == len(PROMPTS)
 
 
+def test_a_sparse_models_decode_span_carries_the_five_expert_counts():
+    """``serve.decode`` of a model with experts: the four routing counts
+    and ``moe_weight_passes`` — the grouped kernel's passes over an
+    expert's matrices, 0 here, where ``ragged_dot`` computes the experts
+    (the kernel is the TPU's path) — from the fifth extra row of the
+    chunk's token matrix."""
+    recorder.configure("off")
+    recorder.clear()
+    p = nt.Pipeline(
+        "appsrc name=src ! tensor_filter framework=llm "
+        "model=hybrid_moe_tiny custom=max_new:5,serve:continuous,slots:2,"
+        "temperature:0.0,block_size:8,prefill_chunk:8,stream_chunk:2 "
+        "invoke-dynamic=true ! tensor_sink name=out", trace_mode="ring")
+    with p:
+        p.push("src", PROMPTS[0])
+        for _ in range(5):
+            p.pull("out", timeout=120)
+        time.sleep(0.2)
+        dec = [e.args for e in recorder.events()
+               if e.stage == STAGE and e.kind == "serve.decode"]
+        p.eos("src")
+        p.wait(timeout=120)
+    recorder.configure("off")
+    recorder.clear()
+    assert dec
+    for a in dec:
+        assert {k for k in a if k.startswith("moe_")} == {
+            "moe_pairs", "moe_experts_hit", "moe_max_per_expert",
+            "moe_zero_pairs", "moe_weight_passes"}
+        assert a["moe_weight_passes"] == 0 < a["moe_experts_hit"]
+
+
 def test_deliveries_under_the_next_chunk_are_marked_and_counted():
     """``serve.emit`` says on which side of the next dispatch it ran
     (``ahead``), and ``llm.serve.deliver_ahead`` counts the same chunks."""
