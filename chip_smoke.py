@@ -360,8 +360,9 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
                paged_heads=((32, 32), (32, 8), (64, 8)),
                paged_lens=(0, 1, 94, 128, 129, 300, 544, 800),
                window: int = 128, grouped_dims=(6144, 2048),
-               grouped=((64, 8, 128, 16, 2), (64, 12, 768, 16, 2)),
-               interpret=None) -> dict:
+               grouped=((64, 8, 128, 16, 2), (64, 12, 768, 16, 2),
+                        (64, 4, 64, 64, 2, (2048, 1536))),
+               paged_narrow=((32, 8, 64),), interpret=None) -> dict:
     """flash_attention, paged_attention, matmul_int4 and grouped_swiglu at
     the serving leg's shapes, called as models/llama.py and models/moe.py
     call them (``interpret`` left at None on the chip; the CPU dry run
@@ -373,13 +374,17 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
     and at MHA, without a window and with ``window`` over a slot's ring,
     on rows that are idle (length 0), end mid-block, end mid-wave (the
     kernel streams 1024 // (block_size * kv_heads) blocks a wave), fill a
-    wave exactly and pass it by one token.
+    wave exactly and pass it by one token.  ``paged_narrow`` (heads, KV
+    heads, head width) adds the form for heads narrower than the 128
+    lanes, two KV heads to a lane row (ops/attention.py): 32/8 heads of
+    64, the convolution cell's.
 
-    The grouped expert kernel runs at both sparse cells' shapes
+    The grouped expert kernel runs at the sparse cells' shapes
     (``grouped``: slots, experts a token, router outputs, experts held,
-    layers in the stack, two of the cells' 7 and 4 so that the leg fits
-    beside whatever the legs before it left on the device;
-    ``grouped_dims``: D, F) — the layer's sizes written into the
+    layers in the stack, two of the cells' 7, 4 and 8 so that the leg fits
+    beside whatever the legs before it left on the device, and (D, F)
+    where they are not ``grouped_dims``: the third cell holds all 64
+    experts of 2048 x 1536) — the layer's sizes written into the
     stack-wide vector at the second layer — with
     the sizes the cell's router gives (every row's choices uniform over
     the router's outputs) and with skewed ones: half the rows on one
@@ -429,13 +434,17 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
     lens = np.asarray(paged_lens, np.int32)
     rows = len(lens)
     live = jnp.asarray((lens > 0).reshape(rows, 1, 1, 1))
-    for heads, hkv in paged_heads:
+    for heads, hkv, hd in [(*hh, head_dim) for hh in paged_heads] \
+            + list(paged_narrow):
         for ring in (False, True):
             width = (window // block_size + 3 if ring
                      else -(-int(lens.max()) // block_size) + 1)
             n_blocks = rows * width
-            qd = arr((rows, 1, heads, head_dim))
-            kp, vp = (arr((n_blocks, block_size, hkv, head_dim))
+            # narrow heads are stored several to a lane row, as
+            # models/llama.py init_paged_cache stores them
+            pack = A.kv_lane_pack(hkv, hd)
+            qd = arr((rows, 1, heads, hd))
+            kp, vp = (arr((n_blocks, block_size, hkv // pack, hd * pack))
                       for _ in range(2))
             tbl = rng.permutation(n_blocks).astype(np.int32).reshape(
                 rows, width)
@@ -444,6 +453,7 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
                 tbl[np.arange(width)[None, :] >= used[:, None]] = n_blocks
             opts = {"window": window, "ring": True} if ring else {}
             run(f"paged_attention {heads}/{hkv}"
+                + (f" x {hd}" if hd != head_dim else "")
                 + (f" window {window} ring {width}" if ring else ""),
                 # idle rows emit garbage the serve loop never reads
                 lambda q, kp, vp, t, n, opts=opts: jnp.where(
@@ -464,8 +474,8 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
             I4.matmul_int4_reference, (h, packed, scale), 0.03)
 
     # the stacks are made on the device: 2.4 GB at the cells' widths
-    D, F = grouped_dims
-    for slots_g, top_k, n_router, held, layers in grouped:
+    for slots_g, top_k, n_router, held, layers, *dims in grouped:
+        D, F = dims[0] if dims else grouped_dims
         M, G = slots_g * top_k, layers * held
         keys = jax.random.split(jax.random.PRNGKey(M), 4)
         xs = jax.random.normal(keys[0], (M, D), jnp.bfloat16)
@@ -481,7 +491,8 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
             groups = np.zeros(G, np.int32)
             groups[(layers - 1) * held:] = sizes
             own = jnp.arange(M)[:, None] < int(sizes.sum())
-            run(f"grouped_swiglu {M}x{G} {tag}",
+            run(f"grouped_swiglu {M}x{G}"
+                + (f" {D}x{F}" if dims else "") + f" {tag}",
                 # rows past the groups are never read
                 lambda x, g, u, d, n, own=own: jnp.where(
                     own, GF.grouped_swiglu(x, g, u, d, n, **opts)[0], 0),

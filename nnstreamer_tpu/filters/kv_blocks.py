@@ -6,8 +6,9 @@ WHICH pool blocks a stream holds, and what the block tables say, is
 decided here, by one ``BlockManager`` the scheduler calls.  The split:
 
 * **Host state lives here** — the free list, per-block reference counts,
-  per-slot block lists, the block table and the window layers' ring
-  table, the prefix chain index — and nothing else writes it.
+  per-slot block lists, the block table, the window layers' ring
+  table and the slot ids that name convolution layers' state, the prefix
+  chain index — and nothing else writes it.
 * **Device state stays with the loop** — the pools, the token / key /
   position vectors and every compiled program.  A copy-on-write fork is
   CHOSEN here (``admit`` returns ``(src, dst)``) and COPIED there.
@@ -49,18 +50,27 @@ class BlockManager:
     def __init__(self, *, slots: int, block_size: int, prefill_chunk: int,
                  n_blocks: int, max_blocks: int, win_ring: int,
                  win_blocks: int, prefix_cache: bool,
-                 count: Callable[..., None]):
+                 count: Callable[..., None], conv_state_bytes: int = 0):
         self.bs, self.chunk = block_size, prefill_chunk
         self.n_blocks = n_blocks
         self.win_ring, self.win_blocks = win_ring, win_blocks
+        #: bytes of the convolution layers' state, ``slots`` streams'
+        #: worth for good (0 = the model has no such layer): slot s's
+        #: columns are row s of the pool's ``conv`` leaf, which no
+        #: allocator touches and no table but ``slot_ids`` names
+        self.conv_state_bytes = conv_state_bytes
+        self.slot_ids = np.arange(slots, dtype=np.int32)
         self.sentinel = n_blocks  # unallocated table entry
         self.park = max_blocks * block_size  # idle-slot position
         self._count = count
         #: a window layer's K/V live in the ring of the slot that wrote
-        #: them and nowhere else, so on a model with window layers no
-        #: other stream can resume from a cached prefix: every lookup
-        #: is a miss and nothing is indexed (docs/SERVING.md §4e)
-        self.share_prefix = bool(prefix_cache) and not win_ring
+        #: them and nowhere else, and a convolution layer's state at the
+        #: end of a shared prefix is in no block at all (a hit would
+        #: continue from zeros), so on a model with either no other
+        #: stream can resume from a cached prefix: every lookup is a
+        #: miss and nothing is indexed (docs/SERVING.md §4e)
+        self.share_prefix = bool(prefix_cache) and not win_ring \
+            and not conv_state_bytes
         self.tables = np.full((slots, max_blocks), self.sentinel, np.int32)
         #: window layers: slot s owns blocks [s * ring, (s + 1) * ring)
         #: of the window pool for good — logical block j of its stream
@@ -96,11 +106,16 @@ class BlockManager:
         """The table argument of a program for the slots ``rows``: a
         copy of the block table (dispatch is asynchronous and the tables
         are mutated in place between dispatches), with the ring table
-        beside it where the model has window layers."""
-        if not self.win_ring:
+        beside it where the model has window layers and the slots' own
+        ids where convolution layers keep state by slot."""
+        if not self.win_ring and not self.conv_state_bytes:
             return self.tables[rows].copy()
-        return {"full": self.tables[rows].copy(),
-                "win": self.win_tables[rows]}
+        out = {"full": self.tables[rows].copy()}
+        if self.win_ring:
+            out["win"] = self.win_tables[rows]
+        if self.conv_state_bytes:
+            out["slot"] = self.slot_ids[rows]
+        return out
 
     # -- allocation ----------------------------------------------------------
     def blocks_for(self, n_tokens: int) -> int:
@@ -313,6 +328,8 @@ class BlockManager:
             # window layers' pool: `win_ring` blocks a slot, for good
             "win_blocks_total": self.win_blocks,
             "win_ring": self.win_ring,
+            # convolution layers' state: every slot's, for good
+            "conv_state_bytes": self.conv_state_bytes,
             # prefix-sharing accounting: blocks whose content + chain
             # hash are indexed (many resting in the free list at
             # refcount 0), and blocks currently mapped by >1 stream

@@ -269,6 +269,11 @@ def serving_plan(cfg, *, slots: int, block_size: int = 16,
       ``pool_bytes`` counts them at the pool's padded width (what HBM
       holds), ``decode_bytes_per_ctx_token`` at ``latent_width`` (what
       the mathematics reads: the padding is the kernel's cost).
+    * ``conv_state_bytes`` — convolution layers (``LayerKind.conv``) keep
+      ``conv_taps - 1`` columns a stream a layer whatever its context,
+      owned by the slot like a ring: ``slots`` streams' worth, counted in
+      ``pool_bytes``; 0 without such layers.  A decode step reads and
+      writes all of it, which no context length changes.
     * ``programs`` — compiled XLA signatures the standing loop ever
       uses.  Without speculation: the ``[slots]``-row paged decode
       chunk, the ``[1, prefill_chunk]`` prefill step, and the slot-token
@@ -313,9 +318,10 @@ def serving_plan(cfg, *, slots: int, block_size: int = 16,
         "n_blocks": n_blocks,
         "pool_bytes": _llama.paged_cache_bytes(
             cfg, n_blocks, bs, dtype=dtype,
-            win_blocks=int(slots) * win_ring),
+            win_blocks=int(slots) * win_ring, slots=int(slots)),
         "win_ring": win_ring,
         "win_blocks": int(slots) * win_ring,
+        "conv_state_bytes": _llama.conv_state_bytes(cfg, slots, dtype),
         "draft_pool_bytes": (
             _llama.paged_cache_bytes(draft_cfg, n_blocks, bs, dtype=dtype)
             if draft_cfg is not None else 0),
@@ -511,7 +517,11 @@ class LLMFramework(Framework):
                     "window layer's ring, and a rejected tail would have "
                     "overwritten rows the window still needs; over a "
                     "latent pool its k+1 queries take the gather "
-                    "reference, not the kernel; this target has "
+                    "reference, not the kernel; a convolution layer's "
+                    "state is advanced by every token the step is fed, "
+                    "so a rejected tail would already have moved it "
+                    "past the accepted prefix, and nothing keeps the "
+                    "columns to step back to; this target has "
                     f"{llama.pattern_traits(self.cfg)} "
                     "(self-drafting from a prediction head: ROADMAP M1)")
         if self.draft_name:
@@ -784,6 +794,13 @@ class LLMFramework(Framework):
                 "window layer's ring (its last window of K/V, per slot) "
                 "has no place in it; continuing from such a snapshot "
                 "would attend an empty window")
+        if self.cfg is not None and self.cfg.n_conv_layers:
+            raise FrameworkError(
+                f"{what} of a slot that owns convolution state is not "
+                "built: a snapshot carries the allocator's blocks only, "
+                "and the convolution layers' last columns (per slot, in "
+                "no block) have no place in it; continuing from such a "
+                "snapshot would filter from zeros")
 
     def snapshot_problems(self, snapshot: Dict) -> List[str]:
         """Compatibility problems adopting ``snapshot`` here (empty =
@@ -1106,7 +1123,8 @@ class _ContinuousLoop:
             slots=fw.slots, block_size=bs, prefill_chunk=fw.prefill_chunk,
             n_blocks=self.n_blocks, max_blocks=self.max_blocks,
             win_ring=self.win_ring, win_blocks=self.win_blocks,
-            prefix_cache=fw.prefix_cache, count=metrics.count)
+            prefix_cache=fw.prefix_cache, count=metrics.count,
+            conv_state_bytes=plan["conv_state_bytes"])
         self.sentinel = self.kv.sentinel  # unallocated table entry
         self.park = self.kv.park  # idle-slot position
         #: per-slot host bookkeeping the serve thread mutates in place
@@ -1226,11 +1244,16 @@ class _ContinuousLoop:
         def prefill_step(params, toks, pool, table, pos0, logit_off):
             """One [1, prefill_chunk] prefill chunk written directly into
             the slot's blocks; returns the ``logit_off`` position's
-            logits ([1, vocab] — the last REAL token on the final chunk)
-            so the first-token sample needs no separate program."""
+            logits ([1, vocab]) so the first-token sample needs no
+            separate program.  ``logit_off`` is the offset of the chunk's
+            last REAL token: on the final chunk the prompt's last, before
+            it the chunk's last column."""
             logits, pool = llama.forward_paged(
                 params, toks, pool, table, pos0, cfg,
-                compute_dtype=fw.dtype, logit_off=logit_off)
+                compute_dtype=fw.dtype, logit_off=logit_off,
+                # the chunk's real tokens end at ``logit_off``: where
+                # state that no position addresses is taken
+                n_valid=logit_off + 1 if cfg.n_conv_layers else None)
             return logits[:, 0], pool
 
         self._prefill = jax.jit(prefill_step, donate_argnums=(2,))
@@ -1741,7 +1764,8 @@ class _ContinuousLoop:
         params = fw.bundle.params
         pool = llama.init_paged_cache(cfg, self.n_blocks, bs,
                                       dtype=fw.dtype,
-                                      win_blocks=self.win_blocks)
+                                      win_blocks=self.win_blocks,
+                                      slots=B)
         d_params = draft_pool = None
         if self._spec:
             d_params = fw.draft_bundle.params
@@ -2539,10 +2563,10 @@ class _ContinuousLoop:
                         sp = begin("serve.prefill_chunk",
                                    st.get("trace", _NO_TRACE)[0], iter=it,
                                    slot=s, pos=p, final=bool(final))
-                    # last REAL token's offset within this chunk (only
-                    # meaningful on the final chunk; intermediate chunks
-                    # are all real tokens and their logits are unused)
-                    off = np.int32(st["T"] - 1 - p if final else 0)
+                    # last REAL token's offset within this chunk
+                    # (intermediate chunks are all real tokens; their
+                    # logits are unused, their slot-owned state is not)
+                    off = np.int32(st["T"] - 1 - p if final else C - 1)
                     logits, pool = self._prefill(
                         params, jnp.asarray(st["prompt"][:, p:p + C]),
                         pool, kv.tabs(slice(s, s + 1)),
@@ -2848,7 +2872,8 @@ class _ContinuousLoop:
                             waiting=len(self._waiting)
                             + len(self._admitting),
                             full_blocks=self.n_blocks - len(kv.free),
-                            win_blocks=kv.win_blocks_live(pos))
+                            win_blocks=kv.win_blocks_live(pos),
+                            conv_state_bytes=kv.conv_state_bytes)
                 if progressed:
                     n_iter = it
                     sp_iter.commit()

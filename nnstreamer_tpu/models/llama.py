@@ -59,13 +59,20 @@ class LayerKind:
     result to its output.  A published layer of two attention blocks
     and two dense FFNs with its experts across them is two such
     sub-layers, and the walk carries the open branch's tensor from one
-    to the other."""
+    to the other.
+
+    ``conv``: the layer's mixer is no attention at all but a gated short
+    convolution (:func:`_conv_mixer`): its state is the last ``conv_taps
+    - 1`` columns of its own input product, a fixed size a stream
+    whatever its context, and lives in no block — cache class
+    ``"conv"``."""
 
     window: int = 0
     rope: bool = True
     ffn: str = "dense"
     latent: bool = False
     shortcut: str = ""
+    conv: bool = False
 
     def __post_init__(self):
         if self.ffn not in ("dense", "experts"):
@@ -80,10 +87,18 @@ class LayerKind:
         if self.shortcut and self.ffn != "dense":
             raise ValueError("a shortcut's ends are dense-FFN layers: the "
                              "expert branch is the shortcut itself")
+        if self.conv and (self.window or self.latent or self.shortcut
+                          or not self.rope):
+            raise ValueError("a convolution layer has no attention to "
+                             "give a window, a latent cache or a rotation "
+                             "(leave them at their defaults), and no "
+                             "shortcut end is built on one")
 
     @property
     def name(self) -> str:
         """The key of this kind's stack under ``params["layers"]``."""
+        if self.conv:
+            return f"conv.{self.ffn}"
         attn = "latent" if self.latent else \
             f"window{self.window}" if self.window else "full"
         return ".".join([attn, "rope" if self.rope else "nope", self.ffn]
@@ -91,9 +106,10 @@ class LayerKind:
 
     @property
     def cache(self) -> str:
-        """The class of paged cache this layer's state lives in: one pool
-        (and one numbering of layers) a class."""
-        return "latent" if self.latent else "win" if self.window else "full"
+        """The class of state this layer keeps for a stream: one pool leaf
+        set (and one numbering of layers) a class."""
+        return "conv" if self.conv else "latent" if self.latent \
+            else "win" if self.window else "full"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -161,6 +177,10 @@ class LlamaConfig:
     v_head_dim: int = 0
     q_lora_scale: float = 1.0
     kv_lora_scale: float = 1.0
+    #: taps of a convolution layer's depthwise causal filter
+    #: (``LayerKind.conv``); a stream's state is the ``conv_taps - 1``
+    #: columns before its next token
+    conv_taps: int = 3
 
     def __post_init__(self):
         pat = tuple(self.pattern)
@@ -181,6 +201,14 @@ class LlamaConfig:
             raise ValueError("a latent-attention layer needs q_lora_rank, "
                              "kv_lora_rank, qk_nope_dim, qk_rope_dim "
                              "(even) and v_head_dim")
+        if any(k.conv for k in pat):
+            if self.conv_taps < 2:
+                raise ValueError(f"conv_taps {self.conv_taps}: a "
+                                 "convolution layer needs two or more")
+            if all(k.conv for k in pat):
+                raise ValueError("a model of convolution layers only has "
+                                 "no paged cache: the paged path reads "
+                                 "its block size off an attention pool")
         is_open = False
         for l, k in enumerate(pat):
             if not k.shortcut:
@@ -198,6 +226,23 @@ class LlamaConfig:
         return self.head_size or self.dim // self.n_heads
 
     @property
+    def kv_lane_pack(self) -> int:
+        """KV heads a row of the K/V pools holds side by side
+        (ops/attention.py ``kv_lane_pack``: 2 for heads of 64) in a
+        patterned model; 1 in the one-kind decoder, whose pool shards by
+        KV head under tensor parallelism (:func:`paged_cache_pspecs`: a
+        packed row would hold heads of two chips).  ``patterned`` is the
+        predicate by which :func:`tp_divisibility_problems` refuses
+        tensor parallelism, so a pool is packed exactly where it is never
+        sharded.  This is the ONE place the layout is decided: the pool
+        is born in it (:func:`init_paged_cache`) and
+        ``ops/attention.py paged_attention`` reads what it is handed."""
+        from ..ops.attention import kv_lane_pack
+
+        return kv_lane_pack(self.n_kv_heads, self.head_dim) \
+            if self.patterned else 1
+
+    @property
     def kinds(self) -> Tuple[LayerKind, ...]:
         return self.pattern or (LayerKind(),) * self.n_layers
 
@@ -210,8 +255,13 @@ class LlamaConfig:
         return sum(1 for k in self.pattern if k.latent)
 
     @property
+    def n_conv_layers(self) -> int:
+        return sum(1 for k in self.pattern if k.conv)
+
+    @property
     def n_full_layers(self) -> int:
-        return self.n_layers - self.n_window_layers - self.n_latent_layers
+        return (self.n_layers - self.n_window_layers - self.n_latent_layers
+                - self.n_conv_layers)
 
     @property
     def has_shortcut(self) -> bool:
@@ -256,6 +306,8 @@ def pattern_traits(cfg: LlamaConfig) -> str:
         ("latent attention (one cache row for all heads)",
          any(k.latent for k in kinds)),
         ("a shortcut expert branch across sub-layers", cfg.has_shortcut),
+        ("convolution layers (state owned by the slot, in no block)",
+         any(k.conv for k in kinds)),
         ("q/k norm", cfg.qk_norm)) if has]
     return "a layer pattern: " + ", ".join(traits or ["mixed layers"])
 
@@ -315,6 +367,21 @@ PRESETS: Dict[str, LlamaConfig] = {
                               scoring="softmax", norm_topk=False,
                               scale=6.0, zero_experts=8, held_first=4,
                               held_count=4),
+    ),
+    # gated short-convolution layers beside attention at toy size: ten
+    # layers in the order conv conv | attn conv conv conv | attn conv
+    # conv conv, the first two dense and the rest sparse (16 experts, 4 a
+    # token, every one held, the chosen weights renormalised with an
+    # epsilon), q/k norm, heads of width 16
+    "conv_moe_tiny": LlamaConfig(
+        vocab=512, dim=64, n_layers=10, n_heads=4, n_kv_heads=2,
+        ffn_hidden=192, max_seq=256, rope_theta=1e6, qk_norm=True,
+        pattern=tuple(
+            LayerKind(conv=l < 2 or l % 4 != 2,
+                      ffn="dense" if l < 2 else "experts")
+            for l in range(10)),
+        experts=ExpertsConfig(n_experts=16, top_k=4, hidden=32,
+                              norm_eps=1e-6),
     ),
 }
 
@@ -376,7 +443,13 @@ def stack_shapes(cfg: LlamaConfig, kind: LayerKind) -> Dict[str, tuple]:
     ``router_bias`` are float32 whatever the weights' type."""
     D, H, Hkv, hd = cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     out = {"ln_attn": (D,), "ln_mlp": (D,)}
-    if kind.latent:
+    if kind.conv:
+        # a convolution layer: ``w_in`` makes the two gates and the value
+        # (thirds b | c | v of its output), ``w_conv`` holds the filter,
+        # a tap a row (the published [D, taps] transposed: D on the
+        # lanes), ``w_out`` writes into the stream
+        out.update(w_in=(D, 3 * D), w_conv=(cfg.conv_taps, D), w_out=(D, D))
+    elif kind.latent:
         # a latent layer: the query and the cache each go through a
         # normed compression; ``wkv_a`` makes the latent and the one
         # rotated key, ``wkv_b`` expands the latent to every head's
@@ -1280,9 +1353,80 @@ def _latent_attention(cfg: LlamaConfig, lp, h, positions, pool=None,
     return attn.reshape(B, T, H * dv), c_flat.reshape(pool_shape)
 
 
+def _conv_mixer(cfg: LlamaConfig, lp, h, state=None, slots=None,
+                pos_offset=None, layer=None, live=None, n_valid=None):
+    """The gated short convolution of one layer on the normed input ``h``
+    [B, T, D] -> (what it adds to the stream [B, T, D], the state).
+
+    ``[b | c | v] = h w_in``; ``u = b * v``; ``z_t = sum_j w_conv[j] *
+    u_{t - (taps - 1) + j}`` (depthwise, causal, ``u`` before the
+    stream's first token zero); ``out = (c * z) w_out``.  What a stream
+    carries from one call to the next is its last ``taps - 1`` columns
+    of ``u``: a fixed size whatever its context.
+
+    One computation for the three forms: the ``T`` new columns are
+    appended to the ``taps - 1`` carried ones and the filter runs over
+    that.  Without ``state`` (the cacheless forward) the carried columns
+    are zeros.  With it — the pool's leaf ``[conv layers, slots, taps -
+    1, D]``, viewed flat like the block pools so that no layer is ever
+    sliced out of it — row ``i`` reads the columns of slot ``slots[i]``,
+    zeros where ``pos_offset[i] == 0`` (a stream's first chunk: whatever
+    the slot's last stream left is never read), and writes back the
+    ``taps - 1`` columns that end at its ``n_valid[i]``-th new one: a
+    prefill chunk is padded to a fixed ``T``, and the state after it is
+    the state after its last REAL token, a value.  ``n_valid`` None =
+    all ``T`` (a decode step).  A row ``live`` does not mark writes
+    nothing: a parked slot decodes garbage, and the slot may be in the
+    middle of its next stream's prefill."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, D = h.shape
+    dt = h.dtype
+    past_n = cfg.conv_taps - 1
+    bcv = _mm(h, lp, "w_in", dt)
+    u = bcv[..., :D] * bcv[..., 2 * D:]
+    if state is None:
+        past = jnp.zeros((B, past_n, D), dt)
+    else:
+        if slots is None or slots.shape != (B,):
+            raise ValueError(
+                "a convolution layer's state is kept by slot: the tables "
+                "need \"slot\", one id a row ([B] int32), got "
+                f"{None if slots is None else slots.shape}")
+        n_slots = state.shape[1]
+        flat = state.reshape((-1,) + state.shape[2:])
+        # an id past the leaf names no stream's state: it reads NaN (so
+        # every logit of the row shows it) and, below, writes nothing —
+        # it is never folded onto another slot's columns
+        rows = jnp.where((slots >= 0) & (slots < n_slots),
+                         layer * n_slots + slots, flat.shape[0])
+        past = jnp.where(
+            (pos_offset == 0)[:, None, None], 0,
+            flat.at[rows].get(mode="fill", fill_value=jnp.nan)).astype(dt)
+    ext = jnp.concatenate([past, u], axis=1)        # [B, past_n + T, D]
+    w = lp["w_conv"].astype(jnp.float32)
+    z = sum(w[j] * ext[:, j:j + T].astype(jnp.float32)
+            for j in range(cfg.conv_taps))
+    out = _mm(bcv[..., D:2 * D] * z.astype(dt), lp, "w_out", dt)
+    if state is None:
+        return out, None
+    if n_valid is None:
+        tail = ext[:, T:]
+    else:
+        tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
+            e, n, past_n, axis=0))(ext, jnp.broadcast_to(n_valid, (B,)))
+    with jax.named_scope("state_write"):
+        # a row that is not live resolves past the leaf and DROPS
+        rows = jnp.where(live, rows, flat.shape[0])
+        flat = flat.at[rows].set(tail.astype(flat.dtype), mode="drop")
+    return out, flat.reshape(state.shape)
+
+
 def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
            attn_fn=None, paged_tables=None, layer=None, kind=None,
-           park=None, live=None, stats_out=None, branch_io=None):
+           park=None, live=None, stats_out=None, branch_io=None,
+           slots=None, n_valid=None):
     """One transformer block.  ``kv=(k_cache, v_cache)`` enables cached
     decode (x is the new suffix, written at ``pos_offset``); ``attn_fn``
     overrides plain causal attention (ring attention under shard_map);
@@ -1306,7 +1450,10 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
     pool, as a 1-tuple (:func:`_latent_attention`).  ``branch_io`` is a
     one-entry list holding the open shortcut branch's tensor: a kind
     that opens writes it, a kind that closes reads it (the walk carries
-    it between)."""
+    it between).  A convolution layer's ``kv`` is its class's state
+    leaf, as a 1-tuple, ``slots`` [B] the slot each row is and
+    ``n_valid`` how many of the ``T`` columns are real tokens
+    (:func:`_conv_mixer`)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -1319,6 +1466,13 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         if kind is not None else None
 
     h = _rmsnorm(x, lp["ln_attn"], cfg.norm_eps)
+    if kind is not None and kind.conv:
+        with jax.named_scope("mixer.conv"):
+            out, state = _conv_mixer(
+                cfg, lp, h, kv[0] if kv is not None else None, slots,
+                pos_offset, layer, live, n_valid)
+        return _feed_forward(cfg, lp, x + out, kind, live, stats_out,
+                             branch_io), (state,)
     if kind is not None and kind.latent:
         attn, c = _latent_attention(
             cfg, lp, h, positions, kv[0] if kv is not None else None,
@@ -1362,6 +1516,11 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         pool_shape = k_pool.shape
         flat = (pool_shape[0] * pool_shape[1],) + pool_shape[2:]
         ring = park is not None
+        if pool_shape[3:] != (Hkv, hd):
+            # narrow heads, stored several to a lane row: the new rows
+            # are the same numbers in the same order
+            k = k.reshape((B, T) + pool_shape[3:])
+            v = v.reshape((B, T) + pool_shape[3:])
         with jax.named_scope("kv_write"):
             blk, off = _paged_rows(pool_shape, paged_tables, pos_offset, T,
                                    layer, park)
@@ -1622,7 +1781,7 @@ def cache_pspecs() -> Dict:
 # -- block-paged KV cache (continuous serving) ------------------------------
 
 def init_paged_cache(cfg: LlamaConfig, n_blocks: int, block_size: int,
-                     dtype="bfloat16", win_blocks: int = 0):
+                     dtype="bfloat16", win_blocks: int = 0, slots: int = 0):
     """Block-pool KV cache: k/v of [L, n_blocks, block_size, H_kv, head_dim].
 
     The pool replaces the dense per-slot [L, B, S_max, ...] cache for
@@ -1648,13 +1807,21 @@ def init_paged_cache(cfg: LlamaConfig, n_blocks: int, block_size: int,
     ALLOCATOR's blocks like ``k``/``v`` (block ``j`` holds the same
     positions in every leaf but the rings) and merged flat the same way;
     ``lanes`` is ``latent_width`` padded as the kernel wants it
-    (ops/attention.py ``latent_pool_width``).  A class the model has no
-    layer of has no leaf."""
+    (ops/attention.py ``latent_pool_width``).  Heads narrower than the
+    128 lanes are stored ``kv_lane_pack`` to a row — ``[..., block_size,
+    H_kv / pack, head_dim * pack]``, the same numbers in the same order,
+    the layout the paged kernel reads (``LlamaConfig.kv_lane_pack``).
+    Convolution layers keep ``conv``: ``[conv layers, slots, conv_taps -
+    1, dim]``, the columns each of the ``slots`` streams carries
+    (:func:`_conv_mixer`) — owned by the slot like a ring, in no block
+    and in no table; such a model refuses ``slots`` < 1.  A class the
+    model has no layer of has no leaf."""
     import jax.numpy as jnp
 
     from ..ops.attention import latent_pool_width
 
-    tail = (block_size, cfg.n_kv_heads, cfg.head_dim)
+    pack = cfg.kv_lane_pack
+    tail = (block_size, cfg.n_kv_heads // pack, cfg.head_dim * pack)
     pool = {}
     if cfg.n_full_layers:
         shape = (cfg.n_full_layers, n_blocks) + tail
@@ -1667,19 +1834,32 @@ def init_paged_cache(cfg: LlamaConfig, n_blocks: int, block_size: int,
         pool["c"] = jnp.zeros(
             (cfg.n_latent_layers, n_blocks, block_size,
              latent_pool_width(cfg.latent_width)), dtype)
+    if cfg.n_conv_layers:
+        if int(slots) < 1:
+            raise ValueError(
+                "a model with convolution layers keeps their state by "
+                "slot: init_paged_cache needs slots >= 1 (the streams the "
+                f"pool serves), got {slots}")
+        pool["conv"] = jnp.zeros(
+            (cfg.n_conv_layers, int(slots), cfg.conv_taps - 1, cfg.dim),
+            dtype)
     return pool
 
 
 #: cache class (``LayerKind.cache``) -> its leaves of the pool
 POOL_LEAVES = {"full": ("k", "v"), "win": ("k_win", "v_win"),
-               "latent": ("c",)}
+               "latent": ("c",), "conv": ("conv",)}
+#: the leaves a SLOT owns for good (a window class's rings, the
+#: convolution layers' state): no allocator hands them out, no table but
+#: the slot's own names them, and no other stream can resume from them
+SLOT_LEAVES = POOL_LEAVES["win"] + POOL_LEAVES["conv"]
 
 
 def allocated_leaves(pool) -> List[str]:
-    """The pool leaves whose blocks the allocator hands out (all but a
-    window class's rings), in a fixed order: what a CoW fork, a drain and
+    """The pool leaves whose blocks the allocator hands out (all but
+    ``SLOT_LEAVES``), in a fixed order: what a CoW fork, a drain and
     an adopt copy block by block."""
-    return sorted(leaf for leaf in pool if not leaf.endswith("_win"))
+    return sorted(leaf for leaf in pool if leaf not in SLOT_LEAVES)
 
 
 def paged_cache_pspecs() -> Dict:
@@ -1738,11 +1918,23 @@ def tp_divisibility_problems(cfg: LlamaConfig, tp: int) -> List[str]:
     return probs
 
 
+def conv_state_bytes(cfg: LlamaConfig, slots: int,
+                     dtype="bfloat16") -> int:
+    """Bytes of the convolution layers' state for ``slots`` streams (the
+    ``conv`` leaf of :func:`init_paged_cache`); 0 without such layers,
+    and for no stream."""
+    itemsize = 2 if str(dtype) in ("bfloat16", "float16") else 4
+    return (cfg.n_conv_layers * int(slots) * (cfg.conv_taps - 1)
+            * cfg.dim * itemsize)
+
+
 def paged_cache_bytes(cfg: LlamaConfig, n_blocks: int, block_size: int,
-                      dtype="bfloat16", win_blocks: int = 0) -> int:
+                      dtype="bfloat16", win_blocks: int = 0,
+                      slots: int = 0) -> int:
     """Static HBM footprint of :func:`init_paged_cache` (k + v of the
     full and window classes, the latent class's rows at their padded
-    width), without building anything — the deep-lint resource
+    width, the convolution layers' state of ``slots`` streams), without
+    building anything — the deep-lint resource
     report prices the pool through this, so the arithmetic lives next to
     the allocation."""
     from ..ops.attention import latent_pool_width
@@ -1754,7 +1946,8 @@ def paged_cache_bytes(cfg: LlamaConfig, n_blocks: int, block_size: int,
     latent = cfg.n_latent_layers * n_blocks * (
         latent_pool_width(cfg.latent_width) if cfg.n_latent_layers else 0)
     return (2 * blocks * cfg.n_kv_heads * cfg.head_dim
-            + latent) * block_size * itemsize
+            + latent) * block_size * itemsize \
+        + conv_state_bytes(cfg, slots, dtype)
 
 
 def resolve_config(model: str, opts: Dict) -> Optional[LlamaConfig]:
@@ -1830,13 +2023,15 @@ def param_bytes_split(cfg: LlamaConfig, quant: str = "",
 
 
 def block_size_of(pool) -> int:
-    """Positions a block of ``pool`` holds (every leaf's axis 2)."""
-    return next(iter(pool.values())).shape[2]
+    """Positions a block of ``pool`` holds (axis 2 of every leaf that is
+    made of blocks: all but the convolution layers' state)."""
+    return next(a for leaf, a in pool.items()
+                if leaf not in POOL_LEAVES["conv"]).shape[2]
 
 
 def forward_paged(params, tokens, pool, block_tables, pos,
                   cfg: LlamaConfig, compute_dtype="bfloat16",
-                  logit_off=None, with_stats=False):
+                  logit_off=None, with_stats=False, n_valid=None):
     """Forward a suffix against the block-paged KV pool.
 
     ``tokens``: [B, T] (T == 1 for the continuous decode step, B == 1 with
@@ -1872,7 +2067,13 @@ def forward_paged(params, tokens, pool, block_tables, pos,
 
     ``with_stats``: also return the expert layers' routing counts of
     this step, int32 [5] (models/moe.py; None for a model without
-    experts)."""
+    experts).
+
+    ``n_valid`` (traced; a scalar or [B]): how many of the ``T`` columns
+    are real tokens, the rest a chunk's padding (None = all).  State that
+    is not addressed by position — a convolution layer's
+    (:func:`_conv_mixer`) — is taken there; K/V rows of the padding are
+    written as before and never attended."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -1886,8 +2087,10 @@ def forward_paged(params, tokens, pool, block_tables, pos,
         # A pool a layer class (POOL_LEAVES) and a table for the
         # allocator's blocks and one for the rings: ``block_tables`` is
         # then ``{"full": [B, max_blocks], "win": [B, ring]}`` (the array
-        # alone where no layer has a window).  Every pool is a carry of
-        # the walk, like the one pool below.
+        # alone where no layer has a window), with ``"slot": [B]``, the
+        # slot each row is, where convolution layers keep their state by
+        # slot.  Every pool is a carry of the walk, like the one pool
+        # below.
         from .moe import N_STATS, merge_stats
 
         tabs = block_tables if isinstance(block_tables, dict) \
@@ -1906,7 +2109,8 @@ def forward_paged(params, tokens, pool, block_tables, pos,
                 cfg, lp, x, positions, kv=tuple(pl[n] for n in leaves),
                 pos_offset=pos, paged_tables=tabs["win" if ring else "full"],
                 layer=slot, kind=kind, park=park if ring else None,
-                live=live, stats_out=got, branch_io=io)
+                live=live, stats_out=got, branch_io=io,
+                slots=tabs.get("slot"), n_valid=n_valid)
             pl = dict(pl, **dict(zip(leaves, kv)))
             if got:
                 stats = merge_stats(stats, got[0])

@@ -73,7 +73,8 @@ class ExpertsConfig:
     same width; scores are sigmoids or a softmax over all the router's
     outputs (``scoring``), with a per-expert correction bias added FOR
     THE CHOICE ONLY; ``norm_topk`` divides the chosen weights by their
-    sum; ``scale`` multiplies them.  ``zero_experts`` identity experts
+    sum (plus ``norm_eps``, where a model's published code adds one);
+    ``scale`` multiplies them.  ``zero_experts`` identity experts
     follow the routed ones in the router's outputs (module docstring).
     ``held_first``/``held_count`` name this process's share of the
     routed experts (``held_count`` 0 = all)."""
@@ -88,6 +89,7 @@ class ExpertsConfig:
     held_first: int = 0
     held_count: int = 0
     zero_experts: int = 0
+    norm_eps: float = 0.0
 
     def __post_init__(self):
         if self.scoring not in ("sigmoid", "softmax"):
@@ -133,7 +135,8 @@ def route(h, lp, ex: ExpertsConfig):
                                ex.top_k)
         w = jnp.take_along_axis(s, idx, axis=-1)
         if ex.norm_topk:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
+            total = jnp.sum(w, axis=-1, keepdims=True)
+            w = w / (total + ex.norm_eps if ex.norm_eps else total)
         return idx, w * ex.scale
 
 

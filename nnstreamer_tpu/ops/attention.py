@@ -324,7 +324,10 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
     llama.py ``window_ring_blocks``).
     """
     B, T, H, D = q.shape
-    n_blocks, bs, hkv, _ = k_pool.shape
+    n_blocks, bs, hkv, d_pool = k_pool.shape
+    # a pool that stores several narrow KV heads to a lane row
+    # (:func:`kv_lane_pack`) is the same numbers in the same order
+    hkv = hkv * d_pool // D
     scale_v = (D ** -0.5) if scale is None else scale
     dt = q.dtype
     # Sentinel entries clip to a real block: their logical positions sit
@@ -363,6 +366,21 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), v_all)
+
+
+def kv_lane_pack(hkv: int, d: int) -> int:
+    """KV heads a pool row holds side by side so that it fills the 128
+    lanes a DMA and an MXU operand tile by: ``128 / d`` for heads of 64,
+    32, ... where the KV heads divide by it, else 1.  A pool ``[blocks,
+    bs, hkv, d]`` stored as ``[blocks, bs, hkv / pack, d * pack]`` is the
+    same numbers in the same order; on a TPU the two are DIFFERENT tiled
+    layouts (a minor dimension of 64 is padded to the lanes), so a pool
+    that is to be read by the kernel is BORN packed
+    (``models/llama.py init_paged_cache``) and :func:`paged_attention`
+    never repacks one: that is a copy of the whole pool a step (0.61 s of
+    2.84 s busy on the chip, PR 35)."""
+    pack = 128 // d if 0 < d < 128 and 128 % d == 0 else 1
+    return pack if hkv % pack == 0 else 1
 
 
 def _paged_wave_blocks(bs: int, hkv: int, d: int, itemsize: int,
@@ -543,9 +561,29 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     through a ring table, :func:`paged_attention_reference`).  Products
     are in the query's dtype with float32 accumulation, the softmax
     statistics in float32: the reference's own precision.
+
+    **Heads narrower than the 128 lanes** (64, 32, ...) go through the
+    SAME kernel with ``pack`` KV heads to a lane row
+    (:func:`kv_lane_pack`): the pool is stored ``[n_blocks, bs, hkv /
+    pack, D * pack]`` — position t's row j holds KV heads ``j * pack .. j
+    * pack + pack - 1`` side by side — and that is the pool the kernel is
+    handed, ``hkv / pack`` packed heads wide.  A query head goes in as
+    ``D * pack`` lanes that are zero but for the ``D`` its own KV head
+    occupies in the packed row, so its score against the row is its score
+    against that head alone; the kernel's rule for which columns a head
+    keeps (``column % packed heads == head // packed group``) is then the
+    right one unchanged, and ``P x V`` leaves each head ``pack`` results
+    side by side of which the wrapper keeps its own.  Every K/V byte is
+    read once, as at 128 lanes; the MXU multiplies the zero lanes, which
+    it had to spare.  The kernel's result is ``[B, H, D * pack]``.  The
+    pool's own layout decides, and nothing here repacks one: a pool of
+    narrow heads stored one head to a row takes the reference on a chip,
+    as before (``D % 128``).
     """
     B, T, H, D = q.shape
-    n_blocks, bs, hkv, _ = k_pool.shape
+    n_blocks, bs, hkv_p, d_p = k_pool.shape
+    pack = d_p // D                 # KV heads the pool holds to a row
+    hkv = hkv_p * pack
     scale_v = (D ** -0.5) if scale is None else scale
     if interpret is None:
         interpret = False
@@ -554,6 +592,23 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                 q, k_pool, v_pool, block_tables, context_lens, scale=scale_v,
                 window=window, ring=ring)
     itemsize = k_pool.dtype.itemsize
+    if pack > 1:
+        if not paged_kernel_enabled() or T != 1 or H % hkv \
+                or k_pool.shape != v_pool.shape:
+            return paged_attention_reference(
+                q, k_pool, v_pool, block_tables, context_lens, scale=scale_v,
+                window=window, ring=ring)
+        # query head h reads KV head h // G: lane group (h // G) % pack
+        lane = (jnp.arange(H) // (H // hkv)) % pack
+        own = (lane[:, None] == jnp.arange(pack)[None, :])[None, None, :, :,
+                                                           None]
+        out = paged_attention(
+            jnp.where(own, q[:, :, :, None, :], 0).reshape(
+                B, T, H, pack * D),
+            k_pool, v_pool, block_tables, context_lens, scale=scale_v,
+            interpret=interpret, window=window, ring=ring)
+        return jnp.sum(jnp.where(own, out.reshape(B, T, H, pack, D), 0),
+                       axis=3)
     if (
         not paged_kernel_enabled()  # TP traces need the shardable path
         or T != 1

@@ -47,7 +47,9 @@ def test_kernel_leg_toy_interpreted():
                                   seq=128, paged_heads=((4, 4), (8, 2)),
                                   paged_lens=(0, 5, 30, 128, 129, 200),
                                   window=32, grouped_dims=(256, 128),
-                                  grouped=((16, 8, 32, 8, 2),),
+                                  grouped=((16, 8, 32, 8, 2),
+                                           (32, 4, 16, 16, 2, (128, 256))),
+                                  paged_narrow=((8, 4, 32),),
                                   interpret=True)
     names = " ".join(facts["rel_err"])
     for kernel in ("flash_attention", "paged_attention", "matmul_int4",
@@ -56,8 +58,14 @@ def test_kernel_leg_toy_interpreted():
     # the grouped kernel ran on the router's sizes and on skewed ones
     assert {"grouped_swiglu 128x16 cell", "grouped_swiglu 128x16 skewed"} \
         <= set(facts["rel_err"])
+    # ... and where every expert is held, at widths of its own
+    assert "grouped_swiglu 128x32 128x256 cell" in facts["rel_err"]
     # the paged kernel ran without a window and with one over a ring
     assert "paged_attention 8/2 window 32 ring 7" in facts["rel_err"]
+    # ... and with heads narrower than the lanes, four to a lane row
+    assert {"paged_attention 8/4 x 32",
+            "paged_attention 8/4 x 32 window 32 ring 7"} \
+        <= set(facts["rel_err"])
 
 
 def test_main_refuses_cpu(capsys):

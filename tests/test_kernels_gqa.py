@@ -321,6 +321,95 @@ class TestPagedWaves:
         _assert_is_reference(got, *case)
 
 
+class TestPagedNarrowHeads:
+    """Heads narrower than the 128 lanes: the same kernel, two (four) KV
+    heads to a lane row (ops/attention.py), interpreted."""
+
+    @staticmethod
+    def _case(heads, d, lens, seed):
+        h, hkv = heads
+        b, width = len(lens), 68       # 68 blocks of 16: contexts to 1,088
+        n_blocks = b * width
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        q = jax.random.normal(ks[0], (b, 1, h, d)).astype(jnp.bfloat16)
+        # stored as models/llama.py init_paged_cache stores them: `pack`
+        # KV heads to a row of 128 lanes
+        pack = A.kv_lane_pack(hkv, d)
+        kp, vp = (jax.random.normal(
+            k, (n_blocks, CELL_BS, hkv // pack, d * pack)).astype(
+                jnp.bfloat16) for k in ks[1:])
+        tbl = np.random.RandomState(seed).permutation(n_blocks).astype(
+            np.int32).reshape(b, width)
+        return q, kp, vp, tbl, np.asarray(lens, np.int32), 0, False
+
+    @pytest.mark.parametrize("ctx", [1, 15, 16, 17, 255, 256, 257, 1056])
+    @pytest.mark.parametrize("heads,d", [((32, 8), 64), ((32, 4), 32)],
+                             ids=["conv_cell32x8x64", "heads_of_32"])
+    def test_matches_reference_and_skips_dead_blocks(self, heads, d, ctx):
+        """Contexts on both sides of a block's and of a wave's edge (16
+        blocks of 16 a wave at four packed heads), the cell's deepest, and
+        an idle row, against the XLA reference at the head's own width;
+        then on a pool whose every block no row holds live is NaN."""
+        pack = 128 // d
+        assert A._paged_wave_blocks(CELL_BS, heads[1] // pack, 128, 2) \
+            * CELL_BS == 256
+        case = self._case(heads, d, [ctx, 0, 77, ctx], seed=ctx)
+        q, kp, vp, tbl, lens, window, ring = case
+        args = (jnp.asarray(tbl), jnp.asarray(lens), window, ring)
+        clean = np.asarray(_cell_kernel(q, kp, vp, *args), np.float32)
+        assert clean.shape == (4, 1, heads[0], d)
+        _assert_is_reference(clean, *case)
+        # the packed pool IS the pool of narrow heads, in the same order:
+        # the reference reads either layout to the same bits
+        plain = (kp.shape[0], CELL_BS, heads[1], d)
+        ref = np.asarray(A.paged_attention_reference(
+            q, kp.reshape(plain), vp.reshape(plain), *args[:2]), np.float32)
+        assert np.array_equal(ref, np.asarray(A.paged_attention_reference(
+            q, kp, vp, *args[:2]), np.float32))
+        live = np.zeros(kp.shape[0], bool)
+        for r, L in enumerate(lens):
+            live[tbl[r, :-(-int(L) // CELL_BS)]] = True
+        dead = jnp.asarray(~live)[:, None, None, None]
+        nan = jnp.asarray(jnp.nan, jnp.bfloat16)
+        dirty = np.asarray(_cell_kernel(
+            q, jnp.where(dead, nan, kp), jnp.where(dead, nan, vp), *args),
+            np.float32)
+        assert np.isfinite(dirty).all()
+        assert np.array_equal(dirty, clean)
+
+    @pytest.mark.parametrize("mode", ["window", "window_ring"])
+    def test_a_window_and_a_ring_pack_the_same_way(self, mode):
+        window, ring = CELL_MODES[mode]
+        lens = np.asarray([200, 0, 129, 3, 800], np.int32)
+        b, width = len(lens), window // CELL_BS + 3 if ring else 64
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        q = jax.random.normal(ks[0], (b, 1, 32, 64)).astype(jnp.bfloat16)
+        kp, vp = (jax.random.normal(
+            k, (b * width, CELL_BS, 4, 128)).astype(jnp.bfloat16)
+            for k in ks[1:])
+        tbl = np.random.RandomState(5).permutation(b * width).astype(
+            np.int32).reshape(b, width)
+        got = np.asarray(_cell_kernel(q, kp, vp, jnp.asarray(tbl),
+                                      jnp.asarray(lens), window, ring),
+                         np.float32)
+        _assert_is_reference(got, q, kp, vp, tbl, lens, window, ring)
+
+    def test_heads_that_do_not_pack_take_the_reference(self):
+        """KV heads that do not fill a lane row (3 heads of 64), a width
+        that does not divide 128, or a pool of narrow heads stored one
+        head to a row (8 of 64: nothing repacks it at the kernel's door)
+        are not handed to the kernel on a chip (its DMA tiles by 128
+        lanes): the call is the reference's."""
+        for hkv, d in ((3, 64), (2, 48), (8, 64)):
+            q = jnp.ones((2, 1, 2 * hkv, d), jnp.bfloat16)
+            kp = jnp.ones((8, 16, hkv, d), jnp.bfloat16)
+            text = jax.jit(lambda q, kp: A.paged_attention(
+                q, kp, kp, jnp.zeros((2, 4), jnp.int32),
+                jnp.ones((2,), jnp.int32), interpret=False)).lower(
+                    q, kp).as_text()
+            assert "tpu_custom_call" not in text
+
+
 class TestServingPlanTraffic:
     """decode_bytes_per_ctx_token must track n_kv_heads — pricing GQA
     traffic at n_heads is the stale prediction the xray reconciliation

@@ -347,14 +347,15 @@ def _sparse_decode_chunk(cfg):
     return decode_chunk
 
 
-def _the_grouped_kernel_is_the_expert_product(text, rows):
+def _the_grouped_kernel_is_the_expert_product(text, rows, width=6144):
     """The compiled text holds the expert product as the repo's kernel —
-    a custom call named ``ragged-dot…`` whose result is ``[rows, 6144]``,
-    what both roofline readers look for — and none of XLA's own, whose
+    a custom call named ``ragged-dot…`` whose result is ``[rows, width]``,
+    what the roofline readers look for — and none of XLA's own, whose
     row tile (``ragged_dot_tiling``) is sized to the static row count."""
     import re
 
-    assert re.search(rf"%ragged-dot-swiglu[.\d]* = f32\[{rows},6144\]", text)
+    assert re.search(rf"%ragged-dot-swiglu[.\d]* = f32\[{rows},{width}\]",
+                     text)
     assert "ragged_dot_tiling" not in text
 
 
@@ -488,3 +489,140 @@ def test_hybrid_decode_chunk_compiles_at_the_cells_shapes(v5e, monkeypatch):
     for name in ("argument_size_in_bytes", "temp_size_in_bytes",
                  "alias_size_in_bytes"):
         print("decode_chunk", name, getattr(mem, name))
+
+
+# -- convolution state beside the paged cache, heads of 64 (PR 35) ----------
+
+#: the convolution cell: 64 slots, 32 query heads over 8 KV heads of 64,
+#: 4,224 blocks of 16, tables of 4,096 / 16 entries
+CONV_SLOTS, CONV_BLOCKS, CONV_MAX_BLOCKS = 64, 4224, 256
+
+
+def _conv_cfg():
+    from nnstreamer_tpu.models.moe import ExpertsConfig
+
+    return llama.LlamaConfig(
+        vocab=65536, dim=2048, n_layers=10, n_heads=32, n_kv_heads=8,
+        ffn_hidden=11776, max_seq=4096, rope_theta=1e6, qk_norm=True,
+        pattern=tuple(
+            llama.LayerKind(conv=l < 2 or l % 4 != 2,
+                            ffn="dense" if l < 2 else "experts")
+            for l in range(10)),
+        experts=ExpertsConfig(n_experts=64, top_k=4, hidden=1536,
+                              norm_eps=1e-6))
+
+
+@pytest.mark.parametrize("slots,hkv,hd", [
+    (64, 8, 64), (4, 8, 64), (64, 4, 32)],
+    ids=["the_cell", "four_slots", "heads_of_32"])
+def test_paged_attention_compiles_at_narrow_heads(v5e, slots, hkv, hd):
+    """Heads narrower than the 128 lanes go through the same kernel, two
+    (four) KV heads to a lane row: the pool of the convolution cell — 2
+    layers x 4,224 blocks of [16, 8, 64] — is handed over as [16 x 4, 128]
+    blocks, STORED that way (a pool stored ``[16, 8, 64]`` is another tiled
+    layout on the chip: repacking it copied the whole pool twice a step,
+    my first chip run), and the call is named as at 128 lanes, its result
+    ``[slots, heads, 128]``."""
+    import re
+
+    pack = A.kv_lane_pack(hkv, hd)
+    assert pack * hd == 128
+    pool = v5e((2 * CONV_BLOCKS, 16, hkv // pack, 128), jnp.bfloat16)
+    lowered = _compile(
+        "paged_attention",
+        lambda q, k, v, t, n: A.paged_attention(q, k, v, t, n,
+                                                interpret=False),
+        v5e((slots, 1, 32, hd), jnp.bfloat16), pool, pool,
+        v5e((slots, CONV_MAX_BLOCKS), jnp.int32), v5e((slots,), jnp.int32))
+    text = lowered.compile().as_text()
+    assert re.search(rf"%paged_attention[.\d]* = bf16\[{slots},32,128\]",
+                     text)
+    # the pool goes to the kernel as it is stored: nothing repacks it
+    assert not re.search(rf"= bf16\[{2 * CONV_BLOCKS},\S* copy\(", text)
+
+
+def test_grouped_swiglu_compiles_with_every_expert_held(v5e):
+    """The grouped kernel at the convolution cell's shapes: 64 slots x
+    top-4 rows (and a prefill chunk's 32 x 4) over 8 layers x 64 experts of
+    2048 x 1536, all of a layer's 64 live."""
+    from nnstreamer_tpu.ops import grouped_ffn as GF
+
+    D, F, G = 2048, 1536, 8 * 64
+    assert GF._stream_tile(D, 2 * F * 2) == 1024
+    assert GF._stream_tile(F, D * 2) == 1536
+    for rows in (256, 128):
+        lowered = _compile(
+            "ragged-dot-swiglu",
+            lambda x, g, u, d, n: GF.grouped_swiglu(
+                x, g, u, d, n, live=64, expect=rows / 64, interpret=False),
+            v5e((rows, D), jnp.bfloat16), v5e((G, D, F), jnp.bfloat16),
+            v5e((G, D, F), jnp.bfloat16), v5e((G, F, D), jnp.bfloat16),
+            v5e((G,), jnp.int32))
+        _the_grouped_kernel_is_the_expert_product(
+            lowered.compile().as_text(), rows, D)
+
+
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_step"])
+def test_conv_serve_programs_compile_at_the_cells_shapes(
+        v5e, monkeypatch, program):
+    """Both programs of the convolution cell at its own sizes — published
+    widths, 10 layers (two leading conv + dense, then two periods of
+    attention, conv, conv, conv, all sparse), 64 experts top-4 every one
+    held, 64 slots, the K/V pool of 4,224 blocks and the slots' state
+    carried and updated in place: they fit the chip; the decode step holds
+    the paged kernel in its narrow-head form (the prefill chunk takes the
+    plain reference) and both hold the grouped expert product; no
+    operation copies the state leaf.  The bytes go to PERF.md section 4
+    (``-rP`` prints them)."""
+    import math
+    import re
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _conv_cfg()
+    assert llama.walk_plan(cfg.kinds) == llama.WalkPlan(2, 4, 2)
+    params = _abstract(v5e, lambda: llama.init_params(cfg, 0, "bfloat16"))
+    pool = _abstract(v5e, lambda: llama.init_paged_cache(
+        cfg, CONV_BLOCKS, 16, slots=CONV_SLOTS))
+    assert {k: v.shape for k, v in pool.items()} == {
+        "k": (2, CONV_BLOCKS, 16, 4, 128), "v": (2, CONV_BLOCKS, 16, 4, 128),
+        "conv": (8, CONV_SLOTS, 2, 2048)}
+
+    def prefill_step(params, pool, toks, table, pos0, logit_off):
+        logits, pool = llama.forward_paged(
+            params, toks, pool, table, pos0, cfg, logit_off=logit_off,
+            n_valid=logit_off + 1)
+        return logits[:, 0], pool
+
+    if program == "decode_chunk":
+        fn, args = _sparse_decode_chunk(cfg), (
+            v5e((CONV_SLOTS,), jnp.int32),
+            {"full": v5e((CONV_SLOTS, CONV_MAX_BLOCKS), jnp.int32),
+             "slot": v5e((CONV_SLOTS,), jnp.int32)},
+            v5e((CONV_SLOTS,), jnp.int32))
+    else:
+        fn, args = prefill_step, (
+            v5e((1, 32), jnp.int32),
+            {"full": v5e((1, CONV_MAX_BLOCKS), jnp.int32),
+             "slot": v5e((1,), jnp.int32)},
+            v5e((1,), jnp.int32), v5e((), jnp.int32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile()
+    text = compiled.as_text()
+    assert ("%paged_attention" in text) == (program == "decode_chunk")
+    _the_grouped_kernel_is_the_expert_product(
+        text, 256 if program == "decode_chunk" else 128, 2048)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(x.shape) * 2 for x in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes     # all leaves in place
+    # no operation copies the state leaf, flat or by layer (its rows are
+    # gathered, 64 at a time, and written by scatters in place)
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= bf16\[(512|8,64),2,2048\]\S* copy\(", line)
+              # ... nor a K/V pool, whole (2 layers x 4,224 blocks)
+              or re.search(r"= bf16\[(8448|2,4224),\S* copy\(", line)]
+    assert not copies, "a pool leaf is copied:\n" + "\n".join(copies)
+    bytes_limit = 16_909_336_064
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < bytes_limit
+    for name in ("argument_size_in_bytes", "temp_size_in_bytes",
+                 "alias_size_in_bytes"):
+        print(program, name, getattr(mem, name))

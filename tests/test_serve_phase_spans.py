@@ -134,7 +134,8 @@ def test_iter_is_monotonic_dense_and_shared(traced):
     # since PR 29 the parent also gauges the blocks live in each pool (the
     # window pool's stay 0 on a model without window layers)
     assert set(parents[-1].args) == {"iter", "live", "waiting",
-                                     "full_blocks", "win_blocks"}
+                                     "full_blocks", "win_blocks",
+                                     "conv_state_bytes"}
     assert all(e.args["win_blocks"] == 0 for e in parents)
     assert max(e.args["full_blocks"] for e in parents) > 0
 
