@@ -153,6 +153,36 @@ def _fold_slot_keys(keys, p, tag):
     return jax.vmap(lambda kd: jax.random.fold_in(kd, tag))(kk)
 
 
+def _commit_first_token(logits, tok, keys, base_key, adm_no, slot, T,
+                        temperature: float, top_k: int = 0,
+                        top_p: float = 1.0):
+    """The tail of the prefill program: sample an admitted stream's first
+    token from the prompt's last ``logits`` ([1, vocab]) and commit it.
+
+    The stream's slot key is ``fold_in(base_key, adm_no)`` and the token,
+    which sits at position ``T``, is drawn with that key folded at ``(T,
+    TAG_SAMPLE)`` like every later one (an argmax at temperature 0).  Both
+    are written into row ``slot`` of ``tok`` [B] and ``keys`` [B, 2];
+    ``slot == B`` (out of range) commits nothing, which is how a chunk
+    that is not a prompt's last goes through the same program.  Returns
+    ``(first, tok, keys)``; ``first`` is int32[4] — the token, the slot
+    key's two words, and whether the logits were all finite — so that ONE
+    fetch brings home what the host mirrors."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("sampler"):
+        slot_key = jax.random.fold_in(base_key, adm_no)
+        kft = jax.random.fold_in(jax.random.fold_in(slot_key, T), TAG_SAMPLE)
+        first = llama.sample_token(logits, kft, temperature, top_k, top_p)
+        tok = tok.at[slot].set(first[0], mode="drop")
+        keys = keys.at[slot].set(slot_key, mode="drop")
+        out = jnp.concatenate([
+            first, jax.lax.bitcast_convert_type(slot_key, jnp.int32),
+            jnp.isfinite(logits).all()[None].astype(jnp.int32)])
+    return out, tok, keys
+
+
 def spec_rejection_commit(pt, dprobs, props, keys, pos, live):
     """Standard speculative rejection sampling, vectorized per slot.
 
@@ -1241,24 +1271,46 @@ class _ContinuousLoop:
         self._decode = jax.jit(
             decode_chunk, static_argnames=("length",), donate_argnums=(2,))
 
-        def prefill_step(params, toks, pool, table, pos0, logit_off):
+        def prefill_step(params, toks, pool, table, ctl, tok, keys,
+                         base_key):
             """One [1, prefill_chunk] prefill chunk written directly into
-            the slot's blocks; returns the ``logit_off`` position's
-            logits ([1, vocab]) so the first-token sample needs no
-            separate program.  ``logit_off`` is the offset of the chunk's
-            last REAL token: on the final chunk the prompt's last, before
-            it the chunk's last column."""
+            the slot's blocks, and on a prompt's final chunk its first
+            token sampled and committed (``_commit_first_token``): the
+            serve thread issues no program of its own between this one and
+            the decode chunk.  ``ctl`` is int32[4], one transfer for the
+            chunk's four host values: its first position; ``logit_off``,
+            the offset of its last REAL token (on the final chunk the
+            prompt's last, before it the chunk's last column); the
+            stream's admission number; and its slot on the final chunk,
+            ``slots`` (out of range: nothing is committed) before it.
+            ONE signature for every chunk, greedy and sampled."""
+            pos0, logit_off, adm_no, slot = ctl[:1], ctl[1], ctl[2], ctl[3]
             logits, pool = llama.forward_paged(
                 params, toks, pool, table, pos0, cfg,
                 compute_dtype=fw.dtype, logit_off=logit_off,
                 # the chunk's real tokens end at ``logit_off``: where
                 # state that no position addresses is taken
                 n_valid=logit_off + 1 if cfg.n_conv_layers else None)
-            return logits[:, 0], pool
+            # the first token's position is the prompt's length
+            first, tok, keys = _commit_first_token(
+                logits[:, 0], tok, keys, base_key, adm_no, slot,
+                pos0[0] + logit_off + 1, temperature, fw.top_k, fw.top_p)
+            return first, tok, keys, pool
 
-        self._prefill = jax.jit(prefill_step, donate_argnums=(2,))
-        # tok updates keep the token vector device-resident (slot index
-        # and value traced: ONE program for every admission)
+        # Under tensor parallelism the carried tok/keys go in replicated
+        # (committed up front, _run_inner) and must come back spelt the
+        # same way, or the second call mints a second signature (the
+        # compiler returns a rank-2 replica as P(None, None), not P()).
+        rep = None
+        if fw.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            rep = NamedSharding(fw.mesh, PartitionSpec())
+        self._prefill = jax.jit(prefill_step, donate_argnums=(2, 5, 6),
+                                out_shardings=(rep, rep, rep, None))
+        # slot-vector updates by value where no program commits them: an
+        # adopted stream's token, the speculative loop's tok_prev and
+        # position twins (slot index and value traced: ONE program)
         self._set_tok = jax.jit(lambda a, i, v: a.at[i].set(v),
                                 donate_argnums=(0,))
         # -- speculative decoding (custom=draft:<preset>,spec_k:K) ------
@@ -1813,16 +1865,17 @@ class _ContinuousLoop:
         # call returns.
         tok = jnp.zeros((B,), jnp.int32)
         tok_prev = jnp.zeros((B,), jnp.int32) if self._spec else None
-        key = jax.random.PRNGKey(fw.seed)
         # Per-slot PRNG state (docs/SERVING.md §4d): each slot's base
         # key is fold_in(PRNGKey(seed), admission number) — a pure
         # function of (seed, admission order), NOT of stream ids (those
         # are process-global and would differ between two same-seed
         # runs in one process, breaking bit-reproducibility).  The
-        # device twin rebuilds by VALUE at admission/adopt events (a
-        # transfer, never a compile); every per-token draw then folds
-        # (absolute position, tag) inside the compiled programs.
-        base_key = np.asarray(jax.random.PRNGKey(fw.seed), np.uint32)
+        # prefill program derives it on a prompt's final chunk, writes
+        # it into the device twin and sends it home with the first
+        # token (step 4 mirrors it into keys_h for drain snapshots);
+        # every per-token draw then folds (absolute position, tag)
+        # inside the compiled programs.
+        base_key = jax.random.PRNGKey(fw.seed)
         adm_no = 0
         keys_h = np.zeros((B, 2), np.uint32)
         keys_dev = jnp.asarray(keys_h)
@@ -1847,7 +1900,7 @@ class _ContinuousLoop:
             from ..parallel.sharding import replicate as _rep
 
             tok = _rep(fw.mesh, tok)
-            key = _rep(fw.mesh, key)
+            base_key = _rep(fw.mesh, base_key)
             keys_dev = _rep(fw.mesh, keys_dev)
             if tok_prev is not None:
                 tok_prev = _rep(fw.mesh, tok_prev)
@@ -1856,18 +1909,18 @@ class _ContinuousLoop:
 
         def push_keys() -> None:
             """Rebuild the device key vector from the host mirror — an
-            admission/adopt-event VALUE move (replicated under TP), so
-            steady-state rounds never touch it."""
+            adopt-event VALUE move (replicated under TP); admissions
+            never touch it, their keys are written in-program."""
             nonlocal keys_dev
             keys_dev = jnp.asarray(keys_h.copy())
             if fw.mesh is not None:
                 keys_dev = _rep(fw.mesh, keys_dev)
 
         def fresh_slot_key() -> np.ndarray:
+            """The next admission number's key, fetched (a host wait on
+            the device): for an adopted snapshot that carries none."""
             nonlocal adm_no
-            k = np.asarray(
-                jax.random.fold_in(jnp.asarray(base_key), adm_no),
-                np.uint32)
+            k = np.asarray(jax.random.fold_in(base_key, adm_no), np.uint32)
             adm_no += 1
             return k
 
@@ -2041,13 +2094,12 @@ class _ContinuousLoop:
         # allocator), writes garbage through them, and frees them —
         # nothing real can attend it (the slot re-parks).
         kv.reserve(0, min(C, self.n_blocks * bs))
-        logits_w, pool = self._prefill(
+        # the prefill commits nothing (slot B): keys_dev stays keys_h's twin
+        _first_w, tok, keys_dev, pool = self._prefill(
             params, jnp.zeros((1, C), jnp.int32), pool,
-            kv.tabs(slice(0, 1)), pos[:1] * 0, np.int32(C - 1))
-        key, sub = jax.random.split(key)
-        first_w = llama.sample_token(logits_w, sub, fw.temperature,
-                                     fw.top_k, fw.top_p)[0]
-        tok = self._set_tok(tok, np.int32(0), first_w)
+            kv.tabs(slice(0, 1)), np.asarray([0, C - 1, 0, B], np.int32),
+            tok, keys_dev, base_key)
+        tok = self._set_tok(tok, np.int32(0), jnp.asarray(np.int32(0)))
         if self._spec:
             # every slot is parked: the propose/verify warm-ups compile
             # their (only) signatures, write nothing (sentinel tables),
@@ -2550,9 +2602,13 @@ class _ContinuousLoop:
 
             # 2. chunked prefill: dispatch up to prefill_budget tokens of
             # [1, C] prefill chunks straight into the admitting streams'
-            # blocks (async — no host sync here).  With no live decode
-            # the budget is waived: there is nothing to interleave with,
-            # and finishing the prompt sooner IS the latency win.
+            # blocks (async — no host sync here).  A prompt's final chunk
+            # samples and commits its first token in the same program
+            # (tok[s], keys_dev[s]): from its dispatch to the decode
+            # chunk's (step 3) this thread waits on the device for nothing
+            # and issues no program of its own.  With no live decode the
+            # budget is waived: there is nothing to interleave with, and
+            # finishing the prompt sooner IS the latency win.
             budget = fw.prefill_budget if (remaining > 0).any() else 1 << 30
             newly_live = []  # (slot, state) — first token syncs in step 4
             for st in list(self._admitting):
@@ -2566,11 +2622,20 @@ class _ContinuousLoop:
                     # last REAL token's offset within this chunk
                     # (intermediate chunks are all real tokens; their
                     # logits are unused, their slot-owned state is not)
-                    off = np.int32(st["T"] - 1 - p if final else C - 1)
-                    logits, pool = self._prefill(
+                    off = st["T"] - 1 - p if final else C - 1
+                    # the stream's slot key is fold_in(seed key, admission
+                    # number) and its first token, at position T, folds
+                    # (T, sample tag) like every later draw: the whole
+                    # stream a pure function of (seed, admission number,
+                    # positions).  Only the final chunk commits (slot B
+                    # is out of range); its number is spent below, once
+                    # the stream is known to go live.
+                    first, tok, keys_dev, pool = self._prefill(
                         params, jnp.asarray(st["prompt"][:, p:p + C]),
                         pool, kv.tabs(slice(s, s + 1)),
-                        np.asarray([p], np.int32), off)
+                        np.asarray([p, off, adm_no, s if final else B],
+                                   np.int32),
+                        tok, keys_dev, base_key)
                     if self._spec:
                         # the draft's prefill twin writes the chunk's
                         # draft K/V into the SAME blocks of the draft
@@ -2583,19 +2648,25 @@ class _ContinuousLoop:
                             np.asarray([p], np.int32))
                     st["p"] = p + C
                     budget -= C
+                    poisoned = False
+                    if final and fw.nan_guard:
+                        # the guard's price: a wait for the prefill with
+                        # nothing else queued on the chip
+                        first = np.asarray(first)
+                        poisoned = not first[3]
                     if rec is not None:
-                        sp.end()
+                        sp.end(sampled=int(final and not poisoned))
                     progressed = True
                     if final:
-                        if fw.nan_guard and \
-                                not np.isfinite(
-                                    np.asarray(logits)).all():
+                        if poisoned:
                             # poison pill: the prompt's own prefill
                             # produced non-finite logits — quarantine
                             # it (DLQ + breaker accounting through the
                             # pipeline's armor) and answer the client
                             # with the typed poison terminator; the
                             # loop keeps serving every other stream
+                            # (what the program wrote into the slot's
+                            # rows no stream reads: the slot re-parks)
                             err = FloatingPointError(
                                 "non-finite prefill logits (nan_guard)")
                             armor_obj = getattr(fw, "_armor", None)
@@ -2616,23 +2687,9 @@ class _ContinuousLoop:
                             retire(s)
                             progressed = True
                             break
-                        # first-token sample stays EAGER (outside jit):
-                        # logits are already device-resident and the
-                        # dispatch overlaps the decode chunk below.
-                        # The admitted stream gets its slot PRNG key
-                        # here; the first token sits at position T, so
-                        # its draw folds (T, sample tag) — the same
-                        # convention the decode scan uses, making the
-                        # whole stream a pure function of (seed,
-                        # admission number, positions).
-                        keys_h[s] = fresh_slot_key()
-                        push_keys()
-                        kft = jax.random.fold_in(jax.random.fold_in(
-                            jnp.asarray(keys_h[s]), st["T"]), 100)
-                        st["first"] = llama.sample_token(
-                            logits, kft, fw.temperature, fw.top_k,
-                            fw.top_p)[0]
-                        tok = self._set_tok(tok, np.int32(s), st["first"])
+                        st["first"] = first
+                        adm_no += 1
+                        metrics.count("llm.serve.first_token_in_prefill")
                         tok_prev_h[s] = st["last_tok"]
                         if self._spec:
                             # the round's refresh step re-feeds the
@@ -2705,17 +2762,20 @@ class _ContinuousLoop:
             metrics.gauge("llm.serve.waiting",
                           float(len(self._waiting) + len(self._admitting)))
 
-            # 4. materialize + emit the admitted first tokens — the
-            # device is already computing the chunk, so this sync rides
-            # under it; the late joiner's first token leaves here, one
-            # dispatch (not one drained queue) after submit.
+            # 4. materialize + emit the admitted first tokens — this wait
+            # is for the PREFILL program, and the device already has the
+            # chunk behind it; the late joiner's first token leaves here,
+            # one dispatch (not one drained queue) after submit.  The
+            # slot's key comes home in the same fetch.
             for st in newly_live:
                 s = st["slot"]
                 if rec is not None:
                     tid, t_adm, p0 = st.get("trace", _NO_TRACE)
                     sp = begin("serve.first_token", tid, iter=it, slot=s)
-                first = int(np.asarray(st["first"]))
+                fetched = np.asarray(st["first"])
+                first = int(fetched[0])
                 tok_h[s] = first
+                keys_h[s] = fetched[1:3].view(np.uint32)
                 first_last = st["n"] == 1 or first == eos
                 self._emit_token(st["emit"], st["meta"], first, 0,
                                  first_last)

@@ -110,7 +110,9 @@ SPAN_KINDS: Dict[str, str] = {
     "serve.prefill_chunk": "continuous LLM serving: one chunked-prefill "
                            "step written into the slot's pool blocks "
                            "(tid = request trace id; args: iter, tid, "
-                           "slot, pos, final; times the ASYNC "
+                           "slot, pos, final, sampled = 1 where the "
+                           "chunk's program also sampled and committed "
+                           "the stream's first token; times the ASYNC "
                            "dispatch — device time overlaps the decode "
                            "chunk by design)",
     "serve.decode": "continuous LLM serving: one paged decode chunk over "
@@ -122,9 +124,11 @@ SPAN_KINDS: Dict[str, str] = {
                     "annotation, serve.decode.wait, covers the blocking "
                     "wait alone)",
     "serve.first_token": "continuous LLM serving: a newly live stream's "
-                         "first sampled id synced to the host and "
-                         "emitted, under the decode chunk in flight (tid "
-                         "= request trace id; args: iter, tid, slot)",
+                         "first token (and slot key), which its prefill "
+                         "program sampled, fetched and emitted: the wait "
+                         "is for that prefill, with the decode chunk "
+                         "already queued behind it (tid = request trace "
+                         "id; args: iter, tid, slot)",
     "serve.emit": "continuous LLM serving: one delivery — tokens of a "
                   "settled chunk pushed downstream one by one, and the "
                   "books of the streams that ended in it (args: iter = "

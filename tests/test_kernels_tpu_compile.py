@@ -171,27 +171,60 @@ def _decode_program(cfg, v5e):
         v5e((POOL_SLOTS,), jnp.int32))
 
 
-def _prefill_program(cfg, v5e):
-    """``filters/llm.py prefill_step``'s shape: one [1, 32] chunk."""
-    def prefill_step(params, pool, toks, table, pos0, logit_off):
+def _prefill_step(cfg, temperature=0.0, top_k=0, top_p=1.0):
+    """``filters/llm.py prefill_step``: one prefill chunk, and at its end
+    the first token sampled and committed by the loop's own
+    ``_commit_first_token`` (greedy, as every cell runs it, unless asked)."""
+    from nnstreamer_tpu.filters.llm import _commit_first_token
+
+    def prefill_step(params, pool, toks, table, ctl, tok, keys, base_key):
+        pos0, logit_off, adm_no, slot = ctl[:1], ctl[1], ctl[2], ctl[3]
         logits, pool = llama.forward_paged(
-            params, toks, pool, table, pos0, cfg, logit_off=logit_off)
-        return logits[:, 0], pool
+            params, toks, pool, table, pos0, cfg, logit_off=logit_off,
+            n_valid=logit_off + 1 if cfg.n_conv_layers else None)
+        first, tok, keys = _commit_first_token(
+            logits[:, 0], tok, keys, base_key, adm_no, slot,
+            pos0[0] + logit_off + 1, temperature, top_k, top_p)
+        return first, tok, keys, pool
 
-    return prefill_step, (
-        v5e((1, 32), jnp.int32), v5e((1, POOL_MAX_BLOCKS), jnp.int32),
-        v5e((1,), jnp.int32), v5e((), jnp.int32))
+    return prefill_step
 
 
-@pytest.mark.parametrize("program", [_decode_program, _prefill_program],
-                         ids=["decode_chunk", "prefill_step"])
+#: what the loop donates to ``prefill_step``: the pool, ``tok``, ``keys``
+PREFILL_DONATED = (1, 5, 6)
+
+
+def _prefill_args(v5e, slots, table):
+    """One [1, 32] chunk over ``table``, its four host values (first
+    position, last real token's offset, admission number, slot), then the
+    carried ``tok`` and slot keys and the seed's key."""
+    return (v5e((1, 32), jnp.int32), table, v5e((4,), jnp.int32),
+            v5e((slots,), jnp.int32), v5e((slots, 2), jnp.uint32),
+            v5e((2,), jnp.uint32))
+
+
+def _prefill_program(cfg, v5e, **sampler):
+    return _prefill_step(cfg, **sampler), _prefill_args(
+        v5e, POOL_SLOTS, v5e((1, POOL_MAX_BLOCKS), jnp.int32))
+
+
+def _sampled_prefill_program(cfg, v5e):
+    return _prefill_program(cfg, v5e, temperature=0.8, top_k=40, top_p=0.9)
+
+
+@pytest.mark.parametrize(
+    "program", [_decode_program, _prefill_program, _sampled_prefill_program],
+    ids=["decode_chunk", "prefill_step", "prefill_step_sampled"])
 def test_serve_program_never_moves_the_pool(v5e, monkeypatch, program):
     """The compiled serve programs write the new K/V rows into the donated
     pool in place: no operation copies the pool, slices a layer out of it
     or writes a layer back, and the program holds no second pool among its
     temporaries.  (A pool scanned as the layer loop's inputs and outputs
     makes both programs do all three: 2.28 GB of copies per decode step
-    at full depth.)"""
+    at full depth.)  The prefill chunk ends in the first token's sampler
+    and commit (PR 37): its temporaries are 645,120 B greedy and 876,032 B
+    sampled where the chunk alone has 322,560 B (chipless compile, PR 37;
+    ``-rP`` prints them)."""
     import dataclasses
     import math
     import re
@@ -203,7 +236,9 @@ def test_serve_program_never_moves_the_pool(v5e, monkeypatch, program):
     pool = _abstract(
         v5e, lambda: llama.init_paged_cache(cfg, POOL_BLOCKS, POOL_BS))
     fn, args = program(cfg, v5e)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+    prefill = program is not _decode_program
+    compiled = jax.jit(
+        fn, donate_argnums=PREFILL_DONATED if prefill else (1,)).lower(
         params, pool, *args).compile()
 
     layer_elems = math.prod(pool["k"].shape[1:])
@@ -222,6 +257,9 @@ def test_serve_program_never_moves_the_pool(v5e, monkeypatch, program):
     layer_bytes = 2 * layer_elems * pool["k"].dtype.itemsize  # K + V
     assert mem.temp_size_in_bytes < layer_bytes
     assert mem.alias_size_in_bytes >= cfg.n_layers * layer_bytes
+    for name in ("argument_size_in_bytes", "temp_size_in_bytes",
+                 "alias_size_in_bytes"):
+        print(name, getattr(mem, name))
 
 
 # -- window and full attention in one paged cache, sparse experts (PR 29) ---
@@ -394,23 +432,16 @@ def test_latent_serve_programs_compile_at_the_cells_shapes(
     assert {k: v.shape for k, v in pool.items()} == {
         "c": (8, LAT_BLOCKS, 16, 640)}
 
-    decode_chunk = _sparse_decode_chunk(cfg)
-
-    def prefill_step(params, pool, toks, table, pos0, logit_off):
-        logits, pool = llama.forward_paged(
-            params, toks, pool, table, pos0, cfg, logit_off=logit_off)
-        return logits[:, 0], pool
-
     if program == "decode_chunk":
-        fn, args = decode_chunk, (
+        fn, donated, args = _sparse_decode_chunk(cfg), (1,), (
             v5e((LAT_SLOTS,), jnp.int32),
             v5e((LAT_SLOTS, LAT_MAX_BLOCKS), jnp.int32),
             v5e((LAT_SLOTS,), jnp.int32))
     else:
-        fn, args = prefill_step, (
-            v5e((1, 32), jnp.int32), v5e((1, LAT_MAX_BLOCKS), jnp.int32),
-            v5e((1,), jnp.int32), v5e((), jnp.int32))
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        fn, donated, args = _prefill_step(cfg), PREFILL_DONATED, \
+            _prefill_args(v5e, LAT_SLOTS,
+                          v5e((1, LAT_MAX_BLOCKS), jnp.int32))
+    compiled = jax.jit(fn, donate_argnums=donated).lower(
         params, pool, *args).compile()
     text = compiled.as_text()
     assert ("%paged_latent_attention" in text) == (program == "decode_chunk")
@@ -459,13 +490,18 @@ def test_grouped_swiglu_compiles(v5e, rows, groups, expect):
         lowered.compile().as_text(), rows)
 
 
-def test_hybrid_decode_chunk_compiles_at_the_cells_shapes(v5e, monkeypatch):
-    """``decode_chunk`` of the patterned cell at its own sizes — 8 layers
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_step"])
+def test_hybrid_serve_programs_compile_at_the_cells_shapes(
+        v5e, monkeypatch, program):
+    """Both programs of the patterned cell at its own sizes — 8 layers
     ``LLLG LLLG``, layer 0 dense, 7 x 16 held experts, 64 slots, 3,200
     blocks and the rings: the expert product is the grouped kernel over
-    ``[512, 6144]`` rows, none of XLA's is left, both pools are updated in
-    place and the temporaries are no larger than PERF.md section 4's
-    (1.53 GB with XLA's product, chipless compile, PR 29)."""
+    ``[512, 6144]`` rows a decode step (``[256, 6144]`` a prefill chunk),
+    none of XLA's is left, both pools are updated in place and the
+    temporaries are no larger than PERF.md section 4's (``decode_chunk``
+    1.53 GB with XLA's product, chipless compile, PR 29; ``prefill_step``
+    588,947,456 B with the first token's sampler and commit at its end,
+    588,802,048 B without, PR 37)."""
     import math
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -473,22 +509,33 @@ def test_hybrid_decode_chunk_compiles_at_the_cells_shapes(v5e, monkeypatch):
     params = _abstract(v5e, lambda: llama.init_params(cfg, 0, "bfloat16"))
     pool = _abstract(v5e, lambda: llama.init_paged_cache(
         cfg, 3200, 16, win_blocks=HYB_SLOTS * HYB_RING))
-    tables = {"full": v5e((HYB_SLOTS, 256), jnp.int32),
-              "win": v5e((HYB_SLOTS, HYB_RING), jnp.int32)}
-    compiled = jax.jit(_sparse_decode_chunk(cfg), donate_argnums=(1,)).lower(
-        params, pool, v5e((HYB_SLOTS,), jnp.int32), tables,
-        v5e((HYB_SLOTS,), jnp.int32)).compile()
+    if program == "decode_chunk":
+        fn, donated, args = _sparse_decode_chunk(cfg), (1,), (
+            v5e((HYB_SLOTS,), jnp.int32),
+            {"full": v5e((HYB_SLOTS, 256), jnp.int32),
+             "win": v5e((HYB_SLOTS, HYB_RING), jnp.int32)},
+            v5e((HYB_SLOTS,), jnp.int32))
+    else:
+        fn, donated, args = _prefill_step(cfg), PREFILL_DONATED, \
+            _prefill_args(v5e, HYB_SLOTS, {
+                "full": v5e((1, 256), jnp.int32),
+                "win": v5e((1, HYB_RING), jnp.int32)})
+    compiled = jax.jit(fn, donate_argnums=donated).lower(
+        params, pool, *args).compile()
     text = compiled.as_text()
-    _the_grouped_kernel_is_the_expert_product(text, 512)
-    assert "%paged_attention" in text
+    _the_grouped_kernel_is_the_expert_product(
+        text, 512 if program == "decode_chunk" else 256)
+    assert ("%paged_attention" in text) == (program == "decode_chunk")
     mem = compiled.memory_analysis()
     pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                      for x in jax.tree_util.tree_leaves(pool))
     assert mem.alias_size_in_bytes >= pool_bytes
-    assert mem.temp_size_in_bytes <= 1_530_000_000, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes <= (
+        1_530_000_000 if program == "decode_chunk" else 590_000_000), \
+        mem.temp_size_in_bytes
     for name in ("argument_size_in_bytes", "temp_size_in_bytes",
                  "alias_size_in_bytes"):
-        print("decode_chunk", name, getattr(mem, name))
+        print(program, name, getattr(mem, name))
 
 
 # -- convolution state beside the paged cache, heads of 64 (PR 35) ----------
@@ -587,25 +634,18 @@ def test_conv_serve_programs_compile_at_the_cells_shapes(
         "k": (2, CONV_BLOCKS, 16, 4, 128), "v": (2, CONV_BLOCKS, 16, 4, 128),
         "conv": (8, CONV_SLOTS, 2, 2048)}
 
-    def prefill_step(params, pool, toks, table, pos0, logit_off):
-        logits, pool = llama.forward_paged(
-            params, toks, pool, table, pos0, cfg, logit_off=logit_off,
-            n_valid=logit_off + 1)
-        return logits[:, 0], pool
-
     if program == "decode_chunk":
-        fn, args = _sparse_decode_chunk(cfg), (
+        fn, donated, args = _sparse_decode_chunk(cfg), (1,), (
             v5e((CONV_SLOTS,), jnp.int32),
             {"full": v5e((CONV_SLOTS, CONV_MAX_BLOCKS), jnp.int32),
              "slot": v5e((CONV_SLOTS,), jnp.int32)},
             v5e((CONV_SLOTS,), jnp.int32))
     else:
-        fn, args = prefill_step, (
-            v5e((1, 32), jnp.int32),
-            {"full": v5e((1, CONV_MAX_BLOCKS), jnp.int32),
-             "slot": v5e((1,), jnp.int32)},
-            v5e((1,), jnp.int32), v5e((), jnp.int32))
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        fn, donated, args = _prefill_step(cfg), PREFILL_DONATED, \
+            _prefill_args(v5e, CONV_SLOTS, {
+                "full": v5e((1, CONV_MAX_BLOCKS), jnp.int32),
+                "slot": v5e((1,), jnp.int32)})
+    compiled = jax.jit(fn, donate_argnums=donated).lower(
         params, pool, *args).compile()
     text = compiled.as_text()
     assert ("%paged_attention" in text) == (program == "decode_chunk")

@@ -533,15 +533,18 @@ class TestContinuousServing:
         fw = self._fw("max_new:8,stream_chunk:2,temperature:0.0,"
                       "serve:continuous,slots:1")
         calls = {"n": 0}
-        real = llm_mod.llama.sample_token
+        real = llm_mod.BlockManager.register
 
+        # once an admission, after its final prefill chunk went out (the
+        # first token is sampled inside that program: no sampler call is
+        # left on the serve thread to fail)
         def dying(*a, **k):
             calls["n"] += 1
-            if calls["n"] > 2:
+            if calls["n"] > 1:
                 raise RuntimeError("injected serve-loop failure")
             return real(*a, **k)
 
-        monkeypatch.setattr(llm_mod.llama, "sample_token", dying)
+        monkeypatch.setattr(llm_mod.BlockManager, "register", dying)
         got = []
         fw.submit([np.array([1, 5, 9], np.int32)], {},
                   lambda t, m: got.append(dict(m)))

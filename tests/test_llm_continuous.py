@@ -1047,3 +1047,206 @@ class TestCensusUnderRunAhead:
         assert after == warm, f"compiled in the loop: {warm} -> {after}"
         # the speculative round keeps its order; the plain loop ran ahead
         assert (ran_ahead > 0) == (extra == "")
+
+
+# ---------------------------------------------------------------------------
+# the first token is sampled and committed inside the prefill program (PR 37)
+# ---------------------------------------------------------------------------
+
+def _log_prefills(serve, log):
+    """Wrap ``_prefill`` to append, per call, the chunk's first position,
+    its slot argument, ``tok`` and the slot keys before and after (read
+    before the call donates them) and the small vector it returns."""
+    inner = serve._prefill
+
+    def prefill(*a):
+        entry = {"pos": int(a[4][0]), "slot": int(a[4][3]),
+                 "tok0": np.asarray(a[5]).copy(),
+                 "keys0": np.asarray(a[6]).copy()}
+        out = inner(*a)
+        entry.update(first=np.asarray(out[0]), tok1=np.asarray(out[1]),
+                     keys1=np.asarray(out[2]))
+        log.append(entry)
+        return out
+
+    serve._prefill = prefill
+
+
+class TestFirstTokenCommittedByThePrefill:
+    def test_only_the_final_chunk_commits_and_only_its_own_row(self):
+        """A prompt of three chunks beside a live stream, then a prompt
+        that hits the first's prefix: each admission commits ONCE, on its
+        final chunk; a chunk that is not final leaves ``tok`` and the slot
+        keys as they were, and the final one changes its own row alone —
+        to the token the stream then emits first."""
+        rng = np.random.default_rng(70)
+        pre = rng.integers(1, 500, (16,), dtype=np.int32)
+        short = rng.integers(1, 500, (3,), dtype=np.int32)
+        long_ = np.concatenate([pre, rng.integers(1, 500, (5,), np.int32)])
+        hit = np.concatenate([pre, rng.integers(1, 500, (2,), np.int32)])
+        fw = _warm_loop(f"max_new:{LONG},{AHEAD}{LOOP}")
+        calls = []
+        _log_prefills(fw._serve, calls)
+        B = 2
+        h0 = metrics.snapshot().get("llm.serve.prefix_hits", 0.0)
+        c = _Stream("c")
+        # c joins from the serve thread once b's prompt blocks are indexed
+        a = _Stream("a")
+        b = _Stream("b", at={1: lambda: fw.submit([hit], {}, c)})
+        try:
+            fw.submit([short], {}, a)
+            fw.submit([long_], {}, b)
+            assert a.done.wait(300) and b.done.wait(300) and c.done.wait(300)
+        finally:
+            fw.close()
+        assert metrics.snapshot().get("llm.serve.prefix_hits", 0.0) > h0
+        # short: one chunk; long_: 21 tokens = three chunks of 8; hit: its
+        # first 16 tokens are shared, so its only chunk starts at 16
+        assert [(c_["pos"], c_["slot"] < B) for c_ in calls] == [
+            (0, True), (0, False), (8, False), (16, True), (16, True)]
+        committed = [c_ for c_ in calls if c_["slot"] < B]
+        for c_ in calls:
+            slot, first = c_["slot"], c_["first"]
+            rows = [r for r in range(B) if r != slot]   # all, if slot == B
+            assert (c_["tok1"][rows] == c_["tok0"][rows]).all()
+            assert (c_["keys1"][rows] == c_["keys0"][rows]).all()
+            if slot < B:
+                assert c_["tok1"][slot] == first[0]
+                assert (c_["keys1"][slot]
+                        == first[1:3].view(np.uint32)).all()
+                assert first[3] == 1        # the logits were finite
+        assert [int(c_["first"][0]) for c_ in committed] == [
+            a.ids[0], b.ids[0], c.ids[0]]
+        # three admissions, three slot keys
+        assert len({tuple(c_["first"][1:3]) for c_ in committed}) == 3
+
+    @pytest.mark.parametrize("how", ["max_new_1", "first_token_eos"])
+    def test_a_stream_that_ends_at_its_first_token_retires_as_before(
+            self, how, monkeypatch, abc):
+        """``n == 1`` and an EOS on token 0: one token with
+        ``stream_last``, slot and blocks free at once — what the program
+        wrote into ``tok[slot]`` is never read, and the stream seated in
+        the slot next is the dense path's."""
+        from nnstreamer_tpu.filters import llm
+
+        prompts, refs = abc
+        if how == "max_new_1":
+            custom = f"max_new:1,{AHEAD}{LOOP}"
+            want = [[r[0]] for r in refs[:2]]
+        else:
+            monkeypatch.setattr(llm.ByteTokenizer, "eos", refs[0][0])
+            custom = f"max_new:{LONG},{AHEAD}{LOOP},stop_eos:1"
+            want = [refs[0][:1], refs[1]]
+            if refs[0][0] in want[1]:
+                want[1] = want[1][:want[1].index(refs[0][0]) + 1]
+        fw = _fw(custom.replace("slots:2", "slots:1"))
+        a, b = _Stream("a"), _Stream("b")
+        try:
+            fw.submit([prompts[0]], {}, a)
+            assert a.done.wait(300)
+            fw.submit([prompts[1]], {}, b)   # seated in the slot a left
+            assert fw.drain(timeout=300)
+            stats = fw._serve.pool_stats()
+        finally:
+            fw.close()
+        a.whole(want[0])
+        b.whole(want[1])
+        assert stats["blocks_free"] == stats["blocks_total"]
+        assert stats["live_streams"] == 0
+
+    def test_nothing_but_the_prefill_runs_before_the_decode_dispatch(
+            self, monkeypatch, abc):
+        """From a final prefill chunk's dispatch to the decode chunk's the
+        serve thread issues ONE program (that prefill) and fetches nothing
+        from the device: no key folded, no sampler, no slot-vector setter,
+        no eager primitive, no ``np.asarray`` of a device array."""
+        import threading
+
+        import jax
+
+        from nnstreamer_tpu.filters import llm
+
+        prompts, refs = abc
+        fw = _warm_loop(f"max_new:{LONG},{AHEAD}{LOOP}")
+        serve = fw._serve
+        B = 2
+        seen, window = [], [False]
+
+        def on_serve_thread_in_window():
+            return window[0] and \
+                threading.current_thread().name == "llm-serve"
+
+        def watched(name, fn):
+            def call(*a, **kw):
+                if on_serve_thread_in_window():
+                    seen.append(name)
+                return fn(*a, **kw)
+            return call
+
+        prefill, decode = serve._prefill, serve._decode
+
+        def prefill_w(*a):
+            out = prefill(*a)
+            if int(a[4][3]) < B:    # the chunk that commits
+                window[0] = True
+                seen.append("prefill_step")
+            return out
+
+        def decode_w(*a, **kw):
+            if window[0]:
+                seen.append("decode_chunk")
+            window[0] = False
+            return decode(*a, **kw)
+
+        serve._prefill, serve._decode = prefill_w, decode_w
+        serve._set_tok = watched("_set_tok", serve._set_tok)
+        monkeypatch.setattr(jax.random, "fold_in",
+                            watched("fold_in", jax.random.fold_in))
+        monkeypatch.setattr(llm.llama, "sample_token",
+                            watched("sample_token", llm.llama.sample_token))
+        # every primitive applied eagerly goes through here (a jitted
+        # function called again does not: the loop's own are wrapped above)
+        from jax._src import core
+
+        monkeypatch.setattr(core.EvalTrace, "process_primitive", watched(
+            "eager operation", core.EvalTrace.process_primitive))
+        real_np = llm.np
+
+        class NpProxy:
+            def __getattr__(self, name):
+                val = getattr(real_np, name)
+                if name != "asarray":
+                    return val
+
+                def asarray(x, *a, **kw):
+                    if isinstance(x, jax.Array) and \
+                            on_serve_thread_in_window():
+                        seen.append("device fetch")
+                    return val(x, *a, **kw)
+                return asarray
+
+        monkeypatch.setattr(llm, "np", NpProxy())
+        got = [_Stream(str(i)) for i in range(3)]
+        try:
+            for p, g in zip(prompts, got):
+                fw.submit([p], {}, g)
+            assert fw.drain(timeout=300)
+        finally:
+            fw.close()
+        for g, w in zip(got, refs):
+            g.whole(w)
+        # two admitted in the first iteration (two final chunks, then the
+        # one decode dispatch), the third into a freed slot later
+        assert seen == ["prefill_step", "prefill_step", "decode_chunk",
+                        "prefill_step", "decode_chunk"], seen
+
+    def test_the_counter_counts_admissions(self, abc):
+        prompts, _refs_ = abc
+        n0 = metrics.snapshot().get("llm.serve.first_token_in_prefill", 0.0)
+        fw = _fw(f"max_new:{CH},{AHEAD}{LOOP}")
+        try:
+            _serve_tokens(fw, list(prompts))
+        finally:
+            fw.close()
+        assert metrics.snapshot()["llm.serve.first_token_in_prefill"] \
+            - n0 == len(prompts)

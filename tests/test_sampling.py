@@ -393,3 +393,90 @@ class TestVerifyTransferBudget:
             assert len(set_tok_calls2) == admission_set_calls
         finally:
             fw.close()
+
+
+# ---------------------------------------------------------------------------
+# the first token is sampled and committed inside the prefill program
+# ---------------------------------------------------------------------------
+
+def _recipe_stream(fw, prompt, adm_no, n):
+    """The stream the serving contract (docs/SERVING.md §4d) prescribes
+    for ``prompt`` as the loop's ``adm_no``-th admission, computed with no
+    serve loop: the slot key is fold_in(PRNGKey(seed), adm_no) and the
+    token at absolute position p is drawn from the filtered logits with
+    that key folded at (p, TAG_SAMPLE).  The first token, at position T,
+    through ``sample_token`` — what the loop called eagerly before the
+    prefill program took the draw over."""
+    import jax
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.filters.llm import TAG_SAMPLE
+
+    slot_key = jax.random.fold_in(jax.random.PRNGKey(fw.seed), adm_no)
+    toks, out = [int(t) for t in prompt], []
+    for _ in range(n):
+        logits = llama.forward(fw.bundle.params, jnp.asarray([toks]),
+                               fw.cfg, compute_dtype=fw.dtype)[:, -1]
+        k = jax.random.fold_in(
+            jax.random.fold_in(slot_key, len(toks)), TAG_SAMPLE)
+        out.append(int(llama.sample_token(
+            logits, k, fw.temperature, fw.top_k, fw.top_p)[0]))
+        toks.append(out[-1])
+    return out, [int(v) for v in np.asarray(slot_key, np.uint32)]
+
+
+FIRST = ("max_new:6,stream_chunk:2,dtype:float32,serve:continuous,slots:2,"
+         "block_size:8,prefill_chunk:4")
+
+
+class TestFirstTokenInPrefill:
+    @pytest.mark.parametrize("sampler", [
+        "temperature:0.0,seed:3",
+        "temperature:0.9,seed:5",
+        "temperature:0.7,top_k:12,seed:11",
+        "temperature:1.1,top_p:0.8,seed:2",
+        "temperature:0.8,top_k:40,top_p:0.9,seed:7"],
+        ids=["greedy", "temperature", "top_k", "top_p", "top_k_top_p"])
+    def test_streams_are_the_recipes_token_for_token(self, sampler):
+        """Three admissions (single-chunk and multi-chunk prompts, the
+        third into a recycled slot): every stream, from its FIRST token
+        on, is what (seed, admission number, positions) prescribe."""
+        rng = np.random.default_rng(60)
+        prompts = [rng.integers(1, 500, (t,), np.int32) for t in (3, 9, 5)]
+        fw = _fw(f"{FIRST},{sampler}")
+        try:
+            want = [_recipe_stream(fw, p, i, 6)[0]
+                    for i, p in enumerate(prompts)]
+            # one after the other: the admission numbers are 0, 1, 2
+            got = [_serve_tokens(fw, [p])[0] for p in prompts]
+        finally:
+            fw.close()
+        assert got == want
+
+    @pytest.mark.parametrize("custom", [SAMPLED, SPEC],
+                             ids=["plain", "spec"])
+    def test_first_token_and_snapshot_key_are_the_recipes(self, custom):
+        """The slot key the program derived comes home with the first
+        token: a drained stream's snapshot carries
+        fold_in(PRNGKey(seed), admission number), word for word, and the
+        token that was sampled with it is the recipe's."""
+        prompt = np.asarray([3, 5, 7, 9, 11], np.int32)
+        fw = _fw(custom.replace("max_new:8", "max_new:64"))
+        try:
+            # admission 0 runs to its end; the drained stream is number 1
+            _serve_tokens(fw, [np.asarray([2, 4], np.int32)])
+            (first,), key = _recipe_stream(fw, prompt, 1, 1)
+            got = Collector()
+            seen = threading.Event()
+
+            def emit(tensors, meta):
+                got(tensors, meta)
+                seen.set()
+
+            fw.submit([prompt], {}, emit)
+            assert seen.wait(120)
+            snap = fw.drain_stream(got.sid, timeout=60)
+        finally:
+            fw.close()
+        assert got.ids[0] == first
+        assert snap["prng_key"] == key

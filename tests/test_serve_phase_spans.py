@@ -140,6 +140,37 @@ def test_iter_is_monotonic_dense_and_shared(traced):
     assert max(e.args["full_blocks"] for e in parents) > 0
 
 
+def test_the_chunk_that_commits_the_first_token_says_so(traced):
+    """``sampled`` is 1 on a prompt's final ``serve.prefill_chunk`` (its
+    program sampled and committed the first token) and 0 on every chunk
+    before it; there is one such chunk for every ``serve.first_token``."""
+    _bufs, spans = traced
+    chunks = [e for e in spans if e.kind == "serve.prefill_chunk"]
+    assert len(chunks) == 5                 # prompts of 1, 3 and 1 chunks
+    for e in chunks:
+        assert e.args["sampled"] == int(e.args["final"]), e
+    firsts = [e for e in spans if e.kind == "serve.first_token"]
+    assert sum(e.args["sampled"] for e in chunks) == len(firsts) \
+        == len(PROMPTS)
+    # a request's committing chunk is dispatched before its first token
+    # is fetched, in the same iteration
+    for f in firsts:
+        mine = [e for e in chunks if e.tid == f.tid and e.args["sampled"]]
+        assert len(mine) == 1
+        assert mine[0].ts + mine[0].dur <= f.ts
+        assert mine[0].args["iter"] == f.args["iter"]
+
+
+def test_the_counter_equals_the_first_token_spans():
+    from nnstreamer_tpu.core.log import metrics
+
+    n0 = metrics.snapshot().get("llm.serve.first_token_in_prefill", 0.0)
+    _bufs, _at_last, spans = _serve("ring")
+    n = metrics.snapshot()["llm.serve.first_token_in_prefill"] - n0
+    assert n == len(PROMPTS) == sum(
+        1 for e in spans if e.kind == "serve.first_token")
+
+
 def test_each_request_has_one_queue_and_one_prefill_that_meet(traced):
     bufs, spans = traced
     tids = {b.meta[tracing.META_TRACE_ID] for b in bufs}

@@ -747,3 +747,59 @@ class TestSpecDeepLint:
         assert elastic.SERVE_KNOB_SIGNATURE["draft"] is True
         assert elastic.SERVE_KNOB_SIGNATURE["spec_k"] is True
         assert elastic.SERVE_KNOB_SIGNATURE["prefix_cache"] is False
+
+
+# ---------------------------------------------------------------------------
+# the first token is committed by the target's prefill program (PR 37)
+# ---------------------------------------------------------------------------
+class TestSpecFirstTokenInPrefill:
+    SPEC = (BASE + ",serve:continuous,slots:2,block_size:8,prefill_chunk:4,"
+            "draft:llama_tiny,spec_k:3,draft_seed:7")
+
+    def test_multi_chunk_prompts_commit_once_and_streams_are_greedy(self):
+        """The speculative loop admits through the same ``prefill_step``:
+        prompts of one, two and four chunks (the third into a recycled
+        slot) each commit ONE first token, streams stay the plain greedy
+        decode's, and the two eager setters left on the admission path
+        (``tok_prev``, the position twin) are all ``_set_tok`` is called
+        for — the token itself no longer goes through it."""
+        rng = np.random.default_rng(21)
+        prompts = [rng.integers(1, 500, (t,), np.int32) for t in (3, 7, 14)]
+        want = [_plain_tokens(p, BASE) for p in prompts]
+        n0 = _metric("llm.serve.first_token_in_prefill")
+        fw = _fw(self.SPEC)
+        try:
+            # the loop is up and warm after one request
+            _serve_tokens(fw, [np.asarray([5, 6], np.int32)])
+            serve = fw._serve
+            sets, commits = [], []
+            real_set, real_prefill = serve._set_tok, serve._prefill
+
+            def set_tok(a, i, v):
+                sets.append((int(i), int(v)))
+                return real_set(a, i, v)
+
+            def prefill(*a):
+                commits.append(int(a[4][3]) < 2)
+                return real_prefill(*a)
+
+            serve._set_tok, serve._prefill = set_tok, prefill
+            got = _serve_tokens(fw, prompts)
+            census = [f._cache_size() for f in (
+                real_prefill, real_set, serve._draft_prefill,
+                serve._propose, serve._verify)]
+        finally:
+            fw.close()
+        for i, w in enumerate(want):
+            assert got[i] == w, f"stream {i}"
+        assert census == [1] * 5
+        # 1 + 2 + 4 chunks, three of them final
+        assert len(commits) == 7 and sum(commits) == 3
+        assert _metric("llm.serve.first_token_in_prefill") - n0 == 4
+        # an admission: tok_prev <- the prompt's last token, pos <- T;
+        # a retirement: pos <- the park position.  Never a sampled token.
+        admitted = [(int(p[-1]), len(p)) for p in prompts]
+        values = [v for _i, v in sets]
+        for last, T in admitted:
+            assert last in values and T in values
+        assert len(sets) == 3 * 2 + 3
