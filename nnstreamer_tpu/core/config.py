@@ -152,7 +152,7 @@ class Config:
     #: watchdog fires / pipeline errors dump the recent window),
     #: ``full`` = unbounded capture for short profiling runs
     trace_mode: str = "off"
-    #: span capacity of the ``ring`` trace mode
+    #: span capacity of the ``ring`` trace mode, shared by its lanes
     #: (``utils.tracing.DEFAULT_RING_CAPACITY`` says why this many)
     trace_ring_capacity: int = 262144
     #: nns-xray predicted-vs-actual reconciliation (utils/xray.py,

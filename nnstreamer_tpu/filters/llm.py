@@ -1947,13 +1947,14 @@ class _ContinuousLoop:
         def decode_closed(kind: str, sp_wait, **args) -> None:
             """The chunk (or speculative round) materialized: its ring
             span opened at the dispatch in step 3, so it is recorded from
-            stamps; the blocking wait alone was its profiler annotation
-            (held: never a ring span of its own)."""
+            stamps — ``dispatch_ns`` of it the host's time inside the
+            jitted call; the blocking wait alone was its profiler
+            annotation (held: never a ring span of its own)."""
             sp_wait.end(hold=True)
             rec.record(kind, _SERVE_STAGE, None, t_dec,
                        sp_wait.ts + sp_wait.dur - t_dec, iter=it,
                        occupancy=int(live.sum()), wait_ns=sp_wait.dur,
-                       **args)
+                       dispatch_ns=dispatch_ns, **args)
 
         def cow_copy(src: int, dst: int) -> None:
             """The device half of a copy-on-write fork the manager chose
@@ -2756,6 +2757,10 @@ class _ContinuousLoop:
                         params, tok, pool, kv.tabs(), pos.copy(),
                         keys_dev, length=fw.chunk)
                     pos[live] += fw.chunk  # parked rows stay parked
+                if rec is not None:
+                    # the jitted call(s) returned: the chip got this
+                    # iteration's work somewhere in here (serve_dispatch_pct)
+                    dispatch_ns = time.monotonic_ns() - t_dec
                 progressed = True
             metrics.gauge("llm.serve.occupancy", float(live.sum()))
             metrics.gauge("llm.serve.free_blocks", float(len(kv.free)))
@@ -2932,8 +2937,7 @@ class _ContinuousLoop:
                             waiting=len(self._waiting)
                             + len(self._admitting),
                             full_blocks=self.n_blocks - len(kv.free),
-                            win_blocks=kv.win_blocks_live(pos),
-                            conv_state_bytes=kv.conv_state_bytes)
+                            win_blocks=kv.win_blocks_live(pos))
                 if progressed:
                     n_iter = it
                     sp_iter.commit()

@@ -15,10 +15,12 @@ Three trace modes (``Config.trace_mode`` / ``Pipeline(trace_mode=...)``):
 * ``off``  — the default.  No recorder is installed: every hot-path hook
   reduces to one ``is not None`` check, and no meta stamps are written.
 * ``ring`` — always-on flight recorder: the last ``trace_ring_capacity``
-  spans in a ``deque(maxlen=...)``.  Appends are GIL-atomic (no lock on
-  the hot path); eviction is oldest-first.  This is the post-mortem mode:
-  watchdog fires and ``Pipeline._record_error`` dump the recent window to
-  the log automatically.
+  spans, one ``deque`` a stage (a *lane*); over the bound the LONGEST
+  lane gives up its oldest, so that a stage that records a span for
+  every token cannot evict the few a serve loop records an iteration.
+  Appends are GIL-atomic (no lock on the hot path).  This is the
+  post-mortem mode: watchdog fires and ``Pipeline._record_error`` dump
+  the recent window to the log automatically.
 * ``full`` — unbounded event list for short profiling runs that must not
   lose the head of the timeline.
 
@@ -50,6 +52,7 @@ from __future__ import annotations
 import collections
 import itertools
 import json
+import math
 import os
 import threading
 import time
@@ -118,7 +121,10 @@ SPAN_KINDS: Dict[str, str] = {
     "serve.decode": "continuous LLM serving: one paged decode chunk over "
                     "the live slots (args: iter, occupancy, chunk, "
                     "wait_ns = how long the host blocked on the chunk's "
-                    "tokens; opens at dispatch and closes at chunk "
+                    "tokens, dispatch_ns = the host's time inside the "
+                    "jitted call the span opens with, an upper estimate "
+                    "of how long the chip still had nothing; opens at "
+                    "dispatch and closes at chunk "
                     "materialization, so it covers the device time and "
                     "overlaps serve.first_token; its profiler "
                     "annotation, serve.decode.wait, covers the blocking "
@@ -147,7 +153,8 @@ SPAN_KINDS: Dict[str, str] = {
                       "move, no program touched)",
     "serve.spec_verify": "continuous LLM serving: one speculative round "
                          "(draft propose + k+1-wide target verify; "
-                         "args: iter, occupancy, k, wait_ns; closes at "
+                         "args: iter, occupancy, k, wait_ns, dispatch_ns "
+                         "= propose + verify calls' host time; closes at "
                          "round materialization like serve.decode; "
                          "annotation serve.spec_verify.wait)",
     "admit.shed": "query-server admission shed a request under backlog "
@@ -240,12 +247,16 @@ SPAN_KINDS: Dict[str, str] = {
 # are declared in core/meta_keys.py — the shared protocol registry —
 # and re-exported above for the existing importers.
 
-#: A traced serving pipeline records about four spans for every token it
-#: delivers, so this is what a 7B loop at 1,000 tokens/s on one chip writes
-#: in a minute (at 65,536 the ring held its last 16 s, less than the
-#: benchmark's 45 s window, and the ring readers lost the stretch the
-#: device profile is taken in — PERF.md §6, PR 27).  ≈ 0.4 KB a span, so
-#: 100 MB of host memory when full.
+#: Spans kept over all stages: ≈ 0.4 KB a span, so 100 MB of host memory
+#: when full, however many stages record.  Each stage has a lane of its
+#: own and only the longest lane is evicted from, so a lane keeps at
+#: least capacity / lanes (65,536 of a serving pipeline's four: source,
+#: filter, sink and the loop).  A serve loop writes about ten spans an
+#: iteration into its lane, ``llm.serve``, whatever the token rate: a few
+#: thousand in a minute, which the three or four spans the runtime writes
+#: for every token that crosses a sink can no longer evict (in ONE ring
+#: they left the loop's readers the newest 22–40 s of a 45 s window —
+#: PERF.md §6, PRs 27, 38).
 DEFAULT_RING_CAPACITY = 262144
 
 #: random 31-bit process epoch: the high half of every trace id minted by
@@ -302,21 +313,35 @@ class Span(NamedTuple):
 
 
 class FlightRecorder:
-    """Lock-cheap ring buffer of :class:`Span` events.
+    """Lock-free ring buffer of :class:`Span` events, one lane a stage.
 
-    The hot path is :meth:`record` → ``deque.append`` — GIL-atomic, so
-    concurrent runner threads never contend on a lock, and a bounded
-    ``maxlen`` deque evicts oldest-first without allocation churn.  The
-    lock below guards only cold operations (configure/clear/snapshot
-    consistency of mode flips).  ``active`` is the single attribute every
-    instrumentation site checks; with mode ``off`` callers hold ``None``
-    instead of the recorder, so the off cost is one pointer test.
+    The hot path is :meth:`record` → one dict lookup → ``deque.append`` —
+    GIL-atomic, so concurrent runner threads never contend on a lock.
+    ``capacity`` bounds the SUM of the lanes: once it is reached, every
+    span recorded takes the oldest span of the LONGEST lane out, so a
+    lane that holds no more than ``capacity / lanes`` is never evicted
+    from, and a stage that records a span for every token evicts only
+    itself.  The lock below guards only cold operations (configure/clear/
+    snapshot consistency of mode flips).  ``active`` is the single
+    attribute every instrumentation site checks; with mode ``off``
+    callers hold ``None`` instead of the recorder, so the off cost is one
+    pointer test.
     """
 
     def __init__(self, mode: str = "off",
                  capacity: int = DEFAULT_RING_CAPACITY):
         self._lock = threading.Lock()
-        self._ring: collections.deque = collections.deque(maxlen=capacity)
+        #: stage -> its spans in record order (a lane)
+        self._lanes: Dict[str, collections.deque] = {}
+        #: spans kept over all lanes; None = unbounded (``full``)
+        self._bound: Optional[int] = capacity
+        #: spans the lanes may still take before each evicts one; counted
+        #: without a lock, so a step may be lost: ``_trim`` counts anew
+        self._room: float = capacity
+        #: the lane that was longest at the last ``_trim``, and how many
+        #: evictions it serves before the next
+        self._victim: collections.deque = collections.deque()
+        self._tick = 0
         #: peer trace epoch -> (offset_ns, uncertainty_ns, sampled_at_ns)
         #: fed by the query handshake / periodic clock echoes (cold path)
         self._clock: Dict[int, "tuple[int, int, int]"] = {}
@@ -328,26 +353,18 @@ class FlightRecorder:
 
     def configure(self, mode: str,
                   capacity: Optional[int] = None) -> "FlightRecorder":
-        """Switch mode (off/ring/full).  ``ring`` bounds the buffer at
-        ``capacity`` spans; ``full`` is unbounded; ``off`` stops recording
-        but keeps already-captured events readable (post-mortem).
-
-        Re-configuring with the SAME bound keeps the live deque; changing
-        it rebuilds the deque (existing spans carried over), and a
-        concurrent lock-free ``record`` that already fetched the old
-        reference may land its span in the orphan — acceptable for a
-        flight recorder (reconfigure happens at pipeline construction,
-        not mid-stream, and loses at most the handful of spans in
-        flight), and the alternative is a lock on every hot-path append."""
+        """Switch mode (off/ring/full).  ``ring`` bounds the lanes'
+        sum at ``capacity`` spans; ``full`` is unbounded; ``off`` stops
+        recording but keeps already-captured events readable
+        (post-mortem).  The lanes live on through every switch; a smaller
+        bound trims the longest of them."""
         if mode not in ("off", "ring", "full"):
             raise ValueError(
                 f"trace_mode must be off|ring|full, got {mode!r}")
         with self._lock:
             cap = capacity or self.capacity or DEFAULT_RING_CAPACITY
-            if mode == "ring" and (self._ring.maxlen != cap):
-                self._ring = collections.deque(self._ring, maxlen=cap)
-            elif mode == "full" and self._ring.maxlen is not None:
-                self._ring = collections.deque(self._ring)
+            self._bound = {"ring": cap, "full": None}.get(mode, self._bound)
+            self._trim()
             self.mode = mode
             self.capacity = cap
             self.active = mode != "off"
@@ -356,23 +373,81 @@ class FlightRecorder:
     # -- hot path ----------------------------------------------------------
     def record(self, kind: str, stage: str, tid: Optional[int], /,
                ts_ns: int, dur_ns: int, **args) -> None:
-        """Append one span.  No lock: deque.append is GIL-atomic and the
-        ring's maxlen does the eviction.  ``kind``/``stage``/``tid`` are
+        """Append one span to its stage's lane and, once the lanes are
+        full, take one out.  No lock: the lookup, the ``setdefault`` that
+        makes a lane on a stage's first span, ``deque.append`` and
+        ``deque.popleft`` are each GIL-atomic.
+        ``kind``/``stage``/``tid`` are
         positional-only so ``args`` may carry a ``tid`` of its own (a
         request-bound span repeats its trace id there: consumers that
         are handed args alone still see which request it was)."""
-        self._ring.append(
-            Span(ts_ns, dur_ns, kind, stage, tid, args or None))
+        lanes = self._lanes
+        lane = lanes.get(stage)
+        if lane is None:
+            lane = lanes.setdefault(stage, collections.deque())
+        lane.append(Span(ts_ns, dur_ns, kind, stage, tid, args or None))
+        if self._room > 0:
+            self._room -= 1
+        else:
+            self._evict()
+
+    def _evict(self) -> None:
+        """One span in, one out (evicting in batches instead doubles the
+        cost of a record: a burst of allocations wakes the collector).
+        The longest lane is chosen anew every ``capacity / 256``
+        evictions; below 256 every time, so small rings are exact."""
+        self._tick -= 1
+        if self._tick >= 0:
+            try:
+                self._victim.popleft()
+                return
+            except IndexError:      # cleared, or trimmed by another thread
+                pass
+        self._trim()
+
+    def _trim(self) -> None:
+        """Bring the lanes' sum back to the bound, oldest spans of the
+        longest lane first, and name that lane the next evictions'
+        victim.  Lock-free like ``record``: two threads trimming at once
+        take a few spans too many, never too few."""
+        bound = self._bound
+        if bound is None:
+            self._room = math.inf
+            return
+        lanes = list(self._lanes.values()) or [collections.deque()]
+        lanes.sort(key=len)
+        excess = sum(map(len, lanes)) - bound
+        while excess > 0:
+            longest = lanes[-1]
+            # down to the runner-up; 64 at a time where lanes tie
+            lead = len(longest) - (len(lanes[-2]) if len(lanes) > 1 else 0)
+            n = min(excess, max(lead, 64))
+            excess -= n
+            try:
+                for _ in range(n):
+                    longest.popleft()
+            except IndexError:      # another thread trimmed it meanwhile
+                pass
+            lanes.sort(key=len)
+        self._victim = lanes[-1]
+        self._tick = bound >> 8
+        self._room = -excess
 
     # -- cold path ---------------------------------------------------------
     def events(self) -> List[Span]:
-        """Snapshot of the current ring, oldest first."""
-        return list(self._ring)
+        """Snapshot of every lane as one list, in close order (a span is
+        recorded as it closes; one recorded later from stamps, or held
+        and committed after its children, is put where it closed).
+        ≈ 50 ms of one core at 262,144 spans: for dumps and reports."""
+        evs = [e for lane in list(self._lanes.values()) for e in list(lane)]
+        evs.sort(key=lambda e: e.ts + e.dur)
+        return evs
 
     def clear(self) -> None:
-        self._ring.clear()
+        self._lanes = {}
         with self._lock:
             self._clock.clear()
+            self._trim()
 
     def note_clock(self, peer_epoch: int, offset_ns: int,
                    uncertainty_ns: int) -> None:
@@ -395,7 +470,7 @@ class FlightRecorder:
             return dict(self._clock)
 
     def __len__(self) -> int:
-        return len(self._ring)
+        return sum(len(lane) for lane in list(self._lanes.values()))
 
     def recent(self, seconds: float) -> List[Span]:
         """Spans whose END falls within ``seconds`` of the newest event
@@ -403,7 +478,7 @@ class FlightRecorder:
         evs = self.events()
         if not evs:
             return []
-        horizon = max(e.ts + e.dur for e in evs) - int(seconds * 1e9)
+        horizon = evs[-1].ts + evs[-1].dur - int(seconds * 1e9)
         return [e for e in evs if e.ts + e.dur >= horizon]
 
 

@@ -468,16 +468,17 @@ def test_a_slots_second_stream_is_that_stream_served_alone(toy, churn):
 
 def test_the_census_stays_three_and_the_spans_say_the_state(churn):
     """One signature a program however the slots churned, the routing
-    changed and the state's values moved; ``serve.iter`` carries the
-    state's bytes beside the blocks, ``serve.decode`` the five expert
-    counts; the pool's accounting reports the state."""
+    changed and the state's values moved; the pool's accounting reports
+    the state's bytes (a constant of the deployment: since PR 38 no span
+    repeats it every iteration), ``serve.decode`` the five expert
+    counts."""
     _, _, seen = churn
     assert seen["census"] == (1, 1, 1)
     state = 8 * 3 * 2 * 64 * 4      # conv layers x slots x 2 x D, float32
     assert seen["stats"]["conv_state_bytes"] == state
     assert seen["stats"]["blocks_free"] == seen["stats"]["blocks_total"]
     iters = [e.args for e in seen["events"] if e.kind == "serve.iter"]
-    assert iters and all(a["conv_state_bytes"] == state for a in iters)
+    assert iters and not any("conv_state_bytes" in a for a in iters)
     dec = [e.args for e in seen["events"] if e.kind == "serve.decode"]
     assert dec and all(
         {"moe_pairs", "moe_experts_hit", "moe_max_per_expert",

@@ -31,8 +31,12 @@ def _desc(extra=""):
     return ("appsrc name=src ! tensor_filter framework=llm "
             f"model=llama_tiny custom=max_new:{MAX_NEW},serve:continuous,"
             "slots:2,temperature:0.0,block_size:8,prefill_chunk:8,"
-            f"stream_chunk:2{extra} invoke-dynamic=true ! "
+            f"stream_chunk:2{extra} invoke-dynamic=true name=f ! "
             "tensor_sink name=out")
+
+
+#: what the loop's accounting said at the end of the last ``_serve``
+POOL_STATS = {}
 
 
 def _serve(trace_mode, extra="", idle_s=0.2):
@@ -51,6 +55,8 @@ def _serve(trace_mode, extra="", idle_s=0.2):
         at_last = [e for e in recorder.events() if e.stage == STAGE]
         time.sleep(idle_s)
         after = [e for e in recorder.events() if e.stage == STAGE]
+        POOL_STATS.clear()
+        POOL_STATS.update(p.element("f").fw._serve.pool_stats())
         p.eos("src")
         p.wait(timeout=120)
     recorder.configure("off")
@@ -61,7 +67,7 @@ def _serve(trace_mode, extra="", idle_s=0.2):
 @pytest.fixture(scope="module")
 def traced():
     bufs, _at_last, spans = _serve("ring")
-    return bufs, spans
+    return bufs, spans, dict(POOL_STATS)
 
 
 def _by_iter(spans):
@@ -77,7 +83,7 @@ def _inside(e, parent):
 
 
 def test_every_iteration_holds_its_children_without_overlap(traced):
-    _bufs, spans = traced
+    _bufs, spans, _stats = traced
     iters = _by_iter(spans)
     assert len(iters) >= 3
     parent_of = {}
@@ -113,14 +119,14 @@ def test_every_iteration_holds_its_children_without_overlap(traced):
 
 
 def test_children_sum_to_no_more_than_the_parent(traced):
-    _bufs, spans = traced
+    _bufs, spans, _stats = traced
     for parent in (e for e in spans if e.kind == "serve.iter"):
         assert sum(e.dur for e in spans
                    if e.kind in TILING and _inside(e, parent)) <= parent.dur
 
 
 def test_iter_is_monotonic_dense_and_shared(traced):
-    _bufs, spans = traced
+    _bufs, spans, stats = traced
     parents = [e for e in spans if e.kind == "serve.iter"]
     nums = [e.args["iter"] for e in sorted(parents, key=lambda e: e.ts)]
     assert nums == list(range(1, len(nums) + 1))
@@ -134,8 +140,9 @@ def test_iter_is_monotonic_dense_and_shared(traced):
     # since PR 29 the parent also gauges the blocks live in each pool (the
     # window pool's stay 0 on a model without window layers)
     assert set(parents[-1].args) == {"iter", "live", "waiting",
-                                     "full_blocks", "win_blocks",
-                                     "conv_state_bytes"}
+                                     "full_blocks", "win_blocks"}
+    # a constant of the deployment is the accounting's, not every span's
+    assert stats["conv_state_bytes"] == 0
     assert all(e.args["win_blocks"] == 0 for e in parents)
     assert max(e.args["full_blocks"] for e in parents) > 0
 
@@ -144,7 +151,7 @@ def test_the_chunk_that_commits_the_first_token_says_so(traced):
     """``sampled`` is 1 on a prompt's final ``serve.prefill_chunk`` (its
     program sampled and committed the first token) and 0 on every chunk
     before it; there is one such chunk for every ``serve.first_token``."""
-    _bufs, spans = traced
+    _bufs, spans, _stats = traced
     chunks = [e for e in spans if e.kind == "serve.prefill_chunk"]
     assert len(chunks) == 5                 # prompts of 1, 3 and 1 chunks
     for e in chunks:
@@ -172,7 +179,7 @@ def test_the_counter_equals_the_first_token_spans():
 
 
 def test_each_request_has_one_queue_and_one_prefill_that_meet(traced):
-    bufs, spans = traced
+    bufs, spans, _stats = traced
     tids = {b.meta[tracing.META_TRACE_ID] for b in bufs}
     assert len(tids) == len(PROMPTS)
     for tid in tids:
@@ -200,11 +207,15 @@ def test_each_request_has_one_queue_and_one_prefill_that_meet(traced):
 
 
 def test_decode_wait_is_part_of_the_decode_span(traced):
-    _bufs, spans = traced
+    _bufs, spans, _stats = traced
     dec = [e for e in spans if e.kind == "serve.decode"]
     assert dec
     for e in dec:
         assert 0 <= e.args["wait_ns"] <= e.dur
+        # the jitted call's own host time, stamped at its return: the
+        # chip had nothing before it, and the span covers it
+        assert 0 < e.args["dispatch_ns"] <= e.dur
+        assert e.args["dispatch_ns"] + e.args["wait_ns"] <= e.dur
         assert e.args["chunk"] == 2 and e.args["occupancy"] >= 1
     # the wait is an annotation alone: the ring keeps the whole decode
     assert not [e for e in spans if e.kind.endswith(".wait")]
@@ -272,6 +283,38 @@ def test_deliveries_under_the_next_chunk_are_marked_and_counted():
     assert emits[-1].args["ahead"] == 0 and emits[-1].args["retired"] >= 1
 
 
+def test_an_idle_delivery_is_known_by_where_it_lies(traced):
+    """What ``serve_gap_emit_pct`` reads needs no arg of its own: the
+    delivery made with nothing queued on the chip is the ``ahead`` = 0
+    span inside the iteration that dispatched its chunk, after that
+    chunk closed and before anything else is dispatched; one put off
+    lies in the NEXT iteration, under its chunk."""
+    _bufs, spans, _stats = traced
+    parent_of = {e.args["iter"]: e for e in spans if e.kind == "serve.iter"}
+    decode_of = {e.args["iter"]: e for e in spans
+                 if e.kind == "serve.decode"}
+    emits = [e for e in spans if e.kind == "serve.emit"]
+    idle = [e for e in emits if _inside(e, parent_of[e.args["iter"]])]
+    assert idle and len(idle) < len(emits)
+    for e in idle:
+        dec = decode_of[e.args["iter"]]
+        assert e.args["ahead"] == 0 and dec.ts + dec.dur <= e.ts
+        # nothing was dispatched between the chunk's close and its end
+        assert not [x for x in spans
+                    if x.kind in ("serve.decode", "serve.prefill_chunk")
+                    and dec.ts + dec.dur <= x.ts < e.ts + e.dur]
+    for e in emits:
+        if e.args["ahead"]:
+            nxt = decode_of[e.args["iter"] + 1]
+            assert nxt.ts <= e.ts and _inside(
+                e, parent_of[e.args["iter"] + 1])
+    # the first stream ended with the third prompt waiting for its slot:
+    # that chunk's delivery was put off
+    first_end = min((e for e in emits if e.args["retired"]),
+                    key=lambda e: e.ts)
+    assert first_end not in idle
+
+
 def test_an_idle_loop_records_nothing():
     _bufs, at_last, after = _serve("ring", idle_s=0.4)
     # twenty spins of the idle loop later, at most the iteration that
@@ -284,7 +327,7 @@ def test_an_idle_loop_records_nothing():
 
 
 def test_span_kinds_names_every_kind_the_loop_records(traced):
-    _bufs, spans = traced
+    _bufs, spans, _stats = traced
     kinds = {e.kind for e in spans}
     assert kinds >= TILING | {"serve.iter", "serve.decode", "serve.admit",
                               "serve.queue", "serve.prefill"}
@@ -374,12 +417,64 @@ def test_off_mode_records_nothing_and_constructs_no_annotation(monkeypatch):
     assert set(emit.kw) == {"iter", "tokens", "retired", "ahead"}
 
 
+class _CountingClock:
+    """Stands in for the ``time`` module in ``filters/llm.py``."""
+
+    def __init__(self):
+        self.ns_calls = 0
+
+    def monotonic_ns(self):
+        self.ns_calls += 1
+        return time.monotonic_ns()
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_off_mode_takes_no_stamp_for_the_dispatch(monkeypatch):
+    """The dispatch's return is stamped only where a recorder is live:
+    with ``trace_mode=off`` the loop reads the nanosecond clock once a
+    decode dispatch (``t_dec``, as before) and once an admission, and
+    that is all; traced, once more for every dispatch."""
+    from nnstreamer_tpu.filters import llm
+
+    dispatches = []
+    real_init = llm._ContinuousLoop.__init__
+
+    def init(self, fw):
+        real_init(self, fw)
+        decode = self._decode
+
+        def counted(*a, **kw):
+            dispatches.append(1)
+            return decode(*a, **kw)
+
+        self._decode = counted
+
+    monkeypatch.setattr(llm._ContinuousLoop, "__init__", init)
+    clock = _CountingClock()
+    monkeypatch.setattr(llm, "time", clock)
+    bufs, _at_last, spans = _serve("off")
+    assert len(bufs) == MAX_NEW * len(PROMPTS) and spans == []
+    # a loop's first call of the program is its warm-up, outside the loop
+    n_off = len(dispatches) - 1
+    assert n_off > 0 and clock.ns_calls == n_off + len(PROMPTS)
+    off = clock.ns_calls
+    _bufs, _at_last, spans = _serve("ring")
+    n_ring = len(dispatches) - n_off - 2
+    assert n_ring == sum(1 for e in spans if e.kind == "serve.decode")
+    # t_dec and the stamp at the call's return (an admission's stamp is
+    # its span's own start there)
+    assert clock.ns_calls - off == 2 * n_ring
+
+
 def test_speculative_rounds_carry_iter_and_wait():
     _bufs, _at_last, spans = _serve("ring", ",draft:llama_tiny,spec_k:2")
     rounds = [e for e in spans if e.kind == "serve.spec_verify"]
     assert rounds and not [e for e in spans if e.kind == "serve.decode"]
     for e in rounds:
         assert 0 <= e.args["wait_ns"] <= e.dur and e.args["k"] == 2
+        assert 0 < e.args["dispatch_ns"] <= e.dur
     assert {e.args["iter"] for e in rounds} == \
         {e.args["iter"] for e in spans if e.kind == "serve.emit"}
     assert sum(e.args["tokens"] for e in spans if e.kind == "serve.emit") \

@@ -102,6 +102,154 @@ def test_recent_window():
     assert [e.stage for e in spans] == ["new"]
 
 
+# -- lanes: a span is evicted only by later spans of its own stage ----------
+
+def _serve_like(rec, n=10, t0=1_000):
+    """``n`` closed spans of the serve loop's stage, 1 µs apart."""
+    for i in range(n):
+        rec.record("serve.decode", "llm.serve", None, ts_ns=t0 + i * 1000,
+                   dur_ns=500, iter=i)
+
+
+def test_per_token_spans_cannot_evict_the_serve_loops():
+    """(i) 300,000 spans of a sink, more than the capacity, leave the ten
+    spans the serve loop recorded before them where they were, and the
+    sum of the lanes never passes the capacity."""
+    cap = tracing.DEFAULT_RING_CAPACITY
+    rec = FlightRecorder("ring")            # the default capacity
+    _serve_like(rec)
+    for i in range(300_000):
+        rec.record("e2e", "out", i, 100_000 + i, 1)
+        if i % 50_000 == 0:
+            assert len(rec) == min(cap, i + 11)
+    evs = rec.events()
+    assert [e.args["iter"] for e in evs if e.stage == "llm.serve"] \
+        == list(range(10))
+    out = [e.tid for e in evs if e.stage == "out"]
+    assert out == list(range(300_000 - len(out), 300_000))
+    assert len(rec) == len(evs) == cap
+
+
+def test_within_a_stage_the_oldest_closed_goes_first():
+    """(ii) What ``benchmark/ring_spans.covered_window`` argues from:
+    every span of a stage that ended after the earliest surviving one is
+    still there, whatever the other stages recorded meanwhile — and a
+    lane is never cut below its share, capacity / lanes."""
+    rec = FlightRecorder("ring", capacity=8)
+    for i in range(20):
+        rec.record("serve.decode", "llm.serve", None, ts_ns=i * 1000,
+                   dur_ns=900, iter=i)
+        for j in range(50):
+            rec.record("fetch", "out", j, i * 1000 + j, 1)
+        assert len(rec) == min(8, 51 * (i + 1))
+    mine = [e for e in rec.events() if e.stage == "llm.serve"]
+    assert [e.args["iter"] for e in mine] == list(range(16, 20))
+    first_end = min(e.ts + e.dur for e in mine)
+    assert all(e.ts + e.dur >= first_end for e in mine)
+    assert [e.tid for e in rec.events() if e.stage == "out"] \
+        == list(range(46, 50))
+
+
+def test_many_stages_share_one_bound():
+    """The always-on mode's host memory does not grow with the number of
+    elements: forty stages that each record past the capacity hold the
+    capacity between them, in equal shares."""
+    rec = FlightRecorder("ring", capacity=4096)
+    for i in range(6000):
+        for s in range(40):
+            rec.record("stage", f"el{s}", i, i * 1000 + s, 10)
+    assert len(rec) == 4096
+    sizes = [len(lane) for lane in rec._lanes.values()]
+    assert len(sizes) == 40 and max(sizes) - min(sizes) <= 4096 >> 8
+
+
+def test_concurrent_records_hold_the_bound():
+    """No lock on the hot path: four threads recording into three lanes
+    raise nothing, and the sum ends at the bound but for the steps that
+    raced (a trim counts anew)."""
+    import threading
+
+    rec = FlightRecorder("ring", capacity=2048)
+    errors = []
+
+    def work(k):
+        try:
+            for i in range(20_000):
+                rec.record("stage", f"el{k % 3}", i, i * 1000 + k, 10)
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    threads = [threading.Thread(target=work, args=(k,)) for k in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert errors == []
+    rec.record("stage", "el0", 0, 0, 1)     # a quiet record settles it
+    rec._trim()
+    assert len(rec) == 2048
+    assert min(len(lane) for lane in rec._lanes.values()) >= 2048 // 3 - 64
+
+
+def test_readers_see_every_lane_as_one_list_in_close_order(caplog):
+    """(iii) ``events``, ``recent``, ``len``, ``clear``, ``configure``,
+    ``to_chrome`` and the watchdog's dump over three lanes."""
+    rec = FlightRecorder("ring", capacity=16)
+    now = time.monotonic_ns()
+    rec.record("serve.iter", "llm.serve", None, now - 9_000_000, 8_000_000)
+    rec.record("queue", "f", 1, now - 8_000_000, 1_000_000)
+    rec.record("e2e", "out", 1, now - 8_000_000, 5_000_000)
+    # recorded last from stamps, closed first
+    rec.record("serve.queue", "llm.serve", 1, now - 20_000_000_000, 1_000)
+    evs = rec.events()
+    assert [e.kind for e in evs] == ["serve.queue", "queue", "e2e",
+                                     "serve.iter"]
+    assert [e.ts + e.dur for e in evs] == sorted(e.ts + e.dur for e in evs)
+    assert len(rec) == 4
+    assert [e.kind for e in rec.recent(1.0)] == ["queue", "e2e", "serve.iter"]
+    obj = to_chrome(evs)
+    assert validate_chrome(obj) == []
+    assert {m["args"]["name"] for m in obj["traceEvents"]
+            if m["name"] == "thread_name"} == {"llm.serve", "f", "out"}
+    with caplog.at_level(logging.ERROR, logger="test.tracing"):
+        n = tracing.dump_recent_to_log(logging.getLogger("test.tracing"),
+                                       seconds=1.0, rec=rec)
+    assert n == 3 and "serve.iter" in caplog.text and "e2e" in caplog.text
+    # the lanes live through a switch; a smaller bound trims the longest
+    lanes = dict(rec._lanes)
+    rec.configure("ring", capacity=16)
+    assert all(rec._lanes[k] is v for k, v in lanes.items())
+    rec.configure("ring", capacity=3)
+    assert sorted(e.kind for e in rec.events()) == ["e2e", "queue",
+                                                    "serve.queue"]
+    rec.configure("full")
+    for i in range(40):
+        rec.record("fetch", "out", i, now + i, 1)
+    assert len(rec) == 43
+    rec.configure("off")
+    assert len(rec.events()) == 43          # still readable, post-mortem
+    rec.clear()
+    assert len(rec) == 0 and rec.events() == [] and rec.recent(1.0) == []
+
+
+def test_two_lanes_survive_dump_load_and_merge(tmp_path):
+    rec = FlightRecorder("ring", capacity=7)
+    _serve_like(rec, n=3)
+    for i in range(9):
+        rec.record("e2e", "out", 100 + i, 2_000 + i * 1000, 10)
+    path = str(tmp_path / "a.ring")
+    assert tracing.dump_ring(path, rec, proc="server") == 7
+    ring = tracing.load_ring(path)
+    assert ring["spans"] == rec.events() and ring["proc"] == "server"
+    assert [s.stage for s in ring["spans"]].count("llm.serve") == 3
+    obj, stats = tracing.merge_rings([ring, ring])
+    assert stats["spans"] == 14 and validate_chrome(obj) == []
+    tracks = {(m["pid"], m["args"]["name"]) for m in obj["traceEvents"]
+              if m["name"] == "thread_name"}
+    assert tracks == {(1, "llm.serve"), (1, "out"),
+                      (2, "llm.serve"), (2, "out")}
+
+
 # -- trace-id propagation --------------------------------------------------
 
 def test_trace_ids_assigned_and_unique():
