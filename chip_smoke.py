@@ -358,15 +358,17 @@ def serving_leg(model: str = "llama2_7b", n_layers: int = 0,
 def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
                dim: int = 4096, ffn: int = 11008, slots: int = 4,
                block_size: int = 16, seq: int = 128,
-               paged_heads=((32, 32), (32, 8), (64, 8)),
+               paged_heads=((32, 32), (32, 8), (64, 8), (20, 1)),
                paged_lens=(0, 1, 94, 128, 129, 300, 544, 800),
                window: int = 128, grouped_dims=(6144, 2048),
                grouped=((64, 8, 128, 16, 2), (64, 12, 768, 16, 2),
                         (64, 4, 64, 64, 2, (2048, 1536))),
                paged_narrow=((32, 8, 64),),
-               chunk_heads=((32, 8, 128), (64, 8, 128), (32, 8, 64)),
+               chunk_heads=((32, 8, 128), (64, 8, 128), (32, 8, 64),
+                            (20, 1, 128)),
                chunks=((32, (0, 32), 0.01), (32, (0, 544, 4096), 0.008),
                        (256, (0, 544, 4096), 0.008)),
+               ssm=((64, 2, 64, 16, 5120),),
                interpret=None) -> dict:
     """flash_attention, paged_attention, matmul_int4 and grouped_swiglu at
     the serving leg's shapes, called as models/llama.py and models/moe.py
@@ -408,7 +410,18 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
     stack-wide vector at the second layer — with
     the sizes the cell's router gives (every row's choices uniform over
     the router's outputs) and with skewed ones: half the rows on one
-    expert (several visits), a quarter on another, seven on the last."""
+    expert (several visits), a quarter on another, seven on the last.
+
+    The state-space update kernel (ops/ssm.py) runs at ``ssm`` (rows,
+    layers of the state leaf, slots, state size, channels: the
+    state-space cell's widths over two of its 26 layers) against its
+    ``jax.numpy`` twin, the rows' slots a permutation and every fourth
+    row parked, every fifth a stream's first token: its result ``y`` in
+    bfloat16 to 0.02 of the largest (a few bf16 steps of a sum over 16),
+    the whole leaf after the update — the live rows' tiles and the
+    untouched rest — to 1e-5 (float32 arithmetic, its order apart).  The
+    paged kernel and the chunk kernel also run at the cell's 20 query
+    heads over one KV head."""
     import jax
     import jax.numpy as jnp
 
@@ -543,6 +556,30 @@ def kernel_leg(n_heads: int = 32, n_kv_heads=(32, 8), head_dim: int = 128,
                     own, GF.grouped_swiglu_reference(x, g, u, d, n), 0),
                 (xs, *ws, jnp.asarray(groups)), 0.03)
         del xs, ws
+
+    from nnstreamer_tpu.ops import ssm as S
+
+    for rows_s, layers_s, slots_s, N, E in ssm:
+        keys = jax.random.split(jax.random.PRNGKey(N * E), 8)
+        state = jax.random.normal(keys[0], (layers_s, slots_s, N, E))
+        ids = jnp.asarray(rng.permutation(slots_s)[:rows_s], jnp.int32)
+        live = jnp.arange(rows_s) % 4 != 3
+        fresh = jnp.arange(rows_s) % 5 == 1
+        step = jax.nn.softplus(jax.random.normal(keys[1], (rows_s, E)) - 4)
+        a = -jnp.broadcast_to(jnp.arange(1, N + 1, dtype=jnp.float32)[
+            :, None], (N, E))
+        args = (state, jnp.int32(layers_s - 1), ids, live, fresh,
+                arr((rows_s, E)), step, arr((rows_s, E)),
+                jax.random.normal(keys[2], (rows_s, N)),
+                jax.random.normal(keys[3], (rows_s, N)), a,
+                jnp.ones((E,), jnp.float32))
+        for part, tol in ((0, 0.02), (1, 1e-5)):
+            run(f"ssm_update {rows_s}x{N}x{E} "
+                + ("y" if part == 0 else "state"),
+                lambda *x, part=part: S.ssm_update(*x, **kw)[part],
+                lambda *x, part=part: S.ssm_update_reference(*x)[part],
+                args, tol)
+        del state, args
     return {"rel_err": errs}
 
 

@@ -7,7 +7,8 @@ decided here, by one ``BlockManager`` the scheduler calls.  The split:
 
 * **Host state lives here** — the free list, per-block reference counts,
   per-slot block lists, the block table, the window layers' ring
-  table and the slot ids that name convolution layers' state, the prefix
+  table and the slot ids that name convolution and state-space layers'
+  state, the prefix
   chain index — and nothing else writes it.
 * **Device state stays with the loop** — the pools, the token / key /
   position vectors and every compiled program.  A copy-on-write fork is
@@ -50,7 +51,8 @@ class BlockManager:
     def __init__(self, *, slots: int, block_size: int, prefill_chunk: int,
                  n_blocks: int, max_blocks: int, win_ring: int,
                  win_blocks: int, prefix_cache: bool,
-                 count: Callable[..., None], conv_state_bytes: int = 0):
+                 count: Callable[..., None], conv_state_bytes: int = 0,
+                 ssm_state_bytes: int = 0):
         self.bs, self.chunk = block_size, prefill_chunk
         self.n_blocks = n_blocks
         self.win_ring, self.win_blocks = win_ring, win_blocks
@@ -59,18 +61,24 @@ class BlockManager:
         #: columns are row s of the pool's ``conv`` leaf, which no
         #: allocator touches and no table but ``slot_ids`` names
         self.conv_state_bytes = conv_state_bytes
+        #: bytes of the state-space layers' float32 state, likewise (row
+        #: s of the pool's ``ssm`` leaf is slot s's)
+        self.ssm_state_bytes = ssm_state_bytes
+        #: whether some layer keeps state by slot: the programs are then
+        #: handed the slots' ids
+        self.slot_state = bool(conv_state_bytes or ssm_state_bytes)
         self.slot_ids = np.arange(slots, dtype=np.int32)
         self.sentinel = n_blocks  # unallocated table entry
         self.park = max_blocks * block_size  # idle-slot position
         self._count = count
         #: a window layer's K/V live in the ring of the slot that wrote
-        #: them and nowhere else, and a convolution layer's state at the
-        #: end of a shared prefix is in no block at all (a hit would
-        #: continue from zeros), so on a model with either no other
-        #: stream can resume from a cached prefix: every lookup is a
-        #: miss and nothing is indexed (docs/SERVING.md §4e)
+        #: them and nowhere else, and a convolution or state-space layer's
+        #: state at the end of a shared prefix is in no block at all (a
+        #: hit would continue from zeros), so on a model with either no
+        #: other stream can resume from a cached prefix: every lookup is
+        #: a miss and nothing is indexed (docs/SERVING.md §4e)
         self.share_prefix = bool(prefix_cache) and not win_ring \
-            and not conv_state_bytes
+            and not self.slot_state
         self.tables = np.full((slots, max_blocks), self.sentinel, np.int32)
         #: window layers: slot s owns blocks [s * ring, (s + 1) * ring)
         #: of the window pool for good — logical block j of its stream
@@ -107,13 +115,13 @@ class BlockManager:
         copy of the block table (dispatch is asynchronous and the tables
         are mutated in place between dispatches), with the ring table
         beside it where the model has window layers and the slots' own
-        ids where convolution layers keep state by slot."""
-        if not self.win_ring and not self.conv_state_bytes:
+        ids where convolution or state-space layers keep state by slot."""
+        if not self.win_ring and not self.slot_state:
             return self.tables[rows].copy()
         out = {"full": self.tables[rows].copy()}
         if self.win_ring:
             out["win"] = self.win_tables[rows]
-        if self.conv_state_bytes:
+        if self.slot_state:
             out["slot"] = self.slot_ids[rows]
         return out
 
@@ -330,6 +338,8 @@ class BlockManager:
             "win_ring": self.win_ring,
             # convolution layers' state: every slot's, for good
             "conv_state_bytes": self.conv_state_bytes,
+            # state-space layers' float32 state: every slot's, for good
+            "ssm_state_bytes": self.ssm_state_bytes,
             # prefix-sharing accounting: blocks whose content + chain
             # hash are indexed (many resting in the free list at
             # refcount 0), and blocks currently mapped by >1 stream

@@ -301,9 +301,14 @@ def serving_plan(cfg, *, slots: int, block_size: int = 16,
       the mathematics reads: the padding is the kernel's cost).
     * ``conv_state_bytes`` — convolution layers (``LayerKind.conv``) keep
       ``conv_taps - 1`` columns a stream a layer whatever its context,
-      owned by the slot like a ring: ``slots`` streams' worth, counted in
+      and state-space layers (``LayerKind.ssm``) the last
+      ``ssm_conv_taps - 1`` columns of their filter's input, owned by
+      the slot like a ring: ``slots`` streams' worth, counted in
       ``pool_bytes``; 0 without such layers.  A decode step reads and
       writes all of it, which no context length changes.
+    * ``ssm_state_bytes`` — a state-space layer's float32 state,
+      ``ssm_state x ssm_inner`` a stream a layer, owned and counted
+      alike; 0 without such layers.
     * ``programs`` — compiled XLA signatures the standing loop ever
       uses.  Without speculation: the ``[slots]``-row paged decode
       chunk, the ``[1, prefill_chunk]`` prefill step, and the slot-token
@@ -352,6 +357,7 @@ def serving_plan(cfg, *, slots: int, block_size: int = 16,
         "win_ring": win_ring,
         "win_blocks": int(slots) * win_ring,
         "conv_state_bytes": _llama.conv_state_bytes(cfg, slots, dtype),
+        "ssm_state_bytes": _llama.ssm_state_bytes(cfg, slots),
         "draft_pool_bytes": (
             _llama.paged_cache_bytes(draft_cfg, n_blocks, bs, dtype=dtype)
             if draft_cfg is not None else 0),
@@ -551,7 +557,8 @@ class LLMFramework(Framework):
                     "state is advanced by every token the step is fed, "
                     "so a rejected tail would already have moved it "
                     "past the accepted prefix, and nothing keeps the "
-                    "columns to step back to; this target has "
+                    "columns to step back to, nor a state-space layer's "
+                    "state matrix; this target has "
                     f"{llama.pattern_traits(self.cfg)} "
                     "(self-drafting from a prediction head: ROADMAP M1)")
         if self.draft_name:
@@ -831,6 +838,14 @@ class LLMFramework(Framework):
                 "and the convolution layers' last columns (per slot, in "
                 "no block) have no place in it; continuing from such a "
                 "snapshot would filter from zeros")
+        if self.cfg is not None and self.cfg.n_ssm_layers:
+            raise FrameworkError(
+                f"{what} of a slot that owns state-space state is not "
+                "built: a snapshot carries the allocator's blocks only, "
+                "and a state-space layer's state matrix and filter "
+                "columns (per slot, in no block) have no place in it; "
+                "continuing from such a snapshot would scan from zeros "
+                f"(this model has {llama.pattern_traits(self.cfg)})")
 
     def snapshot_problems(self, snapshot: Dict) -> List[str]:
         """Compatibility problems adopting ``snapshot`` here (empty =
@@ -1145,6 +1160,9 @@ class _ContinuousLoop:
         #: whether the decode chunk returns the expert layers' routing
         #: counts, as extra rows of its token matrix
         self._moe = cfg.experts is not None
+        #: whether the model has state-space layers: their spans' args
+        #: and the reset counter (docs/OBSERVABILITY.md)
+        self._ssm = bool(cfg.n_ssm_layers)
         #: the host side of the paged cache (filters/kv_blocks.py): free
         #: list, refcounts, block and ring tables, prefix chain index.
         #: The scheduler below calls it; the pools stay device state of
@@ -1154,7 +1172,8 @@ class _ContinuousLoop:
             n_blocks=self.n_blocks, max_blocks=self.max_blocks,
             win_ring=self.win_ring, win_blocks=self.win_blocks,
             prefix_cache=fw.prefix_cache, count=metrics.count,
-            conv_state_bytes=plan["conv_state_bytes"])
+            conv_state_bytes=plan["conv_state_bytes"],
+            ssm_state_bytes=plan["ssm_state_bytes"])
         self.sentinel = self.kv.sentinel  # unallocated table entry
         self.park = self.kv.park  # idle-slot position
         #: per-slot host bookkeeping the serve thread mutates in place
@@ -1290,7 +1309,7 @@ class _ContinuousLoop:
                 compute_dtype=fw.dtype, logit_off=logit_off,
                 # the chunk's real tokens end at ``logit_off``: where
                 # state that no position addresses is taken
-                n_valid=logit_off + 1 if cfg.n_conv_layers else None)
+                n_valid=logit_off + 1 if cfg.slot_state else None)
             # the first token's position is the prompt's length
             first, tok, keys = _commit_first_token(
                 logits[:, 0], tok, keys, base_key, adm_no, slot,
@@ -1312,6 +1331,9 @@ class _ContinuousLoop:
         #: chunk kernel (ops/attention.py), counted on its trace at the
         #: warm-up: its attention layers without a window, or none
         self.attn_kernel_layers = 0
+        #: the decode step's state-space layers whose update took the
+        #: kernel (ops/ssm.py), counted on its trace at the warm-up
+        self.ssm_kernel_layers = 0
         # slot-vector updates by value where no program commits them: an
         # adopted stream's token, the speculative loop's tok_prev and
         # position twins (slot index and value traced: ONE program)
@@ -1816,6 +1838,7 @@ class _ContinuousLoop:
         import jax.numpy as jnp
 
         from ..ops import attention as _attn
+        from ..ops import ssm as _ssm
 
         fw, cfg = self.fw, self.fw.cfg
         B, bs, C = fw.slots, fw.block_size, fw.prefill_chunk
@@ -2127,11 +2150,19 @@ class _ContinuousLoop:
                 kv.tabs(), pos_dev, keys_dev)
             np.asarray(em_w)
         else:
-            toks_w, tok, pool = self._decode(
-                params, tok, pool, kv.tabs(), pos.copy(), keys_dev,
-                length=fw.chunk)
+            warm = (params, tok, pool, kv.tabs(), pos.copy(), keys_dev)
+            if self._ssm:
+                # traced once, as the prefill above: a turn of the chunk's
+                # step scan is one decode step
+                self.ssm_kernel_layers = _attn.kernel_calls(
+                    self._decode.trace(*warm, length=fw.chunk).jaxpr,
+                    _ssm.KERNEL) // fw.chunk
+            toks_w, tok, pool = self._decode(*warm, length=fw.chunk)
             np.asarray(toks_w)
         kv.release(0)
+        # the decode span's state-space arg: a constant of the program
+        ssm_args = ({"ssm_kernel_layers": self.ssm_kernel_layers}
+                    if self._ssm else {})
 
         # Everything alive now lives as long as the loop does: modules,
         # jax's internals, the compiled programs.  A full collection
@@ -2625,17 +2656,22 @@ class _ContinuousLoop:
                 while budget > 0 and st["p"] < st["P"]:
                     s, p = st["slot"], st["p"]
                     final = p + C >= st["P"]
-                    if rec is not None:
-                        sp = begin("serve.prefill_chunk",
-                                   st.get("trace", _NO_TRACE)[0], iter=it,
-                                   slot=s, pos=p, final=bool(final),
-                                   attn_kernel_layers=self.attn_kernel_layers)
-                    if self.attn_kernel_layers:
-                        metrics.count("llm.serve.prefill_attn_kernel")
                     # last REAL token's offset within this chunk
                     # (intermediate chunks are all real tokens; their
                     # logits are unused, their slot-owned state is not)
                     off = st["T"] - 1 - p if final else C - 1
+                    if rec is not None:
+                        sp = begin("serve.prefill_chunk",
+                                   st.get("trace", _NO_TRACE)[0], iter=it,
+                                   slot=s, pos=p, final=bool(final),
+                                   attn_kernel_layers=self.attn_kernel_layers,
+                                   **({"ssm_scan_cols": off + 1}
+                                      if self._ssm else {}))
+                    if self.attn_kernel_layers:
+                        metrics.count("llm.serve.prefill_attn_kernel")
+                    if self._ssm and p == 0:
+                        # the chunk at position 0 zeroes the slot's state
+                        metrics.count("llm.serve.ssm_state_resets")
                     # the stream's slot key is fold_in(seed key, admission
                     # number) and its first token, at position T, folds
                     # (T, sample tag) like every later draw: the whole
@@ -2851,7 +2887,7 @@ class _ContinuousLoop:
                     # dispatch (~us) and hide the actual device time —
                     # the number the trace exists to attribute
                     decode_closed("serve.decode", sp, chunk=fw.chunk,
-                                  **moe_args)
+                                  **moe_args, **ssm_args)
                 self._undelivered = settled = settle(host)
                 # Delivery is Python per token (33 ms for 255 tokens on
                 # the 7B cell, my chip runs, PR 30) with nothing queued on

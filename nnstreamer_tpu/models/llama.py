@@ -65,7 +65,13 @@ class LayerKind:
     convolution (:func:`_conv_mixer`): its state is the last ``conv_taps
     - 1`` columns of its own input product, a fixed size a stream
     whatever its context, and lives in no block — cache class
-    ``"conv"``."""
+    ``"conv"``.
+
+    ``ssm``: the layer's mixer is a selective state-space layer (Mamba-1,
+    :func:`_ssm_mixer`): its state is a float32 matrix ``[ssm_state,
+    ssm_inner]`` a stream and the last ``ssm_conv_taps - 1`` columns of
+    its convolution's input, a fixed size whatever the context, in no
+    block — cache class ``"ssm"``."""
 
     window: int = 0
     rope: bool = True
@@ -73,6 +79,7 @@ class LayerKind:
     latent: bool = False
     shortcut: str = ""
     conv: bool = False
+    ssm: bool = False
 
     def __post_init__(self):
         if self.ffn not in ("dense", "experts"):
@@ -93,12 +100,21 @@ class LayerKind:
                              "give a window, a latent cache or a rotation "
                              "(leave them at their defaults), and no "
                              "shortcut end is built on one")
+        if self.ssm and (self.conv or self.window or self.latent
+                         or self.shortcut or not self.rope):
+            raise ValueError("a state-space layer has no attention to give "
+                             "a window, a latent cache or a rotation, is "
+                             "no convolution layer (leave them at their "
+                             "defaults), and no shortcut end is built on "
+                             "one")
 
     @property
     def name(self) -> str:
         """The key of this kind's stack under ``params["layers"]``."""
         if self.conv:
             return f"conv.{self.ffn}"
+        if self.ssm:
+            return f"ssm.{self.ffn}"
         attn = "latent" if self.latent else \
             f"window{self.window}" if self.window else "full"
         return ".".join([attn, "rope" if self.rope else "nope", self.ffn]
@@ -108,8 +124,9 @@ class LayerKind:
     def cache(self) -> str:
         """The class of state this layer keeps for a stream: one pool leaf
         set (and one numbering of layers) a class."""
-        return "conv" if self.conv else "latent" if self.latent \
-            else "win" if self.window else "full"
+        return "conv" if self.conv else "ssm" if self.ssm \
+            else "latent" if self.latent else "win" if self.window \
+            else "full"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -181,6 +198,14 @@ class LlamaConfig:
     #: (``LayerKind.conv``); a stream's state is the ``conv_taps - 1``
     #: columns before its next token
     conv_taps: int = 3
+    #: a state-space layer (``LayerKind.ssm``): its channels are
+    #: ``ssm_expand * dim``, its state ``ssm_state`` rows of them, the
+    #: step size's rank ``ssm_dt_rank``, its causal depthwise filter
+    #: ``ssm_conv_taps`` taps
+    ssm_expand: int = 2
+    ssm_state: int = 16
+    ssm_dt_rank: int = 0
+    ssm_conv_taps: int = 4
 
     def __post_init__(self):
         pat = tuple(self.pattern)
@@ -205,10 +230,19 @@ class LlamaConfig:
             if self.conv_taps < 2:
                 raise ValueError(f"conv_taps {self.conv_taps}: a "
                                  "convolution layer needs two or more")
-            if all(k.conv for k in pat):
-                raise ValueError("a model of convolution layers only has "
-                                 "no paged cache: the paged path reads "
-                                 "its block size off an attention pool")
+        if any(k.ssm for k in pat) and not (
+                self.ssm_expand > 0 and self.ssm_state > 0
+                and self.ssm_dt_rank > 0 and self.ssm_conv_taps >= 2):
+            raise ValueError("a state-space layer needs ssm_expand, "
+                             "ssm_state and ssm_dt_rank, and two or more "
+                             "ssm_conv_taps")
+        if pat and all(k.conv or k.ssm for k in pat):
+            what = " and ".join(name for name, has in (
+                ("convolution", any(k.conv for k in pat)),
+                ("state-space", any(k.ssm for k in pat))) if has)
+            raise ValueError(f"a model of {what} layers only has no paged "
+                             "cache: the paged path reads its block size "
+                             "off an attention pool")
         is_open = False
         for l, k in enumerate(pat):
             if not k.shortcut:
@@ -259,9 +293,25 @@ class LlamaConfig:
         return sum(1 for k in self.pattern if k.conv)
 
     @property
+    def n_ssm_layers(self) -> int:
+        return sum(1 for k in self.pattern if k.ssm)
+
+    @property
     def n_full_layers(self) -> int:
         return (self.n_layers - self.n_window_layers - self.n_latent_layers
-                - self.n_conv_layers)
+                - self.n_conv_layers - self.n_ssm_layers)
+
+    @property
+    def ssm_inner(self) -> int:
+        """A state-space layer's channels (``E``)."""
+        return self.ssm_expand * self.dim
+
+    @property
+    def slot_state(self) -> bool:
+        """Whether some layer keeps state by slot that no position
+        addresses (convolution or state-space layers): a program is then
+        told each row's slot and how many of a chunk's columns are real."""
+        return bool(self.n_conv_layers or self.n_ssm_layers)
 
     @property
     def has_shortcut(self) -> bool:
@@ -308,6 +358,8 @@ def pattern_traits(cfg: LlamaConfig) -> str:
         ("a shortcut expert branch across sub-layers", cfg.has_shortcut),
         ("convolution layers (state owned by the slot, in no block)",
          any(k.conv for k in kinds)),
+        ("state-space layers (a float32 state matrix owned by the slot, "
+         "in no block)", any(k.ssm for k in kinds)),
         ("q/k norm", cfg.qk_norm)) if has]
     return "a layer pattern: " + ", ".join(traits or ["mixed layers"])
 
@@ -383,6 +435,17 @@ PRESETS: Dict[str, LlamaConfig] = {
         experts=ExpertsConfig(n_experts=16, top_k=4, hidden=32,
                               norm_eps=1e-6),
     ),
+    # state-space layers beside attention at toy size: four layers in
+    # the order ssm ssm attn ssm, the attention unrotated with four query
+    # heads over one KV head of 16, 128 channels a state-space layer,
+    # a state of 16, a step size of rank 8, filters of 4 taps
+    "ssm_hybrid_tiny": LlamaConfig(
+        vocab=512, dim=64, n_layers=4, n_heads=4, n_kv_heads=1,
+        ffn_hidden=128, max_seq=256, norm_eps=1e-6, ssm_expand=2,
+        ssm_state=16, ssm_dt_rank=8, ssm_conv_taps=4,
+        pattern=tuple(LayerKind(rope=False) if l == 2
+                      else LayerKind(ssm=True) for l in range(4)),
+    ),
 }
 
 
@@ -449,6 +512,20 @@ def stack_shapes(cfg: LlamaConfig, kind: LayerKind) -> Dict[str, tuple]:
         # a tap a row (the published [D, taps] transposed: D on the
         # lanes), ``w_out`` writes into the stream
         out.update(w_in=(D, 3 * D), w_conv=(cfg.conv_taps, D), w_out=(D, D))
+    elif kind.ssm:
+        # a state-space layer: ``w_in`` makes x and the gate z (halves of
+        # its output), ``w_conv`` (a tap a row, channels on the lanes)
+        # and ``conv_b`` filter x, ``w_x`` makes the step size's low-rank
+        # input and B and C (each normed with its gain), ``w_dt`` and
+        # ``dt_bias`` the step size; ``A_log`` is stored [state,
+        # channels] (the published [channels, state] transposed, as the
+        # state itself is), ``ssm_d`` the skip; ``w_out`` writes into the
+        # stream
+        E, N, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+        out.update(w_in=(D, 2 * E), w_conv=(cfg.ssm_conv_taps, E),
+                   conv_b=(E,), w_x=(E, R + 2 * N), dt_norm=(R,),
+                   b_norm=(N,), c_norm=(N,), w_dt=(R, E), dt_bias=(E,),
+                   A_log=(N, E), ssm_d=(E,), w_out=(E, D))
     elif kind.latent:
         # a latent layer: the query and the cache each go through a
         # normed compression; ``wkv_a`` makes the latent and the one
@@ -477,9 +554,11 @@ def stack_shapes(cfg: LlamaConfig, kind: LayerKind) -> Dict[str, tuple]:
     return out
 
 
-#: leaves kept in float32 (gains, and the router: see models/moe.py)
+#: leaves kept in float32 (gains, the router: see models/moe.py, and
+#: what a state-space layer's recurrence reads beside its state)
 F32_LEAVES = ("ln_attn", "ln_mlp", "q_norm", "k_norm", "q_a_norm",
-              "kv_a_norm", "w_router", "router_bias")
+              "kv_a_norm", "w_router", "router_bias", "dt_norm", "b_norm",
+              "c_norm", "dt_bias", "A_log", "ssm_d")
 
 
 def kind_layers(cfg: LlamaConfig) -> Dict[str, List[int]]:
@@ -513,10 +592,20 @@ def _init_stacks(cfg: LlamaConfig, key, norm_init) -> Dict:
                 stack[leaf] = jax.random.normal(
                     k, (n,) + shape, "float32") * np.float32(
                         shape[0] ** -0.5)
+            elif leaf == "A_log":
+                # S4D-real: a = -(1 .. N) in every channel
+                stack[leaf] = np.broadcast_to(np.log(np.arange(
+                    1, shape[0] + 1, dtype=np.float32))[:, None],
+                    (n,) + shape).copy()
+            elif leaf == "dt_bias":
+                # a step size near softplus(-4) = 0.018
+                stack[leaf] = np.full((n,) + shape, -4.0, np.float32)
             elif leaf in F32_LEAVES:
                 stack[leaf] = np.ones((n,) + shape, np.float32)
             else:
-                stack[leaf] = norm_init(k, (n,) + shape, shape[-2])
+                stack[leaf] = norm_init(k, (n,) + shape,
+                                        shape[-2] if len(shape) > 1
+                                        else shape[0])
         stacks[name] = stack
     return stacks
 
@@ -1389,18 +1478,10 @@ def _conv_mixer(cfg: LlamaConfig, lp, h, state=None, slots=None,
     if state is None:
         past = jnp.zeros((B, past_n, D), dt)
     else:
-        if slots is None or slots.shape != (B,):
-            raise ValueError(
-                "a convolution layer's state is kept by slot: the tables "
-                "need \"slot\", one id a row ([B] int32), got "
-                f"{None if slots is None else slots.shape}")
-        n_slots = state.shape[1]
-        flat = state.reshape((-1,) + state.shape[2:])
-        # an id past the leaf names no stream's state: it reads NaN (so
-        # every logit of the row shows it) and, below, writes nothing —
-        # it is never folded onto another slot's columns
-        rows = jnp.where((slots >= 0) & (slots < n_slots),
-                         layer * n_slots + slots, flat.shape[0])
+        # an id past the leaf reads NaN (so every logit of the row shows
+        # it) and, below, writes nothing
+        flat, rows = _slot_rows("a convolution layer's", state, slots,
+                                layer, B)
         past = jnp.where(
             (pos_offset == 0)[:, None, None], 0,
             flat.at[rows].get(mode="fill", fill_value=jnp.nan)).astype(dt)
@@ -1411,16 +1492,162 @@ def _conv_mixer(cfg: LlamaConfig, lp, h, state=None, slots=None,
     out = _mm(bcv[..., D:2 * D] * z.astype(dt), lp, "w_out", dt)
     if state is None:
         return out, None
-    if n_valid is None:
-        tail = ext[:, T:]
-    else:
-        tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
-            e, n, past_n, axis=0))(ext, jnp.broadcast_to(n_valid, (B,)))
+    tail = _state_tail(ext, n_valid, past_n)
     with jax.named_scope("state_write"):
         # a row that is not live resolves past the leaf and DROPS
         rows = jnp.where(live, rows, flat.shape[0])
         flat = flat.at[rows].set(tail.astype(flat.dtype), mode="drop")
     return out, flat.reshape(state.shape)
+
+
+def _slot_rows(what: str, leaf, slots, layer, B: int):
+    """Where each of the ``B`` rows' state lies in a slot-owned leaf
+    ``[layers, slots, ...]`` viewed flat: ``(the flat leaf, rows [B])``.
+    An id past the leaf names no stream's state: it resolves one row past
+    the flat leaf, where a read fills and a write drops — it is never
+    folded onto another slot's state."""
+    import jax.numpy as jnp
+
+    if slots is None or slots.shape != (B,):
+        raise ValueError(
+            f"{what} state is kept by slot: the tables need \"slot\", one "
+            "id a row ([B] int32), got "
+            f"{None if slots is None else slots.shape}")
+    n_slots = leaf.shape[1]
+    flat = leaf.reshape((-1,) + leaf.shape[2:])
+    return flat, jnp.where((slots >= 0) & (slots < n_slots),
+                           layer * n_slots + slots, flat.shape[0])
+
+
+def _state_tail(ext, n_valid, n):
+    """The ``n`` columns of ``ext`` [B, n + T, ...] that end at each row's
+    ``n_valid``-th new one (None: at the last)."""
+    import jax
+    import jax.numpy as jnp
+
+    if n_valid is None:
+        return ext[:, ext.shape[1] - n:]
+    return jax.vmap(lambda e, k: jax.lax.dynamic_slice_in_dim(
+        e, k, n, axis=0))(ext, jnp.broadcast_to(n_valid, (ext.shape[0],)))
+
+
+def _ssm_mixer(cfg: LlamaConfig, lp, h, state=None, slots=None,
+               pos_offset=None, layer=None, live=None, n_valid=None):
+    """The selective state-space mixer (Mamba-1) of one layer on the normed
+    input ``h`` [B, T, D] -> (what it adds to the stream [B, T, D], the
+    state: the ``ssm`` and ``ssm_conv`` leaves).
+
+    ``[x | z] = h w_in``; ``x <- silu(conv(x) + conv_b)``, a causal
+    depthwise filter of ``ssm_conv_taps`` taps; ``[d | B | C] = x w_x``,
+    each RMS-normed with its gain; ``dt = softplus(d w_dt + dt_bias)``;
+    ``a = -exp(A_log)``; then ``s_t = exp(dt_t a) s_{t-1} + (dt_t x_t)
+    (x) B_t`` and ``y_t = s_t . C_t + ssm_d x_t`` over a float32 state
+    ``s`` [N, E]; ``out = (y * silu(z)) w_out``.  What a stream carries
+    from one call to the next: ``s`` and the filter's last ``taps - 1``
+    input columns.
+
+    Three forms of the recurrence, all float32.  Without ``state`` (the
+    cacheless forward) it runs over the sequence step by step from zero.
+    With it — the pool's leaves ``ssm`` [ssm layers, slots, N, E] and
+    ``ssm_conv`` [ssm layers, slots, (taps - 1) * E], viewed flat so that
+    no layer is ever sliced out — row ``i`` reads slot ``slots[i]``'s (zeros
+    where ``pos_offset[i] == 0``: a stream's first chunk never reads what
+    the slot's last stream left): a decode step (``T == 1``) updates the
+    state in place in one kernel (ops/ssm.py); a prefill chunk runs the
+    recurrence as an associative scan over its ``T`` columns, in which a
+    column past the row's ``n_valid`` has ``dt = 0`` — ``exp(0 a) = 1``
+    and no input — so the state after the chunk is the state after its
+    last REAL token, a value, as the filter's columns are.  A row ``live``
+    does not mark writes nothing, as in :func:`_conv_mixer`."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    B, T, D = h.shape
+    dt = h.dtype
+    E, N, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    past_n = cfg.ssm_conv_taps - 1
+    f32 = jnp.float32
+    xz = _mm(h, lp, "w_in", dt)
+    x, z = xz[..., :E], xz[..., E:]
+    if state is None:
+        past = jnp.zeros((B, past_n, E), dt)
+    else:
+        s_leaf, c_leaf = state
+        c_flat, rows = _slot_rows("a state-space layer's", c_leaf, slots,
+                                  layer, B)
+        s_flat = s_leaf.reshape((-1,) + s_leaf.shape[2:])
+        fresh = pos_offset == 0
+        past = jnp.where(
+            fresh[:, None, None], 0,
+            c_flat.at[rows].get(mode="fill", fill_value=jnp.nan).reshape(
+                B, past_n, E)).astype(dt)
+    ext = jnp.concatenate([past, x], axis=1)     # [B, past_n + T, E]
+    w = lp["w_conv"].astype(f32)
+    xc = jax.nn.silu(sum(w[j] * ext[:, j:j + T].astype(f32)
+                         for j in range(cfg.ssm_conv_taps))
+                     + lp["conv_b"].astype(f32)).astype(dt)
+    dbc = _mm(xc, lp, "w_x", dt)
+    eps = cfg.norm_eps
+    delta = _rmsnorm(dbc[..., :R], lp["dt_norm"], eps)
+    bm = _rmsnorm(dbc[..., R:R + N], lp["b_norm"], eps).astype(f32)
+    cm = _rmsnorm(dbc[..., R + N:], lp["c_norm"], eps).astype(f32)
+    step = jax.nn.softplus(_mm(delta, lp, "w_dt", dt).astype(f32)
+                           + lp["dt_bias"])             # [B, T, E]
+    a = -jnp.exp(lp["A_log"].astype(f32))               # [N, E]
+    d = lp["ssm_d"].astype(f32)
+    x32 = xc.astype(f32)
+
+    def gate(y):
+        # the skip and the gate, as the kernel applies them
+        return ((y + d * x32) * jax.nn.silu(z.astype(f32))).astype(dt)
+
+    if state is None:
+        with jax.named_scope("ssm.scan"):
+            def one(s, col):
+                st, xt, bt, ct = col
+                s = jnp.exp(st[:, None, :] * a) * s \
+                    + (st * xt)[:, None, :] * bt[:, :, None]
+                return s, jnp.sum(s * ct[:, :, None], axis=1)
+
+            _, ys = lax.scan(one, jnp.zeros((B, N, E), f32),
+                             tuple(jnp.moveaxis(v, 1, 0)
+                                   for v in (step, x32, bm, cm)))
+            y = gate(jnp.moveaxis(ys, 0, 1))
+        return _mm(y, lp, "w_out", dt), None
+    writes = jnp.where(live, rows, c_flat.shape[0])
+    if T == 1:
+        from ..ops.ssm import ssm_update
+
+        with jax.named_scope("ssm.update"):
+            y, s_leaf = ssm_update(s_leaf, layer, slots, live, fresh,
+                                   xc[:, 0], step[:, 0], z[:, 0], bm[:, 0],
+                                   cm[:, 0], a, d)
+        y = y[:, None]
+    else:
+        with jax.named_scope("ssm.scan"):
+            if n_valid is not None:
+                real = jnp.arange(T)[None, :] < jnp.broadcast_to(
+                    n_valid, (B,))[:, None]
+                step = jnp.where(real[..., None], step, 0.0)
+            s0 = jnp.where(fresh[:, None, None], 0.0, s_flat.at[rows].get(
+                mode="fill", fill_value=jnp.nan).astype(f32))
+            decay = jnp.exp(step[:, :, None, :] * a)    # [B, T, N, E]
+            inp = (step * x32)[:, :, None, :] * bm[..., None]
+            decay, inp = lax.associative_scan(
+                lambda l, r: (l[0] * r[0], r[0] * l[1] + r[1]),
+                (decay, inp), axis=1)
+            s = decay * s0[:, None] + inp
+            y = gate(jnp.sum(s * cm[..., None], axis=2))
+        with jax.named_scope("state_write"):
+            s_flat = s_flat.at[writes].set(s[:, -1].astype(s_flat.dtype),
+                                           mode="drop")
+            s_leaf = s_flat.reshape(s_leaf.shape)
+    with jax.named_scope("state_write"):
+        c_flat = c_flat.at[writes].set(
+            _state_tail(ext, n_valid, past_n).reshape(B, past_n * E).astype(
+                c_flat.dtype), mode="drop")
+    return _mm(y, lp, "w_out", dt), (s_leaf, c_flat.reshape(c_leaf.shape))
 
 
 def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
@@ -1453,7 +1680,8 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
     it between).  A convolution layer's ``kv`` is its class's state
     leaf, as a 1-tuple, ``slots`` [B] the slot each row is and
     ``n_valid`` how many of the ``T`` columns are real tokens
-    (:func:`_conv_mixer`)."""
+    (:func:`_conv_mixer`); a state-space layer's its two leaves
+    (:func:`_ssm_mixer`)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -1466,6 +1694,12 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         if kind is not None else None
 
     h = _rmsnorm(x, lp["ln_attn"], cfg.norm_eps)
+    if kind is not None and kind.ssm:
+        with jax.named_scope("mixer.ssm"):
+            out, state = _ssm_mixer(cfg, lp, h, kv, slots, pos_offset,
+                                    layer, live, n_valid)
+        return _feed_forward(cfg, lp, x + out, kind, live, stats_out,
+                             branch_io), state
     if kind is not None and kind.conv:
         with jax.named_scope("mixer.conv"):
             out, state = _conv_mixer(
@@ -1827,8 +2061,15 @@ def init_paged_cache(cfg: LlamaConfig, n_blocks: int, block_size: int,
     Convolution layers keep ``conv``: ``[conv layers, slots, conv_taps -
     1, dim]``, the columns each of the ``slots`` streams carries
     (:func:`_conv_mixer`) — owned by the slot like a ring, in no block
-    and in no table; such a model refuses ``slots`` < 1.  A class the
-    model has no layer of has no leaf."""
+    and in no table; such a model refuses ``slots`` < 1.  State-space
+    layers keep two leaves so owned (:func:`_ssm_mixer`): ``ssm``
+    ``[ssm layers, slots, ssm_state, ssm_inner]`` in float32 whatever
+    ``dtype`` — the channels on the lanes: a ``[..., ssm_inner,
+    ssm_state]`` layout would pad 16 lanes to 128 — and ``ssm_conv``
+    ``[ssm layers, slots, (ssm_conv_taps - 1) * ssm_inner]``, a slot's
+    filter columns side by side on the lanes (a dimension of 3 columns
+    would be tiled as 4 or 8 and copied into another layout around every
+    decode chunk).  A class the model has no layer of has no leaf."""
     import jax.numpy as jnp
 
     from ..ops.attention import latent_pool_width
@@ -1856,16 +2097,32 @@ def init_paged_cache(cfg: LlamaConfig, n_blocks: int, block_size: int,
         pool["conv"] = jnp.zeros(
             (cfg.n_conv_layers, int(slots), cfg.conv_taps - 1, cfg.dim),
             dtype)
+    if cfg.n_ssm_layers:
+        if int(slots) < 1:
+            raise ValueError(
+                "a model with state-space layers keeps their state by "
+                "slot: init_paged_cache needs slots >= 1 (the streams the "
+                f"pool serves), got {slots}")
+        n, E = cfg.n_ssm_layers, cfg.ssm_inner
+        pool["ssm"] = jnp.zeros((n, int(slots), cfg.ssm_state, E),
+                                jnp.float32)
+        pool["ssm_conv"] = jnp.zeros(
+            (n, int(slots), (cfg.ssm_conv_taps - 1) * E), dtype)
     return pool
 
 
 #: cache class (``LayerKind.cache``) -> its leaves of the pool
 POOL_LEAVES = {"full": ("k", "v"), "win": ("k_win", "v_win"),
-               "latent": ("c",), "conv": ("conv",)}
+               "latent": ("c",), "conv": ("conv",),
+               "ssm": ("ssm", "ssm_conv")}
+#: the leaves that hold state by slot and by no position: no block
+#: size, no table
+STATE_LEAVES = POOL_LEAVES["conv"] + POOL_LEAVES["ssm"]
 #: the leaves a SLOT owns for good (a window class's rings, the
-#: convolution layers' state): no allocator hands them out, no table but
-#: the slot's own names them, and no other stream can resume from them
-SLOT_LEAVES = POOL_LEAVES["win"] + POOL_LEAVES["conv"]
+#: convolution and state-space layers' state): no allocator hands them
+#: out, no table but the slot's own names them, and no other stream can
+#: resume from them
+SLOT_LEAVES = POOL_LEAVES["win"] + STATE_LEAVES
 
 
 def allocated_leaves(pool) -> List[str]:
@@ -1933,12 +2190,22 @@ def tp_divisibility_problems(cfg: LlamaConfig, tp: int) -> List[str]:
 
 def conv_state_bytes(cfg: LlamaConfig, slots: int,
                      dtype="bfloat16") -> int:
-    """Bytes of the convolution layers' state for ``slots`` streams (the
-    ``conv`` leaf of :func:`init_paged_cache`); 0 without such layers,
+    """Bytes of the filters' carried columns for ``slots`` streams: the
+    convolution layers' (the ``conv`` leaf of :func:`init_paged_cache`)
+    and the state-space layers' (``ssm_conv``); 0 without such layers,
     and for no stream."""
     itemsize = 2 if str(dtype) in ("bfloat16", "float16") else 4
-    return (cfg.n_conv_layers * int(slots) * (cfg.conv_taps - 1)
-            * cfg.dim * itemsize)
+    return (cfg.n_conv_layers * (cfg.conv_taps - 1) * cfg.dim
+            + cfg.n_ssm_layers * (cfg.ssm_conv_taps - 1) * cfg.ssm_inner) \
+        * int(slots) * itemsize
+
+
+def ssm_state_bytes(cfg: LlamaConfig, slots: int) -> int:
+    """Bytes of the state-space layers' float32 state for ``slots``
+    streams (the ``ssm`` leaf of :func:`init_paged_cache`, no lane
+    padding); 0 without such layers, and for no stream."""
+    return (cfg.n_ssm_layers * int(slots) * cfg.ssm_state * cfg.ssm_inner
+            * 4)
 
 
 def paged_cache_bytes(cfg: LlamaConfig, n_blocks: int, block_size: int,
@@ -1946,7 +2213,8 @@ def paged_cache_bytes(cfg: LlamaConfig, n_blocks: int, block_size: int,
                       slots: int = 0) -> int:
     """Static HBM footprint of :func:`init_paged_cache` (k + v of the
     full and window classes, the latent class's rows at their padded
-    width, the convolution layers' state of ``slots`` streams), without
+    width, the convolution and state-space layers' state of ``slots``
+    streams), without
     building anything — the deep-lint resource
     report prices the pool through this, so the arithmetic lives next to
     the allocation."""
@@ -1960,7 +2228,7 @@ def paged_cache_bytes(cfg: LlamaConfig, n_blocks: int, block_size: int,
         latent_pool_width(cfg.latent_width) if cfg.n_latent_layers else 0)
     return (2 * blocks * cfg.n_kv_heads * cfg.head_dim
             + latent) * block_size * itemsize \
-        + conv_state_bytes(cfg, slots, dtype)
+        + conv_state_bytes(cfg, slots, dtype) + ssm_state_bytes(cfg, slots)
 
 
 def resolve_config(model: str, opts: Dict) -> Optional[LlamaConfig]:
@@ -2037,9 +2305,9 @@ def param_bytes_split(cfg: LlamaConfig, quant: str = "",
 
 def block_size_of(pool) -> int:
     """Positions a block of ``pool`` holds (axis 2 of every leaf that is
-    made of blocks: all but the convolution layers' state)."""
+    made of blocks: all but ``STATE_LEAVES``)."""
     return next(a for leaf, a in pool.items()
-                if leaf not in POOL_LEAVES["conv"]).shape[2]
+                if leaf not in STATE_LEAVES).shape[2]
 
 
 def forward_paged(params, tokens, pool, block_tables, pos,
@@ -2085,8 +2353,9 @@ def forward_paged(params, tokens, pool, block_tables, pos,
     ``n_valid`` (traced; a scalar or [B]): how many of the ``T`` columns
     are real tokens, the rest a chunk's padding (None = all).  State that
     is not addressed by position — a convolution layer's
-    (:func:`_conv_mixer`) — is taken there; K/V rows of the padding are
-    written as before and never attended."""
+    (:func:`_conv_mixer`), a state-space layer's (:func:`_ssm_mixer`) —
+    is taken there; K/V rows of the padding are written as before and
+    never attended."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -2101,8 +2370,8 @@ def forward_paged(params, tokens, pool, block_tables, pos,
         # allocator's blocks and one for the rings: ``block_tables`` is
         # then ``{"full": [B, max_blocks], "win": [B, ring]}`` (the array
         # alone where no layer has a window), with ``"slot": [B]``, the
-        # slot each row is, where convolution layers keep their state by
-        # slot.  Every pool is a carry of the walk, like the one pool
+        # slot each row is, where convolution or state-space layers keep
+        # their state by slot.  Every pool is a carry of the walk, like the one pool
         # below.
         from .moe import N_STATS, merge_stats
 

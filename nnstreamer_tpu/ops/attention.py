@@ -780,21 +780,27 @@ def _paged_chunk_attention(q, k_pool, v_pool, block_tables, context_lens, *,
         0, 2, 1, 3, 4)[:, pad:].reshape(B, T, H, D)
 
 
-def chunk_kernel_calls(jaxpr) -> int:
-    """How many times a program runs :data:`CHUNK_KERNEL`: its calls in
-    ``jaxpr`` (open or closed), a call inside a scan once a turn — so a
-    prefill program's count is the attention layers that took the kernel.
+def kernel_calls(jaxpr, name: str) -> int:
+    """How many times a program runs the Pallas kernel named ``name``: its
+    calls in ``jaxpr`` (open or closed), a call inside a scan once a turn.
     Every branch of a ``cond`` is counted; a ``while`` body once."""
     n = 0
     for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
         if eqn.primitive.name == "pallas_call":
-            n += eqn.params.get("name") == CHUNK_KERNEL
+            n += eqn.params.get("name") == name
             continue
-        inner = sum(chunk_kernel_calls(j)
+        inner = sum(kernel_calls(j, name)
                     for j in jax.core.jaxprs_in_params(eqn.params))
         n += inner * (eqn.params["length"] if eqn.primitive.name == "scan"
                       else 1)
     return n
+
+
+def chunk_kernel_calls(jaxpr) -> int:
+    """How many times a program runs :data:`CHUNK_KERNEL`
+    (:func:`kernel_calls`): a prefill program's count is the attention
+    layers that took the kernel."""
+    return kernel_calls(jaxpr, CHUNK_KERNEL)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,

@@ -54,10 +54,11 @@ def test_kernel_leg_toy_interpreted():
                                   chunks=((8, (0, 8, 30), 0.01),
                                           (8, (0, 200), 0.008),
                                           (40, (0, 200), 0.008)),
+                                  ssm=((6, 2, 8, 16, 256),),
                                   interpret=True)
     names = " ".join(facts["rel_err"])
     for kernel in ("flash_attention", "paged_attention", "matmul_int4",
-                   "grouped_swiglu"):
+                   "grouped_swiglu", "ssm_update"):
         assert kernel in names
     # the grouped kernel ran on the router's sizes and on skewed ones
     assert {"grouped_swiglu 128x16 cell", "grouped_swiglu 128x16 skewed"} \
@@ -75,6 +76,10 @@ def test_kernel_leg_toy_interpreted():
     assert {"paged_attention chunk 8 4/2 to 30",
             "paged_attention chunk 8 8/4 x 32 to 200",
             "paged_attention chunk 40 8/4 x 32 to 200"} \
+        <= set(facts["rel_err"])
+    # ... and the state-space update kernel against its twin, on rows
+    # live, parked and fresh
+    assert {"ssm_update 6x16x256 y", "ssm_update 6x16x256 state"} \
         <= set(facts["rel_err"])
 
 

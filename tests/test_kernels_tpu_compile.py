@@ -273,8 +273,9 @@ def _chunk_kernel_layers(fn, donated, *args):
                          ids=["prefill_chunk", "spec_verify",
                               "prefill_chunk_256"])
 @pytest.mark.parametrize("heads,hd", [((32, 8), 128), ((64, 8), 128),
-                                      ((32, 8), 64)],
-                         ids=["mistral32x8", "exaone64x8", "conv_cell32x8x64"])
+                                      ((32, 8), 64), ((20, 1), 128)],
+                         ids=["mistral32x8", "exaone64x8", "conv_cell32x8x64",
+                              "ssm_cell20x1"])
 def test_paged_chunk_attention_compiles(v5e, heads, hd, T, rows):
     """The chunk kernel alone at the cells' head layouts over a table of
     4,096 / 16 entries: a prefill chunk of 32 and a speculative verify of
@@ -834,3 +835,155 @@ def test_decode_programs_are_pinned(v5e, monkeypatch, cell):
     assert bodies, "the decode program calls no Pallas kernel"
     digest = hashlib.sha256("\n".join([text] + bodies).encode()).hexdigest()
     assert digest == DECODE_PINS[cell], digest
+
+
+# -- state-space layers beside the paged cache, MQA 20/1 -------------------
+
+#: the state-space cell: 64 slots, 20 query heads over 1 KV head of 128,
+#: 4,224 blocks of 16, tables of 4,096 / 16 entries
+SSM_SLOTS, SSM_BLOCKS = 64, 4224
+
+
+def _ssm_cfg():
+    """The published Jamba2-3B geometry, whole: 28 layers, attention at
+    layers 7 and 21, Mamba layers of 5120 channels, a state of 16."""
+    return llama.LlamaConfig(
+        vocab=65536, dim=2560, n_layers=28, n_heads=20, n_kv_heads=1,
+        ffn_hidden=8192, max_seq=4096, norm_eps=1e-6, ssm_expand=2,
+        ssm_state=16, ssm_dt_rank=160, ssm_conv_taps=4,
+        pattern=tuple(llama.LayerKind(rope=False) if l % 14 == 7
+                      else llama.LayerKind(ssm=True) for l in range(28)))
+
+
+def _dense_decode_chunk(cfg):
+    """``filters/llm.py decode_chunk`` for a model without experts: 8
+    steps, the pools carried."""
+    def decode_chunk(params, pool, tok, tables, pos):
+        def step(carry, _):
+            tok, pool, p = carry
+            logits, pool = llama.forward_paged(
+                params, tok[:, None], pool, tables, p, cfg)
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return (nxt, pool, p + 1), nxt
+
+        (tok, pool, _), toks = jax.lax.scan(
+            step, (tok, pool, pos), None, length=8)
+        return toks.T, tok, pool
+
+    return decode_chunk
+
+
+@pytest.mark.parametrize("slots", [64, 4])
+def test_paged_attention_compiles_at_20_heads_over_1(v5e, slots):
+    """The decode kernel at the state-space cell's attention: 20 query
+    heads over ONE KV head of 128 (a block's 16 rows fill the bfloat16
+    sublane tile on their own), its result ``[slots, 20, 128]``."""
+    import re
+
+    pool = v5e((2 * SSM_BLOCKS, 16, 1, 128), jnp.bfloat16)
+    lowered = _compile(
+        "paged_attention",
+        lambda q, k, v, t, n: A.paged_attention(q, k, v, t, n,
+                                                interpret=False),
+        v5e((slots, 1, 20, 128), jnp.bfloat16), pool, pool,
+        v5e((slots, 256), jnp.int32), v5e((slots,), jnp.int32))
+    assert re.search(rf"%paged_attention[.\d]* = bf16\[{slots},20,128\]",
+                     lowered.compile().as_text())
+
+
+@pytest.mark.parametrize("rows", [64, 1])
+def test_ssm_update_compiles(v5e, rows):
+    """The update kernel at the cell's widths, over the whole state leaf
+    (26 layers x 64 slots x [16, 5120] float32), which it updates where
+    it lies: donated, the leaf is aliased to the result and no operation
+    copies it."""
+    import re
+
+    from nnstreamer_tpu.ops import ssm as S
+
+    lowered = jax.jit(
+        lambda st, l, sl, lv, fr, x, dt, z, b, c, a, d: S.ssm_update(
+            st, l, sl, lv, fr, x, dt, z, b, c, a, d, interpret=False),
+        donate_argnums=(0,)).lower(
+        v5e((26, SSM_SLOTS, 16, 5120), jnp.float32), v5e((), jnp.int32),
+        v5e((rows,), jnp.int32), v5e((rows,), jnp.bool_),
+        v5e((rows,), jnp.bool_), v5e((rows, 5120), jnp.bfloat16),
+        v5e((rows, 5120), jnp.float32), v5e((rows, 5120), jnp.bfloat16),
+        v5e((rows, 16), jnp.float32), v5e((rows, 16), jnp.float32),
+        v5e((16, 5120), jnp.float32), v5e((5120,), jnp.float32))
+    assert f'kernel_name = "{S.KERNEL}"' in lowered.as_text()
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert f"%{S.KERNEL}" in text
+    assert not re.search(r"= f32\[26,64,16,5120\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_step"])
+def test_ssm_serve_programs_compile_at_the_cells_shapes(
+        v5e, monkeypatch, program):
+    """Both programs of the state-space cell at its own sizes — the
+    published widths and all 28 layers (two periods of 14: thirteen Mamba
+    layers and one unrotated attention layer), 64 slots, the K/V pool of
+    4,224 blocks of [16, 1, 128] and the slots' state carried and updated
+    in place.  The ``ssm`` leaf is allocated at its logical size, 64 x
+    8,519,680 B: [16, 5120] float32 tiles fill (8, 128) tiles with no
+    lane padding.  The decode step runs the update kernel in every Mamba
+    layer and the paged kernel in both attention layers; the prefill
+    chunk scans (no update kernel) and takes the chunk kernel in both; no
+    operation copies a state leaf.  The bytes go to PERF.md section 4
+    (``-rP`` prints them)."""
+    import math
+    import re
+
+    from nnstreamer_tpu.ops import ssm as S
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _ssm_cfg()
+    assert llama.walk_plan(cfg.kinds) == llama.WalkPlan(0, 14, 2)
+    params = _abstract(v5e, lambda: llama.init_params(cfg, 0, "bfloat16"))
+    pool = _abstract(v5e, lambda: llama.init_paged_cache(
+        cfg, SSM_BLOCKS, 16, slots=SSM_SLOTS))
+    assert {k: (v.shape, v.dtype) for k, v in pool.items()} == {
+        "k": ((2, SSM_BLOCKS, 16, 1, 128), jnp.bfloat16),
+        "v": ((2, SSM_BLOCKS, 16, 1, 128), jnp.bfloat16),
+        "ssm": ((26, SSM_SLOTS, 16, 5120), jnp.float32),
+        "ssm_conv": ((26, SSM_SLOTS, 3 * 5120), jnp.bfloat16)}
+    assert math.prod(pool["ssm"].shape) * 4 == SSM_SLOTS * 8_519_680 \
+        == llama.ssm_state_bytes(cfg, SSM_SLOTS)
+
+    slot = v5e((SSM_SLOTS,), jnp.int32)
+    if program == "decode_chunk":
+        fn, donated, args = _dense_decode_chunk(cfg), (1,), (
+            slot, {"full": v5e((SSM_SLOTS, 256), jnp.int32), "slot": slot},
+            slot)
+    else:
+        fn, donated, args = _prefill_step(cfg), PREFILL_DONATED, \
+            _prefill_args(v5e, SSM_SLOTS, {
+                "full": v5e((1, 256), jnp.int32),
+                "slot": v5e((1,), jnp.int32)})
+    jitted = jax.jit(fn, donate_argnums=donated)
+    jaxpr = jitted.trace(params, pool, *args).jaxpr
+    compiled = jitted.lower(params, pool, *args).compile()
+    text = compiled.as_text()
+    decode = program == "decode_chunk"
+    # 26 Mamba layers a step, 8 steps a chunk
+    assert A.kernel_calls(jaxpr, S.KERNEL) == (26 * 8 if decode else 0)
+    assert (f"%{S.KERNEL}" in text) == decode
+    assert ("%paged_attention" in text) == decode
+    assert A.chunk_kernel_calls(jaxpr) == (0 if decode else 2)
+    # the state leaf's layout: (8, 128) tiles over [16, 5120], no padding
+    assert re.search(r"f32\[26,64,16,5120\]\{3,2,1,0:T\(8,128\)\}", text)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                     for x in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes     # all leaves in place
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= (f32|bf16)\[(26,64|1664),\S* copy\(", line)
+              or re.search(r"= bf16\[(8448|2,4224),\S* copy\(", line)]
+    assert not copies, "a pool leaf is copied:\n" + "\n".join(copies)
+    bytes_limit = 16_909_336_064
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < bytes_limit
+    for name in ("argument_size_in_bytes", "temp_size_in_bytes",
+                 "alias_size_in_bytes"):
+        print(program, name, getattr(mem, name))
