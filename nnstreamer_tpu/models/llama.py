@@ -1719,14 +1719,17 @@ def _block(cfg: LlamaConfig, lp, x, positions, kv=None, pos_offset=None,
         q = qkv[..., :H * hd].reshape(B, T, H, hd)
         k = qkv[..., H * hd:(H + Hkv) * hd].reshape(B, T, Hkv, hd)
         v = qkv[..., (H + Hkv) * hd:].reshape(B, T, Hkv, hd)
-    elif paged_tables is not None and T > 1:
-        # A prefill chunk (or a speculative verify): the barrier keeps the
-        # three products row-major, as their weights are stored.  Without
-        # it XLA lays each product out head-major for the per-head work
-        # after it, and gets there by copying the whole weight transposed
-        # first, every chunk: a layer's int8 wq, wk and wv at Mistral's
-        # widths (24 MiB), a bf16 wq of 96 MiB at K-EXAONE's.  A decode
-        # step (T == 1) makes no such copy.
+    elif paged_tables is not None:
+        # Every paged call (a decode step, a prefill chunk, a speculative
+        # verify): the barrier keeps the three products row-major, as their
+        # weights are stored, so each product reads its layer's weight
+        # where it lies.  Without it XLA lays each product out head-major
+        # for the per-head work after it, and gets there by copying the
+        # weights transposed first: a prefill chunk a layer's int8 wq, wk
+        # and wv at Mistral's widths (24 MiB), a bf16 wq of 96 MiB at
+        # K-EXAONE's; a decode chunk the whole stack once, hoisted out of
+        # its step scan, and then a slice of that copy in every layer of
+        # every step (≈ 10 % of decode_c32's device time, PERF.md §5).
         q, k, v = lax.optimization_barrier(
             tuple(_mm(h, lp, n, dt) for n in ("wq", "wk", "wv")))
         q = q.reshape(B, T, H, hd)

@@ -44,6 +44,26 @@ def _abstract(v5e, make):
         lambda x: v5e(x.shape, x.dtype), jax.eval_shape(make))
 
 
+@pytest.fixture(scope="module")
+def decode_programs(v5e):
+    """``_decode_case(v5e, cell)`` jitted, its arguments, and its program
+    compiled for v5e, made once for every test of this file that reads it
+    (a 64-slot program takes 15-40 s to compile on a CPU host).  The
+    caller steers ``jax.default_backend`` to the TPU before the first
+    call."""
+    made = {}
+
+    def program(cell):
+        if cell not in made:
+            fn, params, pool, args = _decode_case(v5e, cell)
+            jitted = jax.jit(fn, donate_argnums=(1,))
+            args = (params, pool) + tuple(args)
+            made[cell] = jitted, args, jitted.lower(*args).compile()
+        return made[cell]
+
+    return program
+
+
 def _compile(name, fn, *args):
     lowered = jax.jit(fn).lower(*args)
     text = lowered.as_text()
@@ -215,7 +235,8 @@ def _sampled_prefill_program(cfg, v5e):
 @pytest.mark.parametrize(
     "program", [_decode_program, _prefill_program, _sampled_prefill_program],
     ids=["decode_chunk", "prefill_step", "prefill_step_sampled"])
-def test_serve_program_never_moves_the_pool(v5e, monkeypatch, program):
+def test_serve_program_never_moves_the_pool(v5e, monkeypatch, decode_programs,
+                                           program):
     """The compiled serve programs write the new K/V rows into the donated
     pool in place: no operation copies the pool, slices a layer out of it
     or writes a layer back, and the program holds no second pool among its
@@ -235,11 +256,12 @@ def test_serve_program_never_moves_the_pool(v5e, monkeypatch, program):
         v5e, lambda: llama.init_params_int8(cfg, 0, "bfloat16"))
     pool = _abstract(
         v5e, lambda: llama.init_paged_cache(cfg, POOL_BLOCKS, POOL_BS))
-    fn, args = program(cfg, v5e)
-    prefill = program is not _decode_program
-    compiled = jax.jit(
-        fn, donate_argnums=PREFILL_DONATED if prefill else (1,)).lower(
-        params, pool, *args).compile()
+    if program is _decode_program:
+        compiled = decode_programs("dense")[2]
+    else:
+        fn, args = program(cfg, v5e)
+        compiled = jax.jit(fn, donate_argnums=PREFILL_DONATED).lower(
+            params, pool, *args).compile()
 
     layer_elems = math.prod(pool["k"].shape[1:])
     moved = {layer_elems, cfg.n_layers * layer_elems}
@@ -569,7 +591,7 @@ def test_grouped_swiglu_compiles(v5e, rows, groups, expect):
 
 @pytest.mark.parametrize("program", ["decode_chunk", "prefill_step"])
 def test_hybrid_serve_programs_compile_at_the_cells_shapes(
-        v5e, monkeypatch, program):
+        v5e, monkeypatch, decode_programs, program):
     """Both programs of the patterned cell at its own sizes — 8 layers
     ``LLLG LLLG``, layer 0 dense, 7 x 16 held experts, 64 slots, 3,200
     blocks and the rings: the expert product is the grouped kernel over
@@ -589,18 +611,14 @@ def test_hybrid_serve_programs_compile_at_the_cells_shapes(
     pool = _abstract(v5e, lambda: llama.init_paged_cache(
         cfg, 3200, 16, win_blocks=HYB_SLOTS * HYB_RING))
     if program == "decode_chunk":
-        fn, donated, args = _sparse_decode_chunk(cfg), (1,), (
-            v5e((HYB_SLOTS,), jnp.int32),
-            {"full": v5e((HYB_SLOTS, 256), jnp.int32),
-             "win": v5e((HYB_SLOTS, HYB_RING), jnp.int32)},
-            v5e((HYB_SLOTS,), jnp.int32))
+        compiled = decode_programs("hybrid")[2]
     else:
         fn, donated, args = _prefill_step(cfg), PREFILL_DONATED, \
             _prefill_args(v5e, HYB_SLOTS, {
                 "full": v5e((1, 256), jnp.int32),
                 "win": v5e((1, HYB_RING), jnp.int32)})
-    compiled = jax.jit(fn, donate_argnums=donated).lower(
-        params, pool, *args).compile()
+        compiled = jax.jit(fn, donate_argnums=donated).lower(
+            params, pool, *args).compile()
     text = compiled.as_text()
     _the_grouped_kernel_is_the_expert_product(
         text, 512 if program == "decode_chunk" else 256)
@@ -698,7 +716,7 @@ def test_grouped_swiglu_compiles_with_every_expert_held(v5e):
 
 @pytest.mark.parametrize("program", ["decode_chunk", "prefill_step"])
 def test_conv_serve_programs_compile_at_the_cells_shapes(
-        v5e, monkeypatch, program):
+        v5e, monkeypatch, decode_programs, program):
     """Both programs of the convolution cell at its own sizes — published
     widths, 10 layers (two leading conv + dense, then two periods of
     attention, conv, conv, conv, all sparse), 64 experts top-4 every one
@@ -722,18 +740,14 @@ def test_conv_serve_programs_compile_at_the_cells_shapes(
         "conv": (8, CONV_SLOTS, 2, 2048)}
 
     if program == "decode_chunk":
-        fn, donated, args = _sparse_decode_chunk(cfg), (1,), (
-            v5e((CONV_SLOTS,), jnp.int32),
-            {"full": v5e((CONV_SLOTS, CONV_MAX_BLOCKS), jnp.int32),
-             "slot": v5e((CONV_SLOTS,), jnp.int32)},
-            v5e((CONV_SLOTS,), jnp.int32))
+        compiled = decode_programs("conv")[2]
     else:
         fn, donated, args = _prefill_step(cfg), PREFILL_DONATED, \
             _prefill_args(v5e, CONV_SLOTS, {
                 "full": v5e((1, CONV_MAX_BLOCKS), jnp.int32),
                 "slot": v5e((1,), jnp.int32)})
-    compiled = jax.jit(fn, donate_argnums=donated).lower(
-        params, pool, *args).compile()
+        compiled = jax.jit(fn, donate_argnums=donated).lower(
+            params, pool, *args).compile()
     text = compiled.as_text()
     assert ("%paged_attention" in text) == (program == "decode_chunk")
     # the stage's two attention layers take the chunk kernel, packed
@@ -778,13 +792,16 @@ def _decode_case(v5e, cell):
                    dict(win_blocks=HYB_SLOTS * HYB_RING)),
         "latent": (_latent_cfg(), LAT_SLOTS, LAT_BLOCKS, {}),
         "conv": (_conv_cfg(), CONV_SLOTS, CONV_BLOCKS,
-                 dict(slots=CONV_SLOTS))}[cell]
+                 dict(slots=CONV_SLOTS)),
+        "ssm": (_ssm_cfg(), SSM_SLOTS, SSM_BLOCKS,
+                dict(slots=SSM_SLOTS))}[cell]
     table = v5e((slots, 256), jnp.int32)
+    by_slot = {"full": table, "slot": v5e((slots,), jnp.int32)}
     tables = {"hybrid": {"full": table,
                          "win": v5e((slots, HYB_RING), jnp.int32)},
-              "latent": table,
-              "conv": {"full": table, "slot": v5e((slots,), jnp.int32)}}
-    return (_sparse_decode_chunk(cfg),
+              "latent": table, "conv": by_slot, "ssm": by_slot}
+    chunk = _dense_decode_chunk if cell == "ssm" else _sparse_decode_chunk
+    return (chunk(cfg),
             _abstract(v5e, lambda: llama.init_params(cfg, 0, "bfloat16")),
             _abstract(v5e, lambda: llama.init_paged_cache(
                 cfg, blocks, 16, **extra)),
@@ -808,13 +825,15 @@ def _kernel_bodies(jaxpr):
 
 #: sha256 of each serving geometry's decode program: its text as lowered
 #: for v5e with the kernels' serialized bodies blanked (they carry source
-#: locations), then the traced bodies of its Pallas kernels.  The chunk
-#: kernel for prefill left all four as they were before it.
+#: locations), then the traced bodies of its Pallas kernels.  The q/k/v
+#: products' barrier on every paged call moved the three whose attention
+#: layers are paged (dense, hybrid, conv); the latent program never
+#: reaches that branch and kept its pin.
 DECODE_PINS = dict(
-    dense="cf130d3754a378cb3abe7bd4f3da542f9b26e5b8aa77b85814533d9f3825d789",
-    hybrid="272da89e4f0914b3040b394188651cd8a35c061df23719027e8fa1956a40b64b",
+    dense="432702e967facb32ae0bacd72e4ebc6d765147cb0c5b3bcdcb435d5a7c62ed34",
+    hybrid="2968a2cf7372fc9dd5407ec8209bde97bd5d96ff4b69a66a687334269f5f936c",
     latent="5579861e54db05a2d86df8c34faafaacf79eeb81476b15f48512267490af0dd0",
-    conv="db59664dcb757c145c1252c561af100080a30790c206578b6c597e9d33b43de1")
+    conv="51ff110734b75652047592b747f517f1a4882e1f4d5f7ff27aa6c6be3b665d51")
 
 
 @pytest.mark.parametrize("cell", sorted(DECODE_PINS))
@@ -835,6 +854,51 @@ def test_decode_programs_are_pinned(v5e, monkeypatch, cell):
     assert bodies, "the decode program calls no Pallas kernel"
     digest = hashlib.sha256("\n".join([text] + bodies).encode()).hexdigest()
     assert digest == DECODE_PINS[cell], digest
+
+
+#: temporaries of each decode program's chipless v5e compile, in bytes,
+#: that its q/k/v products keep under: the programs that copied the
+#: weights transposed held 18,608,640 (dense, 4 layers), 1,519,780,352
+#: (hybrid), 22,133,760 (conv) and 113,265,152 (ssm); reading them where
+#: they lie, 565,248, 16,971,776, 12,745,216 and 86,792,704
+QKV_TEMP_BOUNDS = dict(dense=1 << 20, hybrid=64 << 20, conv=16 << 20,
+                       ssm=96 << 20)
+
+
+@pytest.mark.parametrize("cell", sorted(QKV_TEMP_BOUNDS))
+def test_decode_programs_read_qkv_where_they_lie(monkeypatch, decode_programs,
+                                                cell):
+    """The decode program of every geometry whose attention layers are
+    paged reads each layer's q, k and v weights where they lie: no
+    operation copies or transposes a ``w[qkv]`` stack, and none takes a
+    layer's projection out of one (its slice fuses into the product).
+    Without the barrier on the three products XLA copied each stack
+    transposed once a chunk and sliced a layer out of that copy in every
+    layer of every step: ≈ 10 % of ``decode_c32``'s device time on a v5e.
+    ``-rP`` prints the temporaries."""
+    import re
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _jitted, (params, *_), compiled = decode_programs(cell)
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if re.fullmatch(r"w[qkv](_q)?", str(getattr(path[-1], "key", ""))):
+            dims = ",".join(map(str, leaf.shape))
+            shapes |= {dims, ",".join(map(str, (1,) + leaf.shape[1:]))}
+    assert shapes, "no q/k/v weight stack in the parameters"
+    # an operation whose result is a stack or a layer's slice of one: a
+    # copy or transpose, a fusion, or a slice that is a fusion's root
+    # (a slice that is not a root is fused into its product)
+    inst = re.compile(r"^(ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(")
+    moved = [line.strip()[:160] for line in compiled.as_text().splitlines()
+             for m in [inst.match(line.strip())] if m
+             and m.group(2) in shapes
+             and (m.group(3) in ("copy", "copy-start", "transpose", "fusion")
+                  or m.group(1) and "slice" in m.group(3))]
+    assert not moved, "a q/k/v weight is moved:\n" + "\n".join(moved)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(cell, "temp_size_in_bytes", temp)
+    assert temp < QKV_TEMP_BOUNDS[cell], temp
 
 
 # -- state-space layers beside the paged cache, MQA 20/1 -------------------
@@ -921,7 +985,7 @@ def test_ssm_update_compiles(v5e, rows):
 
 @pytest.mark.parametrize("program", ["decode_chunk", "prefill_step"])
 def test_ssm_serve_programs_compile_at_the_cells_shapes(
-        v5e, monkeypatch, program):
+        v5e, monkeypatch, decode_programs, program):
     """Both programs of the state-space cell at its own sizes — the
     published widths and all 28 layers (two periods of 14: thirteen Mamba
     layers and one unrotated attention layer), 64 slots, the K/V pool of
@@ -952,19 +1016,14 @@ def test_ssm_serve_programs_compile_at_the_cells_shapes(
     assert math.prod(pool["ssm"].shape) * 4 == SSM_SLOTS * 8_519_680 \
         == llama.ssm_state_bytes(cfg, SSM_SLOTS)
 
-    slot = v5e((SSM_SLOTS,), jnp.int32)
     if program == "decode_chunk":
-        fn, donated, args = _dense_decode_chunk(cfg), (1,), (
-            slot, {"full": v5e((SSM_SLOTS, 256), jnp.int32), "slot": slot},
-            slot)
+        jitted, args, compiled = decode_programs("ssm")
     else:
-        fn, donated, args = _prefill_step(cfg), PREFILL_DONATED, \
-            _prefill_args(v5e, SSM_SLOTS, {
-                "full": v5e((1, 256), jnp.int32),
-                "slot": v5e((1,), jnp.int32)})
-    jitted = jax.jit(fn, donate_argnums=donated)
-    jaxpr = jitted.trace(params, pool, *args).jaxpr
-    compiled = jitted.lower(params, pool, *args).compile()
+        jitted = jax.jit(_prefill_step(cfg), donate_argnums=PREFILL_DONATED)
+        args = (params, pool) + _prefill_args(v5e, SSM_SLOTS, {
+            "full": v5e((1, 256), jnp.int32), "slot": v5e((1,), jnp.int32)})
+        compiled = jitted.lower(*args).compile()
+    jaxpr = jitted.trace(*args).jaxpr
     text = compiled.as_text()
     decode = program == "decode_chunk"
     # 26 Mamba layers a step, 8 steps a chunk

@@ -407,6 +407,62 @@ class TestPoolCarriedThroughLayers:
                    wrote=[6], logit_off=5)
 
 
+def _barriers(jaxpr, times=1):
+    """The operand counts of the ``optimization_barrier`` equations of
+    ``jaxpr``, each listed as many times as the scans around it run it."""
+    import jax
+
+    found = []
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        if eqn.primitive.name == "optimization_barrier":
+            found += [len(eqn.invars)] * times
+        inner = times * (eqn.params["length"] if eqn.primitive.name == "scan"
+                         else 1)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _barriers(sub, inner)
+    return found
+
+
+class TestProjectionsKeepTheirLayout:
+    """Every paged call — a decode step as much as a prefill chunk —
+    passes each full or window attention layer's q, k and v products
+    through ONE optimization barrier, so that the compiled program reads
+    their weights where they lie (tests/test_kernels_tpu_compile.py
+    holds the compiled decode programs to it); the latent, convolution
+    and state-space mixers take none."""
+
+    @pytest.mark.parametrize("T", [1, 4], ids=["decode_step", "chunk"])
+    @pytest.mark.parametrize("preset,quant", [
+        ("llama_tiny", None), ("llama_tiny", "int8"), ("llama_small", None),
+        ("hybrid_moe_tiny", None), ("conv_moe_tiny", None),
+        ("ssm_hybrid_tiny", None), ("latent_shortcut_tiny", None)])
+    def test_a_barrier_in_every_attention_layer(self, preset, quant, T):
+        import jax
+        import jax.numpy as jnp
+
+        cfg = llama.PRESETS[preset]
+        B, bs = 2, 4
+        init = llama.init_params_int8 if quant else llama.init_params
+        params = jax.eval_shape(lambda: init(cfg, 0, "float32"))
+        ring = llama.window_ring_blocks(cfg, bs, 8)
+        pool = jax.eval_shape(lambda: llama.init_paged_cache(
+            cfg, 16, bs, "float32", win_blocks=B * ring, slots=B))
+        rows = jax.ShapeDtypeStruct((B,), jnp.int32)
+        tables = jax.ShapeDtypeStruct((B, 8), jnp.int32)
+        if ring:
+            tables = {"full": tables,
+                      "win": jax.ShapeDtypeStruct((B, ring), jnp.int32)}
+        elif cfg.slot_state:
+            tables = {"full": tables, "slot": rows}
+        jaxpr = jax.jit(lambda *a: llama.forward_paged(
+            *a, cfg, "float32")).trace(
+            params, jax.ShapeDtypeStruct((B, T), jnp.int32), pool, tables,
+            rows).jaxpr
+        attention = sum(not (k.conv or k.ssm or k.latent)
+                        for k in cfg.kinds)
+        assert _barriers(jaxpr) == [3] * attention
+
+
 class TestPagedAttentionKernel:
     def _case(self, rng, B=4, H=4, hkv=2, D=16, bs=8, n_blocks=16,
               max_blocks=4, lens=(1, 5, 8, 29)):
