@@ -1281,7 +1281,7 @@ class _ContinuousLoop:
             toks = jnp.moveaxis(toks, 0, 1)  # [B, length]
             if stats is not None:
                 # the expert layers' counts of each step ride home as
-                # five extra rows of the token matrix: the fetch that
+                # five (six) extra rows of the token matrix: the fetch that
                 # brings the chunk's tokens brings them, no sync of
                 # their own
                 toks = jnp.concatenate([toks, stats.T], axis=0)
@@ -2872,7 +2872,9 @@ class _ContinuousLoop:
                     # computed, held experts hit, most pairs on one,
                     # identity pairs (no expert computed them) — and,
                     # over all rows, the times the grouped kernel
-                    # streamed an expert's matrices
+                    # streamed an expert's matrices; a group-limited
+                    # router's row B+5: live rows that kept a group of
+                    # the held experts
                     moe = host[B:]
                     host = host[:B]
                     moe_args = {"moe_pairs": int(moe[0].sum()),
@@ -2880,6 +2882,8 @@ class _ContinuousLoop:
                                 "moe_max_per_expert": int(moe[2].max()),
                                 "moe_zero_pairs": int(moe[3].sum()),
                                 "moe_weight_passes": int(moe[4].sum())}
+                    if len(moe) > 5:
+                        moe_args["moe_group_hits"] = int(moe[5].sum())
                 if rec is not None:
                     # the decode span closes HERE, at materialization:
                     # the jit call above only enqueued the async

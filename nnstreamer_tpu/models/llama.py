@@ -163,6 +163,69 @@ def walk_plan(kinds) -> WalkPlan:
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN's rescaling of the rotation (the ``rope_scaling`` group of a
+    published config of ``type`` yarn): the rotated pairs of frequency
+    index ``i`` below the correction range keep their frequency, those
+    above it are divided by ``factor``, a linear ramp joins them
+    (:func:`rope_frequencies`); cos and sin are multiplied by
+    ``m(factor, mscale) / m(factor, mscale_all_dim)`` and a latent
+    layer's softmax scale by ``m(factor, mscale_all_dim) ** 2``, with
+    ``m(s, a) = 0.1 a ln s + 1`` (:func:`yarn_mscale`)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def __post_init__(self):
+        if not (self.factor > 1 and self.original_max_position > 0
+                and self.beta_fast > self.beta_slow > 0):
+            raise ValueError(
+                f"rope scaling {self}: YaRN needs a factor above 1, the "
+                "original context, and beta_fast > beta_slow > 0")
+
+
+def yarn_mscale(factor: float, a: float) -> float:
+    """``m(s, a) = 0.1 a ln s + 1``, 1 where ``s <= 1``."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * a * math.log(factor) + 1.0
+
+
+def rope_frequencies(theta: float, d: int, scaling=None):
+    """The rotation of ``d`` rotated dims: (``freqs`` [d / 2] float32 of
+    the half-split pairs ``(i, i + d/2)``, what cos and sin are multiplied
+    by).  Without ``scaling`` (a :class:`RopeScaling`) ``theta ** (-i /
+    (d/2))`` and 1.  With it, YaRN: ``low``/``high`` are the frequency
+    indices whose wavelength fits ``beta_fast``/``beta_slow`` turns into
+    the original context, ``ramp = clip((i - low) / (high - low), 0,
+    1)``, ``freq = freq / factor * ramp + freq * (1 - ramp)``."""
+    import math
+
+    import jax.numpy as jnp
+
+    half = d // 2
+    if scaling is None:
+        return theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half), 1.0
+    base = np.float64(theta) ** (-np.arange(half, dtype=np.float64) / half)
+
+    def index(turns):   # the i whose wavelength fits ``turns`` turns
+        return d * math.log(scaling.original_max_position
+                            / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(index(scaling.beta_fast)), 0)
+    high = min(math.ceil(index(scaling.beta_slow)), d - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    freqs = base / scaling.factor * ramp + base * (1 - ramp)
+    gain = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+        scaling.factor, scaling.mscale_all_dim)
+    return jnp.asarray(freqs, jnp.float32), gain
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab: int = 32000
     dim: int = 4096
@@ -194,6 +257,9 @@ class LlamaConfig:
     v_head_dim: int = 0
     q_lora_scale: float = 1.0
     kv_lora_scale: float = 1.0
+    #: YaRN's rescaling of the rotation (:class:`RopeScaling`; None = the
+    #: rotation as ``rope_theta`` gives it), built for latent layers
+    rope_scaling: Optional[RopeScaling] = None
     #: taps of a convolution layer's depthwise causal filter
     #: (``LayerKind.conv``); a stream's state is the ``conv_taps - 1``
     #: columns before its next token
@@ -226,6 +292,13 @@ class LlamaConfig:
             raise ValueError("a latent-attention layer needs q_lora_rank, "
                              "kv_lora_rank, qk_nope_dim, qk_rope_dim "
                              "(even) and v_head_dim")
+        if self.rope_scaling is not None and not all(
+                k.latent or not k.rope or k.conv or k.ssm
+                for k in self.kinds):
+            raise ValueError(
+                "rope scaling is built for latent attention layers only: "
+                "the softmax scale it changes reaches a latent layer's "
+                "kernel, the other layers' kernels fix theirs")
         if any(k.conv for k in pat):
             if self.conv_taps < 2:
                 raise ValueError(f"conv_taps {self.conv_taps}: a "
@@ -324,6 +397,16 @@ class LlamaConfig:
         return self.kv_lora_rank + self.qk_rope_dim
 
     @property
+    def latent_scale(self) -> float:
+        """A latent layer's softmax scale: ``(nope + rope) ** -0.5``, times
+        ``m(factor, mscale_all_dim) ** 2`` under YaRN."""
+        scale = (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+        if self.rope_scaling is not None:
+            r = self.rope_scaling
+            scale *= yarn_mscale(r.factor, r.mscale_all_dim) ** 2
+        return scale
+
+    @property
     def max_window(self) -> int:
         return max((k.window for k in self.pattern), default=0)
 
@@ -419,6 +502,25 @@ PRESETS: Dict[str, LlamaConfig] = {
                               scoring="softmax", norm_topk=False,
                               scale=6.0, zero_experts=8, held_first=4,
                               held_count=4),
+    ),
+    # latent attention under YaRN before plain expert layers at toy size:
+    # layer 0 dense, then three sparse layers whose sigmoid router splits
+    # 16 experts into 4 groups of 4 and keeps 2 of them a token, 4
+    # experts a token beside a shared one, this process holding experts
+    # 4..7 (group 1)
+    "latent_moe_tiny": LlamaConfig(
+        vocab=512, dim=64, n_layers=4, n_heads=4, n_kv_heads=1,
+        ffn_hidden=128, max_seq=256, norm_eps=1e-6,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16,
+        rope_scaling=RopeScaling(factor=32.0, original_max_position=64,
+                                 mscale=1.0, mscale_all_dim=1.0),
+        pattern=tuple(LayerKind(latent=True,
+                                ffn="dense" if l == 0 else "experts")
+                      for l in range(4)),
+        experts=ExpertsConfig(n_experts=16, top_k=4, hidden=32, shared=1,
+                              scale=2.5, norm_eps=1e-20, n_group=4,
+                              topk_group=2, held_first=4, held_count=4),
     ),
     # gated short-convolution layers beside attention at toy size: ten
     # layers in the order conv conv | attn conv conv conv | attn conv
@@ -1288,19 +1390,22 @@ def _rmsnorm(x, w, eps, scale: float = 1.0):
     return (x32 * inv).astype(x.dtype) * w.astype(x.dtype)
 
 
-def _rope(x, positions, theta):
-    """Rotary embedding. x: [B, T, H, D_head]; positions: [B, T] or [T]."""
+def _rope(x, positions, theta, scaling=None):
+    """Rotary embedding. x: [B, T, H, D_head]; positions: [B, T] or [T];
+    the frequencies of :func:`rope_frequencies`."""
     import jax.numpy as jnp
 
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs, gain = rope_frequencies(theta, d, scaling)
     pos = jnp.asarray(positions, jnp.float32)
     if pos.ndim == 1:
         pos = pos[None, :]
     ang = pos[..., None] * freqs  # [B, T, half]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if gain != 1.0:
+        cos, sin = cos * gain, sin * gain
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
@@ -1368,7 +1473,9 @@ def _latent_attention(cfg: LlamaConfig, lp, h, positions, pool=None,
     unrotated part ``q_C`` and a rotated ``q_R``; ``[c | k_R] = h wkv_a``,
     ``c <- kv_lora_scale * N(c)``, ``k_R`` rotated and shared by all
     heads; ``[k_C | v] = c wkv_b`` per head; scores ``(q_C . k_C + q_R .
-    k_R) / sqrt(nope + rope)``.  **The cache row of a token is ``c | k_R``**
+    k_R) / sqrt(nope + rope)`` (``LlamaConfig.latent_scale``: YaRN
+    rescales it, and the rotation, where ``rope_scaling`` says so).
+    **The cache row of a token is ``c | k_R``**
     (``latent_width`` values, zero-padded to the pool's lanes), written
     into the whole pool viewed flat like K and V are (:func:`_block`).
 
@@ -1392,15 +1499,17 @@ def _latent_attention(cfg: LlamaConfig, lp, h, positions, pool=None,
     dt = h.dtype
     H, r = cfg.n_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    scale = (dn + dr) ** -0.5
+    scale = cfg.latent_scale
     cq = _rmsnorm(_mm(h, lp, "wq_a", dt), lp["q_a_norm"], cfg.norm_eps,
                   cfg.q_lora_scale)
     q = _mm(cq, lp, "wq_b", dt).reshape(B, T, H, dn + dr)
-    q_c, q_r = q[..., :dn], _rope(q[..., dn:], positions, cfg.rope_theta)
+    q_c, q_r = q[..., :dn], _rope(q[..., dn:], positions, cfg.rope_theta,
+                                  cfg.rope_scaling)
     kva = _mm(h, lp, "wkv_a", dt)
     c = _rmsnorm(kva[..., :r], lp["kv_a_norm"], cfg.norm_eps,
                  cfg.kv_lora_scale)
-    k_r = _rope(kva[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    k_r = _rope(kva[..., None, r:], positions, cfg.rope_theta,
+                cfg.rope_scaling)[:, :, 0]
     wkv_b = lp["wkv_b"].astype(dt).reshape(r, H, dn + dv)
     wk, wv = wkv_b[..., :dn], wkv_b[..., dn:]
 
@@ -2350,8 +2459,8 @@ def forward_paged(params, tokens, pool, block_tables, pos,
     row instead of T.
 
     ``with_stats``: also return the expert layers' routing counts of
-    this step, int32 [5] (models/moe.py; None for a model without
-    experts).
+    this step, int32 [5], [6] where the router's choice is group-limited
+    (models/moe.py ``n_stats``; None for a model without experts).
 
     ``n_valid`` (traced; a scalar or [B]): how many of the ``T`` columns
     are real tokens, the rest a chunk's padding (None = all).  State that
@@ -2376,7 +2485,7 @@ def forward_paged(params, tokens, pool, block_tables, pos,
         # slot each row is, where convolution or state-space layers keep
         # their state by slot.  Every pool is a carry of the walk, like the one pool
         # below.
-        from .moe import N_STATS, merge_stats
+        from .moe import merge_stats, n_stats
 
         tabs = block_tables if isinstance(block_tables, dict) \
             else {"full": block_tables}
@@ -2401,7 +2510,8 @@ def forward_paged(params, tokens, pool, block_tables, pos,
                 stats = merge_stats(stats, got[0])
             return x, pl, stats, io[0]
 
-        stats0 = jnp.zeros((N_STATS,), jnp.int32) if cfg.experts else None
+        stats0 = jnp.zeros((n_stats(cfg.experts),), jnp.int32) \
+            if cfg.experts else None
         x, pool, stats, _ = _walk_pattern(
             cfg, params["layers"],
             (x, dict(pool), stats0, _no_branch(cfg, x)), step)

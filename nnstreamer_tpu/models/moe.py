@@ -42,6 +42,17 @@ scores must not depend on bf16 rounding); ``we_gate``, ``we_up`` [held,
 D, F], ``we_down`` [held, F, D]; ``ws_gate``, ``ws_up`` [D, Fs],
 ``ws_down`` [Fs, D] with ``Fs = shared * F``.
 
+**Group-limited routing.**  A router may split its routed experts into
+``n_group`` groups of consecutive ids and let a token choose only among the
+``topk_group`` groups that score best, a group's score being the sum of its
+two largest biased scores; the experts of every other group are masked to
+``-inf`` before the ``top_k`` choice, so they can never be chosen.  Under
+expert parallelism a group is the experts of a few ranks (two chips for
+the benchmark's A.X-K1 share: 24 experts a group, 12 a rank), so the rule
+also bounds the ranks a token is dispatched to.  Where the groups are
+live, ``stats`` gains a sixth entry: the live rows whose kept groups
+include a group of this rank's experts.
+
 **The expert matrices are never sliced out of their stack.**  A grouped
 product is a custom call, and a slice that feeds one is materialized:
 taking layer ``l``'s ``[held, D, F]`` out of a kind's ``[n, held, D,
@@ -62,7 +73,8 @@ from typing import Optional
 
 #: leaves :func:`moe_ffn` takes as the kind's whole stack (see above)
 STACKED_LEAVES = ("we_gate", "we_up", "we_down")
-#: entries of :func:`moe_ffn`'s ``stats``
+#: entries of :func:`moe_ffn`'s ``stats`` (one more where the router's
+#: choice is group-limited: :func:`n_stats`)
 N_STATS = 5
 
 
@@ -76,6 +88,8 @@ class ExpertsConfig:
     sum (plus ``norm_eps``, where a model's published code adds one);
     ``scale`` multiplies them.  ``zero_experts`` identity experts
     follow the routed ones in the router's outputs (module docstring).
+    ``n_group`` groups of consecutive routed experts, of which a token
+    keeps ``topk_group`` (1 and 1 = no groups; module docstring).
     ``held_first``/``held_count`` name this process's share of the
     routed experts (``held_count`` 0 = all)."""
 
@@ -90,6 +104,8 @@ class ExpertsConfig:
     held_count: int = 0
     zero_experts: int = 0
     norm_eps: float = 0.0
+    n_group: int = 1
+    topk_group: int = 1
 
     def __post_init__(self):
         if self.scoring not in ("sigmoid", "softmax"):
@@ -106,10 +122,51 @@ class ExpertsConfig:
                 f"held experts [{self.held_first}, "
                 f"{self.held_first + self.n_held}) outside the "
                 f"{self.n_experts} routed")
+        if self.n_group < 1 or self.n_experts % self.n_group:
+            raise ValueError(f"n_group {self.n_group}: the {self.n_experts} "
+                             "routed experts split into groups of equal "
+                             "size")
+        if self.grouped:
+            size = self.group_size
+            if size < 2:
+                raise ValueError(
+                    f"groups of {size} expert: a group's score is the sum "
+                    "of its two best, so a group needs at least two")
+            if not 1 <= self.topk_group <= self.n_group:
+                raise ValueError(f"topk_group {self.topk_group} of "
+                                 f"{self.n_group} groups")
+            if self.top_k > self.topk_group * size:
+                raise ValueError(
+                    f"top_k {self.top_k} from {self.topk_group} kept groups "
+                    f"of {size} experts")
+            if self.zero_experts:
+                raise ValueError(
+                    "identity experts with groups: identity experts are no "
+                    "group's, and the group-limited choice is built over "
+                    "the routed experts only")
+        elif self.topk_group != 1:
+            raise ValueError(f"topk_group {self.topk_group} without groups "
+                             "(n_group 1)")
 
     @property
     def n_held(self) -> int:
         return self.held_count or self.n_experts
+
+    @property
+    def grouped(self) -> bool:
+        """Whether the choice is group-limited."""
+        return self.n_group > 1
+
+    @property
+    def group_size(self) -> int:
+        return self.n_experts // self.n_group
+
+    @property
+    def held_groups(self) -> range:
+        """The groups this process's experts lie in."""
+        size = self.group_size
+        return range(self.held_first // size,
+                     (self.held_first + self.n_held - 1) // size + 1)
 
     @property
     def n_router(self) -> int:
@@ -117,11 +174,36 @@ class ExpertsConfig:
         return self.n_experts + self.zero_experts
 
 
-def route(h, lp, ex: ExpertsConfig):
+def n_stats(ex: ExpertsConfig) -> int:
+    """Entries of :func:`moe_ffn`'s ``stats`` for this router."""
+    return N_STATS + ex.grouped
+
+
+def keep_groups(biased, ex: ExpertsConfig):
+    """``biased`` [N, n_router] (scores plus the correction bias) -> (the
+    same with every expert outside the token's ``topk_group`` best groups
+    at ``-inf``, the kept groups [N, n_group] bool).  A group scores the
+    sum of its two largest ``biased``; of equal scores the lower id wins,
+    as in ``top_k``."""
+    import jax
+    import jax.numpy as jnp
+
+    N = biased.shape[0]
+    by_group = biased.reshape(N, ex.n_group, ex.group_size)
+    score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(score, ex.topk_group)
+    kept = jnp.zeros((N, ex.n_group), bool).at[
+        jnp.arange(N)[:, None], best].set(True)
+    masked = jnp.where(kept[:, :, None], by_group, -jnp.inf)
+    return masked.reshape(N, ex.n_experts), kept
+
+
+def route(h, lp, ex: ExpertsConfig, kept=None):
     """The router over ALL its outputs: ``h`` [N, D] -> (``idx`` [N, k]
     the chosen experts, ids from ``n_experts`` on being identity experts,
     ``w`` [N, k] float32 their weights, normalised (where the model
-    does) over all k chosen wherever they live)."""
+    does) over all k chosen wherever they live).  ``kept``, a list,
+    receives a group-limited router's kept groups [N, n_group]."""
     import jax
     import jax.numpy as jnp
 
@@ -131,8 +213,13 @@ def route(h, lp, ex: ExpertsConfig):
                          precision=jax.lax.Precision.HIGHEST)
         s = jax.nn.sigmoid(logits) if ex.scoring == "sigmoid" \
             else jax.nn.softmax(logits, axis=-1)
-        _, idx = jax.lax.top_k(s + lp["router_bias"].astype(jnp.float32),
-                               ex.top_k)
+        biased = s + lp["router_bias"].astype(jnp.float32)
+        if ex.grouped:
+            with jax.named_scope("moe.router.groups"):
+                biased, groups = keep_groups(biased, ex)
+            if kept is not None:
+                kept.append(groups)
+        _, idx = jax.lax.top_k(biased, ex.top_k)
         w = jnp.take_along_axis(s, idx, axis=-1)
         if ex.norm_topk:
             total = jnp.sum(w, axis=-1, keepdims=True)
@@ -142,14 +229,17 @@ def route(h, lp, ex: ExpertsConfig):
 
 def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
     """``h`` [B, T, D] (already normed) -> (this rank's partial FFN
-    output [B, T, D], ``stats``).  ``stats`` is int32 [5] — routed pairs
-    computed by held experts, held experts hit, most pairs on one expert,
-    identity pairs, all four over the rows ``live`` [B] marks (all when
-    None), and the weight passes the grouped kernel made: how many times
-    an expert's three matrices were streamed, over ALL rows (a parked
-    row's pairs are computed like any other), 0 where ``ragged_dot`` ran;
-    passes / experts hit = 1 says every expert hit was streamed once.
-    It is what the serve loop's ``serve.decode`` span reports."""
+    output [B, T, D], ``stats``).  ``stats`` is int32 [:func:`n_stats`]
+    — routed pairs computed by held experts, held experts hit, most pairs
+    on one expert, identity pairs, all four over the rows ``live`` [B]
+    marks (all when None), and the weight passes the grouped kernel
+    made: how many times an expert's three matrices were streamed, over
+    ALL rows (a parked row's pairs are computed like any other), 0 where
+    ``ragged_dot`` ran;
+    passes / experts hit = 1 says every expert hit was streamed once;
+    where the choice is group-limited, the live rows whose kept groups
+    include one of this rank's (module docstring).  It is what the serve
+    loop's ``serve.decode`` span reports."""
     import jax
     import jax.nn as jnn
     import jax.numpy as jnp
@@ -159,7 +249,8 @@ def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
     B, T, D = h.shape
     N, k, E = B * T, ex.top_k, ex.n_held
     x = h.reshape(N, D)
-    idx, w = route(x, lp, ex)
+    kept = []
+    idx, w = route(x, lp, ex, kept)
 
     with jax.named_scope("moe.experts"):
         # pairs sorted by local expert; an expert held elsewhere sorts
@@ -215,17 +306,25 @@ def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
         lcounts = jnp.zeros((E + 1,), jnp.int32).at[
             jnp.where(lheld, idx - ex.held_first, E).reshape(N * k)
         ].add(1)[:E]
-    stats = jnp.stack([lcounts.sum(), (lcounts > 0).sum(), lcounts.max(),
-                       zero.sum(), passes]).astype(jnp.int32)
+    counts = [lcounts.sum(), (lcounts > 0).sum(), lcounts.max(), zero.sum(),
+              passes]
+    if kept:
+        g = ex.held_groups
+        hits = jnp.any(kept[0][:, g.start:g.stop], axis=-1)
+        if live is not None:
+            hits = hits & jnp.repeat(live, T)
+        counts.append(hits.sum())
+    stats = jnp.stack(counts).astype(jnp.int32)
     return out.astype(dt).reshape(B, T, D), stats
 
 
 def merge_stats(a: Optional[object], b):
-    """Sums pairs, experts hit, identity pairs and weight passes, keeps
-    the largest per-expert load."""
+    """Sums pairs, experts hit, identity pairs, weight passes and group
+    hits, keeps the largest per-expert load."""
     import jax.numpy as jnp
 
     if a is None:
         return b
     return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2]),
-                      a[3] + b[3], a[4] + b[4]])
+                      a[3] + b[3], a[4] + b[4]]
+                     + [a[i] + b[i] for i in range(N_STATS, b.shape[0])])

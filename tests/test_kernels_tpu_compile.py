@@ -1046,3 +1046,123 @@ def test_ssm_serve_programs_compile_at_the_cells_shapes(
     for name in ("argument_size_in_bytes", "temp_size_in_bytes",
                  "alias_size_in_bytes"):
         print(program, name, getattr(mem, name))
+
+
+# -- latent attention under YaRN before grouped-routing experts ------------
+
+#: the A.X-K1 share: 64 slots, 64 heads on ONE cache row of 512 + 64
+#: values, 8 latent layers, 4,224 blocks of 16, tables of 4096 / 16
+AXK_SLOTS, AXK_BLOCKS = 64, 4224
+
+
+def _axk1_cfg():
+    """The benchmark's A.X-K1 share at published widths: layer 0 dense
+    (18,432), then 7 expert layers of 12 held experts of 2,048 out of 192
+    in 8 groups of 24 (4 kept, 8 a token) beside a shared expert; YaRN
+    factor 32 over an original 4,096."""
+    from nnstreamer_tpu.models.moe import ExpertsConfig
+
+    return llama.LlamaConfig(
+        vocab=20480, dim=7168, n_layers=8, n_heads=64, n_kv_heads=1,
+        ffn_hidden=18432, max_seq=4096, norm_eps=1e-6,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
+        v_head_dim=128,
+        rope_scaling=llama.RopeScaling(32.0, 4096, 32.0, 1.0, 1.0, 1.0),
+        pattern=tuple(llama.LayerKind(latent=True,
+                                      ffn="dense" if l == 0 else "experts")
+                      for l in range(8)),
+        experts=ExpertsConfig(n_experts=192, top_k=8, hidden=2048, shared=1,
+                              scale=2.5, norm_eps=1e-20, n_group=8,
+                              topk_group=4, held_first=0, held_count=12))
+
+
+def _weights_moved(text, params, leaves):
+    """Operations of the compiled ``text`` whose result is a whole stack
+    of one of ``leaves`` or one layer's slice of it: a copy or transpose,
+    a fusion, or a slice that is a fusion's root (a slice that is not a
+    root fuses into its product)."""
+    import re
+
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if str(getattr(path[-1], "key", "")) in leaves and leaf.ndim > 2:
+            shapes |= {",".join(map(str, leaf.shape)),
+                       ",".join(map(str, (1,) + leaf.shape[1:]))}
+    assert shapes, "none of the leaves in the parameters"
+    inst = re.compile(r"^(ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(")
+    return [line.strip()[:160] for line in text.splitlines()
+            for m in [inst.match(line.strip())] if m
+            and m.group(2) in shapes
+            and (m.group(3) in ("copy", "copy-start", "transpose", "fusion")
+                 or m.group(1) and "slice" in m.group(3))]
+
+
+#: argument and temporary bytes of the two programs' chipless v5e compile
+#: (the decode program's temporaries are nearly all the latent
+#: projections' transposed copies, below)
+AXK_BYTES = dict(decode_chunk=(11_744_482_304, 555_612_160),
+                 prefill_step=(11_744_419_840, 1_844_736))
+#: what the shared latent path moves in LongCat's programs too, whose
+#: pins hold them: the absorbed form's products read ``wq_b``, ``wkv_a``
+#: and ``wkv_b`` head-major, and XLA copies those stacks transposed once
+#: a chunk and slices a layer out of the copy in every layer of every
+#: step (PERF.md section 7)
+LATENT_MOVED = ("wq_b", "wkv_a", "wkv_b")
+
+
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_step"])
+def test_grouped_latent_serve_programs_compile_at_the_cells_shapes(
+        v5e, monkeypatch, program):
+    """Both programs of the A.X-K1 cell at its own sizes — published
+    widths, layer 0 then the 7 expert layers as a scan, 64 slots, the
+    latent pool of 4,224 blocks carried and updated in place: they fit
+    the chip, the decode step holds the latent kernel and both hold the
+    grouped expert product over ``[512, 7168]`` / ``[256, 7168]`` rows
+    (the rule: 2.7 and 1.3 rows a router output), the routing counts ride
+    home in six rows, and no weight of the dense layer, the router, the
+    experts or the shared expert, nor ``wq_a`` or ``wo``, is copied or
+    sliced out of its stack; the three latent projections the shared
+    latent path moves (``LATENT_MOVED``) are the only ones moved.  The
+    argument and temporary bytes are pinned (PERF.md section 4; ``-rP``
+    prints them)."""
+    import math
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _axk1_cfg()
+    assert llama.walk_plan(cfg.kinds) == llama.WalkPlan(1, 1, 7)
+    params = _abstract(v5e, lambda: llama.init_params(cfg, 0, "bfloat16"))
+    pool = _abstract(v5e, lambda: llama.init_paged_cache(cfg, AXK_BLOCKS, 16))
+    assert {k: v.shape for k, v in pool.items()} == {
+        "c": (8, AXK_BLOCKS, 16, 640)}
+    assert math.prod(pool["c"].shape) * 2 == 692_060_160
+
+    table = v5e((AXK_SLOTS, 256), jnp.int32)
+    if program == "decode_chunk":
+        fn, donated, args = _sparse_decode_chunk(cfg), (1,), (
+            v5e((AXK_SLOTS,), jnp.int32), table, v5e((AXK_SLOTS,), jnp.int32))
+        out = jax.eval_shape(fn, params, pool, *args)
+        assert out[0].shape == (AXK_SLOTS + 6, 8)   # tokens, then 6 counts
+    else:
+        fn, donated, args = _prefill_step(cfg), PREFILL_DONATED, \
+            _prefill_args(v5e, AXK_SLOTS, v5e((1, 256), jnp.int32))
+    compiled = jax.jit(fn, donate_argnums=donated).lower(
+        params, pool, *args).compile()
+    text = compiled.as_text()
+    decode = program == "decode_chunk"
+    assert ("%paged_latent_attention" in text) == decode
+    _the_grouped_kernel_is_the_expert_product(
+        text, 512 if decode else 256, width=7168)
+    moved = _weights_moved(text, params, (
+        "wq_a", "wo", "w_gate", "w_up", "w_down", "we_gate", "we_up",
+        "we_down", "ws_gate", "ws_up", "ws_down"))
+    assert not moved, "a weight is moved:\n" + "\n".join(moved)
+    assert _weights_moved(text, params, LATENT_MOVED)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(pool["c"].shape) * 2
+    bytes_limit = 16_909_336_064
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < bytes_limit
+    for name in ("argument_size_in_bytes", "temp_size_in_bytes",
+                 "alias_size_in_bytes"):
+        print(program, name, getattr(mem, name))
+    assert (mem.argument_size_in_bytes, mem.temp_size_in_bytes) \
+        == AXK_BYTES[program]
