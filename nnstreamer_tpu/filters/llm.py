@@ -1334,6 +1334,10 @@ class _ContinuousLoop:
         #: the decode step's state-space layers whose update took the
         #: kernel (ops/ssm.py), counted on its trace at the warm-up
         self.ssm_kernel_layers = 0
+        #: the decode step's expert layers, whose combine reads each
+        #: token's rows where they lie (models/moe.py), counted on its
+        #: trace at the warm-up
+        self.moe_combine_dense_layers = 0
         # slot-vector updates by value where no program commits them: an
         # adopted stream's token, the speculative loop's tok_prev and
         # position twins (slot index and value traced: ONE program)
@@ -1837,6 +1841,7 @@ class _ContinuousLoop:
         import jax
         import jax.numpy as jnp
 
+        from ..models import moe as _moe
         from ..ops import attention as _attn
         from ..ops import ssm as _ssm
 
@@ -2151,18 +2156,25 @@ class _ContinuousLoop:
             np.asarray(em_w)
         else:
             warm = (params, tok, pool, kv.tabs(), pos.copy(), keys_dev)
-            if self._ssm:
+            if self._ssm or self._moe:
                 # traced once, as the prefill above: a turn of the chunk's
                 # step scan is one decode step
+                jaxpr = self._decode.trace(*warm, length=fw.chunk).jaxpr
                 self.ssm_kernel_layers = _attn.kernel_calls(
-                    self._decode.trace(*warm, length=fw.chunk).jaxpr,
-                    _ssm.KERNEL) // fw.chunk
+                    jaxpr, _ssm.KERNEL) // fw.chunk
+                self.moe_combine_dense_layers = _moe.combine_calls(
+                    jaxpr) // fw.chunk
             toks_w, tok, pool = self._decode(*warm, length=fw.chunk)
             np.asarray(toks_w)
         kv.release(0)
-        # the decode span's state-space arg: a constant of the program
-        ssm_args = ({"ssm_kernel_layers": self.ssm_kernel_layers}
-                    if self._ssm else {})
+        # the decode span's state-space and expert-combine args: constants
+        # of the program
+        program_args = {}
+        if self._ssm:
+            program_args["ssm_kernel_layers"] = self.ssm_kernel_layers
+        if self._moe:
+            program_args["moe_combine_dense_layers"] = \
+                self.moe_combine_dense_layers
 
         # Everything alive now lives as long as the loop does: modules,
         # jax's internals, the compiled programs.  A full collection
@@ -2891,7 +2903,7 @@ class _ContinuousLoop:
                     # dispatch (~us) and hide the actual device time —
                     # the number the trace exists to attribute
                     decode_closed("serve.decode", sp, chunk=fw.chunk,
-                                  **moe_args, **ssm_args)
+                                  **moe_args, **program_args)
                 self._undelivered = settled = settle(host)
                 # Delivery is Python per token (33 ms for 255 tokens on
                 # the 7B cell, my chip runs, PR 30) with nothing queued on

@@ -63,6 +63,19 @@ hands the WHOLE stack over (``STACKED_LEAVES``) with the layer's index
 of the leading dims, no data moves), and the layer's group sizes are
 written at ``[l * held, (l + 1) * held)`` of a vector that is zero
 elsewhere: groups of size zero own no row and are not read.
+
+**The combine reads each token's rows where they lie.**  Each token's
+routed outputs are weighted and summed into its row (the named scope
+``moe.combine``): the row of pair ``j`` of token ``n`` lies where the sort
+put it (the inverse of the sort's permutation), and the sum is ``top_k``
+row gathers of ``[N, D]``, each weighted where its pair is held and zero
+elsewhere, added in the order of the token's choices — float32 products
+and sums, the arithmetic of the scatter-add it replaced in another order.
+A token reads its own pairs' rows only, so nothing one row holds (a NaN
+left past the groups, a parked slot's garbage) reaches another.  On a v5e
+the scatter-add into ``[N, D]`` ran at ≈ 15 % of its memory floor (131
+µs a layer at ``[512, 7168]`` -> 64 rows, PERF.md section 5); the
+serve loop counts the decode step's combines (:func:`combine_calls`).
 """
 
 from __future__ import annotations
@@ -76,6 +89,8 @@ STACKED_LEAVES = ("we_gate", "we_up", "we_down")
 #: entries of :func:`moe_ffn`'s ``stats`` (one more where the router's
 #: choice is group-limited: :func:`n_stats`)
 N_STATS = 5
+#: the named scope of the combine (module docstring)
+COMBINE_SCOPE = "moe.combine"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,6 +242,47 @@ def route(h, lp, ex: ExpertsConfig, kept=None):
         return idx, w * ex.scale
 
 
+def combine(y, order, held, w):
+    """Each token's routed outputs, weighted and summed into its row:
+    ``y`` [N * k, D] float32 the grouped product's rows in the order
+    ``order`` [N * k] sorted the pairs, ``held`` [N, k] whether a held
+    expert computed a token's pair, ``w`` [N, k] its weight -> [N, D]
+    float32.  A pair that is not held reads as zero, whatever its row
+    holds (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope(COMBINE_SCOPE):
+        # the row the sort put each (token, choice) pair in
+        P = order.shape[0]
+        at = jnp.zeros((P,), jnp.int32).at[order].set(
+            jnp.arange(P, dtype=jnp.int32), unique_indices=True)
+        at = at.reshape(w.shape)
+        terms = [jnp.where(held[:, i, None], y[at[:, i]] * w[:, i, None], 0.0)
+                 for i in range(w.shape[1])]
+        return sum(terms[1:], terms[0])
+
+
+def combine_calls(jaxpr) -> int:
+    """How many combines a program runs: the runs of consecutive equations
+    traced under :data:`COMBINE_SCOPE` in ``jaxpr`` (open or closed), a
+    run inside a scan once a turn.  Every branch of a ``cond`` is
+    counted; a ``while`` body once."""
+    import jax
+
+    n, inside = 0, False
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        here = COMBINE_SCOPE in str(eqn.source_info.name_stack).split("/")
+        n += here and not inside
+        inside = here
+        if not here:
+            inner = sum(combine_calls(j)
+                        for j in jax.core.jaxprs_in_params(eqn.params))
+            n += inner * (eqn.params["length"]
+                          if eqn.primitive.name == "scan" else 1)
+    return n
+
+
 def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
     """``h`` [B, T, D] (already normed) -> (this rank's partial FFN
     output [B, T, D], ``stats``).  ``stats`` is int32 [:func:`n_stats`]
@@ -276,14 +332,8 @@ def moe_ffn(h, lp, ex: ExpertsConfig, dt, live=None):
             x[tok].astype(dt), *(we[leaf].astype(dt)
                                  for leaf in STACKED_LEAVES), groups,
             live=E, expect=N * k / ex.n_router)
-        # rows past the groups belong to no expert: whatever the grouped
-        # product left there is not read
-        hs = held.reshape(N * k)[order]
-        wp = w.reshape(N * k)[order]
-        routed = jnp.zeros((N, D), jnp.float32).at[tok].add(
-            jnp.where(hs[:, None], y * wp[:, None], 0.0))
+        out = combine(y, order, held, w)
 
-    out = routed
     if ex.shared:
         with jax.named_scope("moe.shared"):
             g = jnn.silu(x.astype(dt) @ lp["ws_gate"].astype(dt))

@@ -495,6 +495,19 @@ def _the_grouped_kernel_is_the_expert_product(text, rows, width=6144):
     assert "ragged_dot_tiling" not in text
 
 
+def _the_combine_is_no_scatter(text, width):
+    """No scatter into ``f32[rows, width]`` under ``moe.experts`` is left
+    in the compiled text: each token's routed outputs are gathered where
+    the sort put them and summed into its row (``models/moe.py combine``),
+    where a scatter-add ran at ≈ 15 % of its memory floor on a v5e."""
+    import re
+
+    scatters = [line.strip()[:200] for line in text.splitlines()
+                if re.search(rf"= f32\[\d+,{width}\]\S* scatter\(", line)
+                and "moe.experts" in line]
+    assert not scatters, "the combine is a scatter:\n" + "\n".join(scatters)
+
+
 def test_paged_latent_attention_compiles(v5e):
     """The latent decode kernel alone at the cell's shapes: 64 query heads
     of 576 on one pool row padded to 640, the value its first 512 columns."""
@@ -554,6 +567,7 @@ def test_latent_serve_programs_compile_at_the_cells_shapes(
     # section 4's temporaries (XLA's grouped product, PR 33) do not grow
     _the_grouped_kernel_is_the_expert_product(
         text, 768 if program == "decode_chunk" else 384)
+    _the_combine_is_no_scatter(text, 6144)
     assert mem.temp_size_in_bytes <= (
         511_662_592 if program == "decode_chunk" else 6_582_784)
     bytes_limit = 16_909_336_064
@@ -622,6 +636,7 @@ def test_hybrid_serve_programs_compile_at_the_cells_shapes(
     text = compiled.as_text()
     _the_grouped_kernel_is_the_expert_product(
         text, 512 if program == "decode_chunk" else 256)
+    _the_combine_is_no_scatter(text, 6144)
     assert ("%paged_attention" in text) == (program == "decode_chunk")
     # a prefill chunk's two full layers take the chunk kernel; its six
     # window layers keep the gather reference over their ring
@@ -756,6 +771,7 @@ def test_conv_serve_programs_compile_at_the_cells_shapes(
         assert _chunk_kernel_layers(fn, donated, params, pool, *args) == 2
     _the_grouped_kernel_is_the_expert_product(
         text, 256 if program == "decode_chunk" else 128, 2048)
+    _the_combine_is_no_scatter(text, 2048)
     mem = compiled.memory_analysis()
     pool_bytes = sum(math.prod(x.shape) * 2 for x in pool.values())
     assert mem.alias_size_in_bytes >= pool_bytes     # all leaves in place
@@ -791,6 +807,7 @@ def _decode_case(v5e, cell):
         "hybrid": (_hybrid_cfg(8), HYB_SLOTS, 3200,
                    dict(win_blocks=HYB_SLOTS * HYB_RING)),
         "latent": (_latent_cfg(), LAT_SLOTS, LAT_BLOCKS, {}),
+        "axk": (_axk1_cfg(), AXK_SLOTS, AXK_BLOCKS, {}),
         "conv": (_conv_cfg(), CONV_SLOTS, CONV_BLOCKS,
                  dict(slots=CONV_SLOTS)),
         "ssm": (_ssm_cfg(), SSM_SLOTS, SSM_BLOCKS,
@@ -799,7 +816,7 @@ def _decode_case(v5e, cell):
     by_slot = {"full": table, "slot": v5e((slots,), jnp.int32)}
     tables = {"hybrid": {"full": table,
                          "win": v5e((slots, HYB_RING), jnp.int32)},
-              "latent": table, "conv": by_slot, "ssm": by_slot}
+              "latent": table, "axk": table, "conv": by_slot, "ssm": by_slot}
     chunk = _dense_decode_chunk if cell == "ssm" else _sparse_decode_chunk
     return (chunk(cfg),
             _abstract(v5e, lambda: llama.init_params(cfg, 0, "bfloat16")),
@@ -825,15 +842,14 @@ def _kernel_bodies(jaxpr):
 
 #: sha256 of each serving geometry's decode program: its text as lowered
 #: for v5e with the kernels' serialized bodies blanked (they carry source
-#: locations), then the traced bodies of its Pallas kernels.  The q/k/v
-#: products' barrier on every paged call moved the three whose attention
-#: layers are paged (dense, hybrid, conv); the latent program never
-#: reaches that branch and kept its pin.
+#: locations), then the traced bodies of its Pallas kernels.  The expert
+#: layers' combine, row gathers in place of a scatter-add, moved the three
+#: with experts (hybrid, latent, conv); dense kept its pin.
 DECODE_PINS = dict(
     dense="432702e967facb32ae0bacd72e4ebc6d765147cb0c5b3bcdcb435d5a7c62ed34",
-    hybrid="2968a2cf7372fc9dd5407ec8209bde97bd5d96ff4b69a66a687334269f5f936c",
-    latent="5579861e54db05a2d86df8c34faafaacf79eeb81476b15f48512267490af0dd0",
-    conv="51ff110734b75652047592b747f517f1a4882e1f4d5f7ff27aa6c6be3b665d51")
+    hybrid="cbae257752cbd793de0cd8a70daa5cad7e168d88acf085a40c7c667493e88152",
+    latent="c0c84f65c69307008b455e19652fe0180b31c0663a35d34ed429d97545017e47",
+    conv="26b4434c5948d8fded0149af761932614fadd3a674f57d3a84ec606c722e4b0f")
 
 
 @pytest.mark.parametrize("cell", sorted(DECODE_PINS))
@@ -854,6 +870,23 @@ def test_decode_programs_are_pinned(v5e, monkeypatch, cell):
     assert bodies, "the decode program calls no Pallas kernel"
     digest = hashlib.sha256("\n".join([text] + bodies).encode()).hexdigest()
     assert digest == DECODE_PINS[cell], digest
+
+
+@pytest.mark.parametrize("cell,combines", [
+    ("hybrid", 7), ("latent", 4), ("conv", 8), ("axk", 7), ("dense", 0),
+    ("ssm", 0)])
+def test_decode_programs_count_their_combines(v5e, monkeypatch, cell,
+                                              combines):
+    """What the serve loop reports as ``moe_combine_dense_layers``: the
+    expert combines of a decode step, counted on the program's trace (8
+    steps a chunk) — every expert layer of the four sparse geometries,
+    none where there are no experts."""
+    from nnstreamer_tpu.models.moe import combine_calls
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, params, pool, args = _decode_case(v5e, cell)
+    jaxpr = jax.jit(fn, donate_argnums=(1,)).trace(params, pool, *args).jaxpr
+    assert combine_calls(jaxpr) == 8 * combines
 
 
 #: temporaries of each decode program's chipless v5e compile, in bytes,
@@ -1100,8 +1133,8 @@ def _weights_moved(text, params, leaves):
 #: argument and temporary bytes of the two programs' chipless v5e compile
 #: (the decode program's temporaries are nearly all the latent
 #: projections' transposed copies, below)
-AXK_BYTES = dict(decode_chunk=(11_744_482_304, 555_612_160),
-                 prefill_step=(11_744_419_840, 1_844_736))
+AXK_BYTES = dict(decode_chunk=(11_744_482_304, 555_579_904),
+                 prefill_step=(11_744_419_840, 1_973_760))
 #: what the shared latent path moves in LongCat's programs too, whose
 #: pins hold them: the absorbed form's products read ``wq_b``, ``wkv_a``
 #: and ``wkv_b`` head-major, and XLA copies those stacks transposed once
@@ -1152,6 +1185,7 @@ def test_grouped_latent_serve_programs_compile_at_the_cells_shapes(
     assert ("%paged_latent_attention" in text) == decode
     _the_grouped_kernel_is_the_expert_product(
         text, 512 if decode else 256, width=7168)
+    _the_combine_is_no_scatter(text, 7168)
     moved = _weights_moved(text, params, (
         "wq_a", "wo", "w_gate", "w_up", "w_down", "we_gate", "we_up",
         "we_down", "ws_gate", "ws_up", "ws_down"))

@@ -284,7 +284,9 @@ def test_a_sparse_models_decode_span_carries_the_five_expert_counts():
     and ``moe_weight_passes`` — the grouped kernel's passes over an
     expert's matrices, 0 here, where ``ragged_dot`` computes the experts
     (the kernel is the TPU's path) — from the fifth extra row of the
-    chunk's token matrix."""
+    chunk's token matrix; beside them ``moe_combine_dense_layers``, the
+    decode step's combines counted on its trace: one in each of
+    ``hybrid_moe_tiny``'s 7 expert layers."""
     recorder.configure("off")
     recorder.clear()
     p = nt.Pipeline(
@@ -307,8 +309,9 @@ def test_a_sparse_models_decode_span_carries_the_five_expert_counts():
     for a in dec:
         assert {k for k in a if k.startswith("moe_")} == {
             "moe_pairs", "moe_experts_hit", "moe_max_per_expert",
-            "moe_zero_pairs", "moe_weight_passes"}
+            "moe_zero_pairs", "moe_weight_passes", "moe_combine_dense_layers"}
         assert a["moe_weight_passes"] == 0 < a["moe_experts_hit"]
+        assert a["moe_combine_dense_layers"] == 7
 
 
 def test_deliveries_under_the_next_chunk_are_marked_and_counted():
